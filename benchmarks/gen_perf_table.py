@@ -10,14 +10,15 @@ driver-captured JSON: the table lives between markers
     ...generated...
     <!-- bench-table:end -->
 
-and ``tests/test_perf_doc.py`` asserts the committed doc byte-matches
-regeneration from its declared source, so a hand-edit or a stale number
-fails CI.
+and the check mode asserts the document byte-matches regeneration from
+its declared source, so a hand-edit or a stale number fails
+(``tests/test_perf_doc.py`` pins that on a synthetic document).
 
 Usage:
     python benchmarks/gen_perf_table.py            # check (exit 1 on drift)
     python benchmarks/gen_perf_table.py --write    # rewrite the block
     python benchmarks/gen_perf_table.py --source BENCH_r03.json --write
+    python benchmarks/gen_perf_table.py --doc ROOT/docs/other.md
 """
 
 import argparse
@@ -128,29 +129,36 @@ def main():
     ap.add_argument("--write", action="store_true")
     ap.add_argument("--source", default=None,
                     help="override the source= file named in the doc")
+    ap.add_argument("--doc", default=DOC,
+                    help="the marked document (<root>/docs/NAME.md; its "
+                         "source file is looked up under <root>)")
     args = ap.parse_args()
 
-    doc = open(DOC).read()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(args.doc)))
+    name = os.path.relpath(args.doc, root)
+    with open(args.doc) as f:
+        doc = f.read()
     m = BEGIN_RE.search(doc)
     if not m or END not in doc:
-        sys.exit("docs/performance.md is missing the bench-table markers")
+        sys.exit(f"{name} is missing the bench-table markers")
     src = args.source or m.group("src")
     begin_line = f"<!-- bench-table:begin source={src} -->"
-    table = generate(os.path.join(REPO, src))
+    table = generate(os.path.join(root, src))
     block = f"{begin_line}\n{table}\n{END}"
 
     start, stop = m.start(), doc.index(END) + len(END)
     new_doc = doc[:start] + block + doc[stop:]
     if args.write:
-        open(DOC, "w").write(new_doc)
+        with open(args.doc, "w") as f:
+            f.write(new_doc)
         print(f"wrote table from {src}")
         return
     if new_doc != doc:
         sys.exit(
-            f"docs/performance.md measured table drifted from {src}; "
+            f"{name} measured table drifted from {src}; "
             "run: python benchmarks/gen_perf_table.py --write"
         )
-    print(f"docs/performance.md matches {src}")
+    print(f"{name} matches {src}")
 
 
 if __name__ == "__main__":

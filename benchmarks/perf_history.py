@@ -83,10 +83,10 @@ def repo_root() -> str:
 
 
 def bench_files(root: Optional[str] = None) -> List[str]:
-    """Committed captures, oldest first.  Primary (remote) captures
-    order before ``_local`` fallbacks of the same revision; both are
-    returned so the differ can fall back when a remote capture failed
-    (r04's relay outage committed a null row)."""
+    """Committed captures, oldest first.  Primary captures order
+    before ``_local`` fallbacks of the same revision; both are returned
+    so the differ can fall back when a primary capture holds a null
+    row."""
     root = root or repo_root()
     found: List[Tuple[int, int, str]] = []
     for name in os.listdir(root):

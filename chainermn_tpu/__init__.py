@@ -13,7 +13,6 @@ Facade parity: ``chainermn/__init__.py`` re-exports (component #1 in
 SURVEY.md section 2).
 """
 
-from chainermn_tpu import _compat  # noqa: F401  (jax API shims; must be first)
 from chainermn_tpu.communicators import (  # noqa: F401
     CommunicatorBase,
     create_communicator,

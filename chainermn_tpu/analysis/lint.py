@@ -24,7 +24,7 @@ Rules
     what ships on the wire.  Sanctioned: ``comm_wire/`` (wire codecs),
     ``functions/`` (the audited wrappers themselves), ``parallel/``
     (SP/TP/EP/pipeline layers), ``communicators/`` (the eager tier),
-    ``optimizers.py`` (the compiled-tier sync), ``_compat.py`` (shims),
+    ``optimizers.py`` (the compiled-tier sync),
     and ``analysis/`` (this package names primitives to find them).
 
 ``untimed-row``
@@ -117,7 +117,6 @@ SANCTIONED = (
     "chainermn_tpu/communicators/",
     "chainermn_tpu/analysis/",
     "chainermn_tpu/optimizers.py",
-    "chainermn_tpu/_compat.py",
 )
 
 SKIP_DIRS = {"__pycache__", ".git", "csrc", "_build", ".claude"}

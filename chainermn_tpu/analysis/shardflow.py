@@ -186,7 +186,7 @@ class _Flow:
         name = eqn.primitive.name
         in_specs = [self._get(env, v) for v in eqn.invars]
 
-        if name in ("pjit", "xla_call", "remat", "remat2", "checkpoint",
+        if name in ("jit", "remat", "remat2", "checkpoint",
                     "custom_jvp_call", "custom_vjp_call",
                     "custom_vjp_call_jaxpr", "closed_call", "core_call"):
             self._descend(eqn, env, in_specs)

@@ -9,8 +9,7 @@ a bare shard_map body) to a :class:`CollectiveTrace` — the ordered list
 of collective primitives with axis names, dtypes, shapes, and the
 enclosing control-flow context — by walking the closed jaxpr recursively
 through ``pjit`` / ``scan`` / ``cond`` / ``while`` / ``shard_map``
-sub-jaxprs (including the ``_compat`` shard_map shim on old jax, which
-binds the same primitive).
+sub-jaxprs.
 
 The walk is static: nothing is compiled or executed, so tracing even a
 ResNet-50 train step costs milliseconds.  Counting is per jaxpr
@@ -395,8 +394,7 @@ def _detail_of(params) -> str:
 
 
 _CTX_LABELS = {
-    "pjit": "pjit",
-    "xla_call": "pjit",
+    "jit": "pjit",
     "scan": "scan",
     "shard_map": "shard_map",
     "remat": "remat",

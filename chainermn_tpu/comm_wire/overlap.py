@@ -72,7 +72,7 @@ from dataclasses import dataclass
 from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
-from jax import core
+from jax.extend import core
 
 OVERLAP_MODES = ("none", "bucket")
 
@@ -92,7 +92,7 @@ _COLLECTIVE_PRIMS = frozenset((
 # while are intentionally absent: reordering inside a loop body changes
 # per-iteration issue order, which is never the wire's program shape
 # (grad-wire collectives live inline in the shard_map body).
-_DESCEND_PRIMS = ("pjit", "shard_map", "xla_call")
+_DESCEND_PRIMS = ("jit", "shard_map")
 
 
 def resolve_overlap(overlap) -> str:
@@ -561,7 +561,7 @@ class OverlappedStep:
         entry = self._entry(args)
         flat = jax.tree_util.tree_leaves(args)
         fn = entry.fn
-        if any(isinstance(l, core.Tracer) for l in flat):
+        if any(isinstance(l, jax.core.Tracer) for l in flat):
             # under an outer trace the flat args own no buffers; the
             # donated variant would only warn "donated buffers not
             # usable" on every trace_collectives walk

@@ -323,10 +323,7 @@ class MultiprocessObjStore:
             # layer would convert a recoverable transient failure into a
             # hard crash.  The payload for a given (key, seq) is
             # deterministic, so overwriting is value-identical.
-            try:
-                client.key_value_set_bytes(k, v, allow_overwrite=True)
-            except TypeError:  # jaxlib without the kwarg
-                client.key_value_set_bytes(k, v)
+            client.key_value_set_bytes(k, v, allow_overwrite=True)
 
         def publish():
             for i in range(0, max(len(payload), 1), MAX_OBJ_CHUNK_BYTES):

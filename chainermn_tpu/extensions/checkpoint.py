@@ -419,25 +419,22 @@ class _MultiNodeCheckpointer:
 
         path = os.path.abspath(target)
         ckptr = self._orbax()
-        try:
-            meta = ckptr.metadata(path)
+        # the per-leaf metadata tree (orbax wraps it in a StepMetadata)
+        meta = ckptr.metadata(path).item_metadata.tree
 
-            def arg_of(m):
-                # true zarr-backed arrays must restore as numpy (the
-                # default would rebuild jax.Arrays and demand the dead
-                # world's shardings); scalar/string leaves live in the
-                # aggregate file and must keep the default restore —
-                # forcing np.ndarray makes orbax look for a zarr entry
-                # that does not exist
-                if type(m).__name__ == "ArrayMetadata":
-                    return ocp.RestoreArgs(restore_type=np.ndarray)
-                return ocp.RestoreArgs()
+        def arg_of(m):
+            # true zarr-backed arrays must restore as numpy (the
+            # default would rebuild jax.Arrays and demand the dead
+            # world's shardings); scalar/string leaves live in the
+            # aggregate file and must keep the default restore —
+            # forcing np.ndarray makes orbax look for a zarr entry
+            # that does not exist
+            if type(m).__name__ == "ArrayMetadata":
+                return ocp.RestoreArgs(restore_type=np.ndarray)
+            return ocp.RestoreArgs()
 
-            restore_args = jax.tree_util.tree_map(arg_of, meta)
-            return ckptr.restore(path, restore_args=restore_args)
-        except Exception:
-            # older orbax without metadata()/RestoreArgs spelling
-            return ckptr.restore(path)
+        restore_args = jax.tree_util.tree_map(arg_of, meta)
+        return ckptr.restore(path, restore_args=restore_args)
 
     def _reshard(self, state, like, old_world, step: int):
         """Route a world-mismatched snapshot through the elastic

@@ -1428,12 +1428,6 @@ def main():
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        # older jax needs gloo selected explicitly for cross-process CPU
-        # collectives; newer releases default to it (or drop the option)
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
     jax.distributed.initialize(
         f"127.0.0.1:{port}", num_processes=nproc, process_id=pid
     )

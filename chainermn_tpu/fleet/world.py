@@ -178,12 +178,13 @@ class FleetWorld:
 
     # -- env wiring -----------------------------------------------------
     def env_for(self, process_index: int) -> Dict[str, str]:
-        """The spawned worker's environment: CPU-mesh substrate (ambient
-        JAX_PLATFORMS popped — the host env may claim a real TPU), the
+        """The spawned worker's environment: CPU-mesh substrate
+        (JAX_PLATFORMS pinned to cpu — a fleet world never takes the
+        chip, which belongs to one process at a time), the
         repo on PYTHONPATH, the fault injector's targeting index, and
         the schedule's rendered specs."""
         env = dict(os.environ)
-        env.pop("JAX_PLATFORMS", None)
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={self.local_devices}"
         )

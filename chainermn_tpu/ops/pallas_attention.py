@@ -37,13 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # pallas is an experimental namespace; degrade gracefully
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    PALLAS_AVAILABLE = True
-except ImportError:  # pragma: no cover
-    PALLAS_AVAILABLE = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -52,6 +47,15 @@ def _should_interpret(interpret: Optional[bool]) -> bool:
     if interpret is not None:
         return interpret
     return jax.default_backend() != "tpu"
+
+
+def _out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """``out_shape`` entry for a ``pallas_call`` on ``operands``: varying
+    over every mesh axis an operand varies over, which ``shard_map``'s
+    vma checking requires a kernel's outputs to declare (outside
+    ``shard_map`` the set is empty)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _round_up(x: int, m: int) -> int:
@@ -503,8 +507,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s_qp, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 8, s_qp), jnp.float32),
+            _out_struct((b * h, s_qp, d), q.dtype, q, k, v),
+            _out_struct((b * h, 8, s_qp), jnp.float32, q, k, v),
         ],
         grid=grid,
         in_specs=[
@@ -877,7 +881,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
 
     dq = pl.pallas_call(
         functools.partial(dq_kernel, **kwargs),
-        out_shape=jax.ShapeDtypeStruct((b * h, s_qp, d), q.dtype),
+        out_shape=_out_struct((b * h, s_qp, d), q.dtype, q, k, v, g),
         grid=(b * h, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),   # q
@@ -895,8 +899,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     dk, dv = pl.pallas_call(
         functools.partial(dkv_kernel, **kwargs),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s_kp, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, s_kp, d), v.dtype),
+            _out_struct((b * h, s_kp, d), k.dtype, q, k, v, g),
+            _out_struct((b * h, s_kp, d), v.dtype, q, k, v, g),
         ],
         grid=(b * h, n_k, n_q),
         in_specs=[
@@ -964,11 +968,6 @@ def flash_attention(q, k, v, causal=False, scale=None,
     for causal/ragged inputs).  Split and legacy are bit-identical
     (``test_split_matches_legacy_exactly``).
     """
-    if not PALLAS_AVAILABLE:
-        raise ImportError(
-            "flash_attention requires jax.experimental.pallas; use "
-            "chainermn_tpu.ops.multi_head_attention on this JAX build"
-        )
     if scale is None:
         scale = q.shape[-1] ** -0.5
     out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
@@ -1201,9 +1200,7 @@ def _flash_with_lse_fwd_rule(q, k, v, causal, scale, block_q, block_k,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     interp = _should_interpret(interpret)
-    if not PALLAS_AVAILABLE or (
-        not interp and (q.shape[1] < 128 or k.shape[1] < 128)
-    ):
+    if not interp and (q.shape[1] < 128 or k.shape[1] < 128):
         # Sub-lane-tile compiled shapes: dense path for value AND grads.
         out, lse = _dense_attention_with_lse(q, k, v, causal, scale)
         return (out, lse), (q, k, v, None, None)
@@ -1375,7 +1372,7 @@ def _flash_decode(q, k_pages, v_pages, block_tables, lengths, scale,
                 pltpu.VMEM((8, 128), jnp.float32),  # running denom
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b * h, 8, d), q.dtype),
+        out_shape=_out_struct((b * h, 8, d), q.dtype, q, k_pages, v_pages),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       q8, kh, vh)
@@ -1409,10 +1406,6 @@ def flash_decode(q, k_pages, v_pages, block_tables, lengths,
     paged attend for its bit-exactness contract; this kernel is the
     TPU fast path (``DecodeEngine(attention_impl="flash")``).
     """
-    if not PALLAS_AVAILABLE:
-        return paged_decode_reference(
-            q, k_pages, v_pages, block_tables, lengths, scale
-        )
     if scale is None:
         scale = q.shape[-1] ** -0.5
     return _flash_decode(q, k_pages, v_pages, block_tables, lengths,
@@ -1472,7 +1465,7 @@ def fused_cast_scale(x: jnp.ndarray, scale: float, dtype,
     launches around its fp16 allreduce (divide-by-size fused with the
     cast-back).  Any shape; internally flattened to lane-aligned tiles.
     """
-    if not PALLAS_AVAILABLE or x.size == 0:
+    if x.size == 0:
         return (x.astype(jnp.float32) * scale).astype(dtype)
     shape = x.shape
     flat = x.reshape(-1)
@@ -1489,7 +1482,7 @@ def fused_cast_scale(x: jnp.ndarray, scale: float, dtype,
         tiled = jnp.pad(tiled, ((0, rows_p - rows), (0, 0)))
     out = pl.pallas_call(
         functools.partial(_cast_scale_kernel, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((rows_p, lane), jnp.dtype(dtype)),
+        out_shape=_out_struct((rows_p, lane), jnp.dtype(dtype), x),
         grid=(rows_p // block_rows,),
         in_specs=[pl.BlockSpec((block_rows, lane), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, lane), lambda i: (i, 0)),

@@ -1354,39 +1354,6 @@ def build_train_step(
             return rep
         return _spec_to_sharding(_state_specs(opt_state, params))
 
-    # Old-shard_map jax tier: autodiff under check_rep=False returns the
-    # UNSUMMED per-shard cotangent for every leaf, so each gradient must
-    # be psummed over exactly the mesh axes its parameter does NOT span
-    # (replicated leaves: all axes; TP-sharded kernels: the data axes).
-    # On current jax the vma machinery inserts these psums itself.
-    from . import _compat as _jax_compat
-
-    def _manual_rep_sum(grads, pspecs):
-        axis_order = tuple(mesh.axis_names)
-
-        def spec_axes(spec):
-            out = set()
-            for part in tuple(spec):
-                if part is None:
-                    continue
-                for a in (part if isinstance(part, tuple) else (part,)):
-                    out.add(a)
-            return out
-
-        def fix(g, spec):
-            missing = tuple(
-                a for a in axis_order if a not in spec_axes(spec)
-            )
-            return lax.psum(g, missing) if missing else g
-
-        # flatten_up_to: PartitionSpec may itself flatten as a pytree,
-        # so pair specs to gradient LEAVES by the gradients' structure
-        leaves, treedef = jax.tree_util.tree_flatten(grads)
-        specs = treedef.flatten_up_to(pspecs)
-        return treedef.unflatten(
-            [fix(g, s) for g, s in zip(leaves, specs)]
-        )
-
     def _make_do_update(params, opt_state, aux, *, hybrid_sync=False):
         """The update/apply/merge_aux tail shared by all three step
         bodies (one definition so the nonfinite where-select ordering
@@ -1423,8 +1390,6 @@ def build_train_step(
                 return lax.pmean(out, axes)
 
             loss, grads = _value_and_grad(global_loss, params, batch)
-            if _jax_compat.OLD_SHARD_MAP:
-                grads = _manual_rep_sum(grads, _param_spec_tree(params))
             aux = None
             if has_aux:
                 loss, aux = loss

@@ -155,13 +155,8 @@ def ring_attention(
       sequence.
     """
     if use_flash is None:
-        try:
-            from chainermn_tpu.ops.pallas_attention import PALLAS_AVAILABLE
-        except ImportError:  # pragma: no cover
-            PALLAS_AVAILABLE = False
         use_flash = (
-            PALLAS_AVAILABLE
-            and jax.default_backend() == "tpu"
+            jax.default_backend() == "tpu"
             and q.shape[1] >= 128
             and k.shape[1] >= 128
             and (not causal or q.shape[1] == k.shape[1])

@@ -65,6 +65,26 @@ def make_model(arch: str, num_classes: int, train: bool):
     return factory(num_classes=num_classes, train=train)
 
 
+def make_loss_fn(model, prep_x):
+    """``loss_fn(params, (x, y, seeds)) -> (loss, new batch_stats)`` for
+    ``build_train_step(has_aux=True)``."""
+
+    def loss_fn(p, batch):
+        x, y, seeds = batch
+        out, mut = model.apply(
+            {"params": p["params"], "batch_stats": p["batch_stats"]},
+            prep_x(x),
+            mutable=["batch_stats"],
+            rngs={"dropout": jax.random.PRNGKey(seeds[0])},
+        )
+        loss = optax.softmax_cross_entropy_with_integer_labels(
+            out, y
+        ).mean()
+        return loss, mut.get("batch_stats", {})
+
+    return loss_fn
+
+
 class _RngBatchIterator:
     """Wraps an iterator, appending per-shard dropout seeds to each batch.
 
@@ -155,6 +175,9 @@ def main(argv=None):
         devices = jax.devices("cpu")
     else:
         devices = jax.devices()
+        from chainermn_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     comm = cmn.create_communicator(args.communicator, devices=devices)
     chief = comm.process_index == 0
     if chief:
@@ -269,21 +292,8 @@ def main(argv=None):
     )
     opt_state = opt.init(params)
 
-    def loss_fn(p, batch):
-        x, y, seeds = batch
-        out, mut = model.apply(
-            {"params": p["params"], "batch_stats": p["batch_stats"]},
-            prep_x(x),
-            mutable=["batch_stats"],
-            rngs={"dropout": jax.random.PRNGKey(seeds[0])},
-        )
-        loss = optax.softmax_cross_entropy_with_integer_labels(
-            out, y
-        ).mean()
-        return loss, mut.get("batch_stats", {})
-
     step = cmn.build_train_step(
-        comm, loss_fn, opt, has_aux=True,
+        comm, make_loss_fn(model, prep_x), opt, has_aux=True,
         merge_aux=lambda p, aux: {**p, "batch_stats": aux},
     )
     params, opt_state = step.place(params, opt_state)
@@ -317,6 +327,12 @@ def main(argv=None):
     )
     trainer.extend(cmn.create_multi_node_evaluator(evaluator, comm))
 
+    # every step's loss, kept as device scalars and read after the run
+    # so that no step waits on the host for it
+    step_losses = []
+    trainer.extend(lambda t: step_losses.append(t.observation["loss"]),
+                   trigger=(1, "iteration"), name="step_losses")
+
     log = T.LogReport(comm=comm)
     trainer.extend(T.Throughput(args.batchsize, comm=comm),
                    trigger=(1, "iteration"))
@@ -342,7 +358,8 @@ def main(argv=None):
     if chief:
         print("final:", {k: round(v, 4) for k, v in final.items()
                          if isinstance(v, float)})
-    return final
+    return {"final": final, "losses": [float(l) for l in step_losses],
+            "comm": comm, "step": step, "trainer": trainer}
 
 
 if __name__ == "__main__":

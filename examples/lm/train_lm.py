@@ -98,6 +98,9 @@ def main(argv=None):
         devices = jax.devices("cpu")
     else:
         devices = jax.devices()
+        from chainermn_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
     import jax.numpy as jnp
     import numpy as np
@@ -206,6 +209,7 @@ def main(argv=None):
 
     rng = np.random.RandomState(1)
     t0, tokens_done, last_loss = time.perf_counter(), 0, float("nan")
+    losses = []  # every reported loss, in step order
     for it in range(1, args.steps + 1):
         rows = rng.randint(0, corpus.shape[0], size=batch)
         toks = step.place_batch(jnp.asarray(corpus[rows]))
@@ -213,6 +217,7 @@ def main(argv=None):
         tokens_done += batch * args.seq_len
         if it % args.report_every == 0 or it == args.steps:
             last_loss = float(metrics["loss"])  # forces completion
+            losses.append(last_loss)
             dt = time.perf_counter() - t0
             if chief:
                 print(f"step {it:5d}  loss {last_loss:.4f}  "
@@ -246,6 +251,7 @@ def main(argv=None):
             print(f"sampled ({tier} KV-cache decode): "
                   f"{out[0].tolist()}")
 
+    served = None
     if args.serve > 0:
         # Serving tier: greedy decode over the trained checkpoint
         # through the continuous-batching engine (paged KV cache,
@@ -293,7 +299,12 @@ def main(argv=None):
                 f"p99 {lat.get('p99_ms', float('nan')):.2f} ms, "
                 f"failed {report['failed']})"
             )
-    return last_loss
+        served = {"model": serve_model, "requests": requests,
+                  "results": results, "report": report}
+    return {"loss": last_loss, "losses": losses, "comm": comm,
+            "model": model, "specs": specs, "step": step,
+            "params": params, "opt_state": opt_state, "batch": toks,
+            "served": served}
 
 
 if __name__ == "__main__":

@@ -68,6 +68,9 @@ def main(argv=None):
             )
     else:
         devices = jax.devices()
+        from chainermn_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     comm = cmn.create_communicator(args.communicator, devices=devices)
     chief = comm.process_index == 0
     if chief:

@@ -83,6 +83,9 @@ def main(argv=None):
         devices = jax.devices("cpu")
     else:
         devices = jax.devices()
+        from chainermn_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     comm = cmn.create_communicator("hybrid", devices=devices,
                                    tp_size=args.tp)
     chief = comm.process_index == 0

@@ -79,6 +79,9 @@ def main(argv=None):
         devices = jax.devices("cpu")
     else:
         devices = jax.devices()
+        from chainermn_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     if len(devices) < 2:
         print("model parallelism needs >= 2 devices; running both stages "
               "on one device", file=sys.stderr)

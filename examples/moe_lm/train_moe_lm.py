@@ -98,6 +98,9 @@ def main(argv=None):
         devices = jax.devices("cpu")
     else:
         devices = jax.devices()
+        from chainermn_tpu.utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
 
     import jax.numpy as jnp
     import numpy as np

@@ -40,16 +40,6 @@ def main():
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        # Older jax (<= 0.4.x) does not enable cross-process CPU
-        # collectives unless the gloo implementation is selected; newer
-        # releases default to it (and may drop the option — hence the
-        # guard).  Without this, every multihost_utils collective dies
-        # with "Multiprocess computations aren't implemented on the CPU
-        # backend".
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
     jax.distributed.initialize(
         f"127.0.0.1:{port}", num_processes=nproc, process_id=pid
     )

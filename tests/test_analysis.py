@@ -5,7 +5,7 @@ Pins, in order of load-bearingness:
 * the jaxpr walker extracts a correct ORDERED CollectiveTrace (axis
   names, dtypes, shapes, control-flow context) through
   ``pjit``/``scan``/``cond``/``while``/``shard_map`` nesting — including
-  the ``_compat`` shard_map shim tier and the eager communicator tier
+  the eager communicator tier
   (``XlaCommunicatorBase.allreduce_grad``'s bucketed path);
 * the walker census AGREES with the HLO-text census on real compiled
   train steps (the transformer step here; ResNet-50 in
@@ -160,13 +160,8 @@ class TestWalker:
         assert len(tr) == 1
         assert tr.records[0].context == ("shard_map", "while/body")
 
-    def test_shard_map_shim_tier(self, mesh8):
-        """``jax.shard_map`` here is the _compat shim on old jax (it
-        forwards to jax.experimental.shard_map) and the native API on
-        current jax — the walker must descend the shard_map eqn either
-        way, and the trace hash must not depend on which tier traced."""
-        from chainermn_tpu import _compat
-
+    def test_bare_shard_map(self, mesh8):
+        """The walker descends a bare ``jax.shard_map`` eqn."""
         sm = jax.shard_map(
             lambda x: lax.pmean(x, "mn"), mesh=mesh8,
             in_specs=(P("mn"),), out_specs=P("mn"), check_vma=False,
@@ -174,7 +169,6 @@ class TestWalker:
         tr = trace_collectives(sm, jnp.zeros((8, 4)))
         assert tr.census() == {"all_reduce": 1}
         assert tr.records[0].context[0] == "shard_map"
-        assert isinstance(_compat.OLD_SHARD_MAP, bool)  # shim resolved
 
     def test_trace_hash_is_value_independent(self, mesh8):
         fn = _smap(lambda x: lax.psum(x, "mn"), mesh8)

@@ -193,7 +193,7 @@ class TestRawCollectiveRule:
         assert "chainermn_tpu/comm_wire/" in SANCTIONED
         assert "chainermn_tpu/functions/" in SANCTIONED
         assert "chainermn_tpu/parallel/" in SANCTIONED
-        assert "chainermn_tpu/_compat.py" in SANCTIONED
+        assert "chainermn_tpu/optimizers.py" in SANCTIONED
         # models/links/extensions are NOT sanctioned — they must route
         # through the wrappers (fixed in this PR)
         assert not any(p.startswith("chainermn_tpu/models") for p in SANCTIONED)

@@ -135,6 +135,18 @@ class TestExampleScripts:
         assert "final:" in out
         assert "sampled (tp-sharded KV-cache decode)" in out
 
+    def test_lm_flash_train(self, tmp_path):
+        """The README's one-chip LM command, ``--flash``: the Pallas
+        kernels (interpreted here) inside ``build_train_step``'s
+        vma-checked shard_map."""
+        out = _run(
+            "lm/train_lm.py", "--cpu-mesh", "--flash", "--steps", "4",
+            "--report-every", "2", "--seq-len", "64", "--d-model", "32",
+            "--n-layers", "2", "--vocab", "64", "--generate", "0",
+            tmp_path=tmp_path,
+        )
+        assert "final:" in out
+
     def test_lm_serve_mode(self, tmp_path):
         """ISSUE 13 satellite: the --serve mode wires the trained
         checkpoint to the continuous-batching engine (greedy decode

@@ -116,7 +116,7 @@ class TestFleetWorldEnvWiring:
         # grouping counts device positions
         assert env[ENV_SLICE] == "4"
         assert "device_count=2" in env["XLA_FLAGS"]
-        assert "JAX_PLATFORMS" not in env
+        assert env["JAX_PLATFORMS"] == "cpu"  # never the chip
         assert json.loads(env[ENV_SPEC]) == sched.specs()
 
     def test_slice_grouping_scales_with_local_devices(self, tmp_path):

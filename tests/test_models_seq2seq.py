@@ -78,20 +78,6 @@ def test_loss_ignores_pad():
     )
 
 
-from chainermn_tpu._compat import OLD_SHARD_MAP
-
-# jax 0.4.x tier (compat shims active): RNG/optimizer numerics differ
-# slightly from the current-jax authoring environment, and these two
-# convergence thresholds sit within that margin (measured: loss 1.390
-# vs the < 1.386 bound; the exact-match assertions in the same tests
-# pass).  Current jax meets the thresholds.
-_old_jax_margin = pytest.mark.xfail(
-    OLD_SHARD_MAP, strict=False,
-    reason="convergence threshold within old-jax numeric margin",
-)
-
-
-@_old_jax_margin
 def test_learns_toy_translation(toy):
     model = Seq2Seq(VOCAB, VOCAB, n_units=64, n_layers=2)
     xs, ys = _batch(toy, range(64))
@@ -154,7 +140,6 @@ def test_encoder_decoder_components(toy):
     assert logits.shape == (3, MAXLEN + 1, VOCAB)
 
 
-@_old_jax_margin
 def test_model_parallel_seq2seq_matches_and_learns(devices8):
     """The MultiNodeChainList split (encoder chip 0, decoder chip 1) must
     train end-to-end; mirrors the reference's seq2seq_mp1 topology."""

@@ -122,21 +122,6 @@ class TestMeshCommunicator:
         assert (comm.dp_size, comm.sp_size, comm.tp_size) == (8, 1, 1)
 
 
-# jax 0.4.x tier: the composed hybrid step's gradient sync relies on
-# current jax's vma machinery; the compat fallback (check_rep=False +
-# a static per-leaf rep-sum, chainermn_tpu/_compat.py) is exact for
-# Megatron-style DP x TP graphs (test_hybrid pins that) but not for the
-# composed MoE/seq-parallel graph, where whether a replicated leaf's
-# cotangent needs a cross-axis psum depends on value-varyingness the
-# static rule cannot see.  Forward numerics still match exactly (the
-# loss-equality first step passes); the post-update trajectories drift.
-_old_jax_vma = pytest.mark.xfail(
-    __import__("chainermn_tpu._compat", fromlist=["OLD_SHARD_MAP"]).OLD_SHARD_MAP,
-    strict=False,
-    reason="composed-graph gradient rep-sum needs current-jax vma",
-)
-
-
 class TestFactorizationOracle:
     """(1,1,1) vs (2,2,2): same global params, same numerics."""
 
@@ -156,12 +141,10 @@ class TestFactorizationOracle:
             _host_tree(p222), l222, _host_tree(p111), l111
         )
 
-    @_old_jax_vma
     def test_losses_match(self, runs):
         _, l222, _, l111 = runs
         np.testing.assert_allclose(l222, l111, rtol=2e-4, atol=1e-5)
 
-    @_old_jax_vma
     def test_updated_params_match(self, runs):
         p222, _, p111, _ = runs
         flat222 = jax.tree_util.tree_leaves_with_path(p222)
@@ -236,7 +219,6 @@ class TestComposedVocabParallel:
             losses.append(float(m["loss"]))
         return _host_tree(params), losses
 
-    @_old_jax_vma
     def test_factorizations_agree(self, devices8):
         comm222 = cmn.create_communicator(
             "mesh", devices=devices8, sp_size=2, tp_size=2

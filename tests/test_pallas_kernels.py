@@ -466,6 +466,37 @@ class TestFlashWithSequenceParallel:
         )
 
 
+class TestFlashUnderVmaCheckedShardMap:
+    """``build_train_step`` runs the loss under a vma-checked
+    ``shard_map``; there a ``pallas_call`` must declare how its outputs
+    vary over the mesh (``_out_struct``), or tracing fails."""
+
+    def test_value_and_grad_batch_sharded(self, mesh8):
+        q, k, v = _qkv(b=8, s=32, h=2, d=8)
+
+        def loss(attend):
+            def per_shard(q, k, v):
+                out = attend(q, k, v)
+                return lax.pmean((out ** 2).mean(), "mn")
+
+            sharded = jax.shard_map(
+                per_shard, mesh=mesh8, in_specs=(P("mn"),) * 3,
+                out_specs=P(),
+            )  # check_vma left ON
+            return jax.jit(jax.value_and_grad(sharded, argnums=(0, 1, 2)))
+
+        flash = loss(lambda q, k, v: flash_attention(
+            q, k, v, True, None, 16, 16, True))
+        dense = loss(lambda q, k, v: multi_head_attention(
+            q, k, v, causal=True))
+        (l_f, g_f), (l_d, g_d) = flash(q, k, v), dense(q, k, v)
+        np.testing.assert_allclose(float(l_f), float(l_d), rtol=1e-5)
+        for a, b in zip(g_f, g_d):
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-6
+            )
+
+
 class TestFusedCastScale:
     @pytest.mark.parametrize("shape", [(7,), (128,), (3, 5, 11), (256, 128)])
     def test_matches_cast_multiply(self, shape):

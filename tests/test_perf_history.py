@@ -1,7 +1,8 @@
 """perf_history bench differ (ISSUE 6 satellite): the first slice of
-the ROADMAP perf-gate item runs in tier-1 as a smoke — the committed
+the ROADMAP perf-gate item runs in tier-1 as a smoke — a
 ``BENCH_r*.json`` trajectory diffs clean, and the regression rules
-behave as documented on synthetic captures.
+behave as documented, all on synthetic captures (the repo commits no
+capture files).
 
 Pure JSON/regex work: no jax import in the tool path.
 """
@@ -33,60 +34,91 @@ def _capture(tmp_path, name, rows):
     return str(p)
 
 
+_HEADLINE = "resnet50_train_images_per_sec_per_chip"
+
+
+def _headline(value, **extra):
+    return {"metric": _HEADLINE, "value": value, "step_time_ms": 44.0,
+            "unit": "images/sec/chip", **extra}
+
+
+def _summary(scale=1.0):
+    """The compact per-config map bench.py's final row carries."""
+    return {
+        name: {"v": v * scale, "ms": 10.0, "u": "tokens/sec/chip"}
+        for name, v in (("mnist", 1.0e7), ("resnet50_mnbn", 2700.0),
+                        ("transformer_lm", 1.3e5), ("moe_lm", 8.6e4),
+                        ("seq2seq_mp", 1.2e6), ("grad_wire", 2800.0))
+    }
+
+
 # ----------------------------------------------------------------------
-# the smoke: the committed trajectory itself
+# the smoke: a capture trajectory on disk, as a repo root would hold it
 # ----------------------------------------------------------------------
-class TestCommittedTrajectory:
-    def test_repo_captures_diff_clean(self):
-        """Acceptance: the two newest comparable committed captures
-        carry shared rows and no regression beyond spread — the same
-        gate a new capture will face."""
-        pair = newest_comparable_pair(REPO)
-        assert pair is not None, "need two comparable BENCH_r*.json"
+class TestCaptureTrajectory:
+    def test_repo_captures_diff_clean(self, tmp_path):
+        """The two newest comparable captures under a root carry shared
+        rows and no regression beyond spread — the gate a new capture
+        faces."""
+        _capture(tmp_path, "BENCH_r01.json", [_headline(2500.0)])
+        _capture(tmp_path, "BENCH_r02.json", [_headline(2900.0)])
+        _capture(tmp_path, "BENCH_r03.json", [_headline(2920.0)])
+        pair = newest_comparable_pair(str(tmp_path))
+        assert pair is not None
+        assert [os.path.basename(p) for p in pair] == [
+            "BENCH_r02.json", "BENCH_r03.json"]
         old, new = (load_rows(p) for p in pair)
-        shared = set(old) & set(new)
-        assert shared, (pair, sorted(old), sorted(new))
+        assert set(old) & set(new) == {_HEADLINE}
         assert diff_rows(old, new) == []
 
-    def test_rich_captures_diff_many_rows_clean(self):
-        """The full-capture pair (r02 -> r05, summary rows flattened)
-        compares the whole tracked config set."""
-        old = load_rows(os.path.join(REPO, "BENCH_r02.json"))
-        new = load_rows(os.path.join(REPO, "BENCH_r05.json"))
+    def test_rich_captures_diff_many_rows_clean(self, tmp_path):
+        """A full-capture pair: the final row's ``summary`` map is
+        flattened to ``<config>.v`` rows, so the whole tracked config
+        set is compared."""
+        old = load_rows(_capture(tmp_path, "BENCH_r02.json", [
+            _headline(2900.0, summary=_summary())]))
+        new = load_rows(_capture(tmp_path, "BENCH_r05.json", [
+            _headline(2920.0, summary=_summary(1.01))]))
+        assert "transformer_lm.v" in old and "moe_lm.v" in new
         assert len(set(old) & set(new)) >= 5
         assert diff_rows(old, new) == []
 
-    def test_failed_captures_fall_back_to_local(self):
-        """r04's remote capture failed (null row) but its committed
-        _local capture carries the measurement — pair selection must
-        use the local fallback for revision 4, not skip the revision
-        (and never compare a revision against its own fallback)."""
-        files = bench_files(REPO)
-        assert any("BENCH_r04.json" in f for f in files)
-        assert load_rows(os.path.join(REPO, "BENCH_r04.json")) == {} or (
-            not any(
-                isinstance(r.get("value"), (int, float))
-                for r in load_rows(
-                    os.path.join(REPO, "BENCH_r04.json")
-                ).values()
-            )
-        )
-        local = load_rows(os.path.join(REPO, "BENCH_r04_local.json"))
-        assert any(
+    def test_failed_captures_fall_back_to_local(self, tmp_path):
+        """A revision whose primary capture failed (null row) but whose
+        ``_local`` capture — a bare row, not a wrapped tail — carries
+        the measurement: pair selection must use the local fallback for
+        that revision, not skip it (and never compare a revision
+        against its own fallback)."""
+        _capture(tmp_path, "BENCH_r03.json", [_headline(2700.0)])
+        failed = _capture(tmp_path, "BENCH_r04.json", [
+            _headline(None, error="no device")])
+        local = tmp_path / "BENCH_r04_local.json"
+        local.write_text(json.dumps(_headline(2750.0)))
+        _capture(tmp_path, "BENCH_r05.json", [_headline(2800.0)])
+        _capture(tmp_path, "BENCH_r05_local.json", [_headline(2810.0)])
+        files = bench_files(str(tmp_path))
+        assert [os.path.basename(f) for f in files] == [
+            "BENCH_r03.json", "BENCH_r04.json", "BENCH_r04_local.json",
+            "BENCH_r05.json", "BENCH_r05_local.json"]
+        assert not any(
             isinstance(r.get("value"), (int, float))
-            for r in local.values()
-        ), "the bare-row _local shape must parse"
-        pair = newest_comparable_pair(REPO)
-        assert "BENCH_r04_local" in pair[0]
-        assert "BENCH_r05.json" in pair[1]
+            for r in load_rows(failed).values()
+        )
+        assert load_rows(str(local))[_HEADLINE]["value"] == 2750.0, (
+            "the bare-row _local shape must parse")
+        pair = newest_comparable_pair(str(tmp_path))
+        assert [os.path.basename(p) for p in pair] == [
+            "BENCH_r04_local.json", "BENCH_r05.json"]
 
-    def test_console_entry_exits_zero_on_clean_history(self):
+    def test_console_entry_exits_zero_on_clean_history(self, tmp_path):
+        old = _capture(tmp_path, "BENCH_r01.json", [_headline(2900.0)])
+        new = _capture(tmp_path, "BENCH_r02.json", [_headline(2920.0)])
         proc = subprocess.run(
-            [sys.executable, "benchmarks/perf_history.py"],
+            [sys.executable, "benchmarks/perf_history.py", old, new],
             capture_output=True, text=True, cwd=REPO,
         )
         assert proc.returncode == 0, proc.stdout + proc.stderr
-        assert "regression" in proc.stdout
+        assert "1 shared row(s), 0 regression(s)" in proc.stdout
 
 
 # ----------------------------------------------------------------------
@@ -312,7 +344,7 @@ class TestDiffRules:
         new = _capture(tmp_path, "BENCH_r91.json", [
             {"metric": "resnet50_train_images_per_sec_per_chip",
              "value": None, "step_time_ms": 44.0,
-             "unit": "images/sec/chip", "error": "relay down"},
+             "unit": "images/sec/chip", "error": "no device"},
         ])
         ro, rn = load_rows(old), load_rows(new)
         assert rn[

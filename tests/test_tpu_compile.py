@@ -1,0 +1,158 @@
+"""Ahead-of-time compiles of the Pallas kernels for a described v5e.
+
+The TPU compiler is installed on the CPU-only test machine and compiles
+for a chip that is described, not attached.  These cases hold the
+main-path kernels at their real widths to "Mosaic accepts this": what
+interpret mode cannot see (a slice not aligned to the tiling, a kernel
+over its VMEM budget, a kernel that cannot be partitioned) is refused
+here.  Nothing executes and nothing is timed — a compile that passes is
+not a chip run (``chip_smoke.py`` is).
+
+Only the worker that runs THIS file loads the TPU library, and it does
+so inside the ``topo`` fixture: nothing is described at import time, and
+everything compiles in the test's own process.
+"""
+
+import functools
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from chainermn_tpu.ops import pallas_attention as pa
+
+
+@pytest.fixture(scope="module")
+def topo():
+    # the compiler otherwise logs under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without the chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _grad_of(attend):
+    """d(sum of outputs)/d(q, k, v): the backward kernels join the
+    program."""
+    def fn(q, k, v):
+        return jax.grad(
+            lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    return fn
+
+
+def _qkv(sharding, b, s, h, d):
+    x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=sharding)
+    return x, x, x
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape,kw", [  # (b, s, h, d), keyword overrides
+    pytest.param((8, 2048, 8, 128), {}, id="s2048_d128"),
+    pytest.param((1, 8192, 8, 128), {}, id="s8192_d128_b1"),
+    pytest.param((8, 2048, 4, 256), {}, id="s2048_d256"),
+    pytest.param((8, 1000, 8, 128), {}, id="ragged_s1000"),
+    pytest.param((8, 2048, 8, 128),
+                 dict(block_q=1024, block_k=2048,
+                      bwd_block_q=1024, bwd_block_k=1024),
+                 id="bench_geometry"),
+    pytest.param((8, 2048, 8, 128), dict(taxonomy="legacy"), id="legacy"),
+])
+def test_flash_attention_compiles(one_chip, shape, kw, grad):
+    flash = functools.partial(
+        pa.flash_attention, causal=True, interpret=False, **kw
+    )
+    text = _compiled_text(
+        _grad_of(flash) if grad else flash, *_qkv(one_chip, *shape))
+    # forward only: 1 kernel; with the gradient: forward + dq + dk/dv
+    assert text.count("tpu_custom_call") >= (3 if grad else 1)
+
+
+@pytest.mark.parametrize("page_size", [8, 16, 128])
+def test_flash_decode_compiles(one_chip, page_size):
+    capacity, heads, d, pages_per_slot = 32, 8, 128, 2048 // page_size
+    num_pages = capacity * pages_per_slot + 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pages = sds((num_pages, page_size, heads, d), jnp.bfloat16)
+    text = _compiled_text(
+        functools.partial(pa.flash_decode, interpret=False),
+        sds((capacity, heads, d), jnp.bfloat16), pages, pages,
+        sds((capacity, pages_per_slot), jnp.int32),
+        sds((capacity,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_fused_cast_scale_compiles_at_resnet50_largest_bucket(one_chip):
+    from chainermn_tpu.comm_wire.planner import plan_of_tree
+    from chainermn_tpu.models import ResNet50
+
+    variables = jax.eval_shape(
+        lambda: ResNet50(num_classes=1000, train=True).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 224, 224, 3), jnp.bfloat16)
+        )
+    )
+    plan = plan_of_tree(variables["params"])
+    n = max(b.size for b in plan.buckets)
+    assert n > 4 * 1024 * 1024  # a real bucket, not a toy
+    text = _compiled_text(
+        lambda x: pa.fused_cast_scale(x, 0.25, jnp.bfloat16,
+                                      interpret=False),
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_ring_flash_compiles_on_four_chip_mesh(topo, grad):
+    from chainermn_tpu.parallel.ring_attention import ring_attention
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("mn_seq",))
+    spec = P(None, "mn_seq")
+    ring = jax.shard_map(
+        lambda q, k, v: ring_attention(
+            q, k, v, "mn_seq", causal=True, use_flash=True,
+            interpret=False,
+        ),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )
+    text = _compiled_text(
+        _grad_of(ring) if grad else ring,
+        *_qkv(NamedSharding(mesh, spec), 1, 8192, 8, 128),
+    )
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text  # the ring itself

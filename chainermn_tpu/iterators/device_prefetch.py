@@ -19,12 +19,42 @@ Typical wiring (the ``--native-loader`` path)::
     it = prefetch_to_device(iter(loader), step.place_batch, depth=2)
     for batch in it:            # already a placed global jax.Array
         params, opt_state, m = step(params, opt_state, batch)
+
+Telemetry (``observability.timeline``; nothing of it exists while
+telemetry is off): ``feed.collate`` spans ``next(host iterator)``,
+``feed.place`` the ``place_fn`` call (the ``device_put`` *enqueue*),
+and ``feed.h2d`` runs from that enqueue to the placed batch being
+ready.  The main thread must not wait for the copy, so each placed batch
+gets a short-lived daemon thread that ``block_until_ready``s it inside
+the span and drops it: the span sits on that thread's line of the trace.
 """
 
 from __future__ import annotations
 
 import collections
+import threading
 from typing import Callable, Iterator, Optional
+
+from ..observability import timeline as _obs
+
+
+def _await_copy(placed) -> None:
+    """``feed.h2d``: wait, inside a span, for one placed batch.  Runs on
+    a thread of its own, so that the span opens at the enqueue whatever
+    earlier copies are still doing; the thread holds a reference to the
+    batch until its copy completed, no longer."""
+    import jax
+
+    nbytes = sum(getattr(x, "nbytes", 0)
+                 for x in jax.tree_util.tree_leaves(placed))
+    with _obs.span("feed.h2d", bytes=nbytes) as sp:
+        try:
+            jax.block_until_ready(placed)
+        except RuntimeError as e:
+            # deleted (donated) before it was ready, or a transfer that
+            # failed, which the step that takes the batch raises itself:
+            # the span is then no copy's time, and says so
+            sp.set(aborted=type(e).__name__)
 
 
 class _DevicePrefetcher:
@@ -54,16 +84,30 @@ class _DevicePrefetcher:
 
     def _top_up(self) -> None:
         while len(self._buf) < self._depth and not self._done:
-            state = self._it.serialize() if self._can_serialize else None
-            try:
-                host = next(self._it)
-            except StopIteration:
-                self._done = True
-                return
+            with _obs.span("feed.collate"):
+                state = self._it.serialize() if self._can_serialize \
+                    else None
+                try:
+                    host = next(self._it)
+                except StopIteration:
+                    self._done = True
+                    return
             # async dispatch: returns a jax.Array immediately, the copy
             # proceeds while the caller's current step computes
-            self._buf.append(self._place(host))
+            with _obs.span("feed.place"):
+                placed = self._place(host)
+            self._watch(placed)
+            self._buf.append(placed)
             self._states.append(state)
+
+    @staticmethod
+    def _watch(placed) -> None:
+        """Start the ``feed.h2d`` observer of one batch while telemetry
+        is on; asked per batch, because telemetry may be installed after
+        the prefetcher was built.  Off, no thread exists."""
+        if _obs.active() is not None:
+            threading.Thread(target=_await_copy, args=(placed,),
+                             name="feed-h2d", daemon=True).start()
 
     def __iter__(self):
         return self

@@ -342,6 +342,11 @@ def make_lm_embed(parent: nn.Module, vocab_size: int, d_model: int,
     )
 
 
+#: device scope (``jax.named_scope``) of the head projection and of the
+#: cross-entropy losses: the head + CE share of a traced step
+HEAD_CE_SCOPE = "head_ce"
+
+
 class TransformerLM(nn.Module):
     """Causal LM: tokens (batch, seq) -> logits (batch, seq, vocab).
 
@@ -447,13 +452,15 @@ class TransformerLM(nn.Module):
         x = nn.LayerNorm(dtype=self.ln_dtype)(x)
         if self.return_hidden:
             return x.astype(jnp.float32)
-        # Weight-tied head.
-        if self.vocab_parallel:
-            return embed.attend(x.astype(jnp.float32))  # local vocab block
-        logits = x.astype(jnp.float32) @ embed.embedding.T
-        return logits
+        # Weight-tied head, under the device scope the losses share
+        with jax.named_scope(HEAD_CE_SCOPE):
+            if self.vocab_parallel:
+                # local vocab block
+                return embed.attend(x.astype(jnp.float32))
+            return x.astype(jnp.float32) @ embed.embedding.T
 
 
+@jax.named_scope(HEAD_CE_SCOPE)
 def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
     """Next-token cross entropy over a (batch, seq) token block."""
     import optax
@@ -494,6 +501,7 @@ def _sp_masked_mean(ce: jnp.ndarray, valid: jnp.ndarray,
     return total / count
 
 
+@jax.named_scope(HEAD_CE_SCOPE)
 def sp_lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray,
                axis_name: str) -> jnp.ndarray:
     """Next-token cross entropy for a sequence-sharded block
@@ -506,6 +514,7 @@ def sp_lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray,
     return _sp_masked_mean(ce, valid, axis_name)
 
 
+@jax.named_scope(HEAD_CE_SCOPE)
 def vp_lm_loss(logits_local: jnp.ndarray, tokens: jnp.ndarray,
                model_axis: str,
                seq_axis: Optional[str] = None) -> jnp.ndarray:

@@ -18,11 +18,20 @@ returning a stateless null context manager (overhead contract:
 disabled-path cost ≤1 % of a CPU-mesh step, pinned by
 ``tests/test_observability.py``).
 
+Every active span is also a ``jax.profiler.TraceAnnotation`` of the
+same name on its thread's line of the profiler's host plane, so one
+``jax.profiler`` trace shows the host's phases over the device's
+operations on one clock; ``step`` / ``update`` are the
+``StepTraceAnnotation`` ("train", ``step_num``) xprof's step view wants.
+
 Span taxonomy (see docs/observability.md for the full table)::
 
     step                 one trainer iteration (update + extensions)
     update               Updater.update (incl. injected-fault sites)
     data.wait            blocking on next(iterator)
+    feed.collate/place   device prefetcher: next(host iterator), the
+                         device_put enqueue (both under data.wait)
+    feed.h2d             enqueue -> placed batch ready (its own thread)
     compute.dispatch     batch placement + compiled-step dispatch
     collective.<name>    eager-tier collective (allreduce, psum buckets)
     wire.pack/ship/reduce  bucket pipeline phases (host-staged tier)
@@ -84,10 +93,26 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
-class _SpanCM:
-    """Context manager recording one span on enter/exit."""
+#: argument that makes a span a *step*: the outermost such span of a
+#: thread enters the profiler as ``StepTraceAnnotation(STEP_NAME,
+#: step_num=...)`` (the marker xprof builds its step view from), with
+#: the span's own name under ``span``; every other span is a
+#: ``TraceAnnotation`` of its name
+STEP_ARG = "step_num"
+STEP_NAME = "train"
 
-    __slots__ = ("_tl", "name", "args", "_t0", "_wall0", "_id", "_parent")
+_profiler = None  # jax.profiler, imported at the first active span
+
+
+class _SpanCM:
+    """Context manager recording one span on enter/exit — on the
+    timeline's monotonic clock, and as an annotation on the calling
+    thread's line of the JAX profiler's ``/host:CPU`` plane, beside the
+    device planes and on their clock (a few hundred ns while no
+    ``jax.profiler`` trace is being taken)."""
+
+    __slots__ = ("_tl", "name", "args", "_t0", "_id", "_parent", "_ann",
+                 "_step")
 
     def __init__(self, tl: "Timeline", name: str, args: dict):
         self._tl = tl
@@ -96,22 +121,38 @@ class _SpanCM:
 
     def set(self, **args) -> None:
         """Attach/overwrite span args mid-span (e.g. payload bytes
-        known only after serialization)."""
+        known only after serialization).  The profiler's annotation
+        keeps the args the span was opened with."""
         self.args.update(args)
 
     def __enter__(self):
+        global _profiler
         tl = self._tl
         stack = tl._stack()
         self._parent = stack[-1] if stack else 0
         self._id = next(tl._ids)
         stack.append(self._id)
-        self._wall0 = time.time()
+        if _profiler is None:
+            import jax.profiler as _profiler
+        local = tl._local
+        self._step = STEP_ARG in self.args \
+            and not getattr(local, "in_step", False)
+        if self._step:
+            local.in_step = True
+            self._ann = _profiler.StepTraceAnnotation(
+                STEP_NAME, span=self.name, **self.args)
+        else:
+            self._ann = _profiler.TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         t1 = time.monotonic()
+        self._ann.__exit__(*exc)
         tl = self._tl
+        if self._step:
+            tl._local.in_step = False
         stack = tl._stack()
         if stack and stack[-1] == self._id:
             stack.pop()
@@ -120,7 +161,6 @@ class _SpanCM:
             "name": self.name,
             "t": self._t0,
             "dur": t1 - self._t0,
-            "wall": self._wall0,
             "sid": self._id,
             "parent": self._parent,
             "tid": tl._tid(),

@@ -159,7 +159,8 @@ def chunked_lm_loss(model, params, tokens, n_chunks=16):
     hidden = twin.apply(params, tokens)          # (b, s, d) fp32
     table = params["params"]["embed"]["embedding"]
     b, s, d = hidden.shape
-    h = hidden[:, :-1].reshape(-1, d)
-    targets = tokens[:, 1:].reshape(-1)
-    ce = chunked_softmax_cross_entropy(h, table, targets, n_chunks)
-    return ce.mean()
+    with jax.named_scope("head_ce"):  # models.transformer.HEAD_CE_SCOPE
+        h = hidden[:, :-1].reshape(-1, d)
+        targets = tokens[:, 1:].reshape(-1)
+        ce = chunked_softmax_cross_entropy(h, table, targets, n_chunks)
+        return ce.mean()

@@ -526,6 +526,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             pltpu.VMEM((bq, 128), jnp.float32),  # running denom (col 0)
         ],
         interpret=interpret,
+        name="_flash_forward",
     )(qb, kb_, vb)
     out = out[:, :s_q].reshape(b, h, s_q, d)
     return jnp.moveaxis(out, 1, 2), lse[:, 0, :s_q]  # (b,s,h,d), (bh,s_q)
@@ -894,6 +895,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         out_specs=pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
+        name="_flash_backward_dq",
     )(qb, kb_, vb, dob, lse_p, delta)
 
     dk, dv = pl.pallas_call(
@@ -920,6 +922,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=interpret,
+        name="_flash_backward_dkdv",
     )(qb, kb_, vb, dob, lse_p, delta)
 
     def from_bh(x, s):

@@ -79,6 +79,11 @@ def _axis_size(comm, axes) -> int:
     return n
 
 
+#: device scope (``jax.named_scope``) of the compiled gradient exchange
+GRAD_SYNC_SCOPE = "grad_sync"
+
+
+@jax.named_scope(GRAD_SYNC_SCOPE)
 def _sync_grads_per_leaf(grads, comm, comm_dtype=None, axes=None):
     """Legacy wire: one collective PER GRADIENT LEAF (267 for
     ResNet-50).  Kept as the `wire="per_leaf"` escape hatch and the
@@ -98,6 +103,7 @@ def _sync_grads_per_leaf(grads, comm, comm_dtype=None, axes=None):
     return jax.tree_util.tree_map(one, grads)
 
 
+@jax.named_scope(GRAD_SYNC_SCOPE)
 def _sync_grads_wire(grads, comm, wire, axes=None, residuals=None,
                      profile=None):
     """Bucketed wire gradient sync: flatten the grad pytree into the
@@ -787,6 +793,7 @@ class _ZeroRedundancyOptimizer(_MultiNodeOptimizer):
                     g.shape[0], -1
                 )
 
+            @jax.named_scope(GRAD_SYNC_SCOPE)
             def scatter(g, hier=False):
                 if hier:
                     part = lax.psum_scatter(  # intra hop, full precision
@@ -1360,6 +1367,7 @@ def build_train_step(
         cannot diverge between lowering paths).  ``hybrid_sync``: the
         hybrid path's autodiff already produced globally-synced grads,
         so a multi-node optimizer must skip its own sync."""
+        @jax.named_scope("optimizer")
         def do_update(g):
             if hybrid_sync and is_mn:
                 updates, new_state = optimizer.update(

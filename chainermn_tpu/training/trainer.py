@@ -46,6 +46,8 @@ class Updater:
             step_fn, "batch_sharding", None
         )
         self.last_metrics: Dict[str, Any] = {}
+        #: updates made by this object (the profiler's step number)
+        self.iteration = 0
 
     @property
     def epoch(self) -> int:
@@ -59,7 +61,10 @@ class Updater:
         # telemetry spans ("update" > "data.wait"/"compute.dispatch"):
         # the data-wait-vs-compute split of the step taxonomy; disabled
         # path is one `is None` check per span (docs/observability.md)
-        with _obs.span("update"):
+        # "update" carries step_num: the outermost such span is the
+        # profiler's StepTraceAnnotation (Trainer.run's "step" when it
+        # drives the loop, this one when update() is called directly)
+        with _obs.span("update", step_num=self.iteration):
             # resilience site: a deterministic mid-run failure point for
             # exercising auto-resume (no-op — one None check — when no
             # injector is active)
@@ -84,6 +89,7 @@ class Updater:
                     batch = jax.device_put(batch, self.batch_sharding)
                 self.params, self.opt_state, self.last_metrics = \
                     self.step_fn(self.params, self.opt_state, batch)
+        self.iteration += 1
         self._observe_host_time()
 
     @staticmethod
@@ -314,7 +320,7 @@ class Trainer:
                     # "step" span: one trainer iteration — update AND
                     # its extensions (a checkpoint stall is step time
                     # the operator pays; the sub-spans split it)
-                    with _obs.span("step", iteration=self.iteration):
+                    with _obs.span("step", step_num=self.iteration):
                         self.updater.update()
                         self.iteration += 1
                         self.observation = {

@@ -21,9 +21,12 @@ Load-bearing pins, in order:
   helper.
 """
 
+import glob
 import itertools
 import json
 import os
+import re
+import threading
 import time
 
 import numpy as np
@@ -34,6 +37,7 @@ import optax
 
 import chainermn_tpu as cmn
 from chainermn_tpu import observability as obs
+from chainermn_tpu.iterators import prefetch_to_device
 from chainermn_tpu.observability import timeline as tl_mod
 from chainermn_tpu.resilience.log import (
     ResilienceLog,
@@ -858,3 +862,220 @@ class TestTimeStepsSamples:
         pos = [s for s in samples if s > 0]
         if pos:
             assert dt == min(pos)
+
+
+# ----------------------------------------------------------------------
+# PR 27: spans in the profiler's trace, feed spans, device scopes,
+# kernel names
+# ----------------------------------------------------------------------
+def _host_events(trace_dir):
+    """``{event name: [stats dict, ...]}`` of the ``/host:CPU`` plane of
+    the one trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.setdefault(ev.name, []).append(dict(ev.stats))
+    return out
+
+
+@pytest.fixture(scope="module")
+def profiled(comm, tmp_path_factory):
+    """One CPU ``jax.profiler`` trace over: a plain active span, two
+    direct ``Updater.update()`` calls, a two-iteration ``Trainer.run``
+    — and the timeline that recorded the same spans."""
+    trainer = _mlp_trainer(comm, stop=(2, "iteration"))
+    trainer.updater.update()  # compile outside the trace
+    trace_dir = str(tmp_path_factory.mktemp("profile"))
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 1
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        with obs.observe() as tel:
+            with obs.span("feed.test", bytes=77):
+                pass
+            trainer.updater.update()
+            trainer.updater.update()
+            trainer.run()
+            jax.block_until_ready(trainer.updater.last_metrics["loss"])
+    finally:
+        jax.profiler.stop_trace()
+    return _host_events(trace_dir), tel
+
+
+class TestProfilerAnnotations:
+    def test_active_span_is_a_trace_annotation_of_its_name(self, profiled):
+        events, _ = profiled
+        assert [int(s["bytes"]) for s in events["feed.test"]] == [77]
+
+    @pytest.mark.parametrize("name", ["data.wait", "compute.dispatch"])
+    def test_every_updater_span_is_in_the_trace(self, profiled, name):
+        events, tel = profiled
+        assert len(events[name]) == len(tel.timeline.spans(name)) == 4
+
+    def test_update_is_a_step_annotation_with_step_num(self, profiled):
+        events, _ = profiled
+        direct = [s for s in events["train"] if s["span"] == "update"]
+        # step_num is the Updater's own count; one update ran before
+        assert [int(s["step_num"]) for s in direct] == [1, 2]
+
+    def test_trainer_step_is_the_step_annotation_update_nests(self,
+                                                              profiled):
+        """Under ``Trainer.run`` the outermost step span (``step``) is
+        the profiler's step; ``update`` inside it is a plain
+        annotation, so that no step holds another."""
+        events, _ = profiled
+        steps = [s for s in events["train"] if s["span"] == "step"]
+        assert [int(s["step_num"]) for s in steps] == [0, 1]
+        assert len(events["update"]) == 2
+        assert len(events["train"]) == 4
+
+    def test_span_events_carry_no_wall_field(self, profiled):
+        _, tel = profiled
+        spans = tel.timeline.spans()
+        assert spans and all("wall" not in s for s in spans)
+        assert tel.timeline.wall0 > 0  # the anchor stays
+
+    def test_disabled_span_enters_no_annotation(self):
+        assert obs.active() is None
+        assert obs.span("update", step_num=3) is tl_mod.NULL_SPAN
+
+
+def _host_batches(n):
+    for i in range(n):
+        yield np.full((4, 8), i, np.float32)
+
+
+class TestFeedSpans:
+    def test_off_no_thread_and_no_spans(self):
+        before = set(threading.enumerate())
+        it = prefetch_to_device(_host_batches(5), jax.device_put, 2)
+        got = [np.asarray(b) for b in it]
+        assert len(got) == 5
+        assert set(threading.enumerate()) <= before
+
+    @pytest.mark.parametrize("name,count", [
+        ("feed.collate", 6),  # the sixth finds the iterator exhausted
+        ("feed.place", 5), ("feed.h2d", 5)])
+    def test_on_records_the_feed_spans(self, name, count):
+        # built BEFORE telemetry is installed, as the runner does
+        it = prefetch_to_device(_host_batches(5), jax.device_put, 2)
+        with obs.observe() as tel:
+            got = [np.asarray(b) for b in it]
+            deadline = time.monotonic() + 5.0
+            while len(tel.timeline.spans("feed.h2d")) < 5 \
+                    and time.monotonic() < deadline:
+                time.sleep(0.01)
+        assert len(tel.timeline.spans(name)) == count
+        if name == "feed.h2d":
+            sp = tel.timeline.spans(name)
+            assert {s["args"]["bytes"] for s in sp} == {4 * 8 * 4}
+            main = tel.timeline.spans("feed.place")[0]["tid"]
+            assert all(s["tid"] != main for s in sp)  # off the main line
+        np.testing.assert_array_equal(
+            np.stack(got), np.stack(list(_host_batches(5))))
+
+    def test_feed_spans_nest_under_data_wait(self, comm):
+        trainer = _mlp_trainer(comm)
+        up = trainer.updater
+        up.iterator = prefetch_to_device(up.iterator,
+                                         up.step_fn.place_batch, 2)
+        with obs.observe() as tel:
+            up.update()
+        wait, = tel.timeline.spans("data.wait")
+        for name in ("feed.collate", "feed.place"):
+            assert {s["parent"] for s in tel.timeline.spans(name)} \
+                == {wait["sid"]}
+
+    def test_each_copy_has_its_own_observer_which_ends_with_it(self):
+        """A span per batch that opens at the enqueue, whatever earlier
+        copies are doing: one short-lived daemon thread a batch, none
+        left once the copies are done, none started with telemetry
+        off again."""
+        def observers():
+            return [t for t in threading.enumerate()
+                    if t.name == "feed-h2d"]
+
+        it = prefetch_to_device(_host_batches(8), jax.device_put, 2)
+        with obs.observe() as tel:
+            next(it)
+            started = observers()
+            assert all(t.daemon for t in started)
+            for t in started:
+                t.join(timeout=5.0)
+            assert not observers()
+            assert len(tel.timeline.spans("feed.h2d")) == 3
+        next(it)
+        assert not observers()
+
+    def test_a_batch_deleted_before_it_was_ready_marks_its_span(self):
+        """A donating step may delete the batch first: the span is then
+        no copy's time and says so; nothing is raised on the thread."""
+        from chainermn_tpu.iterators.device_prefetch import _await_copy
+
+        gone = jax.device_put(np.zeros((4, 8), np.float32))
+        gone.delete()
+        with obs.observe() as tel:
+            _await_copy(gone)
+            _await_copy(jax.device_put(np.zeros((4, 8), np.float32)))
+        aborted, ready = tel.timeline.spans("feed.h2d")
+        assert aborted["args"]["aborted"].endswith("RuntimeError")
+        assert "aborted" not in ready["args"]
+
+
+@pytest.fixture(scope="module")
+def lm_step_hlo(comm):
+    """The compiled HLO text of a tiny LM step through
+    ``create_multi_node_optimizer`` + ``build_train_step``."""
+    from chainermn_tpu.models.transformer import TransformerLM, lm_loss
+
+    model = TransformerLM(vocab_size=64, d_model=32, n_heads=2,
+                          n_layers=1, max_len=16)
+    toks = jnp.zeros((comm.size, 16), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), toks)
+    opt = cmn.create_multi_node_optimizer(optax.adamw(1e-3), comm)
+    step = cmn.build_train_step(
+        comm, lambda p, b: lm_loss(model.apply(p, b), b), opt,
+        donate=False)
+    p, o = step.place(params, opt.init(params))
+    return step.get_jitted(p, o).lower(
+        p, o, step.place_batch(toks)).compile().as_text()
+
+
+class TestDeviceScopes:
+    @pytest.mark.parametrize("scope", [
+        "head_ce", "optimizer", "optimizer/grad_sync", "LayerNorm_0"])
+    def test_compiled_lm_step_carries_the_scope(self, lm_step_hlo, scope):
+        names = re.findall(r'op_name="([^"]*)"', lm_step_hlo)
+        assert any(f"{scope}/" in n or f"({scope})" in n for n in names)
+
+    def test_head_ce_scopes_forward_and_backward(self, lm_step_hlo):
+        names = [n for n in re.findall(r'op_name="([^"]*)"', lm_step_hlo)
+                 if "head_ce" in n]
+        assert any("transpose(" in n for n in names)  # backward
+        assert any("transpose(" not in n for n in names)  # forward
+
+    @pytest.mark.parametrize("kernel", [
+        "_flash_forward", "_flash_backward_dq", "_flash_backward_dkdv"])
+    def test_flash_kernels_keep_their_names_for_the_chip(self, kernel):
+        """Lowered for the TPU (no chip needed): each ``pallas_call`` is
+        a ``tpu_custom_call`` under the name the trace readers match."""
+        from chainermn_tpu.ops.pallas_attention import flash_attention
+
+        assert re.match(r"_flash_(forward|backward)", kernel)
+        q = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16)
+
+        def loss(q, k, v):
+            out = flash_attention(q, k, v, True, None, None, None, False)
+            return out.astype(jnp.float32).sum()
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
+            q, q, q).lower(lowering_platforms=("tpu",)).as_text()
+        assert text.count(f'kernel_name = "{kernel}"') == 1

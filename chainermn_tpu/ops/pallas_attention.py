@@ -24,8 +24,10 @@ All three kernels are DIAGONAL-SPLIT (round 6): each (q block, k block)
 grid point is classified dead / interior / masked, and interior blocks
 (the fully-unmasked majority at long sequence) run a fast branch with
 no iota/mask/select work — see the "Block taxonomy" section below and
-docs/performance.md "Diagonal-split kernel".  The pre-split kernels
-are kept under ``taxonomy="legacy"`` as the bit-exact reference.
+docs/performance.md "Diagonal-split kernel".  A masked block that the
+diagonal crosses squarely is computed in tiles, the ones above the
+diagonal skipped ("Compute tile" below).  The pre-split kernels are
+kept under ``taxonomy="legacy"`` as the reference.
 """
 
 from __future__ import annotations
@@ -220,8 +222,97 @@ def _block_class(first_q, first_k, *, s_k, s_kp, causal, block_q, block_k,
     return _and(live, _not(needs_mask)), _and(live, needs_mask)
 
 
+# Compute tile (PR 28): a masked block that the causal diagonal crosses
+# squarely -- block_q == block_k, first_q == first_k, no padding in the
+# launch -- keeps its DMA block and is COMPUTED in t x t tiles: tiles
+# above the diagonal issue nothing, tiles below it run the interior
+# arithmetic, and only the t x t tiles on the diagonal pay the mask.  At
+# 1024 blocks a tile of 256 executes 10 of the block's 16 tiles and
+# masks 4; a tile of 512, 3 of 4 and 2.
+#
+# Measured on v5e at [48, 2048, 128] bf16 (PERF.md, PR 28; us a call,
+# whole / 512 / 256 / 128): dq 713 / 614 / 563 / 562, dk/dv 956 / 785 /
+# 775 / 800, forward 590 / 544 / 600 / 713.  The forward's time follows
+# its rows (each pays its online-softmax update once a strip), not its
+# elements: finer strips remove matmul work it was not waiting for and
+# add a pipeline bubble each, so it takes the coarse tile.
+_COMPUTE_TILE = {"fwd": 512, "bwd": 256}
+
+
+def _compute_tile(block_q, block_k, kind, *, causal, aligned, tile=None):
+    """THE compute-tile rule: the tile a launch's diagonal blocks are
+    computed in, or ``None`` where every block is computed whole
+    (today's code: non-causal, ``block_q != block_k``, padding on an
+    axis the kernel masks, blocks under two tiles).  ``aligned`` is "no
+    padding on a masked axis" -- then, with square blocks, the masked
+    class of :func:`_block_class` is exactly the blocks on the diagonal.
+    ``kind`` is ``"fwd"`` / ``"bwd"`` as in :func:`block_census`;
+    ``tile`` overrides the default (the private entry points' static
+    argument: interpret-mode tests at tiny shapes)."""
+    t = _COMPUTE_TILE[kind] if tile is None else tile
+    if not (causal and aligned and block_q == block_k):
+        return None
+    if block_q % t or block_q < 2 * t:
+        return None
+    return t
+
+
+def _class_name(interior, masked) -> str:
+    return "masked" if masked else ("interior" if interior else "dead")
+
+
+def _tile_classes(block, t):
+    """Class of each t x t tile (row r, column c) of an aligned diagonal
+    block: :func:`_block_class` itself at tile granularity (the block's
+    own offset cancels, first_q == first_k), so the kernels' strips and
+    the census count from one predicate."""
+    n = block // t
+    return [[_class_name(*_block_class(
+        r * t, c * t, s_k=block, s_kp=block, causal=True, block_q=t,
+        block_k=t)) for c in range(n)] for r in range(n)]
+
+
+#: a block computed whole: one strip, the mask (if any) over all of it
+_WHOLE = slice(None)
+
+
+def _strips(block, tile, masked):
+    """The compute schedule of a block: ``(rows, cols, masked cols)``
+    strips.  Without a tile (or for an interior block) the block whole;
+    with one, an aligned diagonal block as one strip per q tile, each
+    against the run of its live k tiles as ONE rectangle (one matmul a
+    product) whose last tile -- ``masked cols``, relative to the run --
+    the diagonal crosses.  Dead tiles appear nowhere."""
+    if tile is None or not masked:
+        return [(_WHOLE, _WHOLE, _WHOLE if masked else None)]
+    strips = []
+    for r, line in enumerate(_tile_classes(block, tile)):
+        n_live = len(line) - line.count("dead")
+        assert line[:n_live] == ["interior"] * (n_live - 1) + ["masked"]
+        strips.append((slice(r * tile, (r + 1) * tile),
+                       slice(0, n_live * tile),
+                       slice((n_live - 1) * tile, n_live * tile)))
+    return strips
+
+
+def _select(mask, x, fill, masked):
+    """``where(mask, x, fill)`` on the ``masked`` columns of ``x``
+    (``None``: nowhere; the whole: everywhere) -- the columns before
+    them pass through untouched, whole lane tiles sliced off and put
+    back."""
+    if masked is None:
+        return x
+    if masked == _WHOLE:
+        return jnp.where(mask, x, fill)
+    assert masked.stop == x.shape[1]
+    tail = jnp.where(mask, x[:, masked.start:], fill)
+    if not masked.start:
+        return tail
+    return jnp.concatenate([x[:, :masked.start], tail], axis=1)
+
+
 def block_census(s_q: int, s_k: int, block_q: int, block_k: int,
-                 causal: bool, kind: str = "fwd") -> dict:
+                 causal: bool, kind: str = "fwd", tile=None) -> dict:
     """Static census of the block taxonomy for one (batch*head) program
     — the analytic side of the segment-anatomy bench (how many blocks
     of each class a launch executes, so A/B step times divide into
@@ -232,7 +323,17 @@ def block_census(s_q: int, s_k: int, block_q: int, block_k: int,
     (padded q rows would otherwise contribute to dk/dv) — so a ragged
     q tail reclassifies its row of blocks only for ``kind="bwd"``.
     Mirrors the kernels' run-time predicates exactly
-    (``test_block_census_matches_brute_force``)."""
+    (``test_block_census_matches_brute_force``).
+
+    The compute tile's counters (``tile`` as in :func:`_compute_tile`):
+    ``tile`` is the tile the launch's diagonal blocks are computed in
+    (``None``: whole blocks); ``tiles_executed`` / ``tiles_masked`` /
+    ``tiles_skipped`` count the tiles of the masked blocks that issue
+    matmuls, that also pay the mask, and that issue nothing (all 0
+    without a tile); ``executed_units`` / ``masked_units`` are the
+    block-units of work and of masked work a program runs (seq 2048 at
+    1024 blocks: 3 and 2 whole, 2.5 and 1 at tile 512, 2.25 and 0.5 at
+    tile 256)."""
     if kind not in ("fwd", "bwd"):
         raise ValueError(f"kind must be fwd/bwd, got {kind!r}")
     s_qp, s_kp = _round_up(s_q, block_q), _round_up(s_k, block_k)
@@ -246,9 +347,23 @@ def block_census(s_q: int, s_k: int, block_q: int, block_k: int,
                 causal=causal, block_q=block_q, block_k=block_k,
                 s_q=s_q if kind == "bwd" else None, s_qp=s_qp,
             )
-            key = "masked" if masked else (
-                "interior" if interior else "dead")
-            census[key] += 1
+            census[_class_name(interior, masked)] += 1
+    t = _compute_tile(
+        block_q, block_k, kind, causal=causal, tile=tile,
+        aligned=s_k == s_kp and (kind == "fwd" or s_q == s_qp),
+    )
+    # a masked block computed whole counts as one tile on the diagonal
+    tiles = sum(_tile_classes(block_q, t) if t else [["masked"]], [])
+    live, on = len(tiles) - tiles.count("dead"), tiles.count("masked")
+    n_masked = census["masked"]
+    census.update(
+        tile=t,
+        tiles_executed=n_masked * live if t else 0,
+        tiles_masked=n_masked * on if t else 0,
+        tiles_skipped=n_masked * tiles.count("dead"),
+        executed_units=census["interior"] + n_masked * live / len(tiles),
+        masked_units=n_masked * on / len(tiles),
+    )
     return census
 
 
@@ -259,7 +374,8 @@ def launch_census(s_q: int, s_k: int, d: int, block_q=None, block_k=None,
     ``None`` blocks to the defaults, then applies every clamp the entry
     points apply — the head-dim clamp (:func:`_clamp_blocks_for_dim`),
     the q-block lane-tile floor (:func:`_effective_q_block`; compiled
-    TPU floors bq at 128), and the k sequence clamp — and returns
+    TPU floors bq at 128), and the k sequence clamp — and the compute
+    tile (:func:`_compute_tile`), and returns
     ``{"fwd": census, "bwd": census}``.  The bench anatomy rungs use
     this instead of calling :func:`block_census` on the *requested*
     blocks, so a clamped launch cannot print a census for a geometry
@@ -371,7 +487,7 @@ def _flash_fwd_kernel_legacy(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                       l_ref, *, s_k: int, s_kp: int, causal: bool,
                       scale: float, block_q: int, block_k: int,
-                      force_interior: bool = False):
+                      force_interior: bool = False, tile=None):
     """Diagonal-split forward kernel (``taxonomy="split"``).
 
     Same grid/scratch contract as the legacy kernel; each (j, kb) grid
@@ -386,7 +502,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     Exactness vs legacy: on an interior block the legacy mask is
     provably all-true, so ``where(mask, s, -inf)`` is the identity and
     both branches compute the same fp32 expression tree
-    (``test_split_matches_legacy_exactly``)."""
+    (``test_split_matches_legacy_exactly``).
+
+    ``tile`` (:func:`_compute_tile`): a masked block is then an aligned
+    diagonal block and runs strip by strip (:func:`_strips`): each q
+    tile takes ONE online-softmax update against its live keys only,
+    the mask on the last tile of the strip.  Tiles above the diagonal
+    contributed exact zeros (``exp(-1e30 - m)``), so nothing of the
+    mathematics is left out; only fp32 summation order inside the block
+    can differ."""
     j = pl.program_id(1)
     kb = pl.program_id(2)
     n_kb = pl.num_programs(2)
@@ -400,20 +524,24 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     )
 
     def _attend(with_mask):
-        q = q_ref[0].astype(jnp.float32) * scale  # (bq, d)
-        k_blk = k_ref[0].astype(jnp.float32)      # (bk, d)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (bq, bk)
-        if with_mask:
-            mask = _tail_mask(
-                first_q, first_k, s_k=s_k, s_kp=s_kp, causal=causal,
-                block_q=block_q, block_k=block_k,
-            )
-            s = jnp.where(mask, s, _NEG_INF)
+        mask = _piece_mask(
+            first_q, first_k, tile, s_k=s_k, s_kp=s_kp, causal=causal,
+            block_q=block_q, block_k=block_k,
+        ) if with_mask else None
+        for rows, cols, masked in _strips(block_q, tile, with_mask):
+            q = q_ref[0, rows, :].astype(jnp.float32) * scale  # (rows, d)
+            k_blk = k_ref[0, cols, :].astype(jnp.float32)      # (cols, d)
+            v_blk = v_ref[0, cols, :].astype(jnp.float32)
+            s = lax.dot_general(
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (rows, cols)
+            s = _select(mask, s, _NEG_INF, masked)
+            _softmax_update(rows, s, v_blk)
+
+    def _softmax_update(rows, s, v_blk):
         m_blk = jnp.max(s, axis=-1, keepdims=True)
+        stat_shape = (m_blk.shape[0], m_ref.shape[1])
 
         @pl.when(kb == 0)
         def _first():
@@ -421,27 +549,27 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
             # so the online-softmax rescale is provably a no-op — write
             # the block statistics directly.
             p = jnp.exp(s - m_blk)
-            m_ref[:] = jnp.broadcast_to(m_blk, m_ref.shape)
-            l_ref[:] = jnp.broadcast_to(
-                jnp.sum(p, axis=-1, keepdims=True), l_ref.shape
+            m_ref[rows, :] = jnp.broadcast_to(m_blk, stat_shape)
+            l_ref[rows, :] = jnp.broadcast_to(
+                jnp.sum(p, axis=-1, keepdims=True), stat_shape
             )
-            acc_ref[:] = lax.dot_general(
+            acc_ref[rows, :] = lax.dot_general(
                 p, v_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
 
         @pl.when(kb != 0)
         def _rest():
-            m_old = m_ref[:, 0:1]
+            m_old = m_ref[rows, 0:1]
             m_new = jnp.maximum(m_old, m_blk)
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m_old - m_new)
-            l_new = alpha * l_ref[:, 0:1] + jnp.sum(
+            l_new = alpha * l_ref[rows, 0:1] + jnp.sum(
                 p, axis=-1, keepdims=True
             )
-            l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-            m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-            acc_ref[:] = alpha * acc_ref[:] + lax.dot_general(
+            l_ref[rows, :] = jnp.broadcast_to(l_new, stat_shape)
+            m_ref[rows, :] = jnp.broadcast_to(m_new, stat_shape)
+            acc_ref[rows, :] = alpha * acc_ref[rows, :] + lax.dot_general(
                 p, v_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
@@ -470,10 +598,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "scale", "block_q", "block_k", "interpret",
-                     "taxonomy"),
+                     "taxonomy", "tile"),
 )
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   taxonomy="split"):
+                   taxonomy="split", tile=None):
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
     block_q, block_k = _clamp_blocks_for_dim(block_q, block_k, d)
@@ -502,8 +630,13 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
             _flash_fwd_kernel, s_k=s_k, s_kp=s_kp, causal=causal,
             scale=scale, block_q=bq, block_k=bk,
             force_interior=(taxonomy == "interior"),
+            tile=_compute_tile(
+                bq, bk, "fwd", causal=causal, aligned=s_k == s_kp,
+                tile=tile,
+            ) if taxonomy == "split" else None,
         )
     grid = (b * h, s_qp // bq, s_kp // bk)
+    kv_index = _kv_index(bq, bk, causal and taxonomy != "legacy")
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
@@ -513,8 +646,8 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, kb: (i, kb, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, kb: (i, kb, 0)),
+            pl.BlockSpec((1, bk, d), kv_index),
+            pl.BlockSpec((1, bk, d), kv_index),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),
@@ -621,16 +754,45 @@ def _tail_mask(first_q, first_k, *, s_k, s_kp, causal, block_q, block_k,
     return mask
 
 
+def _kv_index(bq, bk, causal):
+    """K/V block index of grid point (i, j, kb), k innermost.  A dead
+    grid point (k block wholly above the diagonal) names the row's last
+    live block again: the pipeline sees an unchanged index and fetches
+    nothing, there and -- the next row starting at block 0 again -- at
+    the step after it, which a dead step's missing compute would leave
+    exposed (v5e, [48, 2048, 128]: dq 563 -> 499 us a call, forward
+    600 -> 572; PERF.md, PR 28).  The dk/dv kernel's dead points come
+    first in their sweep and hide behind the previous step already."""
+    if not causal:
+        return lambda i, j, kb: (i, kb, 0)
+    return lambda i, j, kb: (
+        i, jnp.minimum(kb, (j * bq + bq - 1) // bk), 0)
+
+
+def _piece_mask(first_q, first_k, tile, **geometry):
+    """Mask of a masked piece: the whole block's :func:`_tail_mask`, or
+    with a compute tile the t x t diagonal tile's -- the same causal
+    compare at offsets (0, 0), because an aligned diagonal block has
+    first_q == first_k and every tile on its diagonal the same again
+    (one mask serves them all)."""
+    if tile is None:
+        return _tail_mask(first_q, first_k, **geometry)
+    return _tail_mask(0, 0, s_k=tile, s_kp=tile, causal=True,
+                      block_q=tile, block_k=tile)
+
+
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_acc, *, s_q: int, s_qp: int,
                          s_k: int, s_kp: int, causal: bool, scale: float,
                          block_q: int, block_k: int,
-                         force_interior: bool = False):
+                         force_interior: bool = False, tile=None):
     """Diagonal-split dq kernel: interior blocks recompute p straight
     from the saved log-sum-exp with no iota/mask/select work; only the
     diagonal/tail blocks pay the masked path.  Same grid and numerics
     as the legacy kernel (on interior blocks the legacy mask is all-
-    true, so ``where(mask, p, 0)`` is the identity)."""
+    true, so ``where(mask, p, 0)`` is the identity).  With a compute
+    ``tile`` a masked block runs q strip by q strip against its live
+    keys only (:func:`_strips`)."""
     j = pl.program_id(1)
     kb = pl.program_id(2)
     n_kb = pl.num_programs(2)
@@ -648,31 +810,30 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     )
 
     def _accum(with_mask):
-        q = q_ref[0].astype(jnp.float32)
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
-        if with_mask:
-            mask = _tail_mask(
-                first_q, first_k, s_q=s_q, s_qp=s_qp, s_k=s_k,
-                s_kp=s_kp, causal=causal, block_q=block_q,
-                block_k=block_k,
+        mask = _piece_mask(
+            first_q, first_k, tile, s_q=s_q, s_qp=s_qp, s_k=s_k,
+            s_kp=s_kp, causal=causal, block_q=block_q, block_k=block_k,
+        ) if with_mask else None
+        for rows, cols, masked in _strips(block_q, tile, with_mask):
+            q = q_ref[0, rows, :].astype(jnp.float32)
+            k_blk = k_ref[0, cols, :].astype(jnp.float32)
+            v_blk = v_ref[0, cols, :].astype(jnp.float32)
+            do = do_ref[0, rows, :].astype(jnp.float32)
+            s = lax.dot_general(
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            p = jnp.exp(s - lse_ref[0, 0, rows][:, None])
+            p = _select(mask, p, 0.0, masked)
+            dp = lax.dot_general(
+                do, v_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            p = jnp.where(mask, p, 0.0)
-        dp = lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        dq_acc[:] += lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+            ds = p * (dp - delta_ref[0, 0, rows][:, None])
+            dq_acc[rows, :] += lax.dot_general(
+                ds, k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
 
     @_when(interior)
     def _fast():
@@ -753,10 +914,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, s_q: int,
                           s_qp: int, s_k: int, s_kp: int, causal: bool,
                           scale: float, block_q: int, block_k: int,
-                          force_interior: bool = False):
+                          force_interior: bool = False, tile=None):
     """Diagonal-split dk/dv kernel (grid (batch*head, k_blocks,
     q_blocks); q innermost/sequential) — same taxonomy routing as the
-    split dq kernel."""
+    split dq kernel, the same q strips with a compute ``tile`` (a strip
+    adds into the dk/dv rows of its live keys only)."""
     kb = pl.program_id(1)
     j = pl.program_id(2)
     n_j = pl.num_programs(2)
@@ -775,35 +937,34 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     )
 
     def _accum(with_mask):
-        q = q_ref[0].astype(jnp.float32)
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
-        if with_mask:
-            mask = _tail_mask(
-                first_q, first_k, s_q=s_q, s_qp=s_qp, s_k=s_k,
-                s_kp=s_kp, causal=causal, block_q=block_q,
-                block_k=block_k,
+        mask = _piece_mask(
+            first_q, first_k, tile, s_q=s_q, s_qp=s_qp, s_k=s_k,
+            s_kp=s_kp, causal=causal, block_q=block_q, block_k=block_k,
+        ) if with_mask else None
+        for rows, cols, masked in _strips(block_q, tile, with_mask):
+            q = q_ref[0, rows, :].astype(jnp.float32)
+            k_blk = k_ref[0, cols, :].astype(jnp.float32)
+            v_blk = v_ref[0, cols, :].astype(jnp.float32)
+            do = do_ref[0, rows, :].astype(jnp.float32)
+            s = lax.dot_general(
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+            p = jnp.exp(s - lse_ref[0, 0, rows][:, None])
+            p = _select(mask, p, 0.0, masked)
+            dv_acc[cols, :] += lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-            p = jnp.where(mask, p, 0.0)
-        dv_acc[:] += lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        dk_acc[:] += lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+            dp = lax.dot_general(
+                do, v_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            ds = p * (dp - delta_ref[0, 0, rows][:, None])
+            dk_acc[cols, :] += lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
 
     @_when(interior)
     def _fast():
@@ -822,10 +983,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "scale", "block_q", "block_k", "interpret",
-                     "taxonomy"),
+                     "taxonomy", "tile"),
 )
 def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
-                    interpret, taxonomy="split", g_lse=None):
+                    interpret, taxonomy="split", g_lse=None, tile=None):
     """(b, s, h, d)-layout backward via the two kernels above.
 
     ``g_lse``: optional (b*h, s_q) cotangent of the log-sum-exp output
@@ -878,16 +1039,21 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         dq_kernel, dkv_kernel = _flash_bwd_dq_kernel, _flash_bwd_dkv_kernel
         kwargs = dict(s_q=s_q, s_qp=s_qp, s_k=s_k, s_kp=s_kp,
                       causal=causal, scale=scale, block_q=bq, block_k=bk,
-                      force_interior=(taxonomy == "interior"))
+                      force_interior=(taxonomy == "interior"),
+                      tile=_compute_tile(
+                          bq, bk, "bwd", causal=causal, tile=tile,
+                          aligned=s_k == s_kp and s_q == s_qp,
+                      ) if taxonomy == "split" else None)
 
+    kv_index = _kv_index(bq, bk, causal and taxonomy != "legacy")
     dq = pl.pallas_call(
         functools.partial(dq_kernel, **kwargs),
         out_shape=_out_struct((b * h, s_qp, d), q.dtype, q, k, v, g),
         grid=(b * h, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),   # q
-            pl.BlockSpec((1, bk, d), lambda i, j, kb: (i, kb, 0)),  # k
-            pl.BlockSpec((1, bk, d), lambda i, j, kb: (i, kb, 0)),  # v
+            pl.BlockSpec((1, bk, d), kv_index),                     # k
+            pl.BlockSpec((1, bk, d), kv_index),                     # v
             pl.BlockSpec((1, bq, d), lambda i, j, kb: (i, j, 0)),   # do
             pl.BlockSpec((1, 8, bq), lambda i, j, kb: (i, 0, j)),   # lse
             pl.BlockSpec((1, 8, bq), lambda i, j, kb: (i, 0, j)),   # delta
@@ -969,7 +1135,22 @@ def flash_attention(q, k, v, causal=False, scale=None,
     ``"interior"`` is TIMING ONLY for the segment-anatomy bench (forces
     every live block down the unmasked fast branch; numerically wrong
     for causal/ragged inputs).  Split and legacy are bit-identical
-    (``test_split_matches_legacy_exactly``).
+    (``test_split_matches_legacy_exactly``) wherever the compute tile
+    does not engage.
+
+    Compute tile (no argument: :func:`_compute_tile` resolves it from
+    the launch's shapes): a causal launch whose diagonal blocks are
+    square, aligned and at least two tiles wide computes them in q
+    strips against their live keys only, masking the one tile a strip
+    has on the diagonal -- tile 256 in the backward kernels, 512 in the
+    forward.  At seq 2048 with the default blocks a program then
+    executes 2.25 (forward 2.5) block-units instead of 3 and masks 0.5
+    (1) instead of 2; at seq 8192, 33 (34) instead of 36
+    (:func:`launch_census` reports it).  Measured on v5e at
+    ``[48, 2048, 128]``: forward, dq and dk/dv together -20.9 %
+    (PERF.md, PR 28).  Same result up to fp32 summation order inside a
+    diagonal block; every other launch runs exactly the code it ran
+    before.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5

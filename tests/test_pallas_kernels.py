@@ -202,21 +202,33 @@ class TestFlashAttention:
         )
 
 
-def _brute_census(s_q, s_k, bq, bk, causal, kind):
+def _brute_census(s_q, s_k, bq, bk, causal, kind, tile=None):
     """Oracle block classification from the literal padded mask matrix
     (the kernels classify from corner predicates; this classifies every
-    element and must agree)."""
+    element and must agree) -- and, where the compute tile engages
+    (restated here, not imported: causal, square blocks, no padding on
+    an axis the kernel masks, at least two tiles a block), every
+    ``tile`` x ``tile`` tile of the masked blocks the same way."""
     def up(x, m):
         return (x + m - 1) // m * m
 
+    if tile is None:  # the shipped defaults (measured: PERF.md, PR 28)
+        tile = {"fwd": 512, "bwd": 256}[kind]
     s_qp, s_kp = up(s_q, bq), up(s_k, bk)
     qi = np.arange(s_qp)[:, None]
     kj = np.arange(s_kp)[None, :]
     valid = np.broadcast_to(kj < s_k, (s_qp, s_kp))  # fwd masks only k
     if kind == "bwd":
         valid = valid & (qi < s_q)
+    live = valid & (kj <= qi) if causal else valid
+    engaged = (causal and bq == bk and s_k == s_kp
+               and (kind == "fwd" or s_q == s_qp)
+               and bq % tile == 0 and bq >= 2 * tile)
     census = {"dead": 0, "interior": 0, "masked": 0,
-              "n_q_blocks": s_qp // bq, "n_k_blocks": s_kp // bk}
+              "n_q_blocks": s_qp // bq, "n_k_blocks": s_kp // bk,
+              "tile": tile if engaged else None, "tiles_executed": 0,
+              "tiles_masked": 0, "tiles_skipped": 0,
+              "executed_units": 0.0, "masked_units": 0.0}
     for j in range(s_qp // bq):
         for kb in range(s_kp // bk):
             sl = (slice(j * bq, (j + 1) * bq),
@@ -228,8 +240,26 @@ def _brute_census(s_q, s_k, bq, bk, causal, kind):
                 census["dead"] += 1
             elif c_ok.all() and valid[sl].all():
                 census["interior"] += 1
+                census["executed_units"] += 1.0
             else:
                 census["masked"] += 1
+                if not engaged:
+                    census["executed_units"] += 1.0
+                    census["masked_units"] += 1.0
+                    continue
+                blk, n = live[sl], bq // tile
+                for r in range(n):
+                    for c in range(n):
+                        t = blk[r * tile:(r + 1) * tile,
+                                c * tile:(c + 1) * tile]
+                        if not t.any():
+                            census["tiles_skipped"] += 1
+                            continue
+                        census["tiles_executed"] += 1
+                        census["executed_units"] += 1.0 / n ** 2
+                        if not t.all():
+                            census["tiles_masked"] += 1
+                            census["masked_units"] += 1.0 / n ** 2
     return census
 
 
@@ -258,24 +288,78 @@ class TestDiagonalSplit:
         assert block_census(s_q, s_k, bq, bk, causal, kind=kind) == \
             _brute_census(s_q, s_k, bq, bk, causal, kind)
 
+    @pytest.mark.parametrize("kind", ["fwd", "bwd"])
+    @pytest.mark.parametrize("s_q,s_k,bq,bk,causal,tile", [
+        (32, 32, 16, 16, True, 8),      # 2 tiles a block
+        (64, 64, 32, 32, True, 8),      # 4
+        (192, 192, 64, 64, True, 8),    # 8, three diagonal blocks
+        (2048, 2048, 1024, 1024, True, 256),   # the cells' geometry
+        (2048, 2048, 1024, 1024, True, 128),
+        (8192, 8192, 1024, 1024, True, 256),
+        (32, 32, 16, 16, False, 8),     # the rule leaves these alone:
+        (23, 23, 16, 16, True, 8),      # ragged tails
+        (40, 32, 16, 16, True, 8),      # ragged q only: fwd engages
+        (48, 48, 8, 16, True, 4),       # bq != bk
+        (32, 32, 16, 16, True, 16),     # one tile a block
+        (48, 48, 24, 24, True, 16),     # tile does not divide the block
+    ])
+    def test_tile_census_matches_brute_force(self, kind, s_q, s_k, bq, bk,
+                                             causal, tile):
+        """The compute tile's counters (tiles executed / masked /
+        skipped inside the masked blocks, block-units of work and of
+        masked work) against an element-by-element count."""
+        from chainermn_tpu.ops.pallas_attention import block_census
+
+        got = block_census(s_q, s_k, bq, bk, causal, kind=kind, tile=tile)
+        want = _brute_census(s_q, s_k, bq, bk, causal, kind, tile)
+        units = {"executed_units", "masked_units"}
+        assert {k: v for k, v in got.items() if k not in units} == \
+            {k: v for k, v in want.items() if k not in units}
+        for k in units:
+            assert got[k] == pytest.approx(want[k])
+        n = (bq // tile) ** 2
+        if got["tile"] is not None:
+            assert got["tiles_executed"] + got["tiles_skipped"] == \
+                got["masked"] * n
+
     def test_census_shipping_geometries(self):
         """The numbers the perf doc's anatomy section quotes: block
         counts per (batch*head) program at the shipped configs."""
         from chainermn_tpu.ops.pallas_attention import block_census
 
-        # seq 2048, bwd 1024x1024: 1 of 3 live blocks interior
+        # seq 2048, bwd 1024x1024 (the LM cells): 1 of 3 live blocks
+        # interior; the two diagonal blocks run 10 of their 16 tiles of
+        # 256 and mask 4: 2.25 of 3 block-units executed, 0.5 masked
         c = block_census(2048, 2048, 1024, 1024, True, kind="bwd")
         assert c == {"dead": 1, "interior": 1, "masked": 2,
-                     "n_q_blocks": 2, "n_k_blocks": 2}
+                     "n_q_blocks": 2, "n_k_blocks": 2, "tile": 256,
+                     "tiles_executed": 20, "tiles_masked": 8,
+                     "tiles_skipped": 12, "executed_units": 2.25,
+                     "masked_units": 0.5}
+        # ... and the cells' forward, whose tile is 512: 3 of 4 tiles a
+        # diagonal block, 2 of them masked: 2.5 of 3 units, 1 masked
+        c = block_census(2048, 2048, 1024, 1024, True)
+        assert c == {"dead": 1, "interior": 1, "masked": 2,
+                     "n_q_blocks": 2, "n_k_blocks": 2, "tile": 512,
+                     "tiles_executed": 6, "tiles_masked": 4,
+                     "tiles_skipped": 2, "executed_units": 2.5,
+                     "masked_units": 1.0}
+        c = block_census(2048, 2048, 1024, 1024, True, tile=128)
+        assert (c["executed_units"], c["masked_units"]) == (2.125, 0.25)
         # seq 2048, fwd 1024x2048 (the r5 split geometry): every live
         # block straddles the diagonal — the split buys the forward
         # nothing at this geometry (the anatomy rungs A/B it against
         # 1024x1024, where 1 of 3 live blocks goes fast-path)
         c = block_census(2048, 2048, 1024, 2048, True)
         assert c["interior"] == 0 and c["masked"] == 2
-        # seq 8192, 1024^2: 28 of 36 live blocks interior (78%)
+        assert c["tile"] is None and c["executed_units"] == 2.0
+        # seq 8192, 1024^2: 28 of 36 live blocks interior (78%); the
+        # compute tile executes 33 units of the 36
         c = block_census(8192, 8192, 1024, 1024, True)
         assert (c["dead"], c["interior"], c["masked"]) == (28, 28, 8)
+        assert (c["executed_units"], c["masked_units"]) == (34.0, 4.0)
+        c = block_census(8192, 8192, 1024, 1024, True, kind="bwd")
+        assert (c["executed_units"], c["masked_units"]) == (33.0, 2.0)
         # seq 16384: 120 of 136 live blocks interior (88%)
         c = block_census(16384, 16384, 1024, 1024, True)
         assert (c["dead"], c["interior"], c["masked"]) == (120, 120, 16)
@@ -438,6 +522,179 @@ class TestDiagonalSplit:
         with pytest.raises(ValueError, match="taxonomy"):
             flash_attention(q, k, v, True, None, 8, 8, True, None, None,
                             "diagonalize")
+
+
+class TestComputeTile:
+    """The compute tile inside the diagonal blocks (PR 28): reached at
+    tiny interpret-mode shapes through the private entry points' static
+    ``tile`` argument (the public API resolves it from shapes alone)."""
+
+    TILE = 8
+
+    @staticmethod
+    def _inputs(s_q, s_k, dtype, seed=13):
+        rng = np.random.RandomState(seed)
+
+        def mk(s):
+            return (jnp.asarray(rng.randn(2, s, 2, 8), jnp.float32)
+                    * 0.3).astype(dtype)
+
+        q, k, v, g = mk(s_q), mk(s_k), mk(s_k), mk(s_q)
+        g_lse = jnp.asarray(rng.randn(2 * 2, s_q), jnp.float32) * 0.3
+        return q, k, v, g, g_lse
+
+    @staticmethod
+    def _run(q, k, v, g, g_lse, causal, bq, bk, taxonomy, tile):
+        """(out, lse, dq, dk, dv) through the two private entry points,
+        the lse cotangent folded in as ``flash_attention_with_lse``
+        does."""
+        from chainermn_tpu.ops import pallas_attention as pa
+
+        scale = q.shape[-1] ** -0.5
+        out, lse = pa._flash_forward(q, k, v, causal, scale, bq, bk, True,
+                                     taxonomy, tile=tile)
+        grads = pa._flash_backward(q, k, v, out, lse, g, causal, scale,
+                                   bq, bk, True, taxonomy, g_lse=g_lse,
+                                   tile=tile)
+        return (out, lse) + tuple(grads)
+
+    @staticmethod
+    def _dense(q, k, v, g, g_lse, causal):
+        from chainermn_tpu.ops.pallas_attention import (
+            _dense_attention_with_lse,
+        )
+
+        q, k, v, g = (t.astype(jnp.float32) for t in (q, k, v, g))
+        b, s_q, h, d = q.shape
+        (out, lse), vjp = jax.vjp(
+            lambda q, k, v: _dense_attention_with_lse(
+                q, k, v, causal, d ** -0.5),
+            q, k, v,
+        )
+        g_l = jnp.moveaxis(g_lse.reshape(b, h, s_q), 1, 2)  # (b, s_q, h)
+        lse_bh = jnp.moveaxis(lse, 2, 1).reshape(b * h, s_q)
+        return (out, lse_bh) + tuple(vjp((g, g_l)))
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("n_tiles", [2, 4, 8])
+    def test_tiled_matches_oracle_and_legacy(self, n_tiles, dtype):
+        """Forward, lse, dq, dk, dv with the diagonal blocks computed in
+        2, 4 and 8 tiles a side, against the dense oracle and against
+        the legacy kernels: tiles above the diagonal contributed exact
+        zeros, so only fp32 summation order inside a diagonal block may
+        differ -- float32 tolerance, not bit equality."""
+        from chainermn_tpu.ops.pallas_attention import block_census
+
+        blk = self.TILE * n_tiles
+        s = 3 * blk  # three diagonal, three interior, three dead blocks
+        c = block_census(s, s, blk, blk, True, kind="bwd", tile=self.TILE)
+        assert c["tile"] == self.TILE and c["interior"] == 3
+        assert c["tiles_masked"] == 3 * n_tiles
+        assert c["tiles_skipped"] == 3 * n_tiles * (n_tiles - 1) // 2
+        args = self._inputs(s, s, dtype)
+        tiled = self._run(*args, True, blk, blk, "split", self.TILE)
+        legacy = self._run(*args, True, blk, blk, "legacy", None)
+        dense = self._dense(*args, True)
+        names = ("out", "lse", "dq", "dk", "dv")
+        exact = dtype == jnp.float32
+        for name, t, l, w in zip(names, tiled, legacy, dense):
+            assert t.dtype == l.dtype
+            t, l, w = (np.asarray(x, np.float32) for x in (t, l, w))
+            assert np.isfinite(t).all(), name
+            # against legacy: same operands, same precision
+            np.testing.assert_allclose(
+                t, l, err_msg=name,
+                **(dict(rtol=2e-5, atol=2e-6) if exact or name == "lse"
+                   else dict(rtol=2e-2, atol=2e-3)))
+            # against the dense float32 oracle
+            np.testing.assert_allclose(
+                t, w, err_msg=name,
+                **(dict(rtol=2e-3, atol=2e-4) if exact
+                   else dict(rtol=1e-1, atol=5e-2)))
+
+    def test_tile_off_is_legacy_bit_for_bit(self):
+        """With the tile off (a tile as wide as the block) the split
+        kernels are the legacy ones bit for bit on the same launch -- so
+        the tolerance above is the tile's doing and nothing else's."""
+        args = self._inputs(32, 32, jnp.float32)
+        whole = self._run(*args, True, 16, 16, "split", 16)
+        legacy = self._run(*args, True, 16, 16, "legacy", None)
+        for a, b in zip(whole, legacy):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("s_q,s_k,bq,bk,causal", [
+        (32, 32, 16, 16, False),   # non-causal
+        (48, 48, 16, 8, True),     # bq != bk
+        (40, 40, 8, 32, True),
+        (23, 23, 16, 16, True),    # ragged q and k tails
+        (16, 40, 16, 16, False),   # cross-attention lengths
+        (24, 17, 24, 16, False),   # ... ragged k
+        (32, 32, 8, 8, True),      # a block is one tile wide
+    ])
+    def test_rule_leaves_other_launches_bit_identical(self, s_q, s_k, bq,
+                                                      bk, causal):
+        """Launches the rule does not cover run exactly today's code
+        even when a tile is asked for: bit-identical to legacy, values,
+        lse and all three gradients."""
+        from chainermn_tpu.ops.pallas_attention import block_census
+
+        for kind in ("fwd", "bwd"):
+            assert block_census(s_q, s_k, bq, bk, causal, kind=kind,
+                                tile=self.TILE)["tile"] is None
+        args = self._inputs(s_q, s_k, jnp.float32)
+        asked = self._run(*args, causal, bq, bk, "split", self.TILE)
+        legacy = self._run(*args, causal, bq, bk, "legacy", None)
+        for a, b in zip(asked, legacy):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_rule_table(self):
+        from chainermn_tpu.ops.pallas_attention import _compute_tile
+
+        on = dict(causal=True, aligned=True)
+        assert _compute_tile(1024, 1024, "bwd", **on) == 256  # defaults
+        assert _compute_tile(1024, 1024, "fwd", **on) == 512
+        assert _compute_tile(512, 512, "bwd", **on) == 256
+        assert _compute_tile(512, 512, "fwd", **on) is None   # one tile
+        assert _compute_tile(256, 256, "bwd", **on) is None
+        assert _compute_tile(128, 128, "bwd", **on) is None
+        assert _compute_tile(1024, 2048, "bwd", **on) is None  # not square
+        assert _compute_tile(1024, 1024, "bwd", causal=False,
+                             aligned=True) is None
+        assert _compute_tile(1024, 1024, "bwd", causal=True,
+                             aligned=False) is None
+        assert _compute_tile(1024, 1024, "fwd", tile=128, **on) == 128
+        assert _compute_tile(640, 640, "bwd", **on) is None  # 256 divides not
+
+    def test_public_api_resolves_the_tile_from_shapes(self):
+        """No public argument: ``flash_attention`` at 512 blocks and
+        seq 1024 engages the backward's default tile by itself (the
+        census says so) and agrees with legacy to float32 tolerance,
+        values and gradients."""
+        from chainermn_tpu.ops.pallas_attention import launch_census
+
+        c = launch_census(1024, 1024, 8, 512, 512, interpret=True)
+        assert c["fwd"]["tile"] is None and c["bwd"]["tile"] == 256
+        assert c["bwd"]["executed_units"] == 1 + 2 * 0.75
+        c = launch_census(2048, 2048, 8, interpret=True)
+        assert c["fwd"]["tile"] == 512 and c["bwd"]["tile"] == 256
+        rng = np.random.RandomState(5)
+        q, k, v = (jnp.asarray(rng.randn(1, 1024, 1, 8), jnp.float32) * 0.3
+                   for _ in range(3))
+
+        def run(tax):
+            def f(q, k, v):
+                return jnp.sum(flash_attention(
+                    q, k, v, True, None, 512, 512, True, None, None,
+                    tax) ** 2)
+
+            return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+
+        (l_s, g_s), (l_l, g_l) = run(None), run("legacy")
+        np.testing.assert_allclose(float(l_s), float(l_l), rtol=1e-5)
+        for a, b in zip(g_s, g_l):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-5, atol=2e-6)
 
 
 class TestFlashWithSequenceParallel:

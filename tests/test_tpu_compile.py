@@ -87,6 +87,9 @@ def _qkv(sharding, b, s, h, d):
                       bwd_block_q=1024, bwd_block_k=1024),
                  id="bench_geometry"),
     pytest.param((8, 2048, 8, 128), dict(taxonomy="legacy"), id="legacy"),
+    # the LM cells' own launch (Cerebras-GPT-590M, 4 x 2048 a chip):
+    # default blocks, so the diagonal blocks run in compute tiles
+    pytest.param((4, 2048, 12, 128), {}, id="cells_s2048_h12"),
 ])
 def test_flash_attention_compiles(one_chip, shape, kw, grad):
     flash = functools.partial(

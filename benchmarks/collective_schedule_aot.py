@@ -1,0 +1,170 @@
+#!/usr/bin/env python
+"""Where the dp4 cell's gradient all-reduces sit in the compiled step.
+
+Compiles the train step of ``cgpt590m_dp4_s2048`` (Cerebras-GPT-590M
+through ``build_train_step(param_specs=...)`` with the flash kernels, as
+``examples/lm/train_lm.py --flash`` builds it at ``--sp 1 --tp 1``)
+ahead of time for a *described* ``v5e:2x2`` and prints
+
+* the entry computation's schedule, a character an event (``S`` / ``D``
+  an asynchronous collective's start / done, ``R`` a synchronous
+  collective, ``m`` a matmul fusion, ``k`` a kernel, ``.`` any other
+  fusion; a step of an asynchronous collective reads ``m`` fused onto a
+  matmul, ``e`` onto elementwise compute, ``s`` alone), and
+* the census of the gradient reductions: synchronous against
+  asynchronous, and the asynchronous ones with compute between start
+  and done (``analysis.hlo.collective_schedule``; the same reading
+  ``step.collective_schedule`` gives on the chip).
+
+No chip: nothing runs and nothing is timed.  A compile that passes is
+not a chip run; what the chip makes of a schedule is ``PERF.md``'s to
+say.  The 18-layer step takes 40-130 s to compile here, ``--layers 3``
+about 25 s.
+
+Run:  python benchmarks/collective_schedule_aot.py [--layers 3]
+          [--chips 1] [--options '{"xla_...": "true"}'] [--hlo out.txt]
+
+``--options`` replaces the option set of the step builder's rule
+(``{}``: the program without the rule), to read a candidate before a
+chip is asked for.
+"""
+
+import argparse
+import contextlib
+import json
+import os
+import sys
+import time
+from unittest import mock
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
+
+sys.path.insert(
+    0, os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+)
+
+import jax
+import jax.numpy as jnp
+import optax
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
+
+
+@contextlib.contextmanager
+def one_process():
+    """Described devices have no backend to ask for the process count."""
+    with mock.patch.object(jax, "process_count", lambda backend=None: 1), \
+            mock.patch.object(jax, "process_index", lambda backend=None: 0):
+        yield
+
+
+def build_lm_step(devices, *, n_layers=18, d_model=1536, n_heads=12,
+                  vocab=50257, seq_len=2048, per_chip_batch=4):
+    """The LM cells' step over ``devices`` (described or attached) and
+    its abstract arguments ``(params, opt_state, batch)``, shardings on."""
+    import chainermn_tpu as cmn
+    from chainermn_tpu.functions import collectives as cc
+    from chainermn_tpu.models.transformer import TransformerLM, lm_loss
+    from chainermn_tpu.ops.pallas_attention import flash_attention_fn
+    from chainermn_tpu.parallel import megatron_param_specs
+
+    comm = cmn.create_communicator(
+        "mesh", devices=list(devices), sp_size=1, tp_size=1)
+    mesh = comm.mesh
+    model = TransformerLM(
+        vocab_size=vocab, d_model=d_model, n_heads=n_heads,
+        n_layers=n_layers, max_len=seq_len, dropout_rate=0.0,
+        # interpret=False: the host's backend is the CPU, the target is not
+        attention_fn=flash_attention_fn(interpret=False),
+    )
+    batch_spec = P("mn_data", "mn_seq")
+    rows = per_chip_batch * len(devices)
+    tokens = jax.ShapeDtypeStruct((rows, seq_len), jnp.int32)
+    params = jax.eval_shape(
+        jax.shard_map(
+            lambda t: model.init({"params": jax.random.PRNGKey(0),
+                                  "dropout": jax.random.PRNGKey(1)}, t),
+            mesh=mesh, in_specs=(batch_spec,), out_specs=P(),
+            check_vma=False),
+        tokens)
+    specs = megatron_param_specs(params, model_axis="mn_model")
+    opt = cmn.create_multi_node_optimizer(
+        optax.adamw(1e-3, weight_decay=0.01), comm)
+
+    def loss_fn(p, b):
+        loss = lm_loss(
+            model.apply(p, b, rngs={"dropout": jax.random.PRNGKey(0)}), b)
+        for axis in (comm.seq_axis_name, comm.model_axis_name):
+            loss = cc.pmean(loss, axis)  # width 1: certifies replication
+        return loss
+
+    step = cmn.build_train_step(
+        comm, loss_fn, opt, data_axes=comm.data_axis_names,
+        param_specs=specs, batch_specs=batch_spec)
+
+    def placed(tree, spec_tree):
+        return jax.tree_util.tree_map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
+            tree, spec_tree)
+
+    opt_state = jax.eval_shape(opt.init, params)
+    state_specs = optax.tree_map_params(
+        opt, lambda _leaf, spec: spec, opt_state, specs,
+        transform_non_params=lambda _leaf: P())
+    return step, (placed(params, specs), placed(opt_state, state_specs),
+                  placed(tokens, batch_spec))
+
+
+def main(argv=None):
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--layers", type=int, default=18)
+    p.add_argument("--chips", type=int, default=4, choices=(1, 2, 4))
+    p.add_argument("--options", type=json.loads, default=None,
+                   help="JSON object: replaces the builder's option set")
+    p.add_argument("--hlo", default="", help="write the program text here")
+    args = p.parse_args(argv)
+
+    from jax.experimental import topologies
+
+    from chainermn_tpu import optimizers
+    from chainermn_tpu.analysis.hlo import (
+        WEIGHT_GRADIENT_BYTES,
+        collective_schedule,
+    )
+
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    patch = contextlib.nullcontext() if args.options is None else \
+        mock.patch.object(optimizers, "_ASYNC_GRAD_REDUCE_OPTIONS",
+                          args.options)
+    with one_process(), patch:
+        step, abstract = build_lm_step(
+            topo.devices[:args.chips], n_layers=args.layers)
+        t0 = time.perf_counter()
+        compiled = step.get_jitted(*abstract[:2]).lower(*abstract).compile()
+    text = compiled.as_text()
+    if args.hlo:
+        with open(args.hlo, "w") as f:
+            f.write(text)
+    schedule = collective_schedule(text)
+    memory = compiled.memory_analysis()
+    print(schedule.condensed)
+    for op in schedule.ops:
+        if op.nbytes >= WEIGHT_GRADIENT_BYTES:
+            print(f"  {op.cls:18s} {op.nbytes / 1e6:8.1f} MB  "
+                  f"{'async' if op.asynchronous else 'sync ':5s} "
+                  f"compute inside {op.compute_inside:2d}  "
+                  f"{(op.op_name or '')[-64:]}")
+    print(json.dumps({
+        "layers": args.layers, "chips": args.chips,
+        "compile_s": round(time.perf_counter() - t0, 1),
+        "argument_gb": memory.argument_size_in_bytes / 1e9,
+        "temp_gb": memory.temp_size_in_bytes / 1e9,
+        "census_weight_gradients": schedule.census(WEIGHT_GRADIENT_BYTES),
+        "census_all": schedule.census(),
+    }))
+
+
+if __name__ == "__main__":
+    main()

@@ -50,6 +50,13 @@ LM_ARGV = LM_WIDTH_ARGV + [
 LM_SP_TP_ARGV = LM_WIDTH_ARGV + [
     "--sp", "2", "--tp", "2", "--steps", "2", "--generate", "0",
 ]
+LM_DP_ARGV = LM_WIDTH_ARGV + ["--steps", "2", "--generate", "0"]
+#: of the LM step's weight-gradient all-reduce bytes over four chips, the
+#: share that is asynchronous with a matmul inside: the ahead-of-time
+#: compile at LM_WIDTH_ARGV reads 17 of 34, 0.2195 (the cell's 18-layer
+#: step 44 of 74, 0.361); the embedding's float32 sum, half the bytes
+#: here, blocks
+LM_DP_OVERLAPPED_SHARE = 0.21
 
 
 def check(cond, msg):
@@ -440,12 +447,39 @@ def phase_dp(device, argv=RESNET_ARGV):
         check(runs["none"] == runs["bucket"],
               f"overlap='bucket' losses {runs['bucket']} differ from the "
               f"synchronous {runs['none']}")
+        lm = _lm_dp_schedule(devices)
         report("dp", device, clock, communicator=name, placement=placement,
                losses_n_chips=losses_n, losses_one_chip=losses_1,
                first_step_rel_diff=first, n_buckets=plan.n_buckets,
                bn_statistic_leaves=n_stats,
                all_reduce_census=census, overlap_losses=runs["bucket"],
-               sync_losses=runs["none"])
+               sync_losses=runs["none"], **lm)
+
+
+def _lm_dp_schedule(devices, argv=LM_DP_ARGV):
+    """The LM's ``param_specs`` step, data-parallel over every chip: its
+    gradient all-reduces are autodiff's, one a leaf, and the step builder
+    compiles those that can ride a weight-gradient matmul asynchronous.
+    Read from the program that ran."""
+    from chainermn_tpu.analysis.hlo import WEIGHT_GRADIENT_BYTES
+
+    out = load_example("lm/train_lm.py").main(argv)
+    losses = out["losses"]
+    check(all_finite(losses) and losses[-1] < losses[0],
+          f"LM loss over {len(devices)} chips: {losses}")
+    check(out["comm"].dp_size == len(devices),
+          f"the LM's mesh is dp={out['comm'].dp_size}")
+    schedule = out["step"].collective_schedule(
+        out["params"], out["opt_state"], out["batch"])
+    grads = schedule.census(min_bytes=WEIGHT_GRADIENT_BYTES)
+    check(grads["n_async"] == grads["n_overlapped"]
+          and grads["overlapped_bytes_share"] >= LM_DP_OVERLAPPED_SHARE,
+          f"weight-gradient all-reduces asynchronous with compute inside: "
+          f"under {LM_DP_OVERLAPPED_SHARE} of the bytes, or one without: "
+          f"{grads}\n{schedule.condensed}")
+    return {"lm_dp_losses": losses,
+            "lm_dp_weight_gradient_reductions": grads,
+            "lm_dp_schedule": schedule.condensed}
 
 
 def phase_sp_tp(device, argv=LM_SP_TP_ARGV):

@@ -911,3 +911,86 @@ class TestHloCensus:
             assert_census_agreement(
                 tr, '"stablehlo.all_reduce" "stablehlo.all_reduce"'
             )
+
+
+# ----------------------------------------------------------------------
+# scheduled-program census: asynchronous against synchronous collectives
+# ----------------------------------------------------------------------
+class TestCollectiveSchedule:
+    """``collective_schedule`` on a recorded piece of the LM step as the
+    TPU compiler scheduled it for a v5e 2x2 (``tests/data/``; lines cut
+    to the instructions the reader looks at): a glued synchronous tuple,
+    an asynchronous collective fusion with a kernel and a matmul-fused
+    step inside, one with only a plain elementwise fusion inside, one
+    whose step rides an optimizer fusion, and an ``-start`` / ``-done``
+    pair around a matmul fusion."""
+
+    @pytest.fixture(scope="class")
+    def schedule(self):
+        import os
+
+        from chainermn_tpu.analysis.hlo import collective_schedule
+
+        path = os.path.join(os.path.dirname(__file__), "data",
+                            "scheduled_hlo_v5e.txt")
+        with open(path) as f:
+            return collective_schedule(f.read())
+
+    def test_condensed_schedule(self, schedule):
+        assert schedule.condensed == "RmSkmDS.DSeDSmD"
+
+    @pytest.mark.parametrize("index,cls,nbytes,asynchronous,inside", [
+        # three bf16 weight gradients glued into one blocking all-reduce
+        (0, "all_reduce", 2 * (2 * 1536 * 6144 + 1536 * 4608), False, 0),
+        # start ... kernel, step fused onto a matmul ... done
+        (1, "all_reduce", 2 * 6144 * 1536, True, 2),
+        # asynchronous in form, no step between start and done
+        (2, "all_reduce", 2 * 6144 * 1536, True, 0),
+        # its step fused onto an AdamW update of other leaves
+        (3, "all_reduce", 2 * 6144 * 1536, True, 1),
+        # XLA's own start / done pair (a ring step), a matmul inside
+        (4, "collective_permute", 4 * 256 * 128, True, 1),
+    ], ids=["sync_tuple", "async_overlapped", "async_bare",
+            "async_on_optimizer", "start_done"])
+    def test_each_collective(self, schedule, index, cls, nbytes,
+                             asynchronous, inside):
+        op = schedule.ops[index]
+        assert (op.cls, op.nbytes, op.asynchronous, op.compute_inside) == (
+            cls, nbytes, asynchronous, inside)
+
+    def test_op_name_is_the_issuing_equation(self, schedule):
+        assert schedule.ops[1].op_name.endswith(
+            "TransformerBlock_2/MlpBlock_0/Dense_1/psum_invariant")
+
+    def test_census_shares(self, schedule):
+        big = schedule.census(min_bytes=1 << 20)  # leaves the ring step out
+        assert (big["n_sync"], big["n_async"], big["n_overlapped"]) == (
+            1, 3, 2)
+        total = big["sync_bytes"] + big["async_bytes"]
+        assert big["async_bytes_share"] == pytest.approx(
+            3 * 18874368 / total)
+        assert big["overlapped_bytes_share"] == pytest.approx(
+            2 * 18874368 / total)
+        assert schedule.census()["n_async"] == 4
+
+    def test_unscheduled_or_empty_text(self):
+        from chainermn_tpu.analysis.hlo import collective_schedule
+
+        empty = collective_schedule("")
+        assert empty.ops == () and empty.condensed == ""
+        assert empty.census()["overlapped_bytes_share"] == 0.0
+
+    def test_cpu_step_exposes_its_schedule(self, comm):
+        """``step.collective_schedule`` compiles and reads the step it
+        would dispatch; on the CPU mesh the gradient sum is blocking."""
+        opt = cmn.create_multi_node_optimizer(optax.sgd(0.1), comm)
+        params = {"w": jnp.zeros((4,))}
+
+        def loss(p, b):
+            return 0.5 * jnp.sum((p["w"] - b.mean(axis=0)) ** 2)
+
+        step = build_train_step(comm, loss, opt, donate=False)
+        p, o = step.place(params, opt.init(params))
+        census = step.collective_schedule(p, o, jnp.zeros((8, 4))).census()
+        assert census["n_sync"] + census["n_async"] >= 1
+        assert census["overlapped_bytes_share"] == 0.0

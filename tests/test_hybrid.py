@@ -230,3 +230,75 @@ class TestHybridCommunicator:
         want = np.asarray(x).mean(axis=0)
         for r in range(sub.size):
             np.testing.assert_allclose(out[r], want, rtol=1e-6)
+
+
+class TestGradReduceCompilerOptions:
+    """The step builder's compile options are for TPU meshes whose data
+    axes span chips: every CPU program compiles with none (XLA:CPU
+    rejects ``xla_tpu_*``), whichever body ``_finish_build`` jits."""
+
+    @pytest.mark.parametrize("body", ["param_specs", "wire", "bucket"])
+    def test_cpu_mesh_jit_receives_no_options(
+        self, hybrid_comm, devices8, monkeypatch, body
+    ):
+        seen = []
+        real_jit = jax.jit
+
+        def spy(*args, **kwargs):
+            seen.append(kwargs.get("compiler_options"))
+            return real_jit(*args, **kwargs)
+
+        monkeypatch.setattr(jax, "jit", spy)
+        if body == "param_specs":
+            comm, model = hybrid_comm, TpMLP()
+            params = _tp_params_from_dense(_dense_params())
+            opt = cmn.create_multi_node_optimizer(optax.adam(0.05), comm)
+            step = build_train_step(
+                comm, _mse_loss(model), opt,
+                data_axes=comm.data_axis_names,
+                param_specs=megatron_param_specs(
+                    params, model_axis="mn_model"),
+                donate=False,
+            )
+        else:
+            comm = cmn.create_communicator("tpu", devices=devices8)
+            model, params = DenseMLP(), _dense_params()
+            opt = cmn.create_multi_node_optimizer(
+                optax.adam(0.05), comm,
+                overlap="bucket" if body == "bucket" else "none")
+            step = build_train_step(comm, _mse_loss(model), opt,
+                                    donate=False)
+        params, opt_state = step.place(params, opt.init(params))
+        seen.clear()  # place() and init may jit helpers of their own
+        _, _, metrics = step(params, opt_state, step.place_batch(_batch()))
+        assert np.isfinite(float(metrics["loss"]))
+        assert seen and all(options is None for options in seen)
+
+    @pytest.mark.parametrize("platform,dp,tp,expect_options", [
+        ("cpu", 4, 1, False),  # a CPU mesh never gets options
+        ("tpu", 1, 1, False),  # one chip: the parent's program
+        ("tpu", 4, 1, True),   # gradients summed across every TPU chip
+        ("tpu", 2, 2, False),  # dp x tp: the model axis' all-reduces sit
+        ("tpu", 1, 4, False),  #   on the critical path; never read or timed
+    ])
+    def test_rule_reads_platform_and_reduced_extent(
+        self, platform, dp, tp, expect_options
+    ):
+        from types import SimpleNamespace
+
+        from chainermn_tpu.optimizers import (
+            _ASYNC_GRAD_REDUCE_OPTIONS,
+            _grad_reduce_compiler_options,
+        )
+
+        device = SimpleNamespace(platform=platform)
+        mesh = SimpleNamespace(
+            devices=np.array([device] * (dp * tp), dtype=object).reshape(
+                dp, 1, tp),
+            shape={"mn_data": dp, "mn_seq": 1, "mn_model": tp},
+        )
+        got = _grad_reduce_compiler_options(mesh, ("mn_data", "mn_seq"))
+        assert got == (_ASYNC_GRAD_REDUCE_OPTIONS if expect_options
+                       else None)
+        if expect_options:
+            assert got is not _ASYNC_GRAD_REDUCE_OPTIONS  # a copy

@@ -159,3 +159,75 @@ def test_ring_flash_compiles_on_four_chip_mesh(topo, grad):
     )
     assert "tpu_custom_call" in text
     assert "collective-permute" in text  # the ring itself
+
+
+# ----------------------------------------------------------------------
+# The data-parallel LM step (build_train_step(param_specs=...), flash
+# kernels in): where the compiler puts the gradient all-reduces.
+# ----------------------------------------------------------------------
+#: a cut-down LM: the weight gradients are 0.5-2 MiB in bf16
+_SMALL_LM = dict(n_layers=2, d_model=512, n_heads=4, vocab=4096,
+                 seq_len=1024, per_chip_batch=2)
+#: collectives under this size are gains, biases and the loss
+_WEIGHT_BYTES = 1 << 19
+
+
+@pytest.fixture(scope="module")
+def lm_step_builder(topo):
+    """``build(chips, **sizes) -> (step, abstract args)`` over described
+    devices:
+    ``benchmarks/collective_schedule_aot.py``'s builder at small sizes."""
+    import importlib.util
+    from contextlib import ExitStack
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks",
+        "collective_schedule_aot.py")
+    spec = importlib.util.spec_from_file_location(
+        "collective_schedule_aot", path)
+    aot = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(aot)
+    with ExitStack() as stack:
+        stack.enter_context(aot.one_process())
+        yield lambda chips, **sizes: aot.build_lm_step(
+            topo.devices[:chips], **{**_SMALL_LM, **sizes})
+
+
+def test_dp_step_reduces_weight_gradients_asynchronously(lm_step_builder):
+    step, abstract = lm_step_builder(4)
+    schedule = step.collective_schedule(*abstract)
+    census = schedule.census(min_bytes=_WEIGHT_BYTES)
+    # one all-reduce a leaf, none glued: 4 kernels a layer, 2 embeddings
+    assert census["n_sync"] + census["n_async"] == 10, schedule.condensed
+    # What the option set delivers, no more: the all-reduces that find a
+    # weight-gradient matmul to ride are asynchronous (7 of the 10 here,
+    # 5/11 of the bytes; 44 of 74 and 0.361 of the bytes in the cell's
+    # 18-layer step, benchmarks/collective_schedule_aot.py), each with
+    # that matmul between start and done; the rest, the float32
+    # embedding's among them, still block.  The issue asked for half the
+    # bytes: not reached, and this holds the line at what is.
+    assert census["n_async"] >= 7, schedule.condensed
+    assert census["n_overlapped"] == census["n_async"], schedule.condensed
+    assert census["overlapped_bytes_share"] >= 0.45, schedule.condensed
+    # and where: every start sits behind the last backward kernel (the
+    # compiler defers the weight-gradient matmuls to pair them), not
+    # inside the backward
+    assert schedule.condensed.index("S") > schedule.condensed.rindex("k")
+    assert "k" in schedule.condensed  # the flash kernels are in
+
+
+def test_one_chip_step_is_the_program_without_the_rule(
+        lm_step_builder, monkeypatch):
+    from chainermn_tpu import optimizers
+
+    texts = []
+    for rule in (optimizers._grad_reduce_compiler_options,
+                 lambda mesh, axes: None):
+        monkeypatch.setattr(
+            optimizers, "_grad_reduce_compiler_options", rule)
+        # one call site for both: the program text records its stack
+        step, abstract = lm_step_builder(1, n_layers=1)
+        texts.append(step.get_jitted(*abstract[:2]).lower(
+            *abstract).compile().as_text())
+    assert texts[0] == texts[1]
+    assert "all-reduce" not in texts[0]

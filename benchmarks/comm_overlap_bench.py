@@ -1,16 +1,14 @@
 #!/usr/bin/env python
 """Exposed-communication + double-buffering A/Bs, measured.
 
-The scaling projection (docs/performance.md) rests on the premise that
-the gradient ``psum`` rides the backward window — i.e. the *exposed*
-cost of gradient sync is near zero.  And the double-buffering knob's
-single-chip effect straddled 1.0 across two driver captures (r02
-1.043x, r03 0.971x).  Both claims get numbers here, via the reference's
-DummyCommunicator methodology (SURVEY.md section 5.1): run the same
-training config with and without the exchange, subtract.
+How much of the gradient exchange is *exposed*, and what the
+double-buffering knob buys, via the reference's DummyCommunicator
+methodology (SURVEY.md section 5.1): run the same training config with
+and without the exchange, subtract.  A pre-cell script: no number of
+its is on the ledger; cell B1 (``ROADMAP.md`` Reach B1, the bucketed
+wire on four chips) decides what of it becomes a cell.
 
-Variants (each prints one JSON line; k steps in ONE jitted fori_loop,
-the round-3 noise-proof harness — benchmarks/resnet_mfu_loop.py):
+Variants (each prints one JSON line; k steps in ONE jitted fori_loop):
 
 Three rungs per config:
   *_sync   build_train_step over the real communicator (psum in program)
@@ -41,8 +39,7 @@ schedule).  Both legs run the bit-identical program; only the issue
 order of the bucket psums moves, so the ratio isolates pure
 scheduling.  On the CPU mesh the collectives share the host's cores
 with compute, so the A/B here bounds machinery cost — the ICI win
-needs the TPU capture.  The ``wire_db_on`` rung retired with the
-double-buffering decision rule (docs/performance.md).
+needs the TPU capture.
 
 wire_flat / wire_hier / wire_hier_int8 rungs (ISSUE 11): the multi-hop
 schedule A/B on ONE hierarchical mesh (CPU tier: 2 synthetic slices of
@@ -50,8 +47,7 @@ schedule A/B on ONE hierarchical mesh (CPU tier: 2 synthetic slices of
 baseline, wire_hier the full-precision rs→ar→ag triple, wire_hier_int8
 the int8+EF inter hop.  Every row carries the schedule/codec
 fingerprint (``wire_schedules`` census + ``wire_plan_hash``) so a
-capture pins WHICH program it measured; perf_history gates the rows
-direction-aware like every variant row.
+capture pins WHICH program it measured.
 
 wire_tuned_* rungs (ISSUE 12): the measured-feedback autotune A/B —
 ``wire_tuned_base`` (fixed 4 MiB/6-slot constants) vs ``wire_tuned``
@@ -59,10 +55,10 @@ wire_tuned_* rungs (ISSUE 12): the measured-feedback autotune A/B —
 schedule choice), on the flat CPU mesh and
 (``wire_tuned_hier_base``/``wire_tuned_hier``) the synthetic 2-slice
 hierarchical mesh.  The tuned legs prefer a PINNED profile
-(``CHAINERMN_TPU_WIRE_PROFILE`` whose mesh signature matches — stable
-hash, so perf_history gates the rows) and calibrate in-process only
-without one (fresh hash every capture — perf_history discloses it as
-a retune).  Tuned rows carry ``profile_hash`` /
+(``CHAINERMN_TPU_WIRE_PROFILE`` whose mesh signature matches — a
+stable hash, so captures stay comparable) and calibrate in-process only
+without one (a fresh hash every capture: a disclosed retune).  Tuned
+rows carry ``profile_hash`` /
 ``tuned_bucket_bytes`` / ``tuned_max_buckets`` /
 ``predicted_sync_ms`` beside the plan fingerprints.
 
@@ -137,9 +133,8 @@ def _pinned_profile(mesh):
     ``CHAINERMN_TPU_WIRE_PROFILE``, or ``None`` when the tuned rung
     should calibrate in-process.  A pinned path that no longer resolves
     would otherwise silently demote every capture to in-process
-    calibration — fresh hash each run, so perf_history annotates tuned
-    rows as RETUNED forever and the gate the pin exists for never
-    fires — so a MISSING file is disclosed on stderr (rows go to
+    calibration — fresh hash each run, so tuned rows read as RETUNED
+    forever — so a MISSING file is disclosed on stderr (rows go to
     stdout).  A mesh-signature mismatch stays silent by design: one
     pinned file can only match one rung's mesh, and the other rungs
     falling back fresh is the documented normal capture shape."""
@@ -154,8 +149,8 @@ def _pinned_profile(mesh):
         print(
             f"comm_overlap_bench: {PROFILE_ENV}={pinned!r} does not "
             "exist — falling back to in-process calibration (tuned "
-            "rows get a fresh profile_hash; perf_history will "
-            "disclose them as retuned instead of gating)",
+            "rows get a fresh profile_hash and read as retuned, not "
+            "comparable with a pinned capture)",
             file=sys.stderr,
         )
         return None
@@ -188,10 +183,9 @@ def _run_sync(name, model_ctor, batch_fn, loss_of, tx, *,
 
         # a PINNED profile (the env path, committed beside the capture)
         # takes precedence when it matches this rung's mesh: its hash
-        # is then stable across captures, so perf_history GATES the
-        # tuned rows.  Only without one does the rung calibrate
-        # in-process — a fresh hash every capture, which perf_history
-        # honestly discloses as a retune instead of gating.
+        # is then stable across captures, so tuned rows stay
+        # comparable.  Only without one does the rung calibrate
+        # in-process — a fresh hash every capture, a disclosed retune.
         profile = _pinned_profile(comm.mesh)
         if profile is None:
             sizes = tuple(int(s) for s in os.environ.get(
@@ -264,9 +258,8 @@ def _run_sync(name, model_ctor, batch_fn, loss_of, tx, *,
         extra.setdefault("mesh_shape", dict(comm.mesh.shape))
         if getattr(opt, "profile", None) is not None:
             # tuned-row provenance (ISSUE 12): the profile content
-            # hash makes a retune read as a DISCLOSED config change in
-            # perf_history (annotate, not gate), and the tuned knobs
-            # show what the tuner actually chose
+            # hash makes a retune read as a DISCLOSED config change,
+            # and the tuned knobs show what the tuner actually chose
             extra.setdefault("profile_hash",
                              opt.profile.profile_hash()[:12])
             extra.setdefault("tuned_bucket_bytes", opt.wire.bucket_bytes)
@@ -595,7 +588,7 @@ def _variants():
     # slice topology.  Schedules are EXPLICIT per rung (not "auto") so
     # each row's fingerprint pins what program was measured; the CPU
     # A/B bounds scheduling machinery cost — the DCN-byte win needs the
-    # TPU capture (docs/performance.md "Multi-hop schedules").
+    # TPU capture.
     hier_wire = WireConfig(schedule="hier_rs_ag")
     hier_int8 = WireConfig(codec="int8", error_feedback=True,
                            schedule="hier_rs_ag")
@@ -635,7 +628,7 @@ def _variants():
     # wire_plan_hash / wire_schedules provenance.  On the CPU mesh the
     # profile measures dispatch latency, not interconnect — the A/B
     # bounds tuning machinery cost; the real curves need the TPU
-    # capture (docs/performance.md "Measured-feedback autotuning").
+    # capture.
     for rung, kw in {
         "wire_tuned_base": dict(wire="auto"),
         "wire_tuned": dict(wire="auto", profile="calibrate",
@@ -661,8 +654,7 @@ def _variants():
         ml_ctor, ml_batch, ml_loss_of, ml_tx
     )
     # the conv-mix overlap A/B (ResNet-18 on the virtual mesh): multi-
-    # bucket plan over a real backward chain — the shape the decision
-    # rule (docs/performance.md) judges alongside bench.py's VGG pair
+    # bucket plan over a real backward chain
     for rung, kw in {
         "overlap_resnet_off": dict(wire="auto", overlap="none"),
         "overlap_resnet_on": dict(wire="auto", overlap="bucket"),

@@ -65,9 +65,9 @@ authored collective census and trace hash (what the ``decode_step``
 budget pin enforces), capacity/page geometry, and the batcher's
 p50/p99 token latency.
 
-``tokens_per_sec_per_chip`` is HIGHER-better: ``perf_history`` keys on
-the ``_per_sec``/``per_chip`` spellings (the ``sec_per`` substring
-trap is pinned by tests/test_perf_history.py for this exact unit).
+``tokens_per_sec_per_chip`` is HIGHER-better.  A pre-cell script: no
+number of its is on the ledger; cell B2 (``ROADMAP.md`` Reach B2, the
+first serving cell) decides what of it becomes a cell.
 
 Usage:
     python benchmarks/decode_bench.py                  # real chip
@@ -403,8 +403,8 @@ def _emit_row(name, samples, reports, fingerprints, extra=None):
     rep = reports[-1][1]
     # every paired difference non-positive = the serve wall is inside
     # host jitter (noise floor).  A negative tokens/sec is nonsense
-    # and a committed one would gate forever: report a DISCLOSED null
-    # (perf_history skips null rows by design) instead.
+    # and a committed one would mislead forever: report a DISCLOSED
+    # null instead.
     value = round(tokens / dt / n_chips, 3) if dt > 0 else None
     row = {
         "metric": f"{name}_tokens_per_sec_per_chip",

@@ -59,9 +59,8 @@ the peer RAM ring, one from the shared-FS checkpointer, and the
 Unlike the other rungs these time RECOVERY only (the loss is modeled
 in-process; the world stays formed), so the numbers isolate the tier
 difference from the relaunch gap the other rungs already charge.
-These rows are emitted ``metric``/``value``-keyed (unit ``s``), so
-``perf_history`` regression-gates them directly — ``*_s`` is
-lower-is-better, ``*speedup`` higher-is-better.
+These rows are emitted ``metric``/``value``-keyed (unit ``s``):
+``*_s`` is lower-is-better, ``*speedup`` higher-is-better.
 
 Honesty: the worlds timeshare the host (CI runs this on a single
 core), so these are END-TO-END wall numbers dominated by process
@@ -233,9 +232,7 @@ def run_peer_ab_once(scratch):
 
 
 def _recover_rows(samples):
-    """The A/B rows, ``metric``/``value``-keyed so ``perf_history``
-    loads them directly (the legacy ``name``-keyed rows predate the
-    loader and are skipped by it)."""
+    """The A/B rows, ``metric``/``value``-keyed."""
     rows = []
     extra = {"n_procs": PEER_PROCS, "lose_at": PEER_LOSE_AT,
              "dim": PEER_DIM, "unit": "s"}

@@ -10,8 +10,11 @@ train steps.
 
 Counting caveats, so the cross-check is honest about what it can see:
 
-* one multi-operand ``psum`` eqn lowers to ONE variadic ``all_reduce``
-  op — both sides count 1 (the walker records one eqn);
+* one record an equation, one ``all_reduce`` op an equation in the
+  *lowered* text (jax 0.9 binds a tuple ``psum`` leaf by leaf); the
+  *compiled* text may glue several into one tuple all-reduce, so a
+  count is compared against the lowered text and bytes against the
+  compiled one;
 * a collective inside ``scan`` appears once in the while-loop body on
   both sides;
 * the GSPMD path (``use_shard_map=False``) materializes collectives the

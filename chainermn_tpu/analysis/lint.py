@@ -28,7 +28,8 @@ Rules
     and ``analysis/`` (this package names primitives to find them).
 
 ``untimed-row``
-    A benchmark row (dict literal in ``bench.py`` / ``benchmarks/``)
+    A benchmark row (dict literal in a file under ``benchmarks/`` or
+    named ``bench*.py``)
     carrying a timing-shaped key (``*_ms``, ``sec_per_*``, ``*_per_sec``,
     ``tflops*``, ...) must also carry the min-of-N protocol disclosure
     ``n_measurements`` (``spread_max_over_min`` rides along where >= 2
@@ -638,12 +639,12 @@ def repo_root() -> str:
 
 
 def default_targets(root: Optional[str] = None) -> List[str]:
-    """What the repo gate lints: the package, the benchmarks, the
-    examples, and bench.py.  Tests are deliberately excluded — they
-    construct raw collectives on purpose to exercise the analyzer."""
+    """What the repo gate lints: the package, the benchmarks and the
+    examples.  Tests are deliberately excluded — they construct raw
+    collectives on purpose to exercise the analyzer."""
     root = root or repo_root()
     out = []
-    for name in ("chainermn_tpu", "benchmarks", "examples", "bench.py"):
+    for name in ("chainermn_tpu", "benchmarks", "examples"):
         p = os.path.join(root, name)
         if os.path.exists(p):
             out.append(p)

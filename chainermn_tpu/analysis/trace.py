@@ -50,10 +50,15 @@ _COND_ID_RE = re.compile(r"cond#\d+")
 
 # Communication primitives and the HLO op class each lowers to.  pmean
 # has no primitive of its own (psum + divide), pgather/all_gather_invariant
-# are folded into the gather class.  axis_index / axis_size are *not*
-# communication and are deliberately absent.
+# are folded into the gather class.  psum_invariant is what a psum of a
+# varying value is under a vma-checked shard_map (jax 0.9): every
+# gradient reduction autodiff inserts on the ``param_specs`` body.
+# axis_index / axis_size move nothing, and pvary / pbroadcast only
+# retype a value for the vma check: *not* communication, deliberately
+# absent.
 COLLECTIVE_CLASS = {
     "psum": "all_reduce",
+    "psum_invariant": "all_reduce",
     "pmax": "all_reduce",
     "pmin": "all_reduce",
     "all_gather": "all_gather",
@@ -167,15 +172,13 @@ def wire_bytes(cls: str, payload_bytes: int,
 
 def _source_of(eqn) -> Optional[str]:
     """``file:line`` of the user frame that issued this eqn, if known."""
-    try:
-        from jax._src import source_info_util as siu
+    from jax._src import source_info_util as siu
 
-        fr = siu.user_frame(eqn.source_info)
-        if fr is None:
-            return None
-        return f"{fr.file_name}:{fr.start_line}"
-    except Exception:
+    # jax 0.9: user_frame takes the traceback, not the SourceInfo
+    fr = siu.user_frame(eqn.source_info.traceback)
+    if fr is None:
         return None
+    return f"{fr.file_name}:{fr.start_line}"
 
 
 @dataclass(frozen=True)

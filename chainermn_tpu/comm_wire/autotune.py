@@ -63,7 +63,8 @@ CLI::
 Honesty note: on the CPU test mesh these curves measure XLA dispatch
 latency, not interconnect bandwidth — they exercise the machinery; the
 first on-chip calibration capture is what gives the tuner real ICI/DCN
-numbers (docs/performance.md "Measured-feedback autotuning").
+numbers (none taken: no cell runs the bucketed wire, ``PERF.md``
+section 7, row 0).
 """
 
 from __future__ import annotations

@@ -51,11 +51,10 @@ class _MultiNodeCheckpointer:
         """``use_async``: snapshot through ``ocp.AsyncCheckpointer`` —
         ``save()`` returns once the arrays are copied to host and the
         serialization/write continues on a background thread, so a
-        snapshot does not stall training (measured:
-        benchmarks/checkpoint_bench.py; docs/performance.md "Checkpoint
-        performance").  Commit stays atomic (tmp dir + rename), so the
-        agreement protocol is unaffected: an in-flight save is simply
-        not visible yet.  Call :meth:`wait_until_finished` (or
+        snapshot does not stall training (the stall is not measured on
+        a chip: ``PERF.md`` section 7, rows 6-7).  Commit stays atomic
+        (tmp dir + rename), so the agreement protocol is unaffected: an
+        in-flight save is simply not visible yet.  Call :meth:`wait_until_finished` (or
         ``finalize``) before reading the snapshot back or exiting."""
         if use_async and not use_orbax:
             raise ValueError(

@@ -82,10 +82,8 @@ class MoeMlp(nn.Module):
     # at most once).  Generation twins use this instead of a static
     # prefill-sized capacity: a KV-cache decode step routes only
     # ``batch`` tokens, so its expert queues shrink from
-    # (E, batch*cache_len, d) to (E, batch, d) — measured 5.4x decode
-    # throughput (1,090 -> 5,915 tok/s, benchmarks/generate_bench.py
-    # moe_cache on v5e).  Dense-path only; the expert-parallel branch
-    # keeps ``capacity``.
+    # (E, batch*cache_len, d) to (E, batch, d).  Dense-path only; the
+    # expert-parallel branch keeps ``capacity``.
     no_drop: bool = False
     expert_axis: Optional[str] = None
     # axes to average load-balancing statistics over (see

@@ -31,11 +31,11 @@ def default_norm(size: int, **kw):
 
     ``dtype`` sets the *normalization arithmetic* dtype and defaults to
     fp32; models pass their compute dtype through ``_bind_norm``, so
-    bf16 models normalize in bf16 — measured +29% ResNet-50 step
-    throughput on v5e (benchmarks/resnet_mfu_loop.py: 45.7 vs 59.3
-    ms/step), while batch statistics still ACCUMULATE in fp32 (flax
-    promotes half-precision reductions unless force_float32_reductions
-    is disabled), so mean/var stay accurate over millions of elements."""
+    bf16 models normalize in bf16 (the ResNet-50 cell runs this way:
+    ``PERF.md`` section 2 has what it costs against float32), while
+    batch statistics still ACCUMULATE in fp32 (flax promotes
+    half-precision reductions unless force_float32_reductions is
+    disabled), so mean/var stay accurate over millions of elements."""
     del size
     return nn.BatchNorm(
         use_running_average=kw.pop("use_running_average", None),

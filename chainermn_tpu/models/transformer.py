@@ -286,8 +286,8 @@ class TransformerBlock(nn.Module):
     dropout_rate: float = 0.0
     deterministic: bool = False
     attention_fn: Optional[Callable] = None
-    # fp32 LayerNorm is the numerics-safe default; bf16 exists as a
-    # measured perf knob (benchmarks/transformer_mfu.py `ln_bf16` rung)
+    # fp32 LayerNorm is the numerics-safe default; bf16 is a perf knob
+    # no cell runs (LayerNorm rides the matmul fusions: PERF.md section 5)
     ln_dtype: Any = jnp.float32
 
     @nn.compact
@@ -388,8 +388,8 @@ class TransformerLM(nn.Module):
     # vocab chunk at a time, so the (b, s, V) logits never materialize.
     return_hidden: bool = False
     attention_fn: Optional[Callable] = None
-    # fp32 LayerNorm is the numerics-safe default; bf16 is a measured
-    # perf knob (benchmarks/transformer_mfu.py `ln_bf16` rung)
+    # fp32 LayerNorm is the numerics-safe default; bf16 is a perf knob
+    # no cell runs (LayerNorm rides the matmul fusions: PERF.md section 5)
     ln_dtype: Any = jnp.float32
 
     @nn.compact

@@ -1,10 +1,9 @@
 """Static-vs-measured join: match collective spans to CollectiveRecords.
 
 The analyzer prices every collective statically — ``CollectiveRecord``
-carries ``payload_bytes`` and ``bytes_on_wire`` (ring formulas) — and
-``bench.py`` probes the link ceiling; what was missing is the middle
-term: what each collective *achieved* at runtime.  :func:`attribute`
-joins the timeline's measured collective spans to a trace's records and
+carries ``payload_bytes`` and ``bytes_on_wire`` (ring formulas); the
+other term is what each collective *achieved* at runtime.
+:func:`attribute` joins the timeline's measured collective spans to a trace's records and
 computes per-record achieved bytes/sec, the number "Optimizing
 Allreduce Operations for Modern Heterogeneous Architectures"
 (PAPERS.md) compares against the link ceiling to localize a slow wire.

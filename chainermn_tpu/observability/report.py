@@ -10,7 +10,7 @@ time exceeds the cross-rank spread: the straggler question the
 per-rank timeline alone cannot answer.
 
 Each report appends one JSONL row per phase to ``out/filename``
-(chief-only) in the shape ``perf_history`` diffs direction-aware
+(chief-only), keyed for a direction-aware diff
 (``phase.<name>.p50_ms`` etc., unit ms, lower-is-better), and each
 flagged process is emitted as a ``straggler`` resilience event — so it
 lands both on ``trainer.resilience_log`` and, merged, in the exported

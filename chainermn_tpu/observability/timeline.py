@@ -323,7 +323,7 @@ class Timeline:
     def to_jsonl(self, path: str, *, meta: bool = False) -> str:
         """One JSON object per event, sorted by time, timestamps
         relative to ``t0`` in seconds — the grep/diff-friendly export
-        the mp scenarios and ``perf_history`` consume.
+        the mp scenarios consume.
 
         ``meta=True`` prepends one ``{"type": "meta", ...}`` row
         carrying the wall-clock anchor (``wall0``, captured at the same

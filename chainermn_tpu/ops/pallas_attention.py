@@ -23,11 +23,10 @@ take a dense-recompute fallback instead.
 All three kernels are DIAGONAL-SPLIT (round 6): each (q block, k block)
 grid point is classified dead / interior / masked, and interior blocks
 (the fully-unmasked majority at long sequence) run a fast branch with
-no iota/mask/select work — see the "Block taxonomy" section below and
-docs/performance.md "Diagonal-split kernel".  A masked block that the
-diagonal crosses squarely is computed in tiles, the ones above the
-diagonal skipped ("Compute tile" below).  The pre-split kernels are
-kept under ``taxonomy="legacy"`` as the reference.
+no iota/mask/select work — see the "Block taxonomy" section below.
+A masked block that the diagonal crosses squarely is computed in tiles,
+the ones above the diagonal skipped ("Compute tile" below).  The
+pre-split kernels are kept under ``taxonomy="legacy"`` as the reference.
 """
 
 from __future__ import annotations
@@ -75,7 +74,7 @@ def _effective_q_block(block_q: int, s_q: int, interpret: bool) -> int:
     return bq
 
 
-# Default block geometry (round-4 sweep, benchmarks/longseq_tune.py).
+# Default block geometry.
 # Public entry points take block_q/block_k=None so "caller passed
 # nothing" is distinguishable from "caller asked for exactly 1024".
 _DEFAULT_BLOCK = 1024
@@ -87,8 +86,7 @@ def _clamp_blocks_for_dim(block_q, block_k, d: int, warn: bool = True,
     """Head-dim-aware block clamp (``None`` block = the default).  The
     backward kernel holds three (bq, bk) fp32 score tiles plus
     d-proportional operand/accumulator tiles in scoped VMEM (16 MB hard
-    limit; 1024x2048 at d=128 already exceeds it — measured,
-    benchmarks/longseq_tune.py).
+    limit; 1024x2048 at d=128 already exceeds it).
 
     Threshold history: rounds 1-4 clamped every d > 128 on an
     extrapolated VMEM model; the round-5 probe COMPILED AND RAN the
@@ -155,11 +153,7 @@ def _clamp_blocks_for_dim(block_q, block_k, d: int, warn: bool = True,
 #              (the mask it skips is provably all-true there).
 #   "legacy"   the pre-split kernels, kept verbatim as the in-tree
 #              reference: every live block runs the masked path.
-#   "interior" TIMING ONLY: force every live block down the unmasked
-#              fast branch.  Numerics are intentionally wrong for
-#              causal/ragged inputs — this is the segment-anatomy
-#              bench's per-block-type floor, never a training path.
-_TAXONOMIES = ("split", "legacy", "interior")
+_TAXONOMIES = ("split", "legacy")
 
 
 def _resolve_taxonomy(taxonomy):
@@ -196,7 +190,7 @@ def _not(a):
 
 
 def _block_class(first_q, first_k, *, s_k, s_kp, causal, block_q, block_k,
-                 force_interior=False, s_q=None, s_qp=None):
+                 s_q=None, s_qp=None):
     """THE taxonomy predicate: (interior, masked) for one block.
 
     The single source of truth for block classification — the split
@@ -217,8 +211,6 @@ def _block_class(first_q, first_k, *, s_k, s_kp, causal, block_q, block_k,
         needs_mask = needs_mask | (first_k + block_k > s_k)
     if s_q is not None and s_q < s_qp:
         needs_mask = needs_mask | (first_q + block_q > s_q)
-    if force_interior:
-        return live, False
     return _and(live, _not(needs_mask)), _and(live, needs_mask)
 
 
@@ -313,10 +305,8 @@ def _select(mask, x, fill, masked):
 
 def block_census(s_q: int, s_k: int, block_q: int, block_k: int,
                  causal: bool, kind: str = "fwd", tile=None) -> dict:
-    """Static census of the block taxonomy for one (batch*head) program
-    — the analytic side of the segment-anatomy bench (how many blocks
-    of each class a launch executes, so A/B step times divide into
-    per-block-type costs).
+    """Static census of the block taxonomy for one (batch*head) program:
+    how many blocks of each class a launch executes.
 
     ``kind``: the forward kernel masks only the k axis (padded q rows
     are garbage that gets sliced off), the backward kernels mask q too
@@ -376,10 +366,10 @@ def launch_census(s_q: int, s_k: int, d: int, block_q=None, block_k=None,
     the q-block lane-tile floor (:func:`_effective_q_block`; compiled
     TPU floors bq at 128), and the k sequence clamp — and the compute
     tile (:func:`_compute_tile`), and returns
-    ``{"fwd": census, "bwd": census}``.  The bench anatomy rungs use
-    this instead of calling :func:`block_census` on the *requested*
-    blocks, so a clamped launch cannot print a census for a geometry
-    it never ran.
+    ``{"fwd": census, "bwd": census}``.  ``chip_smoke.py`` prints this
+    instead of calling :func:`block_census` on the *requested* blocks,
+    so a clamped launch cannot print a census for a geometry it never
+    ran.
 
     Two run-time escapes are NOT reflected (they depend on the backend,
     not the geometry): the backward's scoped-VMEM retry can ceil-shrink
@@ -486,8 +476,7 @@ def _flash_fwd_kernel_legacy(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                       l_ref, *, s_k: int, s_kp: int, causal: bool,
-                      scale: float, block_q: int, block_k: int,
-                      force_interior: bool = False, tile=None):
+                      scale: float, block_q: int, block_k: int, tile=None):
     """Diagonal-split forward kernel (``taxonomy="split"``).
 
     Same grid/scratch contract as the legacy kernel; each (j, kb) grid
@@ -520,7 +509,6 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     interior, masked = _block_class(
         first_q, first_k, s_k=s_k, s_kp=s_kp, causal=causal,
         block_q=block_q, block_k=block_k,
-        force_interior=force_interior,
     )
 
     def _attend(with_mask):
@@ -629,11 +617,10 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
         kernel = functools.partial(
             _flash_fwd_kernel, s_k=s_k, s_kp=s_kp, causal=causal,
             scale=scale, block_q=bq, block_k=bk,
-            force_interior=(taxonomy == "interior"),
             tile=_compute_tile(
                 bq, bk, "fwd", causal=causal, aligned=s_k == s_kp,
                 tile=tile,
-            ) if taxonomy == "split" else None,
+            ),
         )
     grid = (b * h, s_qp // bq, s_kp // bk)
     kv_index = _kv_index(bq, bk, causal and taxonomy != "legacy")
@@ -784,8 +771,7 @@ def _piece_mask(first_q, first_k, tile, **geometry):
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_acc, *, s_q: int, s_qp: int,
                          s_k: int, s_kp: int, causal: bool, scale: float,
-                         block_q: int, block_k: int,
-                         force_interior: bool = False, tile=None):
+                         block_q: int, block_k: int, tile=None):
     """Diagonal-split dq kernel: interior blocks recompute p straight
     from the saved log-sum-exp with no iota/mask/select work; only the
     diagonal/tail blocks pay the masked path.  Same grid and numerics
@@ -806,7 +792,6 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     interior, masked = _block_class(
         first_q, first_k, s_q=s_q, s_qp=s_qp, s_k=s_k, s_kp=s_kp,
         causal=causal, block_q=block_q, block_k=block_k,
-        force_interior=force_interior,
     )
 
     def _accum(with_mask):
@@ -913,8 +898,7 @@ def _flash_bwd_dkv_kernel_legacy(q_ref, k_ref, v_ref, do_ref, lse_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, s_q: int,
                           s_qp: int, s_k: int, s_kp: int, causal: bool,
-                          scale: float, block_q: int, block_k: int,
-                          force_interior: bool = False, tile=None):
+                          scale: float, block_q: int, block_k: int, tile=None):
     """Diagonal-split dk/dv kernel (grid (batch*head, k_blocks,
     q_blocks); q innermost/sequential) — same taxonomy routing as the
     split dq kernel, the same q strips with a compute ``tile`` (a strip
@@ -933,7 +917,6 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     interior, masked = _block_class(
         first_q, first_k, s_q=s_q, s_qp=s_qp, s_k=s_k, s_kp=s_kp,
         causal=causal, block_q=block_q, block_k=block_k,
-        force_interior=force_interior,
     )
 
     def _accum(with_mask):
@@ -1039,11 +1022,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         dq_kernel, dkv_kernel = _flash_bwd_dq_kernel, _flash_bwd_dkv_kernel
         kwargs = dict(s_q=s_q, s_qp=s_qp, s_k=s_k, s_kp=s_kp,
                       causal=causal, scale=scale, block_q=bq, block_k=bk,
-                      force_interior=(taxonomy == "interior"),
                       tile=_compute_tile(
                           bq, bk, "bwd", causal=causal, tile=tile,
                           aligned=s_k == s_kp and s_q == s_qp,
-                      ) if taxonomy == "split" else None)
+                      ))
 
     kv_index = _kv_index(bq, bk, causal and taxonomy != "legacy")
     dq = pl.pallas_call(
@@ -1111,11 +1093,9 @@ def flash_attention(q, k, v, causal=False, scale=None,
     online softmax).  ``interpret=None`` auto-selects: compiled on TPU,
     interpreter elsewhere.
 
-    Default blocks (``None``) resolve to 1024x1024 (round-4 sweep,
-    benchmarks/longseq_tune.py at dh=128 on v5e: vs the old 256x512
-    defaults this measured +7.5 % end-to-end at seq 2048 b8 and +24 %
-    at seq 8192 b1; 1024x2048 exceeds the 16 MB scoped-vmem limit in
-    the backward).  Blocks are clamped to the (padded) sequence length,
+    Default blocks (``None``) resolve to 1024x1024 (what both LM cells
+    run; 1024x2048 exceeds the 16 MB scoped-vmem limit in the
+    backward).  Blocks are clamped to the (padded) sequence length,
     so short sequences are unaffected, and shrunk for head dims beyond
     the measured d <= 256 feasibility boundary
     (``_clamp_blocks_for_dim``) so the backward stays inside scoped
@@ -1127,16 +1107,14 @@ def flash_attention(q, k, v, causal=False, scale=None,
     limit binds only the backward (it holds three (bq, bk) fp32 score
     tiles; the forward holds one), so the forward can stream wider K/V
     blocks than the backward survives — e.g. fwd 1024x2048 with bwd
-    1024x1024 (measured: benchmarks/longseq_tune.py round-5 rows).
+    1024x1024 (compiles for v5e: ``tests/test_tpu_compile.py``; not
+    timed on this installation).
 
     ``taxonomy``: block-classification mode (``None`` = ``"split"``,
     the diagonal-split kernels).  ``"legacy"`` runs the pre-split
-    kernels (every live block masked — the in-tree A/B reference);
-    ``"interior"`` is TIMING ONLY for the segment-anatomy bench (forces
-    every live block down the unmasked fast branch; numerically wrong
-    for causal/ragged inputs).  Split and legacy are bit-identical
-    (``test_split_matches_legacy_exactly``) wherever the compute tile
-    does not engage.
+    kernels (every live block masked — the in-tree A/B reference).
+    Split and legacy are bit-identical wherever the compute tile does
+    not engage (``test_split_matches_legacy_exactly``).
 
     Compute tile (no argument: :func:`_compute_tile` resolves it from
     the launch's shapes): a causal launch whose diagonal blocks are
@@ -1431,17 +1409,13 @@ def flash_attention_fn(block_q: Optional[int] = None,
                        block_k: Optional[int] = None,
                        interpret: Optional[bool] = None,
                        bwd_block_q: Optional[int] = None,
-                       bwd_block_k: Optional[int] = None,
-                       taxonomy: Optional[str] = None):
+                       bwd_block_k: Optional[int] = None):
     """Adapter producing the ``attention_fn`` signature used by
-    ``ulysses_attention``: ``(q, k, v, causal, scale)``.  ``taxonomy``
-    passes through to :func:`flash_attention` (the segment-anatomy
-    bench's knob)."""
+    ``ulysses_attention``: ``(q, k, v, causal, scale)``."""
 
     def fn(q, k, v, causal, scale):
         return flash_attention(q, k, v, causal, scale, block_q, block_k,
-                               interpret, bwd_block_q, bwd_block_k,
-                               taxonomy)
+                               interpret, bwd_block_q, bwd_block_k)
 
     return fn
 

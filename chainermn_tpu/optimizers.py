@@ -1005,14 +1005,12 @@ def create_multi_node_optimizer(
     below).
 
     ``double_buffering`` (stale-by-one gradients, reference parity):
-    LEAVE IT OFF unless you have measured a win on your topology.  On a
-    single chip and on the virtual mesh the A/B shows no benefit — on
-    chip the compiled psum already overlaps with backward compute, and
-    the virtual-mesh measurement was 16 % SLOWER with it on
-    (docs/performance.md "Double-buffering, measured"); its design
-    target (DCN-crossing topologies where gradient sync rides a slow
-    link) is the one place it can pay.  ``overlap="bucket"`` hides the
-    same sync without applying stale gradients — prefer it.
+    LEAVE IT OFF unless you have measured a win on your topology: not
+    measured on a chip; no cell runs it (``PERF.md`` section 7, row 0).
+    Its design target (DCN-crossing topologies where gradient sync
+    rides a slow link) is the one place it can pay.
+    ``overlap="bucket"`` hides the same sync without applying stale
+    gradients — prefer it.
     """
     from .comm_wire import resolve_overlap
 

@@ -79,6 +79,7 @@ import hashlib
 import math
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -630,6 +631,16 @@ class PagedKVCache:
         self.v_pages = self.v_pages.at[:, idx].set(
             jnp.asarray(kv.v[:, :n_copy], self.dtype)
         )
+        # Wait for the copies to land.  Dispatch is asynchronous, and a
+        # decode step dispatched right behind an import has attended
+        # over pages the import had not yet written (CPU backend: the
+        # first token after a handoff wrong in 40 of 480 serves of four
+        # requests on two slots, in none of 720 with this wait, in none
+        # of 120 under synchronous dispatch).  What lets the donated
+        # step pass the copies is not understood; an import is a
+        # host-side copy from a file, so the wait costs nothing a
+        # caller could hide.
+        jax.block_until_ready((self.k_pages, self.v_pages))
         self.lengths[slot] = length
         for m, h in enumerate(kv.prefix_chain, start=1):
             if m > n_copy or m * self.page_size > length:
@@ -641,11 +652,16 @@ class PagedKVCache:
         return slot
 
     # -- arrays for the compiled step ----------------------------------
+    # Copies, not views: the CPU backend may alias a host buffer it is
+    # handed (zero-copy when the allocation happens to be aligned), and
+    # the step that reads these is dispatched asynchronously while
+    # ``advance`` / ``admit`` / ``release`` go on to mutate the host
+    # arrays in place.
     def tables_array(self) -> jnp.ndarray:
-        return jnp.asarray(self.block_tables)
+        return jnp.array(self.block_tables)
 
     def lengths_array(self) -> jnp.ndarray:
-        return jnp.asarray(self.lengths)
+        return jnp.array(self.lengths)
 
     def set_pages(self, k_pages, v_pages) -> None:
         """Install the decode step's updated page arrays (functional

@@ -146,9 +146,8 @@ class Profile:
 
     Only the chief process traces by default (every process writes its
     own device's timeline under multi-controller when
-    ``all_processes=True``).  See docs/performance.md for the workflow,
-    including communication-overhead-by-subtraction with the ``dummy``
-    communicator.
+    ``all_processes=True``).  Running the same job on the ``dummy``
+    communicator gives the communication overhead by subtraction.
     """
 
     priority = 170  # before Throughput so the trace brackets real work

@@ -1,5 +1,5 @@
-"""Measurement helpers for ``bench.py`` and the scripts under
-``benchmarks/``.
+"""Measurement helpers for the scripts under ``benchmarks/``,
+``observability/metrics.py`` and ``comm_wire/autotune.py``.
 
 They force completion with a host *value readback* (it needs the bytes,
 so it cannot return before the work is done) and time paired k/2k runs
@@ -112,8 +112,8 @@ def time_kloop(run_k, k: int, repeats: int = 2):
     returns ``(dt, samples)`` where dt is the min positive paired
     difference — per-dispatch link noise that plagues step-at-a-time
     timing cancels because one dispatch covers seconds of device time
-    (benchmarks/resnet_mfu_loop.py's methodology, shared here so the
-    benchmark scripts can't drift apart).  Falls back to the long run's
+    (shared here so the benchmark scripts can't drift apart).  Falls
+    back to the long run's
     average when every paired difference is non-positive (noise floor).
     """
     force_completion(run_k(2))  # compile + warm

@@ -1,6 +1,6 @@
 """Where compiled programs are kept between processes.
 
-Entry points (``chip_smoke.py``, ``bench.py``, the example ``main``s)
+Entry points (``chip_smoke.py``, the example ``main``s)
 call :func:`enable_compile_cache` once, after the platform is chosen and
 before their first compile.
 The cache directory is part of JAX's cache key, so it must not move

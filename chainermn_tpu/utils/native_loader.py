@@ -192,9 +192,9 @@ class NativeImageLoader:
       float32, ready to cast and feed.
     * ``wire="uint8"`` — raw cropped/flipped uint8; normalize ON DEVICE
       inside the jitted step (:func:`device_normalize`).  A quarter of
-      float32's bytes over the host->device link — and uint8 image data
-      compresses far better on entropy-sensitive transports (measured:
-      benchmarks/h2d_bench.py) — which is the standard TPU input design.
+      float32's bytes over the host->device link, which is the
+      standard TPU input design (not timed on a chip: no cell feeds
+      through this loader).
       Augmentation is keyed on (seed, sample ordinal), so both wire
       modes produce identical crops/flips for the same seed.
 

@@ -107,17 +107,25 @@ class TestWalker:
         assert [r.primitive for r in tr] == ["psum"]
         assert tr.census() == {"all_reduce": 1}
 
-    def test_multi_operand_psum_is_one_record(self, mesh8):
+    @pytest.mark.parametrize("check_vma,primitive", [
+        (False, "psum"), (True, "psum_invariant"),
+    ])
+    def test_tuple_psum_is_one_record_a_leaf(self, mesh8, check_vma,
+                                             primitive):
         def f(x):
             a, b = lax.psum((x, x * 2), "mn")
             return a + b
 
-        tr = trace_collectives(_smap(f, mesh8), jnp.zeros((8, 4)))
-        # one variadic eqn -> ONE record carrying both operands (XLA
-        # lowers it to one variadic all-reduce, so census agreement
-        # depends on this)
-        assert len(tr) == 1
-        assert tr.records[0].dtypes == ("float32", "float32")
+        fn = jax.shard_map(f, mesh=mesh8, in_specs=P("mn"), out_specs=P(),
+                           check_vma=check_vma)
+        tr = trace_collectives(fn, jnp.zeros((8, 4)))
+        # jax 0.9 binds a tuple psum leaf by leaf (no variadic eqn any
+        # more): one record an equation, each an all_reduce, under the
+        # vma check as the primitive it emits there.  pvary (the 2.0
+        # retyped for the check) moves nothing and is no record.
+        assert [r.primitive for r in tr] == [primitive] * 2
+        assert [r.dtypes for r in tr] == [("float32",)] * 2
+        assert tr.census() == {"all_reduce": 2}
 
     def test_nested_scan_cond_pjit_contexts(self, mesh8):
         def inner(c):

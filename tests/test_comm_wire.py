@@ -846,8 +846,8 @@ class TestWireBenchRungsCI:
 
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         # a PINNED profile for the flat (mn, 8) mesh: the wire_tuned
-        # rung must prefer it (stable hash -> perf_history can GATE the
-        # row), while the hier rung's mesh signature mismatches and
+        # rung must prefer it (stable hash: captures stay comparable),
+        # while the hier rung's mesh signature mismatches and
         # falls back to in-process calibration (fresh hash -> disclosed
         # retune)
         pinned = BandwidthProfile(

@@ -599,9 +599,9 @@ class TestPeerRecoveryFleet:
         blocked leaves included) against the FS restore of the same
         step, both legs land on the single-world numpy oracle, and the
         merged report shows recover_action → recovered per leg with
-        the peer gap no slower than the FS gap (the >= 5x speedup
-        itself is the bench's perf_history-gated rung — asserting the
-        magnitude here would flake on a loaded CI host)."""
+        the peer gap no slower than the FS gap (the magnitude is the
+        bench's rung — asserting it here would flake on a loaded CI
+        host)."""
         gaps = {}
         for tier in ("peer", "fs"):
             scratch = tmp_path / tier

@@ -302,3 +302,87 @@ class TestGradReduceCompilerOptions:
                        else None)
         if expect_options:
             assert got is not _ASYNC_GRAD_REDUCE_OPTIONS  # a copy
+
+
+def _param_specs_step(devices, dp, tp, loss_fn=None):
+    """A ``param_specs`` step of ``TpMLP`` over a dp x tp CPU mesh and
+    its placed ``(params, opt_state, batch)``."""
+    comm = cmn.create_communicator(
+        "hybrid", devices=devices[:dp * tp], tp_size=tp)
+    params = _tp_params_from_dense(_dense_params())
+    opt = cmn.create_multi_node_optimizer(optax.adam(0.05), comm)
+    step = build_train_step(
+        comm, loss_fn or _mse_loss(TpMLP()), opt,
+        data_axes=comm.data_axis_names,
+        param_specs=megatron_param_specs(params, model_axis="mn_model"),
+        donate=False,
+    )
+    params, opt_state = step.place(params, opt.init(params))
+    return comm, step, (params, opt_state, step.place_batch(_batch()))
+
+
+class TestParamSpecsCollectiveTrace:
+    """On the ``param_specs`` body autodiff sums the gradients, and under
+    jax 0.9's vma-checked shard_map each of those sums is a
+    ``psum_invariant``: the static trace, and with it the cross-rank
+    divergence guard, must see them."""
+
+    @pytest.mark.parametrize("dp,tp", [(2, 1), (4, 1), (2, 2)],
+                             ids=["dp2", "dp4", "dp2_tp2"])
+    def test_one_all_reduce_a_gradient_leaf_over_the_data_axes(
+        self, devices8, dp, tp
+    ):
+        from chainermn_tpu.analysis import assert_census_agreement
+
+        comm, step, args = _param_specs_step(devices8, dp, tp)
+        trace = step.collective_trace(*args)
+        assert {r.cls for r in trace} == {"all_reduce"}
+        over_data = [r for r in trace
+                     if r.axes == tuple(comm.data_axis_names)]
+        # every leaf's gradient, at its per-chip size (a bias' is summed
+        # as a row), and the loss
+        want = [leaf.addressable_shards[0].data.size
+                for leaf in jax.tree_util.tree_leaves(args[0])] + [1]
+        assert sorted(int(np.prod(r.shapes[0])) for r in over_data) \
+            == sorted(want)
+        # the rest are the Megatron layers' own sums over the model axis
+        assert all(r.axes == ("mn_model",)
+                   for r in trace if r not in over_data)
+        # the lowered program holds one all-reduce an equation ...
+        lowered = step.get_jitted(*args[:2]).lower(*args)
+        assert_census_agreement(trace, lowered.as_text())
+        # ... which XLA:CPU may glue into tuples when it compiles: the
+        # compiled step's all-reduces carry the same bytes in no more ops
+        scheduled = step.collective_schedule(*args).ops
+        assert {op.cls for op in scheduled} == {"all_reduce"}
+        assert len(scheduled) <= len(trace)
+        assert sum(op.nbytes for op in scheduled) == sum(
+            r.payload_bytes for r in trace)
+
+    def test_guard_tells_steps_apart_that_differ_by_one_pmean(
+        self, devices8
+    ):
+        from chainermn_tpu.analysis import trace_agreement
+        from chainermn_tpu.functions import collectives as cc
+        from chainermn_tpu.resilience.errors import (
+            CollectiveTraceMismatchError,
+        )
+
+        def one_more(params, batch):
+            return cc.pmean(_mse_loss(TpMLP())(params, batch), "mn_model")
+
+        _, step, args = _param_specs_step(devices8, 2, 2)
+        _, other, other_args = _param_specs_step(devices8, 2, 2, one_more)
+        mine = step.verify_collective_trace(*args)
+        theirs = other.verify_collective_trace(*other_args)
+        # the authored pmean and, from autodiff, its transpose
+        assert len(other.collective_trace(*other_args)) == len(
+            step.collective_trace(*args)) + 2
+        assert mine != theirs
+
+        class Peer:  # a two-process world whose other rank built `other`
+            def allgather_obj(self, h):
+                return [h, theirs]
+
+        with pytest.raises(CollectiveTraceMismatchError):
+            trace_agreement(Peer(), step.collective_trace(*args))

@@ -400,8 +400,7 @@ class TestLoaderThroughput:
         """Native-input evidence (VERDICT r3 #3): measure what the
         loader+host-cast pipeline alone produces at bench shapes
         (128x224x224x3 uint8 -> crop/flip/normalize -> bf16 host cast,
-        no device in the loop; benchmarks/h2d_bench.py measures the
-        host-to-device side).  On a multi-core host the worker threads
+        no device in the loop).  On a multi-core host the worker threads
         scale; on a 1-core host the pipeline is itself host-bound.
         Only a sanity floor is asserted here (wall-clock
         throughput assertions don't belong in a unit suite)."""
@@ -437,11 +436,8 @@ class TestLoaderThroughput:
             loader.close()
         imgs_per_sec = k * batch / dt
         # Sanity floor only: wall-clock throughput in a unit suite must
-        # not fail under CI load.  The *evidence* floor (loader clears
-        # the measured ~160 img/s link ceiling on a multi-core host) is
-        # a bench concern — run this test body manually or see
-        # docs/performance.md "Native-input pipeline" for the measured
-        # numbers.
+        # not fail under CI load.  Whether the loader keeps a chip fed
+        # is a cell's to say (none feeds through it: PERF.md section 7).
         assert imgs_per_sec > 20, (
             f"loader+cast produced only {imgs_per_sec:.0f} img/s - "
             "the native pipeline is pathologically slow"

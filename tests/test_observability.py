@@ -602,7 +602,7 @@ class TestAttribution:
 # MetricsReport
 # ----------------------------------------------------------------------
 class TestMetricsReport:
-    def test_rows_and_jsonl_diffable_by_perf_history(
+    def test_rows_and_jsonl_carry_the_min_of_n_protocol(
         self, comm, tmp_path
     ):
         trainer = _mlp_trainer(comm)
@@ -622,25 +622,13 @@ class TestMetricsReport:
             assert r["n_measurements"] >= 1
         # single-controller world: one process, nobody to straggle
         assert rep.last_report["stragglers"] == []
-        # the JSONL rows load as perf_history pseudo-metrics
+        # the JSONL rows are the report's, one phase a line
         lines = [json.loads(l)
                  for l in open(tmp_path / "metrics.jsonl")]
         assert all("phase" in l for l in lines)
-        import sys
-
-        sys.path.insert(0, os.path.join(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))
-        ), "benchmarks"))
-        import perf_history as ph
-        capture = tmp_path / "cap.json"
-        capture.write_text(json.dumps({
-            "tail": "\n".join(json.dumps(l) for l in lines)
-        }))
-        loaded = ph.load_rows(str(capture))
-        assert any(k.startswith("phase.step.") for k in loaded)
-        assert ph.lower_is_better(
-            "phase.step.p50_ms", loaded["phase.step.p50_ms"]
-        )
+        assert {l["phase"] for l in lines} >= {"step", "update"}
+        assert all(l["p50_ms"] > 0 and l["n_measurements"] >= 1
+                   for l in lines)
 
     def test_report_enables_own_telemetry_when_none_active(self, comm):
         trainer = _mlp_trainer(comm)

@@ -304,9 +304,7 @@ class TestZeroRedundancy:
         """The ZeRO-1 memory claim, measured: per-device optimizer-state
         bytes for a real TransformerLM under adam must drop to ~1/8 on
         the 8-device mesh (exact shard accounting via
-        addressable_shards — the same layout a real TPU mesh gets).
-        The numbers quoted in docs/performance.md's ZeRO table come
-        from this accounting."""
+        addressable_shards — the same layout a real TPU mesh gets)."""
         import jax.tree_util as jtu
 
         from chainermn_tpu.models.transformer import TransformerLM

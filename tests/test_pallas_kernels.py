@@ -503,25 +503,18 @@ class TestDiagonalSplit:
         c = launch_census(8192, 8192, 128, 64, 1024, interpret=True)
         assert c["fwd"]["n_q_blocks"] == 8192 // 64
 
-    def test_interior_taxonomy_timing_only(self):
-        """``taxonomy="interior"`` (the anatomy bench's floor) must
-        equal split exactly when no mask exists (non-causal aligned),
-        and must DIFFER under causal masking — pinning that it is a
-        timing knob, not a numerics mode."""
-        q, k, v = _qkv(s=32, seed=5)
-        args = (None, 16, 16, True, None, None)
-        same = flash_attention(q, k, v, False, *args, "interior")
-        want = flash_attention(q, k, v, False, *args, "split")
-        np.testing.assert_array_equal(np.asarray(same), np.asarray(want))
-        wrong = flash_attention(q, k, v, True, *args, "interior")
-        right = flash_attention(q, k, v, True, *args, "split")
-        assert not np.allclose(np.asarray(wrong), np.asarray(right))
+    @pytest.mark.parametrize("taxonomy", [
+        "diagonalize",
+        "interior",  # a block class, no longer a kernel family
+    ])
+    def test_invalid_taxonomy_raises(self, taxonomy):
+        from chainermn_tpu.ops import pallas_attention as pa
 
-    def test_invalid_taxonomy_raises(self):
+        assert pa._TAXONOMIES == ("split", "legacy")
         q, k, v = _qkv(s=16)
         with pytest.raises(ValueError, match="taxonomy"):
             flash_attention(q, k, v, True, None, 8, 8, True, None, None,
-                            "diagonalize")
+                            taxonomy)
 
 
 class TestComputeTile:
@@ -966,20 +959,6 @@ class TestVmemRetry:
         assert len(seen) == 2  # failed once, retried shrunk
         assert seen[1][0] < seen[0][0]
         assert np.isfinite(np.asarray(g)).all()
-
-
-class TestAnalyticAttnFlops:
-    def test_formula(self):
-        """bench.py's analytic flash-attention FLOP term (the part XLA
-        cannot see): fwd = 4*b*h*s^2*dh, training = 3.5x fwd, causal
-        halves — stated in the docstring, pinned here."""
-        import bench
-
-        b, h, s, dh, L = 2, 8, 1024, 128, 4
-        full = bench._flash_attn_tflops(b, h, s, dh, L, causal=False)
-        assert full == pytest.approx(14.0 * b * h * s * s * dh * L / 1e12)
-        causal = bench._flash_attn_tflops(b, h, s, dh, L, causal=True)
-        assert causal == pytest.approx(full / 2)
 
 
 class TestTimeKloop:

@@ -1,10 +1,9 @@
 """README's advertised test count must match what pytest collects.
 
 Round 3's README said 457 while the suite collected 467 (hand-maintained
-count drifted within the round).  Same cure as docs/performance.md's
-generated table: make the committed number a checked function of the
-tree.  Update the count in README.md's "Tests (`N`: ..." line whenever
-this fails.
+count drifted within the round).  The cure: make the committed number
+a checked function of the tree.  Update the count in README.md's
+"Tests (`N`: ..." line whenever this fails.
 """
 
 import os

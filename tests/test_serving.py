@@ -80,6 +80,54 @@ def _shared_prompts(n, seed=17, page=8):
             for _ in range(n)]
 
 
+def _aligned_copy(a, align=64):
+    """``a`` in a buffer aligned the way the CPU backend wants before it
+    takes a host array without copying it."""
+    raw = np.zeros(a.nbytes + align, np.uint8)
+    off = (-raw.ctypes.data) % align
+    out = raw[off:off + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+def _first_fresh_token(generated):
+    """``(eos, n)``: the first generated token past the first that no
+    earlier one equals, and how many tokens a request stopping on it
+    emits (greedy decode of a random model repeats itself)."""
+    for i in range(1, len(generated)):
+        if generated[i] not in generated[:i]:
+            return generated[i], i + 1
+    raise AssertionError(f"no fresh token in {generated}")
+
+
+#: Two programs for one logits row (another batch shape, the speculative
+#: verify step) agree to float32 rounding, not to the bit: where the
+#: model's top logits lie closer than this, which token a greedy decode
+#: takes is rounding's to say.
+GREEDY_TIE = 1e-5
+
+
+def _assert_same_greedy(lm, got, want):
+    """``got`` / ``want``: greedy decodes (prompt + tokens) of one prompt
+    by two different programs.  Equal token for token, or parting at a
+    step where the model's own float32 logits for the two tokens both
+    lie within ``GREEDY_TIE`` of the largest; what follows such a tie is
+    another sequence and is not compared."""
+    if got == want:
+        return
+    parts = [i for i, (a, b) in enumerate(zip(got, want)) if a != b]
+    assert parts, f"one is a prefix of the other: {got} vs {want}"
+    i = parts[0]
+    model, params = lm
+    row = np.asarray(model.apply(
+        params, jnp.asarray([want[:i]], jnp.int32),
+        rngs={"dropout": jax.random.PRNGKey(0)})[0, -1], np.float32)
+    worse = min(row[got[i]], row[want[i]])
+    assert row.max() - worse <= GREEDY_TIE, (
+        f"decodes part at {i} ({got[i]} vs {want[i]}) where the logits "
+        f"are {row.max() - worse:.3g} apart: {got} vs {want}")
+
+
 def _draft_engine(eng, seed=7, zero=False):
     """A half-width 1-layer draft engine built to ``eng``'s exact cache
     geometry (the SpeculativeBatcher contract)."""
@@ -271,6 +319,25 @@ class TestCacheState:
         assert c.can_admit(20) == c2.can_admit(20)
         assert c.admit(6) == c2.admit(6)
         np.testing.assert_array_equal(c.block_tables, c2.block_tables)
+
+    def test_step_inputs_are_copies_of_the_host_bookkeeping(self):
+        """A step is dispatched asynchronously and the host goes on to
+        ``advance`` / ``release`` in place; the CPU backend takes an
+        aligned host buffer without copying it.  What the step reads
+        must therefore not alias ``lengths`` / ``block_tables`` (it did:
+        decode steps read lengths one ahead, whenever numpy happened to
+        allocate them 64-byte aligned)."""
+        c = self._populated()
+        c.lengths = _aligned_copy(c.lengths)
+        c.block_tables = _aligned_copy(c.block_tables)
+        lengths, tables = c.lengths_array(), c.tables_array()
+        want = c.lengths.copy(), c.block_tables.copy()
+        c.advance(0, 1)
+        c.release(1)
+        assert not np.array_equal(c.lengths, want[0])
+        assert not np.array_equal(c.block_tables, want[1])
+        np.testing.assert_array_equal(np.asarray(lengths), want[0])
+        np.testing.assert_array_equal(np.asarray(tables), want[1])
 
     def test_shape_mismatch_rejected(self):
         c = self._populated()
@@ -496,15 +563,18 @@ class TestFlashDecode:
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=2e-6, atol=2e-6)
 
-    def test_single_page_bit_exact(self):
-        """One live page = online softmax IS the dense softmax: the
-        kernel must match the reference bit for bit."""
+    def test_single_page_matches_dense_reference(self):
+        """One live page = online softmax IS the dense softmax, in
+        exact arithmetic.  The kernel and the gather reference are two
+        programs (the exp, the sums and the divide are not scheduled
+        alike), so float32 rounding is the bound, not bit identity."""
         q, k, v, _ = self._pages()
         bt = jnp.asarray([[1], [4], [6]], jnp.int32)
         lengths = jnp.asarray([5, 8, 3], jnp.int32)
         out = flash_decode(q, k, v, bt, lengths, interpret=True)
         ref = paged_decode_reference(q, k, v, bt, lengths)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), rtol=2e-6, atol=2e-6)
 
     def test_zero_length_slot_returns_zeros(self):
         q, k, v, bt = self._pages()
@@ -731,7 +801,8 @@ class TestTensorParallelDecode:
 class TestContinuousBatcher:
     def test_batched_outputs_equal_single_request_outputs(self, lm):
         """Continuous batching is a SCHEDULING optimization: every
-        request's tokens equal an unbatched run's, bit for bit."""
+        request's tokens are an unbatched run's (a capacity-1 engine is
+        another program: the same greedy decode, ``_assert_same_greedy``)."""
         model, params = lm
         eng = DecodeEngine(model, params, capacity=3, page_size=8)
         reqs = [Request(p, 2 + (i % 5))
@@ -740,7 +811,8 @@ class TestContinuousBatcher:
         solo = DecodeEngine(model, params, capacity=1, page_size=8)
         for r in out:
             assert r.state == "done", r
-            assert r.output == solo.generate(r.prompt, r.max_new_tokens)
+            _assert_same_greedy(
+                lm, r.output, solo.generate(r.prompt, r.max_new_tokens))
 
     def test_joins_and_leaves_share_compiled_programs(self, lm):
         """Padded slot model: membership churn across the whole serve
@@ -760,17 +832,18 @@ class TestContinuousBatcher:
         model, params = lm
         eng = DecodeEngine(model, params, capacity=2, page_size=8)
         probe = eng.generate([5, 9, 11], 6)
-        eos = probe[4]  # the 2nd generated token
+        eos, n = _first_fresh_token(probe[3:])
         r = Request([5, 9, 11], 6, eos_id=eos)
         out = ContinuousBatcher(eng).serve([r])[0]
         assert out.state == "done"
-        assert out.tokens[-1] == eos
-        assert len(out.tokens) == 2
+        assert out.tokens == probe[3:3 + n]
+        assert 1 < n < 6  # stopped early, and not on the first token
 
     def test_recoverable_fault_retries_and_outputs_match(self, lm):
         """An injected transient at the decode step re-queues the
-        in-flight requests; the retried outputs are bit-identical (the
-        request-level slice of the resilience taxonomy)."""
+        in-flight requests; the retried outputs are the unbatched
+        run's greedy decode (the request-level slice of the resilience
+        taxonomy)."""
         model, params = lm
         eng = DecodeEngine(model, params, capacity=2, page_size=8)
         reqs = [Request(p, 4) for p in _prompts(21, 3)]
@@ -790,7 +863,8 @@ class TestContinuousBatcher:
         for r in out:
             assert r.state == "done"
             assert r.retries >= 0
-            assert r.output == solo.generate(r.prompt, r.max_new_tokens)
+            _assert_same_greedy(
+                lm, r.output, solo.generate(r.prompt, r.max_new_tokens))
 
     def test_retry_budget_exhaustion_fails_request_not_batch(self, lm):
         model, params = lm
@@ -1502,8 +1576,9 @@ class TestPrefixSharing:
 
     def test_shared_serve_bit_identical_with_fewer_pages(self, lm):
         """The tentpole acceptance: a high-overlap serve with sharing
-        ON is bit-identical to the sharing-OFF serve AND to the
-        unbatched oracle, while the peak DISTINCT page count drops."""
+        ON is bit-identical to the sharing-OFF serve (the same
+        programs) and the unbatched oracle's greedy decode, while the
+        peak DISTINCT page count drops."""
         model, params = lm
         prompts = _shared_prompts(6)
 
@@ -1528,8 +1603,9 @@ class TestPrefixSharing:
             r1, r0 = hot.finished[rid], cold.finished[rid]
             assert r1.state == "done"
             assert r1.output == r0.output
-            assert r1.output == solo.generate(r1.prompt,
-                                              r1.max_new_tokens)
+            _assert_same_greedy(
+                lm, r1.output,
+                solo.generate(r1.prompt, r1.max_new_tokens))
 
     def test_checkpoint_round_trip_with_live_shared_pages(self):
         """state_dict/load_state_dict carry refcounts and the CoW
@@ -1635,8 +1711,10 @@ class TestSpeculative:
     def test_spec_serve_bit_identical(self, k, lm):
         """Greedy-exact acceptance makes the speculative transcript the
         plain transcript BY CONSTRUCTION: every committed token is a
-        target argmax, so outputs equal the unbatched oracle at any k
-        (k=1 is the degenerate plain-decode control)."""
+        target argmax, so outputs are the unbatched oracle's greedy
+        decode at any k (k=1 is the degenerate plain-decode control).
+        The verify step is another program than the decode step: equal
+        to rounding, hence ``_assert_same_greedy``, not ``==``."""
         model, params = lm
         eng = DecodeEngine(model, params, capacity=2, page_size=8)
         b = SpeculativeBatcher(eng, _draft_engine(eng), k=k)
@@ -1646,7 +1724,8 @@ class TestSpeculative:
         solo = DecodeEngine(model, params, capacity=1, page_size=8)
         for r in out:
             assert r.state == "done", r
-            assert r.output == solo.generate(r.prompt, r.max_new_tokens)
+            _assert_same_greedy(
+                lm, r.output, solo.generate(r.prompt, r.max_new_tokens))
         # both allocators drained clean and in lockstep
         for cache in (eng.cache, b.draft.cache):
             assert cache.used_pages == 0
@@ -1665,7 +1744,8 @@ class TestSpeculative:
         assert b.acceptance_rate == 1.0
         solo = DecodeEngine(model, params, capacity=1, page_size=8)
         for r in out:
-            assert r.output == solo.generate(r.prompt, r.max_new_tokens)
+            _assert_same_greedy(
+                lm, r.output, solo.generate(r.prompt, r.max_new_tokens))
 
     def test_all_rejected_zero_params_draft(self, lm):
         """The other extreme: a zeroed draft proposes a constant token
@@ -1681,7 +1761,8 @@ class TestSpeculative:
         solo = DecodeEngine(model, params, capacity=1, page_size=8)
         for r in out:
             assert r.state == "done"
-            assert r.output == solo.generate(r.prompt, r.max_new_tokens)
+            _assert_same_greedy(
+                lm, r.output, solo.generate(r.prompt, r.max_new_tokens))
 
     def test_eos_retires_inside_a_speculative_commit(self, lm):
         """An eos landing mid-commit truncates exactly where plain
@@ -1690,13 +1771,13 @@ class TestSpeculative:
         model, params = lm
         eng = DecodeEngine(model, params, capacity=2, page_size=8)
         probe = eng.generate([5, 9, 11], 6)
-        eos = probe[4]  # the 2nd generated token
+        eos, n = _first_fresh_token(probe[3:])
         eng2 = DecodeEngine(model, params, capacity=2, page_size=8)
         b = SpeculativeBatcher(eng2, _draft_engine(eng2), k=4)
         out = b.serve([Request([5, 9, 11], 6, eos_id=eos)])[0]
         assert out.state == "done"
-        assert out.tokens[-1] == eos
-        assert len(out.tokens) == 2
+        assert out.tokens == probe[3:3 + n]
+        assert 1 < n <= 4  # inside the first commit of k=4, not its end
 
     def test_rollback_rewinds_lengths_only(self):
         c = _cache()
@@ -2243,7 +2324,7 @@ class TestServingLint:
 
 
 # ----------------------------------------------------------------------
-# decode_bench rungs: CI smoke on the CPU mesh + perf_history direction
+# decode_bench rungs: CI smoke on the CPU mesh
 # ----------------------------------------------------------------------
 class TestDecodeBenchCI:
     def test_decode_rungs_emit_protocol_json_on_cpu_mesh(self, tmp_path):
@@ -2251,11 +2332,8 @@ class TestDecodeBenchCI:
         8-virtual-device CPU mesh and print per-rung JSON carrying the
         min-of-N protocol fields plus the serving fingerprints (the
         ``decode_step`` budget verdict, the decode program's authored
-        census + trace hash, capacity/page geometry) — and every row's
-        metric resolves HIGHER-better under perf_history's direction
-        heuristic (the ``tokens_per_sec_per_chip`` unit contains the
-        ``sec_per`` substring trap).  Tiny shapes via the HUNT_* knobs:
-        a smoke of the harness, not a measurement."""
+        census + trace hash, capacity/page geometry).  Tiny shapes via
+        the HUNT_* knobs: a smoke of the harness, not a measurement."""
         import json as _json
         import subprocess
         import sys
@@ -2283,11 +2361,6 @@ class TestDecodeBenchCI:
             f"--- stdout ---\n{proc.stdout[-3000:]}\n"
             f"--- stderr ---\n{proc.stderr[-3000:]}"
         )
-        sys.path.insert(0, os.path.join(repo, "benchmarks"))
-        try:
-            from perf_history import lower_is_better
-        finally:
-            sys.path.pop(0)
         recs = {}
         for line in proc.stdout.splitlines():
             if line.startswith("{"):
@@ -2307,7 +2380,7 @@ class TestDecodeBenchCI:
             r = recs[name]
             # a noisy CI host can land every paired difference
             # non-positive: the bench then reports a DISCLOSED null
-            # (perf_history skips null rows) — never a negative rate
+            # — never a negative rate
             if r["noise_floor"]:
                 assert r["value"] is None
             else:
@@ -2325,8 +2398,6 @@ class TestDecodeBenchCI:
             assert r["decode_census"] == {}
             assert len(r["decode_trace_hash"]) == 12
             assert r["page_size"] == 8
-            # gated direction-aware: higher-better despite "sec_per"
-            assert not lower_is_better(name, r)
         assert recs["decode_bs1_tokens_per_sec_per_chip"]["capacity"] == 1
         assert recs[
             "decode_saturated_tokens_per_sec_per_chip"]["capacity"] == 2

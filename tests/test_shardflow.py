@@ -46,6 +46,7 @@ from chainermn_tpu.analysis import (
     memory_budget_for,
     shardflow,
     trace_collectives,
+    trace_jaxpr,
     train_step_memory,
     wire_bytes,
 )
@@ -607,13 +608,15 @@ class TestCostModel:
         assert all(r.bytes_on_wire is not None for r in tr)
 
     def test_axis_sizes_seed_for_meshless_traces(self):
-        """A jaxpr with no shard_map mesh (pmap binds the axis without
-        one) prices records only from the caller's seed."""
-        fn = jax.pmap(lambda x: lax.psum(x, "i"), axis_name="i")
-        x = jnp.zeros((1, 4))
-        unpriced = trace_collectives(fn, x)
+        """A jaxpr with no shard_map mesh (an axis bound by ``axis_env``;
+        jax 0.9's pmap is a shard_map and carries one) prices records
+        only from the caller's seed."""
+        jaxpr = jax.make_jaxpr(
+            lambda x: lax.psum(x, "i"), axis_env=[("i", 8)]
+        )(jnp.zeros((4,)))
+        unpriced = trace_jaxpr(jaxpr)
         assert unpriced.records[0].bytes_on_wire is None
-        priced = trace_collectives(fn, x, axis_sizes={"i": 8})
+        priced = trace_jaxpr(jaxpr, axis_sizes={"i": 8})
         assert priced.records[0].world == 8
         assert priced.records[0].bytes_on_wire is not None
 

@@ -14,7 +14,9 @@ ahead of time for a *described* ``v5e:2x2`` and prints
 * the census of the gradient reductions: synchronous against
   asynchronous, and the asynchronous ones with compute between start
   and done (``analysis.hlo.collective_schedule``; the same reading
-  ``step.collective_schedule`` gives on the chip).
+  ``step.collective_schedule`` gives on the chip), beside the program's
+  argument, temporary and code sizes (``memory_analysis()``: what a
+  warm start loads is the code).
 
 No chip: nothing runs and nothing is timed.  A compile that passes is
 not a chip run; what the chip makes of a schedule is ``PERF.md``'s to
@@ -161,6 +163,7 @@ def main(argv=None):
         "compile_s": round(time.perf_counter() - t0, 1),
         "argument_gb": memory.argument_size_in_bytes / 1e9,
         "temp_gb": memory.temp_size_in_bytes / 1e9,
+        "code_mb": memory.generated_code_size_in_bytes / 1e6,
         "census_weight_gradients": schedule.census(WEIGHT_GRADIENT_BYTES),
         "census_all": schedule.census(),
     }))

@@ -52,11 +52,12 @@ LM_SP_TP_ARGV = LM_WIDTH_ARGV + [
 ]
 LM_DP_ARGV = LM_WIDTH_ARGV + ["--steps", "2", "--generate", "0"]
 #: of the LM step's weight-gradient all-reduce bytes over four chips, the
-#: share that is asynchronous with a matmul inside: the ahead-of-time
-#: compile at LM_WIDTH_ARGV reads 17 of 34, 0.2195 (the cell's 18-layer
-#: step 44 of 74, 0.361); the embedding's float32 sum, half the bytes
-#: here, blocks
-LM_DP_OVERLAPPED_SHARE = 0.21
+#: share that is asynchronous with compute inside: the ahead-of-time
+#: compile at LM_WIDTH_ARGV reads 34 of 34, all of the bytes (the cell's
+#: 18-layer step 74 of 74), the embedding's float32 sum among them; with
+#: the k-loop fusions left out of the option set it read 17 of 34,
+#: 0.2195 (PR 31).  Set from the ahead-of-time reading (PR 33)
+LM_DP_OVERLAPPED_SHARE = 0.99
 
 
 def check(cond, msg):
@@ -459,8 +460,8 @@ def phase_dp(device, argv=RESNET_ARGV):
 def _lm_dp_schedule(devices, argv=LM_DP_ARGV):
     """The LM's ``param_specs`` step, data-parallel over every chip: its
     gradient all-reduces are autodiff's, one a leaf, and the step builder
-    compiles those that can ride a weight-gradient matmul asynchronous.
-    Read from the program that ran."""
+    compiles every one asynchronous, riding a weight-gradient matmul or
+    another leaf's AdamW update.  Read from the program that ran."""
     from chainermn_tpu.analysis.hlo import WEIGHT_GRADIENT_BYTES
 
     out = load_example("lm/train_lm.py").main(argv)

@@ -41,6 +41,36 @@ def mesh8(devices8):
     return Mesh(np.array(devices8), ("mn",))
 
 
+@pytest.fixture
+def pinned_and_unpinned_texts(monkeypatch):
+    """``texts(build)``: the compiled text of the step ``build() ->
+    (step, args)`` makes, as ``build_train_step`` jits it and again with
+    ``in_shardings`` taken off its ``jax.jit``: for arguments that lie as
+    the specs say, the pinned program must be the one ``jit`` made when
+    it read the layout off the arguments."""
+    real_jit = jax.jit
+
+    def texts(build):
+        pinned, out = [], []
+
+        def unpinned_jit(*args, **kwargs):
+            pinned.append(kwargs.pop("in_shardings", None))
+            return real_jit(*args, **kwargs)
+
+        for jit in (real_jit, unpinned_jit):
+            monkeypatch.setattr(jax, "jit", jit)
+            # one call site for both: the program text records its stack
+            step, args = build()
+            pinned.clear()  # build() may jit helpers of its own
+            out.append(step.get_jitted(*args[:2]).lower(
+                *args).compile().as_text())
+        monkeypatch.setattr(jax, "jit", real_jit)
+        assert any(shardings is not None for shardings in pinned)
+        return out
+
+    return texts
+
+
 def subprocess_env(devices: int = 8) -> dict:
     """Env for spawning a framework subprocess on a virtual CPU mesh —
     shared by the multi-process tier and the example smoke tests (one

@@ -386,3 +386,107 @@ class TestParamSpecsCollectiveTrace:
 
         with pytest.raises(CollectiveTraceMismatchError):
             trace_agreement(Peer(), step.collective_trace(*args))
+
+
+class _StepCompiles:
+    """Counts backend compiles of ``jit(_step)`` while entered (the event
+    ``cellbench/window.py::CompileCounter`` listens to; ``_cache_size()``
+    counts argument signatures, not programs)."""
+
+    def __enter__(self):
+        from jax import monitoring
+
+        self.count = 0
+        monitoring.register_event_duration_secs_listener(self._on_event)
+        return self
+
+    def _on_event(self, event, duration, fun_name=None, **_):
+        if event.endswith("backend_compile_duration") \
+                and fun_name == "jit(_step)":
+            self.count += 1
+
+    def __exit__(self, *exc):
+        from jax import monitoring
+
+        monitoring.unregister_event_duration_listener(self._on_event)
+
+
+class TestAStepIsOneExecutable:
+    """However params and optimizer state arrive, the step they run is
+    the one program: ``_finish_build`` pins ``in_shardings`` (state that
+    is not committed to the mesh is laid out, not compiled for), and the
+    step lays host values out before ``jit`` sees them (jit keys its
+    trace on an argument's type, which carries the array's mesh)."""
+
+    @pytest.mark.parametrize("n_devices", [1, 4])
+    @pytest.mark.parametrize("body", ["param_specs", "wire"])
+    def test_one_compile_and_equal_results_however_state_arrives(
+        self, devices8, body, n_devices
+    ):
+        devices = devices8[:n_devices]
+        if body == "param_specs":
+            comm = cmn.create_communicator(
+                "hybrid", devices=devices, tp_size=1)
+            host_params = _tp_params_from_dense(_dense_params())
+            opt = cmn.create_multi_node_optimizer(optax.adam(0.05), comm)
+            step = build_train_step(
+                comm, _mse_loss(TpMLP()), opt,
+                data_axes=comm.data_axis_names,
+                param_specs=megatron_param_specs(
+                    host_params, model_axis="mn_model"),
+                donate=False,
+            )
+        else:
+            comm = cmn.create_communicator("tpu", devices=devices)
+            host_params = _dense_params()
+            opt = cmn.create_multi_node_optimizer(optax.adam(0.05), comm)
+            step = build_train_step(
+                comm, _mse_loss(DenseMLP()), opt, donate=False)
+        tree_map = jax.tree_util.tree_map
+        host_state = opt.init(host_params)  # Adam's: all zeros
+        params, state = step.place(host_params, host_state)
+        batch = step.place_batch(_batch())
+        arrivals = {
+            "placed": (params, state),
+            # what the cells' runner hands the step at every reseed: the
+            # outputs of a jit whose inputs are all dead are uncommitted
+            "uncommitted": (
+                params, jax.jit(lambda s: tree_map(jnp.zeros_like, s))(state)),
+            "default_device": (host_params, host_state),
+            "numpy_state": (params, tree_map(np.asarray, state)),
+            "numpy": (tree_map(np.asarray, params),
+                      tree_map(np.asarray, state)),
+        }
+        assert not any(leaf.committed for leaf in jax.tree_util.tree_leaves(
+            arrivals["uncommitted"][1]))
+        with _StepCompiles() as compiles:
+            got = {name: step(p, s, batch)
+                   for name, (p, s) in arrivals.items()}
+            # and the step's own outputs, the steady state of a loop
+            step(*got["placed"][:2], batch)
+        assert compiles.count == 1
+        want = jax.tree_util.tree_leaves(got["placed"])
+        for name, out in got.items():
+            for a, b in zip(want, jax.tree_util.tree_leaves(out)):
+                np.testing.assert_array_equal(
+                    np.asarray(a), np.asarray(b), err_msg=name)
+        assert np.isfinite(float(got["placed"][2]["loss"]))
+
+    @pytest.mark.parametrize("body", ["param_specs", "wire"])
+    def test_placed_call_compiles_to_the_unpinned_text(
+        self, devices8, pinned_and_unpinned_texts, body
+    ):
+        def build():
+            if body == "param_specs":
+                _, step, args = _param_specs_step(devices8, 4, 1)
+                return step, args
+            comm = cmn.create_communicator("tpu", devices=devices8[:4])
+            opt = cmn.create_multi_node_optimizer(optax.adam(0.05), comm)
+            step = build_train_step(
+                comm, _mse_loss(DenseMLP()), opt, donate=False)
+            params = _dense_params()
+            return step, (*step.place(params, opt.init(params)),
+                          step.place_batch(_batch()))
+
+        pinned, unpinned = pinned_and_unpinned_texts(build)
+        assert pinned == unpinned

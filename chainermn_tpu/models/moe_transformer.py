@@ -38,10 +38,13 @@ from jax import lax
 # Communication goes through the audited wrappers (analysis.lint gate).
 from chainermn_tpu.functions import collectives as _cc
 from chainermn_tpu.models.transformer import (
+    HEAD_CE_SCOPE,
+    BlockOptions,
     SelfAttention,
     TransformerBlock,
     lm_loss,
     make_lm_embed,
+    make_norm,
     sp_lm_loss,
     vp_lm_loss,
 )
@@ -50,11 +53,19 @@ from chainermn_tpu.parallel.expert_parallel import (
     compute_capacity,
     dispatch_to_queues,
     expert_parallel_moe,
+    held_experts_moe,
     load_balancing_loss,
     mlp_experts,
     resolve_dispatch_impl,
     route_plan,
 )
+
+#: flax collection the dropless expert layers ``sow`` their counters
+#: into (``moe_rows_routed``, ``moe_rows_computed``, ``moe_dropped``)
+COUNTERS = "counters"
+#: and the one for their routing decisions: ``chosen (tokens, k)``, the
+#: experts each token's routes went to
+ROUTES = "routes"
 
 
 class MoeMlp(nn.Module):
@@ -70,6 +81,21 @@ class MoeMlp(nn.Module):
 
     Returns ``(y, aux)``: combined outputs (dropped tokens yield zeros —
     add the residual outside) and the load-balancing auxiliary loss.
+
+    ``routing="dropless"`` (SiLU-gated experts ``W_d(silu(W_g x) * W_u
+    x)``, parameters ``expert_wg`` / ``expert_wu`` / ``expert_wd``): no
+    capacity queue and no drop.  The layer routes over all
+    ``n_experts`` (float32 softmax, top ``k``, weights renormalised over
+    them) and computes the part of the result that the experts it holds
+    give, every route to them at any imbalance, adding nothing for the
+    absent ones (:func:`~chainermn_tpu.parallel.expert_parallel.held_experts_moe`).
+    ``held=(first, count)`` says which it holds: with ``expert_axis``
+    unset that is one chip's share of a layer several chips hold
+    together (default: all of them); with ``expert_axis`` set every chip
+    of the axis runs this same layer on its own ``n_experts /
+    axis_size`` experts and the parts are summed over the axis.  Its
+    counters are sown into the ``"counters"`` collection, its routing
+    decisions into ``"routes"``.
     """
 
     n_experts: int
@@ -92,6 +118,42 @@ class MoeMlp(nn.Module):
     aux_stat_axes: Optional[tuple] = None
     dispatch_impl: str = "auto"  # einsum | scatter | auto
     dtype: Any = jnp.bfloat16
+    routing: str = "capacity"  # capacity | dropless
+    held: Optional[Tuple[int, int]] = None  # dropless: (first, count)
+
+    def _dropless(self, x, router, local_e: int):
+        """The held experts' part of the layer, without a drop."""
+        from chainermn_tpu.parallel.tensor_parallel import _sharded_init
+
+        b, s, d = x.shape
+        if self.expert_axis is not None:
+            if self.held is not None:
+                raise ValueError("held is the axis's to say under "
+                                 "expert_axis")
+            first = lax.axis_index(self.expert_axis) * local_e
+            count = local_e
+        else:
+            first, count = self.held or (0, self.n_experts)
+        init = nn.initializers.normal(0.02)
+        if self.expert_axis is not None:
+            init = _sharded_init(init, self.expert_axis)
+        wg = self.param("expert_wg", init, (count, d, self.d_ff),
+                        jnp.float32)
+        wu = self.param("expert_wu", init, (count, d, self.d_ff),
+                        jnp.float32)
+        wd = self.param("expert_wd", init, (count, self.d_ff, d),
+                        jnp.float32)
+        y, aux, counters, chosen = held_experts_moe(
+            x.reshape(-1, d), router, wg, wu, wd,
+            num_experts=self.n_experts, k=self.k, first=first,
+            aux_stat_axes=self.aux_stat_axes,
+        )
+        if self.expert_axis is not None:
+            y = _cc.psum(y, self.expert_axis)
+        for name, value in counters.items():
+            self.sow(COUNTERS, name, value)
+        self.sow(ROUTES, "chosen", chosen)
+        return y.reshape(b, s, d).astype(x.dtype), aux
 
     @nn.compact
     def __call__(self, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -109,6 +171,11 @@ class MoeMlp(nn.Module):
             "router", nn.initializers.normal(0.02),
             (d, self.n_experts), jnp.float32,
         )
+        if self.routing == "dropless":
+            return self._dropless(x, router, local_e)
+        if self.routing != "capacity":
+            raise ValueError(f"routing must be capacity or dropless, got "
+                             f"{self.routing!r}")
         # Per-chip expert blocks are independent draws (fold the axis
         # index into the RNG — tensor_parallel's sharded-init convention).
         from chainermn_tpu.parallel.tensor_parallel import _sharded_init
@@ -199,15 +266,18 @@ class MoeTransformerBlock(nn.Module):
     decode: bool = False
     cache_len: int = 0
     attention_fn: Optional[Callable] = None
+    options: BlockOptions = BlockOptions()
+    routing: str = "capacity"
+    held: Optional[Tuple[int, int]] = None
 
     @nn.compact
     def __call__(self, x):
-        ln = lambda: nn.LayerNorm(dtype=jnp.float32)
+        ln = lambda: make_norm(self.options)
         x = x + SelfAttention(
             self.n_heads, dtype=self.dtype, seq_axis=self.seq_axis,
             tp_axis=self.tp_axis, sp_impl=self.sp_impl,
             decode=self.decode, cache_len=self.cache_len,
-            attention_fn=self.attention_fn,
+            attention_fn=self.attention_fn, options=self.options,
         )(ln()(x).astype(self.dtype))
         y, aux = MoeMlp(
             self.n_experts, self.d_ff, k=self.k,
@@ -216,6 +286,7 @@ class MoeTransformerBlock(nn.Module):
             expert_axis=self.expert_axis,
             aux_stat_axes=self.aux_stat_axes,
             dispatch_impl=self.dispatch_impl, dtype=self.dtype,
+            routing=self.routing, held=self.held,
         )(ln()(x).astype(self.dtype))
         return x + y, aux
 
@@ -260,9 +331,66 @@ class MoeTransformerLM(nn.Module):
     decode: bool = False
     cache_len: int = 0
     attention_fn: Optional[Callable] = None
+    # the block's options (norm, grouped-query heads, rotary positions,
+    # q/k norm, block diffusion): transformer.BlockOptions.  With
+    # ``rope_theta`` the model holds no position table.
+    options: BlockOptions = BlockOptions()
+    # expert layers without drops over the held experts: MoeMlp
+    routing: str = "capacity"
+    held: Optional[Tuple[int, int]] = None
+    # an output head of its own (``lm_head (vocab, d)``) instead of the
+    # embedding's transpose
+    tie_head: bool = True
+    # return the final-norm hidden states instead of logits: the
+    # block-diffusion loss applies the head to the noised copy alone
+    return_hidden: bool = False
+
+    def _rotary(self, tokens):
+        """The model on the general attention path: no position table,
+        every block ``options``' block."""
+        d_ff = self.d_ff or 4 * self.d_model
+        embed = make_lm_embed(
+            self, self.vocab_size, self.d_model, self.tp_axis,
+            self.vocab_parallel,
+        )
+        head = embed.embedding if self.tie_head else self.param(
+            "lm_head", nn.initializers.normal(0.02),
+            (self.vocab_size, self.d_model), jnp.float32)
+        x = embed(tokens).astype(self.dtype)
+        aux = jnp.zeros((), jnp.float32)
+        for i in range(self.n_layers):
+            if (i + 1) % self.moe_every == 0:
+                x, a = MoeTransformerBlock(
+                    self.n_heads, d_ff, self.n_experts, k=self.k,
+                    capacity_factor=self.capacity_factor,
+                    capacity=self.capacity, no_drop=self.no_drop,
+                    aux_stat_axes=self.aux_stat_axes,
+                    dispatch_impl=self.dispatch_impl, dtype=self.dtype,
+                    expert_axis=self.expert_axis, options=self.options,
+                    routing=self.routing, held=self.held,
+                )(x)
+                aux = aux + a
+            else:
+                x = TransformerBlock(
+                    self.n_heads, d_ff, dtype=self.dtype,
+                    options=self.options,
+                )(x)
+        x = make_norm(self.options)(x)
+        if self.return_hidden:
+            return x, aux
+        with jax.named_scope(HEAD_CE_SCOPE):
+            return x.astype(jnp.float32) @ head.T, aux
 
     @nn.compact
     def __call__(self, tokens):
+        if self.options.rope_theta:
+            return self._rotary(tokens)
+        if self.options != BlockOptions() or not self.tie_head \
+                or self.return_hidden or self.routing != "capacity":
+            raise ValueError(
+                "the block's options, dropless routing, an untied head "
+                "and return_hidden come with rotary positions "
+                "(options.rope_theta)")
         b, s = tokens.shape
         d_ff = self.d_ff or 4 * self.d_model
         embed = make_lm_embed(
@@ -380,7 +508,7 @@ def moe_param_specs(params, model_axis: str = "mn_model"):
     """PartitionSpec pytree for a :class:`MoeTransformerLM` parameter
     tree: Megatron column/row kernels shard over ``model_axis`` (as in
     :func:`~chainermn_tpu.parallel.megatron_param_specs`), MoE expert
-    blocks (``expert_w1``/``expert_w2``) shard their leading expert
+    blocks (``expert_w*``) shard their leading expert
     dimension over the same axis, VocabParallelEmbed tables shard their
     vocab rows, and everything else (dense embeddings, LayerNorms,
     routers) replicates."""
@@ -395,7 +523,7 @@ def moe_param_specs(params, model_axis: str = "mn_model"):
     def leaf_spec(path, leaf):
         keys = _path_keys(path)
         last = keys[-1] if keys else ""
-        if last in ("expert_w1", "expert_w2"):
+        if last.startswith("expert_w"):
             return P(model_axis)  # leading expert dim
         spec = _tp_leaf_spec(keys, model_axis)
         return P() if spec is None else spec

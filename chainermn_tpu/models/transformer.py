@@ -23,6 +23,7 @@ Design:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Callable, Optional
 
@@ -46,6 +47,99 @@ class MlpBlock(nn.Module):
         h = nn.Dense(self.d_ff, dtype=self.dtype)(x)
         h = nn.gelu(h)
         return nn.Dense(d, dtype=self.dtype)(h)
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockOptions:
+    """What the one transformer block can be besides GPT-2's (the
+    defaults): the options of its norms and of its attention.  A module
+    takes them as one hashable field, ``options``.
+
+    ``norm``: ``"layernorm"`` or ``"rmsnorm"`` (gain only), epsilon
+    ``norm_eps``.  ``n_kv_heads``: key/value heads, each shared by
+    ``n_heads // n_kv_heads`` query heads.  ``head_dim``: width of a
+    head where it is not ``d_model // n_heads``.  ``rope_theta``: rotary
+    positions of that base on q and k (the model then holds no position
+    table).  ``qk_norm``: an RMSNorm over each head of q and k, before
+    the rotation.  ``block_diffusion``: block length ``B`` of
+    block-diffusion training; the sequence axis then holds the clean
+    copy of every sequence followed by its noised copy, both at
+    positions ``0..s-1``, under :func:`ops.pallas_attention.block_diffusion_mask`.
+    ``use_flash``: the Pallas kernels instead of a dense masked softmax.
+    Any of the attention options takes :class:`SelfAttention` off its
+    fused-qkv path onto separate ``q_proj`` / ``k_proj`` / ``v_proj`` /
+    ``o_proj`` kernels; that path is single-device in the sequence and
+    head axes (no ``seq_axis``, ``tp_axis`` or ``decode``)."""
+
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    n_kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    rope_theta: Optional[float] = None
+    qk_norm: bool = False
+    block_diffusion: int = 0
+    use_flash: bool = False
+
+    @property
+    def general_attention(self) -> bool:
+        return bool(self.n_kv_heads or self.head_dim or self.rope_theta
+                    or self.qk_norm or self.block_diffusion)
+
+
+def rms_norm(x, scale, eps: float, dtype):
+    """``x / rms(x) * scale`` over the last axis, statistics in float32,
+    the result in ``dtype``."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (y * scale).astype(dtype)
+
+
+class RMSNorm(nn.Module):
+    """:func:`rms_norm` with a learned gain.  The backward pass computes
+    the float32 intermediates again from ``x`` (``jax.checkpoint``):
+    none of them is kept beside the activation it normalises."""
+
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones,
+                           (x.shape[-1],), jnp.float32)
+        return jax.checkpoint(
+            functools.partial(rms_norm, eps=self.eps, dtype=self.dtype)
+        )(x, scale)
+
+
+def make_norm(options: BlockOptions, dtype=jnp.float32, **kw):
+    """The block's norm: flax ``LayerNorm`` (its epsilon 1e-6, as every
+    model before the option) or :class:`RMSNorm`."""
+    if options.norm == "rmsnorm":
+        return RMSNorm(eps=options.norm_eps, dtype=dtype, **kw)
+    if options.norm != "layernorm":
+        raise ValueError(f"norm must be layernorm or rmsnorm, got "
+                         f"{options.norm!r}")
+    return nn.LayerNorm(dtype=dtype, **kw)
+
+
+def apply_rope(x, positions, theta: float):
+    """Rotary positions on ``x (b, s, heads, dh)``, the halves
+    convention (``rotate_half``), angles in float32."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, None] * freq[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+#: device scope of the attention projections (q, k, v, their norms and
+#: rotation, and the output projection) on the general path
+ATTN_PROJ_SCOPE = "attn_proj"
 
 
 # Data-parallel mesh axis names this package's communicators bind
@@ -118,6 +212,59 @@ class SelfAttention(nn.Module):
     decode: bool = False
     cache_len: int = 0
     attention_fn: Optional[Callable] = None
+    options: BlockOptions = BlockOptions()
+
+    def _general(self, x, causal: bool):
+        """The path of :class:`BlockOptions`' attention options."""
+        o = self.options
+        if self.tp_axis is not None or self.seq_axis is not None \
+                or self.decode:
+            raise ValueError(
+                "grouped-query / rotary / block-diffusion attention is "
+                "single-device in sequence and heads: no seq_axis, "
+                "tp_axis or decode")
+        from chainermn_tpu.ops import pallas_attention as pa
+
+        b, s, d = x.shape
+        hq = self.n_heads
+        hkv = o.n_kv_heads or hq
+        dh = o.head_dim or d // hq
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=self.dtype)
+        # both copies of a block-diffusion pair sit at 0..s/2-1
+        pos = jnp.arange(s) % (s // 2 if o.block_diffusion else s)
+
+        def norm_and_rotate(t, gain):
+            if gain is not None:
+                t = rms_norm(t, gain, o.norm_eps, self.dtype)
+            if o.rope_theta:
+                t = apply_rope(t, pos, o.rope_theta)
+            return t
+
+        with jax.named_scope(ATTN_PROJ_SCOPE):
+            q = dense(hq * dh, name="q_proj")(x).reshape(b, s, hq, dh)
+            k = dense(hkv * dh, name="k_proj")(x).reshape(b, s, hkv, dh)
+            v = dense(hkv * dh, name="v_proj")(x).reshape(b, s, hkv, dh)
+            gains = [self.param(n, nn.initializers.ones, (dh,), jnp.float32)
+                     if o.qk_norm else None for n in ("q_norm", "k_norm")]
+            # float32 inside, recomputed in the backward pass: only the
+            # projections' outputs and the kernels' inputs are kept
+            q = jax.checkpoint(norm_and_rotate)(q, gains[0])
+            k = jax.checkpoint(norm_and_rotate)(k, gains[1])
+        if o.block_diffusion:
+            attend = pa.block_diffusion_attention if o.use_flash \
+                else pa.block_diffusion_attention_dense
+            out = attend(q, k, v, o.block_diffusion)
+        elif o.use_flash and causal:
+            # causal is block-causal at block length 1
+            out, _ = pa.block_causal_attention_with_lse(q, k, v, 1)
+        else:
+            from chainermn_tpu.ops import multi_head_attention
+
+            rep = lambda t: jnp.repeat(t, hq // hkv, axis=2)
+            out = multi_head_attention(q, rep(k), rep(v), causal=causal)
+        with jax.named_scope(ATTN_PROJ_SCOPE):
+            return dense(d, name="o_proj")(out.reshape(b, s, hq * dh))
 
     def _decode_attend(self, q, k, v, b, heads, dh, scale):
         """Append k/v to the cache and attend q against the filled
@@ -169,6 +316,8 @@ class SelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, *, causal: bool = True):
+        if self.options.general_attention:
+            return self._general(x, causal)
         b, s, d = x.shape
         if d % self.n_heads:
             raise ValueError(f"d_model ({d}) % n_heads ({self.n_heads})")
@@ -289,10 +438,11 @@ class TransformerBlock(nn.Module):
     # fp32 LayerNorm is the numerics-safe default; bf16 is a perf knob
     # no cell runs (LayerNorm rides the matmul fusions: PERF.md section 5)
     ln_dtype: Any = jnp.float32
+    options: BlockOptions = BlockOptions()
 
     @nn.compact
     def __call__(self, x):
-        ln = lambda: nn.LayerNorm(dtype=self.ln_dtype)
+        ln = lambda: make_norm(self.options, self.ln_dtype)
 
         def drop(h):
             return _stream_dropout(
@@ -304,7 +454,7 @@ class TransformerBlock(nn.Module):
             self.n_heads, dtype=self.dtype, seq_axis=self.seq_axis,
             tp_axis=self.tp_axis, sp_impl=self.sp_impl,
             decode=self.decode, cache_len=self.cache_len,
-            attention_fn=self.attention_fn,
+            attention_fn=self.attention_fn, options=self.options,
         )(ln()(x).astype(self.dtype)))
         if self.tp_axis is not None:
             mlp = TpMlpBlock(self.d_ff, tp_axis=self.tp_axis,
@@ -470,6 +620,34 @@ def lm_loss(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
     return optax.softmax_cross_entropy_with_integer_labels(
         preds, targets
     ).mean()
+
+
+def noised_copy(tokens: jnp.ndarray, mask: jnp.ndarray, mask_id: int):
+    """Block-diffusion input: the clean copy of every sequence followed
+    by its noised copy (``mask_id`` where ``mask``), ``(b, 2s)``."""
+    return jnp.concatenate(
+        [tokens, jnp.where(mask, mask_id, tokens)], axis=1)
+
+
+@jax.named_scope(HEAD_CE_SCOPE)
+def block_diffusion_loss(hidden: jnp.ndarray, head: jnp.ndarray,
+                         tokens: jnp.ndarray, weights: jnp.ndarray,
+                         dtype=jnp.bfloat16) -> jnp.ndarray:
+    """The block-diffusion objective (BD3-LM, arXiv:2503.09573) from the
+    final hidden states ``(b, 2s, d)`` of a [clean; noised] pair:
+    ``sum_i weights_i * -log p(tokens_i | noised copy, clean prefix)``
+    over the number of sample tokens, the logits taken on the noised
+    copy at the position itself (no shift) against the untied ``head
+    (vocab, d)``; ``weights`` is ``1 / t_b`` on the masked positions of
+    block ``b`` and 0 elsewhere.  Operands in ``dtype``, float32
+    logits."""
+    s = tokens.shape[1]
+    logits = jnp.einsum(
+        "bsd,vd->bsv", hidden[:, s:].astype(dtype), head.astype(dtype),
+        preferred_element_type=jnp.float32)
+    nll = jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+        logits, tokens[..., None], axis=-1)[..., 0]
+    return jnp.sum(nll * weights) / tokens.size
 
 
 def _sp_targets(tokens: jnp.ndarray, axis_name: str):

@@ -1647,3 +1647,524 @@ def fused_cast_scale(x: jnp.ndarray, scale: float, dtype,
         interpret=_should_interpret(interpret),
     )(tiled)
     return out.reshape(-1)[:n].reshape(shape)
+
+
+# ----------------------------------------------------------------------
+# Block-causal, grouped-query flash attention (block-diffusion training)
+# ----------------------------------------------------------------------
+# A second mask family on the same taxonomy.  With the sequence cut into
+# blocks of ``block`` positions, key j is live for query i iff
+#
+#   inclusive   j < block * (i // block + 1)   (own block and earlier)
+#   strict      j < block * (i // block)       (earlier blocks only)
+#
+# Both differ from the causal mask only inside the tiles the diagonal
+# crosses (``block`` divides every tile), so :func:`_block_class` at
+# ``causal=True`` classifies their grid points and tiles exactly, and
+# :func:`_strips` / :func:`_compute_tile` skip the same dead tiles.  The
+# kernels below are the split kernels' bodies with that mask on the
+# diagonal tile and with ``group`` query heads sharing one key/value
+# head: the forward and dq kernels name K/V block ``i // group`` (K/V is
+# never repeated in HBM), the dk/dv kernel sweeps the query blocks of
+# all ``group`` heads of its K/V head and sums over them.  Launches are
+# aligned by contract (``s_q == s_k``, a whole number of square blocks).
+#
+# A strict launch leaves the queries of block 0 with no live key: their
+# rows come out as a finite average with ``lse = -1e30``, which a merge
+# by lse (:func:`block_diffusion_attention`) weighs with exactly 0.
+
+
+def _block_causal_mask(size: int, block: int, strict: bool):
+    """The diagonal piece's mask at offsets (0, 0): every diagonal block
+    and tile starts at a multiple of ``block``, so one mask serves all."""
+    q_idx = lax.broadcasted_iota(jnp.int32, (size, size), 0)
+    k_idx = lax.broadcasted_iota(jnp.int32, (size, size), 1)
+    first = q_idx - lax.rem(q_idx, block)
+    return k_idx < (first if strict else first + block)
+
+
+def _bc_class(j, kb, bs, s):
+    return _block_class(j * bs, kb * bs, s_q=s, s_qp=s, s_k=s, s_kp=s,
+                        causal=True, block_q=bs, block_k=bs)
+
+
+def _bc_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
+                   l_ref, *, s: int, scale: float, bs: int, block: int,
+                   strict: bool, tile):
+    j = pl.program_id(1)
+    kb = pl.program_id(2)
+    n_kb = pl.num_programs(2)
+    interior, masked = _bc_class(j, kb, bs, s)
+
+    def _attend(with_mask):
+        mask = _block_causal_mask(tile or bs, block, strict) \
+            if with_mask else None
+        for rows, cols, masked_cols in _strips(bs, tile, with_mask):
+            q = q_ref[0, rows, :].astype(jnp.float32) * scale
+            k_blk = k_ref[0, cols, :].astype(jnp.float32)
+            v_blk = v_ref[0, cols, :].astype(jnp.float32)
+            sc = lax.dot_general(
+                q, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            sc = _select(mask, sc, _NEG_INF, masked_cols)
+            m_blk = jnp.max(sc, axis=-1, keepdims=True)
+            stat_shape = (m_blk.shape[0], m_ref.shape[1])
+
+            @pl.when(kb == 0)
+            def _first():
+                p = jnp.exp(sc - m_blk)
+                m_ref[rows, :] = jnp.broadcast_to(m_blk, stat_shape)
+                l_ref[rows, :] = jnp.broadcast_to(
+                    jnp.sum(p, axis=-1, keepdims=True), stat_shape)
+                acc_ref[rows, :] = lax.dot_general(
+                    p, v_blk, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+
+            @pl.when(kb != 0)
+            def _rest():
+                m_old = m_ref[rows, 0:1]
+                m_new = jnp.maximum(m_old, m_blk)
+                p = jnp.exp(sc - m_new)
+                alpha = jnp.exp(m_old - m_new)
+                l_new = alpha * l_ref[rows, 0:1] + jnp.sum(
+                    p, axis=-1, keepdims=True)
+                l_ref[rows, :] = jnp.broadcast_to(l_new, stat_shape)
+                m_ref[rows, :] = jnp.broadcast_to(m_new, stat_shape)
+                acc_ref[rows, :] = alpha * acc_ref[rows, :] \
+                    + lax.dot_general(
+                        p, v_blk, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )
+
+    @_when(interior)
+    def _fast():
+        _attend(with_mask=False)
+
+    @_when(masked)
+    def _slow():
+        _attend(with_mask=True)
+
+    @pl.when(kb == n_kb - 1)
+    def _finalize():
+        o_ref[0] = (
+            acc_ref[:] / jnp.maximum(l_ref[:, 0:1], 1e-30)
+        ).astype(o_ref.dtype)
+        lse_ref[0] = jnp.broadcast_to(
+            (m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30)))[
+                None, :],
+            lse_ref.shape[1:],
+        )
+
+
+def _bc_probabilities(q_ref, k_ref, lse_ref, rows, cols, mask, masked_cols,
+                      scale):
+    """The recomputed probability piece both backward kernels start
+    from, and its operands."""
+    q = q_ref[0, rows, :].astype(jnp.float32)
+    k_blk = k_ref[0, cols, :].astype(jnp.float32)
+    sc = lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale
+    p = jnp.exp(sc - lse_ref[0, 0, rows][:, None])
+    return q, k_blk, _select(mask, p, 0.0, masked_cols)
+
+
+def _bc_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dq_acc, *, s: int, scale: float, bs: int,
+                      block: int, strict: bool, tile):
+    j = pl.program_id(1)
+    kb = pl.program_id(2)
+    n_kb = pl.num_programs(2)
+
+    @pl.when(kb == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    interior, masked = _bc_class(j, kb, bs, s)
+
+    def _accum(with_mask):
+        mask = _block_causal_mask(tile or bs, block, strict) \
+            if with_mask else None
+        for rows, cols, masked_cols in _strips(bs, tile, with_mask):
+            _, k_blk, p = _bc_probabilities(
+                q_ref, k_ref, lse_ref, rows, cols, mask, masked_cols, scale)
+            v_blk = v_ref[0, cols, :].astype(jnp.float32)
+            do = do_ref[0, rows, :].astype(jnp.float32)
+            dp = lax.dot_general(
+                do, v_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            ds = p * (dp - delta_ref[0, 0, rows][:, None])
+            dq_acc[rows, :] += lax.dot_general(
+                ds, k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+
+    @_when(interior)
+    def _fast():
+        _accum(with_mask=False)
+
+    @_when(masked)
+    def _slow():
+        _accum(with_mask=True)
+
+    @pl.when(kb == n_kb - 1)
+    def _finalize():
+        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _bc_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dk_ref, dv_ref, dk_acc, dv_acc, *, s: int,
+                       scale: float, bs: int, block: int, strict: bool,
+                       tile, n_q: int):
+    """Grid (batch * kv heads, k blocks, group * q blocks): the innermost
+    axis sweeps the query blocks of each of the group's heads in turn,
+    so dk and dv of the shared head accumulate over the whole group."""
+    kb = pl.program_id(1)
+    t = pl.program_id(2)
+    n_t = pl.num_programs(2)
+    j = lax.rem(t, n_q)
+
+    @pl.when(t == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    interior, masked = _bc_class(j, kb, bs, s)
+
+    def _accum(with_mask):
+        mask = _block_causal_mask(tile or bs, block, strict) \
+            if with_mask else None
+        for rows, cols, masked_cols in _strips(bs, tile, with_mask):
+            q, _, p = _bc_probabilities(
+                q_ref, k_ref, lse_ref, rows, cols, mask, masked_cols, scale)
+            v_blk = v_ref[0, cols, :].astype(jnp.float32)
+            do = do_ref[0, rows, :].astype(jnp.float32)
+            dv_acc[cols, :] += lax.dot_general(
+                p, do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            dp = lax.dot_general(
+                do, v_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            ds = p * (dp - delta_ref[0, 0, rows][:, None])
+            dk_acc[cols, :] += lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale
+
+    @_when(interior)
+    def _fast():
+        _accum(with_mask=False)
+
+    @_when(masked)
+    def _slow():
+        _accum(with_mask=True)
+
+    @pl.when(t == n_t - 1)
+    def _finalize():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _bc_geometry(q, k, block, block_size, interpret, kind, tile):
+    """``(group, bs, tile)`` of a launch, after the contract's checks."""
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    if k.shape != (b, s, hkv, d) or hq % hkv:
+        raise ValueError(
+            f"block-causal attention needs q (b, s, hq, d) and k/v "
+            f"(b, s, hkv, d) with hq % hkv == 0; got {q.shape}, {k.shape}")
+    bs = _effective_q_block(
+        _clamp_blocks_for_dim(block_size, block_size, d, warn=False)[0],
+        s, interpret)
+    if s % bs or bs % block:
+        raise ValueError(
+            f"sequence length {s} must be a whole number of {bs}-blocks, "
+            f"each a whole number of mask blocks of {block}")
+    t = _compute_tile(bs, bs, kind, causal=True, aligned=True, tile=tile)
+    if t is not None and t % block:
+        t = None
+    return hq // hkv, bs, t
+
+
+def _to_bh(x):
+    """(b, s, h, d) -> (b * h, s, d)."""
+    b, s, h, d = x.shape
+    return jnp.moveaxis(x, 2, 1).reshape(b * h, s, d)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block", "strict", "scale", "block_size", "interpret",
+                     "tile"),
+)
+def _bc_forward(q, k, v, block, strict, scale, block_size, interpret,
+                tile=None):
+    b, s, hq, d = q.shape
+    group, bs, t = _bc_geometry(q, k, block, block_size, interpret, "fwd",
+                                tile)
+    n = s // bs
+    kv_index = lambda i, j, kb: (i // group, jnp.minimum(kb, j), 0)
+    out, lse = pl.pallas_call(
+        functools.partial(_bc_fwd_kernel, s=s, scale=scale, bs=bs,
+                          block=block, strict=strict, tile=t),
+        out_shape=[
+            _out_struct((b * hq, s, d), q.dtype, q, k, v),
+            _out_struct((b * hq, 8, s), jnp.float32, q, k, v),
+        ],
+        grid=(b * hq, n, n),
+        in_specs=[
+            pl.BlockSpec((1, bs, d), lambda i, j, kb: (i, j, 0)),
+            pl.BlockSpec((1, bs, d), kv_index),
+            pl.BlockSpec((1, bs, d), kv_index),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, bs, d), lambda i, j, kb: (i, j, 0)),
+            pl.BlockSpec((1, 8, bs), lambda i, j, kb: (i, 0, j)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bs, d), jnp.float32),
+            pltpu.VMEM((bs, 128), jnp.float32),
+            pltpu.VMEM((bs, 128), jnp.float32),
+        ],
+        interpret=interpret,
+        name="_bdflash_forward",
+    )(_to_bh(q), _to_bh(k), _to_bh(v))
+    return (jnp.moveaxis(out.reshape(b, hq, s, d), 1, 2),
+            lse[:, 0])  # (b, s, hq, d), (b * hq, s)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block", "strict", "scale", "block_size", "interpret",
+                     "tile"),
+)
+def _bc_backward(q, k, v, out, lse, g, g_lse, block, strict, scale,
+                 block_size, interpret, tile=None):
+    """dq, dk, dv of one launch; ``g_lse`` (b * hq, s) is the cotangent
+    of the log-sum-exp, folded into ``delta`` as in
+    :func:`_flash_backward`."""
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    group, bs, t = _bc_geometry(q, k, block, block_size, interpret, "bwd",
+                                tile)
+    n = s // bs
+    qb, kb_, vb, dob = _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(g)
+    delta = jnp.sum(dob.astype(jnp.float32)
+                    * _to_bh(out).astype(jnp.float32), axis=-1) \
+        - g_lse.astype(jnp.float32)
+    delta = jnp.broadcast_to(delta[:, None], (b * hq, 8, s))
+    lse8 = jnp.broadcast_to(lse[:, None], (b * hq, 8, s))
+    kwargs = dict(s=s, scale=scale, bs=bs, block=block, strict=strict,
+                  tile=t)
+
+    kv_index = lambda i, j, kb: (i // group, jnp.minimum(kb, j), 0)
+    row = lambda i, j, kb: (i, j, 0)
+    stat = lambda i, j, kb: (i, 0, j)
+    dq = pl.pallas_call(
+        functools.partial(_bc_bwd_dq_kernel, **kwargs),
+        out_shape=_out_struct((b * hq, s, d), q.dtype, q, k, v, g),
+        grid=(b * hq, n, n),
+        in_specs=[
+            pl.BlockSpec((1, bs, d), row),
+            pl.BlockSpec((1, bs, d), kv_index),
+            pl.BlockSpec((1, bs, d), kv_index),
+            pl.BlockSpec((1, bs, d), row),
+            pl.BlockSpec((1, 8, bs), stat),
+            pl.BlockSpec((1, 8, bs), stat),
+        ],
+        out_specs=pl.BlockSpec((1, bs, d), row),
+        scratch_shapes=[pltpu.VMEM((bs, d), jnp.float32)],
+        interpret=interpret,
+        name="_bdflash_backward_dq",
+    )(qb, kb_, vb, dob, lse8, delta)
+
+    # query block of sweep point t under K/V head i: head i * group +
+    # t // n, block t % n -- a dead point (the head's sweep starts above
+    # the diagonal) names the head's first live block again, so the
+    # pipeline fetches nothing for it
+    def q_of(i, kb, t):
+        return i * group + t // n, jnp.maximum(lax.rem(t, n), kb)
+
+    q_row = lambda i, kb, t: (*q_of(i, kb, t), 0)
+    q_stat = lambda i, kb, t: (q_of(i, kb, t)[0], 0, q_of(i, kb, t)[1])
+    kv_row = lambda i, kb, t: (i, kb, 0)
+    dk, dv = pl.pallas_call(
+        functools.partial(_bc_bwd_dkv_kernel, n_q=n, **kwargs),
+        out_shape=[
+            _out_struct((b * hkv, s, d), k.dtype, q, k, v, g),
+            _out_struct((b * hkv, s, d), v.dtype, q, k, v, g),
+        ],
+        grid=(b * hkv, n, group * n),
+        in_specs=[
+            pl.BlockSpec((1, bs, d), q_row),
+            pl.BlockSpec((1, bs, d), kv_row),
+            pl.BlockSpec((1, bs, d), kv_row),
+            pl.BlockSpec((1, bs, d), q_row),
+            pl.BlockSpec((1, 8, bs), q_stat),
+            pl.BlockSpec((1, 8, bs), q_stat),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, bs, d), kv_row),
+            pl.BlockSpec((1, bs, d), kv_row),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((bs, d), jnp.float32),
+            pltpu.VMEM((bs, d), jnp.float32),
+        ],
+        interpret=interpret,
+        name="_bdflash_backward_dkdv",
+    )(qb, kb_, vb, dob, lse8, delta)
+
+    def from_bh(x, h):
+        return jnp.moveaxis(x.reshape(b, h, s, d), 1, 2)
+
+    return from_bh(dq, hq), from_bh(dk, hkv), from_bh(dv, hkv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def block_causal_attention_with_lse(q, k, v, block, strict=False,
+                                    scale=None, block_size=None,
+                                    interpret=None, tile=None):
+    """Block-causal grouped-query flash attention returning ``(out,
+    lse)``, both differentiable.
+
+    ``q`` is (b, s, hq, d), ``k`` / ``v`` (b, s, hkv, d) with ``hq`` a
+    multiple of ``hkv``: query head ``h`` reads key/value head ``h //
+    (hq // hkv)``, which is never repeated in HBM.  Key ``j`` is live
+    for query ``i`` iff ``j < block * (i // block + 1)``, or with
+    ``strict`` iff ``j < block * (i // block)`` (a strict launch's first
+    ``block`` queries see no key: ``lse`` is ``-1e30`` there and ``out``
+    a finite average to be weighed with 0).  ``lse`` is (b, s, hq).
+    ``s`` must be a whole number of square kernel blocks (``block_size``,
+    default 1024, clamped to ``s``), each a whole number of ``block``.
+    """
+    return _bc_fwd_rule(q, k, v, block, strict, scale, block_size,
+                        interpret, tile)[0]
+
+
+def _bc_fwd_rule(q, k, v, block, strict, scale, block_size, interpret,
+                 tile):
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    out, lse = _bc_forward(q, k, v, block, strict, scale, block_size,
+                           _should_interpret(interpret), tile)
+    b, s, hq, _ = q.shape
+    return ((out, jnp.moveaxis(lse.reshape(b, hq, s), 1, 2)),
+            (q, k, v, out, lse))
+
+
+def _bc_bwd_rule(block, strict, scale, block_size, interpret, tile,
+                 residuals, g):
+    q, k, v, out, lse = residuals
+    g_out, g_lse = g
+    b, s, hq, _ = q.shape
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    return _bc_backward(
+        q, k, v, out, lse, g_out,
+        jnp.moveaxis(g_lse, 1, 2).reshape(b * hq, s), block, strict,
+        scale, block_size, _should_interpret(interpret), tile)
+
+
+block_causal_attention_with_lse.defvjp(_bc_fwd_rule, _bc_bwd_rule)
+
+
+def block_causal_mask(s: int, block: int, strict: bool = False):
+    """The dense (s, s) mask of the definition: the tests' and the dense
+    form's side of :func:`block_causal_attention_with_lse`."""
+    i = jnp.arange(s)[:, None]
+    j = jnp.arange(s)[None, :]
+    return j < block * (i // block + (0 if strict else 1))
+
+
+def block_diffusion_mask(s: int, block: int):
+    """Dense (2s, 2s) mask of block-diffusion training over the
+    concatenation [clean; noised] of one sequence: a clean query of
+    block b sees the clean keys of blocks <= b; a noised query of block
+    b the clean keys of blocks < b and the noised keys of block b."""
+    same = (jnp.arange(s)[:, None] // block) == (jnp.arange(s)[None, :]
+                                                  // block)
+    top = jnp.concatenate(
+        [block_causal_mask(s, block), jnp.zeros((s, s), bool)], axis=1)
+    bottom = jnp.concatenate(
+        [block_causal_mask(s, block, strict=True), same], axis=1)
+    return jnp.concatenate([top, bottom], axis=0)
+
+
+def block_diffusion_attention_dense(q, k, v, block, scale=None,
+                                    query_rows=None):
+    """:func:`block_diffusion_attention` as a dense masked softmax in
+    float32 (no kernel): the oracle, and the form for sizes a kernel
+    block cannot tile.  ``query_rows``: that many queries at a time (the
+    (2s, 2s) scores of a long sequence would not fit whole)."""
+    b, s2, hq, d = q.shape
+    hkv = k.shape[2]
+    rows = s2 if query_rows is None else query_rows
+    scale = d ** -0.5 if scale is None else scale
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+
+    @jax.checkpoint
+    def some(args):
+        q_blk, live = args
+        q_blk = q_blk.reshape(b, rows, hkv, hq // hkv, d)
+        sc = jnp.einsum("bqhgd,bkhd->bhgqk", q_blk.astype(jnp.float32),
+                        k32) * scale
+        sc = jnp.where(live[None, None, None], sc, -jnp.inf)
+        out = jnp.einsum("bhgqk,bkhd->bqhgd", jax.nn.softmax(sc, axis=-1),
+                         v32)
+        return out.reshape(b, rows, hq, d)
+
+    out = lax.map(some, (
+        jnp.moveaxis(q.reshape(b, s2 // rows, rows, hq, d), 1, 0),
+        block_diffusion_mask(s2 // 2, block).reshape(s2 // rows, rows, s2)))
+    return jnp.moveaxis(out, 0, 1).reshape(q.shape).astype(q.dtype)
+
+
+def block_diffusion_attention(q, k, v, block, scale=None, block_size=None,
+                              interpret=None, tile=None):
+    """Attention of block-diffusion training (BD3-LM, arXiv:2503.09573)
+    over the concatenation [clean; noised] of every sequence: ``q`` is
+    (b, 2s, hq, d), ``k`` / ``v`` (b, 2s, hkv, d), the result (b, 2s,
+    hq, d) under :func:`block_diffusion_mask`.
+
+    Three pieces, none of which builds an (s, s) array: the clean
+    queries over the clean keys (inclusive block-causal kernel); the
+    noised queries over the clean keys (strict kernel, with its lse);
+    the noised queries over the ``block`` noised keys of their own
+    block (a (block, block) softmax a block, plain XLA), merged with the
+    strict piece by their log-sum-exps."""
+    b, s2, hq, d = q.shape
+    s, hkv = s2 // 2, k.shape[2]
+    scale = d ** -0.5 if scale is None else scale
+    kernel = functools.partial(
+        block_causal_attention_with_lse, block=block, scale=scale,
+        block_size=block_size, interpret=interpret, tile=tile)
+    k_clean, v_clean = k[:, :s], v[:, :s]
+    clean, _ = kernel(q[:, :s], k_clean, v_clean, strict=False)
+    far, far_lse = kernel(q[:, s:], k_clean, v_clean, strict=True)
+
+    @jax.checkpoint  # float32 inside; only its operands are kept
+    def with_own_block(qn, kn, vn, far, far_lse):
+        nb, group = s // block, hq // hkv
+        qn = qn.reshape(b, nb, block, hkv, group, d).astype(jnp.float32)
+        kn = kn.reshape(b, nb, block, hkv, d).astype(jnp.float32)
+        vn = vn.reshape(b, nb, block, hkv, d).astype(jnp.float32)
+        sc = jnp.einsum("bnqhgd,bnkhd->bnqhgk", qn, kn) * scale
+        near_lse = jax.nn.logsumexp(sc, axis=-1)
+        near = jnp.einsum("bnqhgk,bnkhd->bnqhgd",
+                          jnp.exp(sc - near_lse[..., None]), vn)
+        near = near.reshape(b, s, hq, d)
+        near_lse = near_lse.reshape(b, s, hq)
+        top = jnp.maximum(far_lse, near_lse)
+        w_far = jnp.exp(far_lse - top)
+        w_near = jnp.exp(near_lse - top)
+        return ((far.astype(jnp.float32) * w_far[..., None]
+                 + near * w_near[..., None])
+                / (w_far + w_near)[..., None]).astype(q.dtype)
+
+    noised = with_own_block(q[:, s:], k[:, s:], v[:, s:], far, far_lse)
+    return jnp.concatenate([clean, noised], axis=1)

@@ -1173,7 +1173,8 @@ def build_train_step(
     section 2 #21).  If ``merge_aux(params, aux) -> params`` is given, the
     reduced aux is folded back into the returned params *after* the
     optimizer update (so optimizer updates to non-trainable state are
-    overwritten, never accumulated).
+    overwritten, never accumulated).  Without ``merge_aux`` the reduced
+    aux comes back as ``metrics["aux"]`` (a loss function's counters).
 
     Hybrid DP x TP (``param_specs``): on a 2-D mesh (e.g.
     ``HybridCommunicator``'s ``('mn_data', 'mn_model')``), pass
@@ -1469,6 +1470,12 @@ def build_train_step(
             return rep
         return _spec_to_sharding(_state_specs(opt_state, params))
 
+    def _aux_metrics(aux):
+        """An aux that no ``merge_aux`` folds into the parameters is the
+        loss function's own readings (counters): the step hands it back
+        under ``metrics["aux"]``, reduced as above."""
+        return {"aux": aux} if has_aux and merge_aux is None else {}
+
     def _make_do_update(params, opt_state, aux, *, hybrid_sync=False):
         """The update/apply/merge_aux tail shared by all three step
         bodies (one definition so the nonfinite where-select ordering
@@ -1520,7 +1527,8 @@ def build_train_step(
                 _make_do_update(params, opt_state, aux, hybrid_sync=True),
                 bound=True,
             )
-            return params, opt_state, {"loss": loss, **extra}
+            return params, opt_state, {"loss": loss, **_aux_metrics(aux),
+                                       **extra}
 
         def _build(state_specs, pspecs):
             sharded = jax.shard_map(
@@ -1556,7 +1564,8 @@ def build_train_step(
                 bound=True,
             )
             loss = lax.pmean(loss, axes)
-            return params, opt_state, {"loss": loss, **extra}
+            return params, opt_state, {"loss": loss, **_aux_metrics(aux),
+                                       **extra}
 
         def _build(state_specs, pspecs=None):
             del pspecs
@@ -1585,7 +1594,8 @@ def build_train_step(
                 _make_do_update(params, opt_state, aux),
                 bound=False,
             )
-            return params, opt_state, {"loss": loss, **extra}
+            return params, opt_state, {"loss": loss, **_aux_metrics(aux),
+                                       **extra}
 
         def _build(state_shardings, pshardings=None):
             pshardings = rep if pshardings is None else pshardings

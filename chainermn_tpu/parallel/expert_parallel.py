@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from chainermn_tpu.ops.grouped_matmul import grouped_matmul, sum_to_vma
+
 
 def compute_capacity(tokens: int, num_experts: int, k: int,
                      capacity_factor: float) -> int:
@@ -389,3 +391,250 @@ def mlp_experts(w1: jnp.ndarray, w2: jnp.ndarray,
         return jnp.einsum("eth,ehd->etd", h, w2.astype(x.dtype))
 
     return fn
+
+
+# ----------------------------------------------------------------------
+# Routing without drops over the experts a chip holds
+# ----------------------------------------------------------------------
+# The capacity queues above lose a route when an expert's queue is full.
+# The layer below loses none: it is told which experts it holds
+# (``first``, ``count`` of ``num_experts``), routes over all of them,
+# and computes every route that lands on a held expert, at any
+# imbalance.  Routes to experts held elsewhere add nothing here: under
+# expert parallelism another chip computes them, and the sum over the
+# chips (a ``psum`` by the caller) is the whole layer.
+#
+# The held routes are sorted by expert into one buffer of row blocks,
+# each expert's run padded to whole blocks (at least one), and the
+# gated experts run over it as three grouped products
+# (``ops.grouped_matmul``: a static grid, the same work every step
+# whatever the routing was).  The buffer is sized for
+# ``HELD_BUFFER_FACTOR`` times the balanced load; a step whose held
+# routes need more blocks takes the exact dense path instead (every
+# held expert over every token, weighted by its gate), slower and still
+# without a drop.
+# Dispatch and combine are gathers in both directions (their custom
+# gradients gather too): no scatter meets a collision.
+
+
+class HeldRoutes(NamedTuple):
+    """Where each route went.  ``row (tokens, k)``: the buffer row of a
+    route, ``rows`` (one past the buffer) for a route not computed on
+    the fast path; ``route_of_row (rows,)``: the flat route ``token * k
+    + j`` a buffer row holds, ``tokens * k`` for padding;
+    ``block_group``: the held expert of each row block; ``overflow``:
+    the held routes need more blocks than the buffer has."""
+
+    row: jnp.ndarray
+    route_of_row: jnp.ndarray
+    block_group: jnp.ndarray
+    overflow: jnp.ndarray
+    routed: jnp.ndarray
+
+
+#: rows of a block of the sorted buffer (the grouped products' row tile)
+HELD_BLOCK_ROWS = 256
+#: the buffer holds this multiple of the held experts' balanced load
+HELD_BUFFER_FACTOR = 2.0
+
+
+def held_buffer_blocks(tokens: int, num_experts: int, k: int,
+                       count: int) -> int:
+    """Row blocks of the sorted buffer: ``HELD_BUFFER_FACTOR`` times the
+    balanced load of the held experts, plus one block an expert for the
+    padding of its run."""
+    balanced = tokens * k * count / num_experts
+    return int(math.ceil(
+        HELD_BUFFER_FACTOR * balanced / HELD_BLOCK_ROWS)) + count
+
+
+def plan_held_routes(chosen: jnp.ndarray, first, count: int,
+                     block_rows: int, n_blocks: int) -> HeldRoutes:
+    """Lay the routes ``chosen (tokens, k)`` that land on experts
+    ``first .. first + count - 1`` out in a buffer of ``n_blocks`` row
+    blocks, sorted by expert, token order within an expert."""
+    t, k = chosen.shape
+    rows = n_blocks * block_rows
+    local = (chosen - first).reshape(-1)
+    held = (local >= 0) & (local < count)
+    onehot = ((local[:, None] == jnp.arange(count)[None, :])
+              & held[:, None]).astype(jnp.int32)
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - onehot) * onehot, axis=-1)
+    per_expert = jnp.sum(onehot, axis=0)
+    blocks = jnp.maximum(-(-per_expert // block_rows), 1)
+    first_block = jnp.cumsum(blocks) - blocks
+    overflow = jnp.sum(blocks) > n_blocks
+    safe = jnp.clip(local, 0, count - 1)
+    row = jnp.where(held, first_block[safe] * block_rows + rank, rows)
+    row = jnp.minimum(row, rows)  # an overflowing plan is not run
+    block_group = jnp.clip(
+        jnp.sum(jnp.arange(n_blocks)[:, None] >= first_block[None, :],
+                axis=-1) - 1, 0, count - 1).astype(jnp.int32)
+    # the route a buffer row holds: the held routes in (expert, flat
+    # route) order are a stable sort's; row r of expert e's run is the
+    # r-th of them
+    order = jnp.argsort(jnp.where(held, local, count), stable=True)
+    run_start = jnp.cumsum(per_expert) - per_expert
+    r = jnp.arange(rows)
+    e = block_group[r // block_rows]
+    within = r - first_block[e] * block_rows
+    valid = within < per_expert[e]
+    route_of_row = jnp.where(
+        valid, order[jnp.clip(run_start[e] + within, 0, t * k - 1)], t * k)
+    return HeldRoutes(row.reshape(t, k), route_of_row.astype(jnp.int32),
+                      block_group, overflow, jnp.sum(per_expert))
+
+
+def _pad_row(x):
+    return jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
+
+
+def _gather_sum(rows_of, index):
+    """``sum_j rows_of[index[:, j]]`` in float32, a gather a route."""
+    out = jnp.zeros((index.shape[0], rows_of.shape[1]), jnp.float32)
+    for j in range(index.shape[1]):
+        out = out + rows_of[index[:, j]].astype(jnp.float32)
+    return out
+
+
+@jax.custom_vjp
+def dispatch_rows(x, plan: HeldRoutes):
+    """Token rows ``x (tokens, d)`` into the sorted buffer ``(rows,
+    d)``; padding rows are zero."""
+    k = plan.row.shape[1]
+    return _pad_row(x)[plan.route_of_row // k]
+
+
+def _dispatch_fwd(x, plan):
+    return dispatch_rows(x, plan), (plan, x[:0])
+
+
+def _dispatch_bwd(residuals, g):
+    plan, like = residuals
+    dx = _gather_sum(_pad_row(g), plan.row).astype(g.dtype)
+    return sum_to_vma(dx, like), None
+
+
+dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def combine_rows(out, weights, plan: HeldRoutes):
+    """``y[p] = sum_j weights[p, j] * out[row of route (p, j)]``: each
+    token's computed routes, gate-weighted (float32 sum, ``out``'s
+    dtype); a route without a row adds nothing."""
+    padded = _pad_row(out)
+    y = jnp.zeros((weights.shape[0], out.shape[1]), jnp.float32)
+    for j in range(weights.shape[1]):
+        y = y + weights[:, j, None] * padded[plan.row[:, j]].astype(
+            jnp.float32)
+    return y.astype(out.dtype)
+
+
+def _combine_fwd(out, weights, plan):
+    return combine_rows(out, weights, plan), (out, weights, plan)
+
+
+def _combine_bwd(residuals, dy):
+    out, weights, plan = residuals
+    k = weights.shape[1]
+    w_of_row = _pad_row(weights.reshape(-1))[plan.route_of_row]
+    dy_rows = _pad_row(dy)[plan.route_of_row // k].astype(jnp.float32)
+    d_out = (w_of_row[:, None] * dy_rows).astype(out.dtype)
+    dots = jnp.sum(dy_rows * out.astype(jnp.float32), axis=-1)
+    d_weights = _pad_row(dots)[plan.row].astype(weights.dtype)
+    return sum_to_vma(d_out, out), sum_to_vma(d_weights, weights), None
+
+
+combine_rows.defvjp(_combine_fwd, _combine_bwd)
+
+
+#: device scopes (``jax.named_scope``) of the two halves of the layer
+MOE_ROUTE_SCOPE = "moe_route"
+MOE_EXPERTS_SCOPE = "moe_experts"
+
+
+def held_experts_moe(x, router_w, w_gate, w_up, w_down, *,
+                     num_experts: int, k: int, first=0,
+                     aux_stat_axes=None):
+    """One chip's part of a dropless mixture-of-experts layer.
+
+    ``x (tokens, d)``; ``router_w (d, num_experts)``; ``w_gate`` /
+    ``w_up (count, d, f)`` and ``w_down (count, f, d)``: the SiLU-gated
+    experts ``first .. first + count - 1`` held here, ``W_d(silu(W_g x)
+    * W_u x)``.  The router's softmax is float32 over all
+    ``num_experts``; the top ``k`` are kept with weights renormalised
+    over them.  Returns ``(y, aux, counters, chosen)``: the held
+    experts' part of the result; the load-balancing loss over all ``num_experts``
+    (:func:`load_balancing_loss`); and ``moe_rows_routed`` (routes to
+    held experts), ``moe_rows_computed`` (rows the expert products ran
+    over) and ``moe_dropped`` (held routes no path computed: always 0),
+    each an int32 scalar."""
+    t, d = x.shape
+    count = w_gate.shape[0]
+    block_rows = HELD_BLOCK_ROWS
+    n_blocks = held_buffer_blocks(t, num_experts, k, count)
+    with jax.named_scope(MOE_ROUTE_SCOPE):
+        probs = jax.nn.softmax(jnp.einsum(
+            "td,de->te", x.astype(jnp.float32),
+            router_w.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST), axis=-1)
+        top, chosen = lax.top_k(probs, k)
+        weights = top / jnp.sum(top, axis=-1, keepdims=True)
+        raw_routes = jnp.sum(
+            jax.nn.one_hot(chosen, num_experts, dtype=probs.dtype), axis=1)
+        aux = load_balancing_loss(probs, raw_routes, axes=aux_stat_axes)
+        plan = plan_held_routes(chosen, first, count, block_rows, n_blocks)
+    def fast(x, weights, w_gate, w_up, w_down):
+        with jax.named_scope(MOE_ROUTE_SCOPE):
+            rows = dispatch_rows(x, plan)
+        with jax.named_scope(MOE_EXPERTS_SCOPE):
+            gm = lambda a, w: grouped_matmul(a, w, plan.block_group,
+                                             block_rows)
+            # float32 inside, recomputed in the backward pass
+            gated = jax.checkpoint(lambda g, u: (
+                jax.nn.silu(g.astype(jnp.float32)) * u.astype(jnp.float32)
+            ).astype(x.dtype))
+            out = gm(gated(gm(rows, w_gate), gm(rows, w_up)), w_down)
+        with jax.named_scope(MOE_ROUTE_SCOPE):
+            return combine_rows(out, weights, plan)
+
+    def exact(x, weights, w_gate, w_up, w_down):
+        local = chosen - first
+
+        # recomputed in the backward pass from the layer's inputs: the
+        # untaken branch of the ``cond`` then keeps no buffer of its
+        # own (a scan's saved intermediates are allocated either way)
+        @jax.checkpoint
+        def term(x, weights, wg, wu, wd, e):
+            gate = jnp.sum(jnp.where(local == e, weights, 0.0), axis=-1)
+            mm = lambda a, w: jnp.dot(
+                a, w.astype(a.dtype), preferred_element_type=jnp.float32)
+            hidden = (jax.nn.silu(mm(x, wg)) * mm(x, wu)).astype(x.dtype)
+            return gate[:, None] * mm(hidden, wd)
+
+        def one(y, ws):
+            return y + term(x, weights, *ws), None
+
+        zero = jnp.zeros((t, d), jnp.float32)
+        vma = frozenset().union(*(jax.typeof(a).vma for a in (
+            x, weights, w_gate, w_up, w_down)))
+        if vma:  # under shard_map the carry varies as its terms do
+            zero = lax.pcast(zero, tuple(vma), to="varying")
+        with jax.named_scope(MOE_EXPERTS_SCOPE):
+            y, _ = lax.scan(one, zero,
+                            (w_gate, w_up, w_down, jnp.arange(count)))
+        return y.astype(x.dtype)
+
+    y = lax.cond(plan.overflow, exact, fast, x, weights, w_gate, w_up,
+                 w_down)
+    on_fast = jnp.sum(plan.row < n_blocks * block_rows).astype(jnp.int32)
+    counters = {
+        "moe_rows_routed": plan.routed.astype(jnp.int32),
+        "moe_rows_computed": jnp.where(
+            plan.overflow, count * t, n_blocks * block_rows
+        ).astype(jnp.int32),
+        "moe_dropped": jnp.where(
+            plan.overflow, 0, plan.routed.astype(jnp.int32) - on_fast),
+    }
+    return y, aux, counters, chosen

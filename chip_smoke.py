@@ -3,7 +3,8 @@
 
 Run from the repo root on a machine with the chip:
 
-    python chip_smoke.py            # one chip: phases resnet50, lm
+    python chip_smoke.py            # one chip: phases resnet50, lm,
+                                    # block_diffusion
     python chip_smoke.py --chips 4  # four chips: phases sp_tp, dp only
 
 Every phase calls an example's ``main([...])`` in THIS process (one
@@ -158,6 +159,23 @@ def phase_resnet50(device, argv=RESNET_ARGV):
                losses=out["losses"], **val)
 
 
+def _fwd_bwd(attend, q, k, v, g):
+    """``(out, dq, dk, dv)`` of ``attend`` under the cotangent ``g``."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(q, k, v, g):
+        def f(q, k, v):
+            out = attend(q, k, v)
+            return (out.astype(jnp.float32) * g).sum(), out
+
+        (_, out), grads = jax.value_and_grad(
+            f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out, *grads)
+
+    return jax.jit(run)(q, k, v, g)
+
+
 def _flash_vs_dense(b, s, h, d):
     """One flash forward+backward at the train step's attention shape
     against ``ops.attention``'s dense core, both on the chip."""
@@ -171,22 +189,10 @@ def _flash_vs_dense(b, s, h, d):
     q, k, v, g = (jax.random.normal(key, (b, s, h, d), jnp.bfloat16)
                   for key in (kq, kk, kv, kg))
 
-    def fwd_bwd(attend):
-        def run(q, k, v, g):
-            def f(q, k, v):
-                out = attend(q, k, v)
-                return (out.astype(jnp.float32) * g).sum(), out
-
-            (_, out), grads = jax.value_and_grad(
-                f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
-            return (out, *grads)
-
-        return jax.jit(run)(q, k, v, g)
-
-    flash = fwd_bwd(lambda q, k, v: flash_attention(
-        q, k, v, causal=True, interpret=False))
-    dense = fwd_bwd(lambda q, k, v: multi_head_attention(
-        q, k, v, causal=True))
+    flash = _fwd_bwd(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, interpret=False), q, k, v, g)
+    dense = _fwd_bwd(lambda q, k, v: multi_head_attention(
+        q, k, v, causal=True), q, k, v, g)
     errs = {name: max_rel_err(a, r)
             for name, a, r in zip(("out", "dq", "dk", "dv"), flash, dense)}
     check(max(errs.values()) <= BF16_TOL,
@@ -550,6 +556,39 @@ def phase_sp_tp(device, argv=LM_SP_TP_ARGV):
                first_step_rel_diff=diff)
 
 
+def phase_block_diffusion(device, b=1, s=8192, hq=32, hkv=4, d=128,
+                          block=4):
+    """The block-causal grouped-query kernels at the shape of
+    ``sdar30b_train_bd4_s8192`` (one sequence as clean + noised copy)
+    against the dense form, forward and all three gradients."""
+    import jax
+    import jax.numpy as jnp
+
+    from chainermn_tpu.ops.pallas_attention import (
+        block_diffusion_attention,
+        block_diffusion_attention_dense,
+    )
+
+    with PhaseClock() as clock:
+        keys = jax.random.split(jax.random.PRNGKey(0), 4)
+        q, g = (jax.random.normal(key, (b, 2 * s, hq, d), jnp.bfloat16)
+                for key in keys[:2])
+        k, v = (jax.random.normal(key, (b, 2 * s, hkv, d), jnp.bfloat16)
+                for key in keys[2:])
+
+        kernels = _fwd_bwd(lambda q, k, v: block_diffusion_attention(
+            q, k, v, block, interpret=False), q, k, v, g)
+        dense = _fwd_bwd(lambda q, k, v: block_diffusion_attention_dense(
+            q, k, v, block, query_rows=512), q, k, v, g)
+        errs = {name: max_rel_err(a, r) for name, a, r in zip(
+            ("out", "dq", "dk", "dv"), kernels, dense)}
+        check(max(errs.values()) <= BF16_TOL,
+              f"block-diffusion kernels vs dense beyond bf16 tolerance "
+              f"{BF16_TOL}: {errs}")
+        report("block_diffusion", device, clock, shape=[b, 2 * s, hq, hkv, d],
+               block=block, kernels_vs_dense_max_rel_err=errs)
+
+
 def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--chips", type=int, choices=(1, 4), default=1,
@@ -570,8 +609,8 @@ def main(argv=None):
     from chainermn_tpu.utils.compile_cache import enable_compile_cache
 
     print(json.dumps({"compile_cache": enable_compile_cache()}), flush=True)
-    for phase in ((phase_resnet50, phase_lm) if chips == 1
-                  else (phase_sp_tp, phase_dp)):
+    for phase in ((phase_resnet50, phase_lm, phase_block_diffusion)
+                  if chips == 1 else (phase_sp_tp, phase_dp)):
         phase(device)
     print(json.dumps({"ok": True, "device": device}))
 

@@ -23,6 +23,22 @@ Run on a virtual 8-chip mesh (2 data x 2 seq x 2 model):
       python examples/moe_lm/train_moe_lm.py --cpu-mesh --sp 2 --tp 2
 
 On real hardware drop ``--cpu-mesh`` and size ``--sp/--tp`` to the slice.
+
+The block's options make the same script train another family: with
+``--rope-theta`` the model takes the general block (``--rmsnorm``,
+``--n-kv-heads``, ``--head-dim``, ``--qk-norm``, ``--untied-head``,
+SiLU-gated experts routed without drops with ``--dropless``), and
+``--block-diffusion B`` trains it on the block-diffusion objective: a
+batch is the tree ``(tokens, mask, weights)``, the clean and the noised
+copy of every sequence go through every layer under block-causal masks.
+One chip's share of SDAR-30B-A3B-Chat (experts 0..15 of 128, an eighth
+of the vocabulary; ``cellbench/configs/sdar-30b-a3b.json``):
+
+    python examples/moe_lm/train_moe_lm.py --d-model 2048 --n-heads 32 \
+      --n-kv-heads 4 --head-dim 128 --d-ff 768 --n-experts 128 --top-k 8 \
+      --held 0,16 --moe-every 1 --n-layers 4 --vocab 18992 --seq-len 8192 \
+      --batchsize 1 --rope-theta 1e6 --rmsnorm --qk-norm --untied-head \
+      --dropless --block-diffusion 4 --flash --lr 1e-5 --aux-coef 1e-3
 """
 
 import argparse
@@ -52,6 +68,22 @@ def synthetic_corpus(n_seqs, seq_len, vocab, seed=0):
     for t in range(1, seq_len):
         toks[:, t] = succ[toks[:, t - 1], choice[:, t]]
     return toks
+
+
+def block_diffusion_batch(tokens, block, mask_id, rng, t_min=1e-3):
+    """One block-diffusion draw over ``tokens (rows, s)``: a noise level
+    ``t_b ~ U(t_min, 1]`` a block of ``block`` positions, each of its
+    tokens masked with probability ``t_b``.  Returns the batch tree
+    ``(tokens, mask, weights)``, ``weights = 1 / t_b`` on the masked
+    positions and 0 elsewhere."""
+    import numpy as np
+
+    rows, s = tokens.shape
+    t = 1.0 - rng.uniform(0.0, 1.0 - t_min, size=(rows, s // block))
+    t = np.repeat(t, block, axis=1)
+    mask = (rng.uniform(size=(rows, s)) < t) & (tokens != mask_id)
+    weights = np.where(mask, 1.0 / t, 0.0).astype(np.float32)
+    return tokens.astype(np.int32), mask, weights
 
 
 def main(argv=None):
@@ -85,7 +117,48 @@ def main(argv=None):
                         "thread loader (GIL-free, deterministic)")
     p.add_argument("--cpu-mesh", action="store_true",
                    help="run on a virtual CPU device mesh (testing)")
+    g = p.add_argument_group(
+        "the block's options (models.transformer.BlockOptions)")
+    g.add_argument("--rope-theta", type=float, default=None,
+                   help="rotary positions of this base: the general "
+                        "block, no position table (needs --sp 1)")
+    g.add_argument("--rmsnorm", action="store_true")
+    g.add_argument("--n-kv-heads", type=int, default=None,
+                   help="key/value heads shared by groups of query heads")
+    g.add_argument("--head-dim", type=int, default=None)
+    g.add_argument("--qk-norm", action="store_true",
+                   help="RMSNorm over each head of q and k")
+    g.add_argument("--untied-head", action="store_true")
+    g.add_argument("--flash", action="store_true",
+                   help="the Pallas attention kernels (TPU)")
+    g.add_argument("--d-ff", type=int, default=None,
+                   help="width of the MLP / of one expert")
+    g.add_argument("--top-k", type=int, default=2)
+    g.add_argument("--moe-every", type=int, default=2)
+    g.add_argument("--dropless", action="store_true",
+                   help="SiLU-gated experts routed without drops")
+    g.add_argument("--held", default=None, metavar="FIRST,COUNT",
+                   help="the experts this chip holds of --n-experts "
+                        "(one chip's share of a layer; --tp 1)")
+    g.add_argument("--return-routes", action="store_true",
+                   help="hand every step's routing decisions back in "
+                        "metrics['aux']['routes'] (layers, tokens, k)")
+    g.add_argument("--block-diffusion", type=int, default=0, metavar="B",
+                   help="train on the block-diffusion objective with "
+                        "blocks of B positions (mask id: --vocab - 1)")
     args = p.parse_args(argv)
+    general = args.rope_theta is not None
+    if not general and (args.rmsnorm or args.n_kv_heads or args.head_dim
+                        or args.qk_norm or args.untied_head or args.flash
+                        or args.dropless or args.held
+                        or args.return_routes or args.block_diffusion):
+        p.error("the block's options come with --rope-theta")
+    if general and (args.sp != 1 or args.generate or args.vocab_parallel):
+        p.error("--rope-theta needs --sp 1, --generate 0 and a dense "
+                "vocabulary: the general block has no sequence-parallel, "
+                "decode or vocab-parallel path yet")
+    if args.held and (args.tp != 1 or not args.dropless):
+        p.error("--held is one chip's share: --tp 1 and --dropless")
 
     import chainermn_tpu as cmn
 
@@ -107,10 +180,18 @@ def main(argv=None):
     import optax
     from jax.sharding import PartitionSpec as P
 
+    from chainermn_tpu.functions import collectives as cc
     from chainermn_tpu.models.moe_transformer import (
+        COUNTERS,
+        ROUTES,
         MoeTransformerLM,
         moe_lm_loss,
         moe_param_specs,
+    )
+    from chainermn_tpu.models.transformer import (
+        BlockOptions,
+        block_diffusion_loss,
+        noised_copy,
     )
     from chainermn_tpu.parallel import sharded_init
 
@@ -123,21 +204,52 @@ def main(argv=None):
               f"tp={comm.tp_size}  {comm!r}")
 
     batch = args.batchsize or 2 * comm.dp_size
-    model = MoeTransformerLM(
+    sizes = dict(
         vocab_size=args.vocab, d_model=args.d_model, n_heads=args.n_heads,
-        n_layers=args.n_layers, n_experts=args.n_experts, moe_every=2,
-        k=2, capacity_factor=1.25, max_len=args.seq_len,
-        seq_axis="mn_seq", tp_axis="mn_model", expert_axis="mn_model",
-        vocab_parallel=args.vocab_parallel,
-        aux_stat_axes=("mn_data", "mn_seq", "mn_model"),
+        n_layers=args.n_layers, n_experts=args.n_experts, d_ff=args.d_ff,
+        moe_every=args.moe_every, k=args.top_k, capacity_factor=1.25,
+        max_len=args.seq_len, vocab_parallel=args.vocab_parallel,
+        # every axis that splits tokens (the general block's expert
+        # layer sees all of its data shard's tokens on every chip)
+        aux_stat_axes=("mn_data", "mn_seq") + (() if general
+                                                else ("mn_model",)),
     )
+    mask_id = args.vocab - 1
+    if general:
+        # attention whole on every chip over its own sequences, the
+        # experts over the model axis (or this chip's share of them)
+        model = MoeTransformerLM(
+            **sizes, expert_axis="mn_model" if args.tp > 1 else None,
+            options=BlockOptions(
+                norm="rmsnorm" if args.rmsnorm else "layernorm",
+                n_kv_heads=args.n_kv_heads, head_dim=args.head_dim,
+                rope_theta=args.rope_theta, qk_norm=args.qk_norm,
+                block_diffusion=args.block_diffusion,
+                use_flash=args.flash,
+            ),
+            routing="dropless" if args.dropless else "capacity",
+            held=tuple(int(x) for x in args.held.split(","))
+            if args.held else None,
+            tie_head=not args.untied_head,
+            return_hidden=bool(args.block_diffusion),
+        )
+    else:
+        model = MoeTransformerLM(
+            **sizes, seq_axis="mn_seq", tp_axis="mn_model",
+            expert_axis="mn_model",
+        )
 
     corpus = synthetic_corpus(
         max(batch * 8, 64), args.seq_len, args.vocab, seed=0
     )
-    sample = jnp.asarray(corpus[:batch])
+    if args.block_diffusion:
+        corpus = np.minimum(corpus, mask_id - 1)  # the mask id is no token
+        sample = jnp.concatenate([jnp.asarray(corpus[:batch])] * 2, axis=1)
+    else:
+        sample = jnp.asarray(corpus[:batch])
     params, specs = sharded_init(
-        lambda t: model.init(jax.random.PRNGKey(0), t),
+        # the counters a dropless layer sows are no parameters
+        lambda t: {"params": model.init(jax.random.PRNGKey(0), t)["params"]},
         comm.mesh, (P("mn_data", "mn_seq"),), moe_param_specs, sample,
     )
     n_params = sum(
@@ -158,9 +270,55 @@ def main(argv=None):
             vocab_parallel=args.vocab_parallel,
         )
 
+    def general_loss_fn(p, b):
+        """The general block's loss and its expert layers' counters
+        (summed over layers and chips)."""
+        tokens = b[0] if args.block_diffusion else b
+        inputs = noised_copy(tokens, b[1], mask_id) \
+            if args.block_diffusion else tokens
+        (out, aux), sown = model.apply(p, inputs,
+                                       mutable=[COUNTERS, ROUTES])
+        if args.block_diffusion:
+            head = p["params"]["lm_head"] if args.untied_head \
+                else p["params"]["embed"]["embedding"]
+            main = block_diffusion_loss(out, head, tokens, b[2])
+            total = main + args.aux_coef * aux
+        else:
+            total = moe_lm_loss((out, aux), tokens, aux_coef=args.aux_coef)
+        for axis in ("mn_seq", "mn_model"):  # certify: see moe_lm_loss
+            total = cc.pmean(total, axis)
+        counters = {}
+        for path, values in jax.tree_util.tree_leaves_with_path(
+                sown.get(COUNTERS, {})):
+            name = path[-2].key
+            # over the chips whose counts differ: data shards, and the
+            # model axis where the experts lie over it
+            axes = tuple(a for a in comm.axis_names
+                         if a in jax.typeof(values).vma)
+            counters[name] = counters.get(name, 0) + (
+                cc.psum(values, axes) if axes else values)
+        if args.return_routes:
+            # every layer's (tokens, k) choices, the data shards' tokens
+            # one after another as the batch's rows are
+            routes = jnp.stack(jax.tree_util.tree_leaves(sown[ROUTES]))
+            for a in comm.axis_names:
+                if a in jax.typeof(routes).vma:
+                    # an all-gather the type system knows to be the
+                    # same on every chip: each lays its rows into zeros
+                    # at its own place, and the sum is the whole
+                    n, t = jax.lax.axis_size(a), routes.shape[1]
+                    whole = jnp.zeros((routes.shape[0], n * t,
+                                       routes.shape[2]), routes.dtype)
+                    routes = cc.psum(jax.lax.dynamic_update_slice(
+                        whole, routes, (0, jax.lax.axis_index(a) * t, 0)),
+                        a)
+            counters["routes"] = routes
+        return total, counters
+
     step = cmn.build_train_step(
-        comm, loss_fn, opt, data_axes=comm.data_axis_names,
-        param_specs=specs, batch_specs=P("mn_data", "mn_seq"),
+        comm, general_loss_fn if general else loss_fn, opt,
+        data_axes=comm.data_axis_names, param_specs=specs,
+        batch_specs=P("mn_data", "mn_seq"), has_aux=general,
     )
     params, opt_state = step.place(params, opt.init(params))
 
@@ -185,7 +343,12 @@ def main(argv=None):
             toks = step.place_batch(jnp.asarray(next(loader)))
         else:
             rows = rng.randint(0, corpus.shape[0], size=batch)
-            toks = step.place_batch(jnp.asarray(corpus[rows]))
+            toks = corpus[rows]
+            if args.block_diffusion:
+                toks = block_diffusion_batch(
+                    toks, args.block_diffusion, mask_id, rng)
+            toks = step.place_batch(jax.tree_util.tree_map(jnp.asarray,
+                                                           toks))
         params, opt_state, metrics = step(params, opt_state, toks)
         tokens_done += batch * args.seq_len
         if it % args.report_every == 0 or it == args.steps:
@@ -230,7 +393,9 @@ def main(argv=None):
             tier = "vp+tp/ep" if args.vocab_parallel else "tp/ep"
             print(f"sampled ({tier}-sharded MoE KV-cache decode): "
                   f"{out[0].tolist()}")
-    return last_loss
+    return {"loss": last_loss, "comm": comm, "model": model,
+            "specs": specs, "step": step, "params": params,
+            "opt_state": opt_state, "batch": toks, "metrics": metrics}
 
 
 if __name__ == "__main__":

@@ -1514,11 +1514,13 @@ def scenario_serving_churn_phase1(pid, nproc, scratch):
     served = replica.serve()  # process 1 dies inside (env fault spec)
     # replica 0 (the coordination-service host) lingers so the targeted
     # kill lands before the leader disappears, then exits hard —
-    # jax.distributed teardown would block on the dead peer
+    # jax.distributed teardown would block on the dead peer.  Ten
+    # seconds: on a loaded host replica 1 has been seen more than one
+    # second behind, and then dies of the lost leader, not of its fault
     print("RESULT " + json.dumps(
         {"served": sorted(served), "replica": pid}
     ), flush=True)
-    time.sleep(1.0)
+    time.sleep(10.0)
     os._exit(0)
 
 

@@ -239,3 +239,47 @@ def test_pinned_step_is_the_program_without_the_pin(
     pinned, unpinned = pinned_and_unpinned_texts(
         lambda: lm_step_builder(chips, n_layers=1))
     assert pinned == unpinned
+
+
+def test_block_diffusion_attention_compiles_at_the_cell_shape(one_chip):
+    """The block-causal grouped-query kernels of ``sdar30b_train_bd4_s8192``
+    (one 8192-token sequence as clean + noised copy, 32 query and 4
+    key/value heads of 128, block length 4): both launches of the
+    forward, dq and dk/dv kernels."""
+    q = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    text = _compiled_text(
+        _grad_of(functools.partial(
+            pa.block_diffusion_attention, block=4, interpret=False)),
+        q, kv, kv)
+    for kernel in ("_bdflash_forward", "_bdflash_backward_dq",
+                   "_bdflash_backward_dkdv"):
+        assert text.count(f"{kernel}/pallas_call") >= 2, kernel
+
+
+def test_grouped_matmul_compiles_at_the_cell_shape(one_chip):
+    """The expert layer's grouped products at the cell's sizes: 144 row
+    blocks of 256 through 16 experts of 2048 x 768, forward and both
+    gradients, up and down projections."""
+    from chainermn_tpu.ops import grouped_matmul as gm
+
+    rows = jax.ShapeDtypeStruct((144 * 256, 2048), jnp.bfloat16,
+                                sharding=one_chip)
+    up = jax.ShapeDtypeStruct((16, 2048, 768), jnp.float32,
+                              sharding=one_chip)
+    down = jax.ShapeDtypeStruct((16, 768, 2048), jnp.float32,
+                                sharding=one_chip)
+    groups = jax.ShapeDtypeStruct((144,), jnp.int32, sharding=one_chip)
+
+    def loss(x, up, down, groups):
+        hidden = gm.grouped_matmul(x, up, groups, 256, False)
+        return gm.grouped_matmul(hidden, down, groups, 256,
+                                 False).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, (0, 1, 2)), rows, up, down, groups)
+    # the first product forward (a sum's gradient needs no value of the
+    # second), two input gradients, two weight gradients
+    assert text.count('custom_call_target="tpu_custom_call"') >= 5
+    assert "_grouped_matmul_dw" in text

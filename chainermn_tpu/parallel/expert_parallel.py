@@ -414,7 +414,15 @@ def mlp_experts(w1: jnp.ndarray, w2: jnp.ndarray,
 # held expert over every token, weighted by its gate), slower and still
 # without a drop.
 # Dispatch and combine are gathers in both directions (their custom
-# gradients gather too): no scatter meets a collision.
+# gradients gather too): no scatter meets a collision.  A layer moves
+# rows four times (``held_rows_moved`` counts them).  Token rows into
+# the buffer, dispatch's forward and combine's backward: one gather of
+# the buffer's rows each.  Buffer rows back to token rows, combine's
+# forward and dispatch's backward (``sum_rows_by_token``, gate-weighted
+# and with weight 1): a gather of ``tokens`` rows a route, most of them
+# the zero of a route held elsewhere, out of the buffer, the larger
+# operand; it is cut into column pieces that XLA keeps in on-chip
+# memory, where a gathered row costs a tenth of what it does from HBM.
 
 
 class HeldRoutes(NamedTuple):
@@ -446,6 +454,49 @@ def held_buffer_blocks(tokens: int, num_experts: int, k: int,
     balanced = tokens * k * count / num_experts
     return int(math.ceil(
         HELD_BUFFER_FACTOR * balanced / HELD_BLOCK_ROWS)) + count
+
+
+#: XLA:TPU (libtpu 0.0.34) gathers rows at 3-4 ns a row from an operand
+#: it keeps in the chip's 128 MiB of on-chip memory, at 13-15 ns from one
+#: under 128 MiB left in HBM and at 34-38 ns from a larger one, whatever
+#: the indices are (PERF.md, PR 36).  In a whole train step it kept
+#: pieces of 36 MiB there and not pieces of 72: half of that memory is
+#: the most an operand may take, so that the next can be staged beside it
+GATHER_OPERAND_BYTES = 64 << 20
+
+
+def gather_column_pieces(rows: int, width: int, itemsize: int) -> int:
+    """Into how many column pieces (a power of two, each a multiple of
+    128 columns) a ``(rows, width)`` gather operand is cut so that a
+    piece is at most ``GATHER_OPERAND_BYTES``; 1 where the whole is."""
+    pieces = 1
+    while (rows * (width // pieces) * itemsize > GATHER_OPERAND_BYTES
+           and width % (256 * pieces) == 0):
+        pieces *= 2
+    return pieces
+
+
+def held_rows_moved(tokens: int, num_experts: int, k: int, count: int,
+                    width: int, itemsize: int = 2) -> dict:
+    """What a layer's four movements of rows gather, forward and
+    backward, from the Python ints the layer itself works from (static,
+    not traced): ``rows`` gathered and ``gathers`` made by movement,
+    the buffer's ``buffer_rows``, and the column ``pieces`` of
+    ``piece_bytes`` the buffer is gathered from
+    (:func:`sum_rows_by_token`)."""
+    rows = held_buffer_blocks(tokens, num_experts, k, count) \
+        * HELD_BLOCK_ROWS
+    pieces = gather_column_pieces(rows, width, itemsize)
+    return {
+        "buffer_rows": rows, "pieces": pieces,
+        "piece_bytes": rows * (width // pieces) * itemsize,
+        "rows": {"dispatch": rows, "combine": tokens * k,
+                 "combine_backward": rows,
+                 "dispatch_backward": tokens * k},
+        "gathers": {"dispatch": 1, "combine": k * pieces,
+                    "combine_backward": 1,
+                    "dispatch_backward": k * pieces},
+    }
 
 
 def plan_held_routes(chosen: jnp.ndarray, first, count: int,
@@ -489,18 +540,53 @@ def _pad_row(x):
     return jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
 
 
-def _gather_sum(rows_of, index):
-    """``sum_j rows_of[index[:, j]]`` in float32, a gather a route."""
-    out = jnp.zeros((index.shape[0], rows_of.shape[1]), jnp.float32)
-    for j in range(index.shape[1]):
-        out = out + rows_of[index[:, j]].astype(jnp.float32)
-    return out
+def _take_rows(a, index):
+    """``a[index]`` for an in-bounds ``index (n,)``: the gather itself,
+    without what NumPy-style indexing traces around it (a step traces
+    this 256 times)."""
+    return lax.gather(
+        a, index[:, None],
+        lax.GatherDimensionNumbers(offset_dims=(1,),
+                                   collapsed_slice_dims=(0,),
+                                   start_index_map=(0,)),
+        (1, a.shape[1]), mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+def sum_rows_by_token(rows_of, weights, plan: HeldRoutes):
+    """The movement from buffer rows to token rows: ``y[p] = sum_j
+    weights[p, j] * rows_of[plan.row[p, j]]`` (``weights`` None: 1), a
+    route without a row adding nothing; float32 sum in ``j`` order,
+    rounded once to ``rows_of``'s dtype.  One gather of ``tokens`` rows
+    a route and column piece (:func:`gather_column_pieces`: what is
+    static here picks the number, no argument does; one gather of all
+    routes' rows a piece is not kept on chip and costs twice today's)."""
+    t, k = plan.row.shape
+    rows, d = rows_of.shape
+    # by route: index clipped into the buffer, rows of routes without a
+    # buffer row masked out (no zero row is appended: no copy for it)
+    index = jnp.minimum(plan.row, rows - 1).T
+    computed = (plan.row < rows).T[:, :, None]
+    gate = None if weights is None else weights.T[:, :, None]
+    pieces = gather_column_pieces(rows, d, rows_of.dtype.itemsize)
+    width = d // pieces
+    parts = []
+    for c in range(pieces):
+        piece = rows_of[:, c * width:(c + 1) * width]
+        y = jnp.zeros((t, width), jnp.float32)
+        for j in range(k):
+            v = jnp.where(computed[j],
+                          _take_rows(piece, index[j]).astype(jnp.float32),
+                          0.0)
+            y = y + (v if gate is None else gate[j] * v)
+        parts.append(y.astype(rows_of.dtype))
+    return parts[0] if pieces == 1 else jnp.concatenate(parts, axis=1)
 
 
 @jax.custom_vjp
 def dispatch_rows(x, plan: HeldRoutes):
     """Token rows ``x (tokens, d)`` into the sorted buffer ``(rows,
-    d)``; padding rows are zero."""
+    d)``; padding rows are zero.  Its gradient is the movement back,
+    :func:`sum_rows_by_token` with weight 1."""
     k = plan.row.shape[1]
     return _pad_row(x)[plan.route_of_row // k]
 
@@ -511,8 +597,7 @@ def _dispatch_fwd(x, plan):
 
 def _dispatch_bwd(residuals, g):
     plan, like = residuals
-    dx = _gather_sum(_pad_row(g), plan.row).astype(g.dtype)
-    return sum_to_vma(dx, like), None
+    return sum_to_vma(sum_rows_by_token(g, None, plan), like), None
 
 
 dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
@@ -522,13 +607,10 @@ dispatch_rows.defvjp(_dispatch_fwd, _dispatch_bwd)
 def combine_rows(out, weights, plan: HeldRoutes):
     """``y[p] = sum_j weights[p, j] * out[row of route (p, j)]``: each
     token's computed routes, gate-weighted (float32 sum, ``out``'s
-    dtype); a route without a row adds nothing."""
-    padded = _pad_row(out)
-    y = jnp.zeros((weights.shape[0], out.shape[1]), jnp.float32)
-    for j in range(weights.shape[1]):
-        y = y + weights[:, j, None] * padded[plan.row[:, j]].astype(
-            jnp.float32)
-    return y.astype(out.dtype)
+    dtype); a route without a row adds nothing.  Its gradient gathers
+    ``dy`` into the buffer's rows (``d_out``, and ``d_weights`` from
+    their products with ``out``)."""
+    return sum_rows_by_token(out, weights, plan)
 
 
 def _combine_fwd(out, weights, plan):

@@ -413,6 +413,201 @@ def test_both_paths_of_the_held_layer_agree_with_reference(
             int(np.ceil(buffer_factor * t * 2 * 4 / 8 / 8)) + 4))
 
 
+# -- the movement from buffer rows to token rows ---------------------------
+def _handmade_routes():
+    """64 tokens' top 8 of 128, experts 16..31 held: token 0 has all 8
+    routes held, tokens 1..9 none, held expert 31 gets no route (an
+    empty run), expert 17 exactly 8 (its run ends on a block edge)."""
+    t, k = 64, 8
+    chosen = np.tile(64 + np.arange(k), (t, 1))  # held elsewhere
+    chosen[0] = 16 + np.arange(k)
+    chosen[10:17, 0] = 17
+    rng = np.random.default_rng(5)
+    for p in range(20, t):
+        n = rng.integers(0, 4)
+        chosen[p, rng.choice(k, n, replace=False)] = rng.choice(
+            np.arange(18, 31), n, replace=False)
+    return chosen, 128, 16, 16
+
+
+def _random_routes(t, k, num_experts, seed):
+    logits = np.random.default_rng(seed).normal(size=(t, num_experts))
+    return np.argsort(-logits, axis=-1)[:, :k]
+
+
+#: name -> (chosen, num_experts, first, count)
+ROUTE_CASES = {
+    "handmade": _handmade_routes(),
+    "random": (_random_routes(64, 8, 128, 1), 128, 32, 16),
+    "all_held": (_random_routes(32, 2, 8, 2), 8, 0, 8),
+}
+#: row width of the tests' buffers: up to 4 pieces of 128 columns
+WIDTH = 512
+
+
+@pytest.fixture(params=[1, 2, 4], ids=lambda n: f"pieces{n}")
+def pieces(request, monkeypatch):
+    """The buffer is gathered whole, or cut into 2 or 4 column pieces:
+    the limit is set so that the cases' buffers (192 to 256 float32
+    rows of ``WIDTH``) come to that number."""
+    monkeypatch.setattr(expert_parallel, "GATHER_OPERAND_BYTES",
+                        256 * WIDTH * 4 // request.param)
+    return request.param
+
+
+def _plan_of(case):
+    """The plan of a case (8-row blocks: call under ``small_blocks``)
+    and its dense form ``(tokens, k, rows)``: 1 where a route has a
+    buffer row."""
+    chosen, num_experts, first, count = ROUTE_CASES[case]
+    t, k = chosen.shape
+    n_blocks = expert_parallel.held_buffer_blocks(t, num_experts, k, count)
+    plan = expert_parallel.plan_held_routes(
+        jnp.asarray(chosen, jnp.int32), first, count, 8, n_blocks)
+    assert not bool(plan.overflow)
+    return plan, jax.nn.one_hot(plan.row, n_blocks * 8, dtype=jnp.float32)
+
+
+def test_handmade_routes_hold_the_cases_they_name(small_blocks):
+    chosen, _, first, count = ROUTE_CASES["handmade"]
+    plan, dense = _plan_of("handmade")
+    held = (chosen >= first) & (chosen < first + count)
+    assert held[0].all() and not held[1:10].any()
+    per_expert = [(chosen == first + e).sum() for e in range(count)]
+    assert per_expert[1] == 8 and per_expert[15] == 0
+    # every held route has one row, every row one route; expert 17's
+    # run fills block 1 and the next block is the next expert's
+    np.testing.assert_array_equal(dense.sum(-1), held)
+    assert float(dense.sum((0, 1)).max()) == 1.0
+    assert int(plan.block_group[1]) == 1 and int(plan.block_group[2]) == 2
+    assert (np.asarray(plan.route_of_row[8:16]) < chosen.size).all()
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["gate_weighted", "weight_one"])
+@pytest.mark.parametrize("case", list(ROUTE_CASES))
+def test_sum_rows_by_token_against_one_hot(case, weighted, pieces,
+                                           small_blocks):
+    plan, dense = _plan_of(case)
+    t, k, rows = dense.shape
+    assert expert_parallel.gather_column_pieces(rows, WIDTH, 4) == pieces
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    rows_of = jax.random.normal(ks[0], (rows, WIDTH))
+    weights = jax.random.uniform(ks[1], (t, k)) if weighted else None
+    got = expert_parallel.sum_rows_by_token(rows_of, weights, plan)
+    want = jnp.einsum("pjr,pj,rd->pd", dense,
+                      weights if weighted else jnp.ones((t, k)), rows_of)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    low = expert_parallel.sum_rows_by_token(
+        rows_of.astype(jnp.bfloat16), weights, plan)
+    assert low.dtype == jnp.bfloat16  # float32 sum, one rounding
+    np.testing.assert_allclose(low.astype(jnp.float32), want, atol=0.05)
+
+
+@pytest.mark.parametrize("case", list(ROUTE_CASES))
+def test_dispatch_and_combine_gradients_against_one_hot(case, pieces,
+                                                        small_blocks):
+    plan, dense = _plan_of(case)
+    t, k, rows = dense.shape
+    ks = jax.random.split(jax.random.PRNGKey(4), 5)
+    x = jax.random.normal(ks[0], (t, WIDTH))
+    out = jax.random.normal(ks[1], (rows, WIDTH))
+    weights = jax.random.uniform(ks[2], (t, k))
+    cot_rows = jax.random.normal(ks[3], (rows, WIDTH))
+    cot = jax.random.normal(ks[4], (t, WIDTH))
+
+    def program(x, out, weights):
+        return (jnp.sum(expert_parallel.dispatch_rows(x, plan) * cot_rows)
+                + jnp.sum(expert_parallel.combine_rows(out, weights, plan)
+                          * cot))
+
+    def one_hot(x, out, weights):
+        return (jnp.sum(jnp.einsum("pjr,pd->rd", dense, x) * cot_rows)
+                + jnp.sum(jnp.einsum("pjr,pj,rd->pd", dense, weights, out)
+                          * cot))
+
+    np.testing.assert_allclose(program(x, out, weights),
+                               one_hot(x, out, weights), rtol=1e-5)
+    got = jax.grad(program, (0, 1, 2))(x, out, weights)
+    want = jax.grad(one_hot, (0, 1, 2))(x, out, weights)
+    for g, w in zip(got, want):  # sums over WIDTH columns in float32
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("n_pieces", [1, 2], ids=["whole", "pieces2"])
+def test_expert_axis_gradients_are_the_uncut_layers(n_pieces, small_blocks,
+                                                    monkeypatch):
+    """Under ``shard_map`` (2 of 8 experts a chip, the buffer gathered
+    whole or in two column pieces) every gradient of the summed parts
+    is the uncut layer's: the custom gradients' cotangents vary as
+    their primals do."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    cfg = dict(UNCUT, hidden_size=256)
+    rows = 8 * expert_parallel.held_buffer_blocks(64, 8, 2, 2)
+    monkeypatch.setattr(expert_parallel, "GATHER_OPERAND_BYTES",
+                        rows * 256 * 4 // n_pieces)
+    assert expert_parallel.held_rows_moved(
+        64, 8, 2, 2, width=256, itemsize=4)["pieces"] == n_pieces
+    weights = ref.init_weights(ref.seed_key(24), cfg)
+    layer = {n: weights[n][0] for n in ref.LAYER_LEAVES}
+    ks = jax.random.split(jax.random.PRNGKey(8), 2)
+    u = jax.random.normal(ks[0], (1, 64, 256))
+    cot = jax.random.normal(ks[1], (64, 256))
+    mesh = Mesh(np.array(jax.devices("cpu")[:4]), ("experts",))
+    mlp = MoeMlp(8, 32, k=2, routing="dropless", expert_axis="experts",
+                 dtype=jnp.float32)
+    names = dict(router="router", expert_wg="w_gate", expert_wu="w_up",
+                 expert_wd="w_down")
+    params = {"params": {p: layer[r] for p, r in names.items()}}
+    specs = {"params": {p: P() if p == "router" else P("experts")
+                        for p in names}}
+    part = jax.shard_map(
+        lambda p, u: mlp.apply(p, u, mutable=[COUNTERS, ROUTES])[0][0],
+        mesh=mesh, in_specs=(specs, P()), out_specs=P())
+    got = jax.jit(jax.grad(
+        lambda p, u: jnp.sum(part(p, u)[0] * cot), (0, 1)))(params, u)
+    want = jax.grad(lambda layer, u: jnp.sum(ref.expert_layer(
+        u[0], layer, cfg, ref._ein(False))[0] * cot), (0, 1))(layer, u)
+    np.testing.assert_allclose(got[1], want[1], atol=2e-5)
+    for p, r in names.items():
+        np.testing.assert_allclose(got[0]["params"][p], want[0][r],
+                                   atol=2e-5)
+
+
+def test_rows_moved_at_the_cells_shapes():
+    """The static census at ``sdar30b_train_bd4_s8192``'s layer: the
+    rows gathered are the parent's 335 872; what changed is the operand
+    a route's gather reads from, a quarter of the buffer's columns."""
+    moved = expert_parallel.held_rows_moved(16384, 128, 8, 16, width=2048)
+    assert moved["buffer_rows"] == 36864
+    assert moved["pieces"] == 4 and moved["piece_bytes"] == 36 << 20
+    assert moved["rows"] == {
+        "dispatch": 36864, "combine": 131072, "combine_backward": 36864,
+        "dispatch_backward": 131072}
+    assert sum(moved["rows"].values()) == 335872
+    assert moved["gathers"] == {
+        "dispatch": 1, "combine": 32, "combine_backward": 1,
+        "dispatch_backward": 32}
+
+
+@pytest.mark.parametrize("shape, pieces", [
+    ((64, 8, 2, 8, 64), 1),         # a small layer holding all 8 experts
+    ((16384, 128, 8, 16, 128), 1),  # 128 columns are not cut
+    ((8192, 128, 8, 16, 2048), 2),  # half the cell's tokens: 76 MiB
+    ((16384, 128, 8, 128, 2048), 16),  # all 128 held: pieces of 128 columns
+], ids=["small", "narrow", "half", "all_held"])
+def test_gather_pieces_follow_the_buffers_bytes(shape, pieces):
+    *layer, width = shape
+    moved = expert_parallel.held_rows_moved(*layer, width=width)
+    assert moved["pieces"] == pieces
+    assert moved["gathers"]["combine"] == layer[2] * pieces
+    assert moved["piece_bytes"] * pieces == moved["buffer_rows"] * width * 2
+    assert pieces == 1 or width // pieces == 128 \
+        or moved["piece_bytes"] <= expert_parallel.GATHER_OPERAND_BYTES
+
+
 def test_reference_follows_a_programs_route_only_inside_the_tie_window():
     cfg = dict(num_experts_per_tok=2, route_tie_window=0.03)
     # one token, four experts: probabilities 0.4, 0.3, 0.295, 0.005

@@ -61,12 +61,21 @@ def one_process():
 
 
 def build_lm_step(devices, *, n_layers=18, d_model=1536, n_heads=12,
-                  vocab=50257, seq_len=2048, per_chip_batch=4):
+                  vocab=50257, seq_len=2048, per_chip_batch=4, d_ff=None,
+                  options=None, chunked_ce=0, lr=1e-3):
     """The LM cells' step over ``devices`` (described or attached) and
-    its abstract arguments ``(params, opt_state, batch)``, shardings on."""
+    its abstract arguments ``(params, opt_state, batch)``, shardings on.
+    ``options`` (a ``BlockOptions``), ``d_ff`` and ``chunked_ce`` build
+    the example's model under its block flags instead of the GPT-2
+    block with the flash kernels as ``attention_fn``."""
     import chainermn_tpu as cmn
     from chainermn_tpu.functions import collectives as cc
-    from chainermn_tpu.models.transformer import TransformerLM, lm_loss
+    from chainermn_tpu.models.transformer import (
+        BlockOptions,
+        TransformerLM,
+        lm_loss,
+    )
+    from chainermn_tpu.ops import chunked_lm_loss
     from chainermn_tpu.ops.pallas_attention import flash_attention_fn
     from chainermn_tpu.parallel import megatron_param_specs
 
@@ -75,9 +84,11 @@ def build_lm_step(devices, *, n_layers=18, d_model=1536, n_heads=12,
     mesh = comm.mesh
     model = TransformerLM(
         vocab_size=vocab, d_model=d_model, n_heads=n_heads,
-        n_layers=n_layers, max_len=seq_len, dropout_rate=0.0,
+        n_layers=n_layers, max_len=seq_len, dropout_rate=0.0, d_ff=d_ff,
         # interpret=False: the host's backend is the CPU, the target is not
-        attention_fn=flash_attention_fn(interpret=False),
+        attention_fn=None if options else flash_attention_fn(
+            interpret=False),
+        options=options or BlockOptions(),
     )
     batch_spec = P("mn_data", "mn_seq")
     rows = per_chip_batch * len(devices)
@@ -91,11 +102,13 @@ def build_lm_step(devices, *, n_layers=18, d_model=1536, n_heads=12,
         tokens)
     specs = megatron_param_specs(params, model_axis="mn_model")
     opt = cmn.create_multi_node_optimizer(
-        optax.adamw(1e-3, weight_decay=0.01), comm)
+        optax.adamw(lr, weight_decay=0.01), comm)
 
     def loss_fn(p, b):
-        loss = lm_loss(
-            model.apply(p, b, rngs={"dropout": jax.random.PRNGKey(0)}), b)
+        loss = chunked_lm_loss(model, p, b, chunked_ce) if chunked_ce \
+            else lm_loss(
+                model.apply(p, b, rngs={"dropout": jax.random.PRNGKey(0)}),
+                b)
         for axis in (comm.seq_axis_name, comm.model_axis_name):
             loss = cc.pmean(loss, axis)  # width 1: certifies replication
         return loss

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +52,8 @@ class MlpBlock(nn.Module):
 @dataclasses.dataclass(frozen=True)
 class BlockOptions:
     """What the one transformer block can be besides GPT-2's (the
-    defaults): the options of its norms and of its attention.  A module
+    defaults): the options of its norms, of its attention, of its
+    sequence mixer and MLP, and of the stream around them.  A module
     takes them as one hashable field, ``options``.
 
     ``norm``: ``"layernorm"`` or ``"rmsnorm"`` (gain only), epsilon
@@ -65,11 +66,29 @@ class BlockOptions:
     block-diffusion training; the sequence axis then holds the clean
     copy of every sequence followed by its noised copy, both at
     positions ``0..s-1``, under :func:`ops.pallas_attention.block_diffusion_mask`.
+    ``attention_scale``: the factor on ``q k^T`` where it is not
+    ``head_dim ** -0.5``, handed to the kernels as their ``scale``.
     ``use_flash``: the Pallas kernels instead of a dense masked softmax.
     Any of the attention options takes :class:`SelfAttention` off its
     fused-qkv path onto separate ``q_proj`` / ``k_proj`` / ``v_proj`` /
     ``o_proj`` kernels; that path is single-device in the sequence and
-    head axes (no ``seq_axis``, ``tp_axis`` or ``decode``)."""
+    head axes (no ``seq_axis``, ``tp_axis`` or ``decode``).
+
+    ``layer_types``: the kind of each layer's sequence mixer,
+    ``"attention"`` or ``"mamba"`` (:class:`Mamba2Mixer`), layer ``i``
+    taking entry ``i % len(layer_types)``; ``None``: attention in every
+    layer.  The mixer's sizes: ``ssm_heads`` heads of ``ssm_head_dim``
+    (its inner width is their product), a state of ``ssm_state`` a head
+    channel, ``ssm_conv`` taps of the causal convolution, the scan's
+    ``ssm_chunk``.  ``gated_mlp``: ``W_out(SiLU(g) * u)`` with ``[g | u]
+    = W_in x``, no biases (:class:`GatedMlp`), instead of GELU with
+    biases.  ``no_positions``: the model holds no position table and
+    attention sees no position at all.  The stream's multipliers:
+    ``embedding_multiplier`` on the token embedding,
+    ``residual_multiplier`` on what a mixer or an MLP adds to the
+    stream, ``logits_scaling`` dividing the logits.  ``remat_blocks``:
+    the backward pass computes each block's forward again from the
+    block's input, the only activation kept a block."""
 
     norm: str = "layernorm"
     norm_eps: float = 1e-6
@@ -79,11 +98,35 @@ class BlockOptions:
     qk_norm: bool = False
     block_diffusion: int = 0
     use_flash: bool = False
+    attention_scale: Optional[float] = None
+    layer_types: Optional[Tuple[str, ...]] = None
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
+    gated_mlp: bool = False
+    no_positions: bool = False
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    remat_blocks: bool = False
 
     @property
     def general_attention(self) -> bool:
         return bool(self.n_kv_heads or self.head_dim or self.rope_theta
-                    or self.qk_norm or self.block_diffusion)
+                    or self.qk_norm or self.block_diffusion
+                    or self.attention_scale or self.no_positions)
+
+    def layer_type(self, layer: int) -> str:
+        """The kind of layer ``layer``'s sequence mixer."""
+        if not self.layer_types:
+            return "attention"
+        kind = self.layer_types[layer % len(self.layer_types)]
+        if kind not in ("attention", "mamba"):
+            raise ValueError(f"layer_types holds 'attention' and 'mamba', "
+                             f"got {kind!r}")
+        return kind
 
 
 def rms_norm(x, scale, eps: float, dtype):
@@ -254,15 +297,18 @@ class SelfAttention(nn.Module):
         if o.block_diffusion:
             attend = pa.block_diffusion_attention if o.use_flash \
                 else pa.block_diffusion_attention_dense
-            out = attend(q, k, v, o.block_diffusion)
+            out = attend(q, k, v, o.block_diffusion,
+                         scale=o.attention_scale)
         elif o.use_flash and causal:
             # causal is block-causal at block length 1
-            out, _ = pa.block_causal_attention_with_lse(q, k, v, 1)
+            out, _ = pa.block_causal_attention_with_lse(
+                q, k, v, 1, scale=o.attention_scale)
         else:
             from chainermn_tpu.ops import multi_head_attention
 
             rep = lambda t: jnp.repeat(t, hq // hkv, axis=2)
-            out = multi_head_attention(q, rep(k), rep(v), causal=causal)
+            out = multi_head_attention(q, rep(k), rep(v), causal=causal,
+                                       scale=o.attention_scale)
         with jax.named_scope(ATTN_PROJ_SCOPE):
             return dense(d, name="o_proj")(out.reshape(b, s, hq * dh))
 
@@ -398,6 +444,112 @@ class SelfAttention(nn.Module):
         return nn.Dense(d, use_bias=False, dtype=self.dtype)(out)
 
 
+#: device scopes of the gated MLP and of the state-space mixer (the
+#: convolution's and the scan's lie inside the mixer's: ops.ssd_scan)
+GATED_MLP_SCOPE = "gated_mlp"
+SSM_MIXER_SCOPE = "ssm_mixer"
+
+
+class GatedMlp(nn.Module):
+    """``W_out(SiLU(g) * u)`` with ``[g | u] = W_in x``, no biases: one
+    ``in_proj`` kernel of ``(d, 2 d_ff)`` and ``out_proj`` of ``(d_ff,
+    d)``."""
+
+    d_ff: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    @jax.named_scope(GATED_MLP_SCOPE)
+    def __call__(self, x):
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=self.dtype)
+        gate, up = jnp.split(dense(2 * self.d_ff, name="in_proj")(x), 2,
+                             axis=-1)
+        return dense(x.shape[-1], name="out_proj")(nn.silu(gate) * up)
+
+
+def _ssm_rates(key, shape, dtype=jnp.float32):
+    """``A_log``: the log of a rate drawn uniformly from [1, 16]."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _ssm_taps(key, shape, dtype=jnp.float32):
+    """The convolution's taps: uniform in +-1/2 (``k ** -0.5`` at the
+    four taps every published Mamba-2 has)."""
+    return jax.random.uniform(key, shape, dtype, -0.5, 0.5)
+
+
+def _ssm_step_bias(key, shape, dtype=jnp.float32):
+    """``dt_bias``: the inverse softplus of a step drawn log-uniformly
+    from [1e-3, 1e-1]."""
+    step = jnp.exp(jax.random.uniform(
+        key, shape, dtype, jnp.log(1e-3), jnp.log(1e-1)))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+class Mamba2Mixer(nn.Module):
+    """The sequence mixer of a Mamba-2 layer (arXiv:2405.21060; HF
+    ``GraniteMoeHybridMambaLayer``), sized by ``options``' ``ssm_*``
+    fields, one group of ``B`` and ``C`` for all heads.  On ``x (b, s,
+    d)``:
+
+        [z | xBC | dt] = in_proj(x)          widths inner | inner + 2n | h
+        [x' | B | C] = SiLU(conv1d(xBC))     causal, depthwise, with bias
+        y = ssd_scan(x', softplus(dt + dt_bias), -exp(A_log), B, C, D)
+        out_proj(RMSNorm(y * SiLU(z)))       the gate goes in before the norm
+
+    with ``inner = ssm_heads * ssm_head_dim`` and a learned gain on the
+    norm.  Products in ``dtype``; the step, the rates, the scan's decays
+    and states and the norm in float32.  Single-device in the sequence
+    and the heads, as the scan is (:mod:`chainermn_tpu.ops.ssd_scan`):
+    raises under ``seq_axis``, ``tp_axis`` or ``decode``."""
+
+    options: BlockOptions
+    dtype: Any = jnp.bfloat16
+    seq_axis: Optional[str] = None
+    tp_axis: Optional[str] = None
+    decode: bool = False
+
+    @nn.compact
+    @jax.named_scope(SSM_MIXER_SCOPE)
+    def __call__(self, x):
+        if self.tp_axis is not None or self.seq_axis is not None \
+                or self.decode:
+            raise ValueError(
+                "the state-space mixer is single-device in sequence and "
+                "heads: no seq_axis, tp_axis or decode")
+        from chainermn_tpu.ops.ssd_scan import causal_conv1d, ssd_scan
+
+        o = self.options
+        b, s, d = x.shape
+        h, p, n = o.ssm_heads, o.ssm_head_dim, o.ssm_state
+        inner = h * p
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=self.dtype)
+        f32 = lambda name, init, shape: self.param(name, init, shape,
+                                                   jnp.float32)
+        taps = f32("conv_kernel", _ssm_taps, (o.ssm_conv, inner + 2 * n))
+        conv_bias = f32("conv_bias", nn.initializers.zeros,
+                        (inner + 2 * n,))
+        rates = -jnp.exp(f32("A_log", _ssm_rates, (h,)))
+        step_bias = f32("dt_bias", _ssm_step_bias, (h,))
+        skip = f32("D", nn.initializers.ones, (h,))
+        gain = f32("norm", nn.initializers.ones, (inner,))
+
+        z, xbc, dt = jnp.split(
+            dense(2 * inner + 2 * n + h, name="in_proj")(x),
+            [inner, 2 * inner + 2 * n], axis=-1)
+        xbc = nn.silu(causal_conv1d(xbc, taps, conv_bias))
+        xs, B, C = jnp.split(xbc, [inner, inner + n], axis=-1)
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + step_bias)
+        y = ssd_scan(xs.reshape(b, s, h, p), dt, rates, B, C, skip,
+                     chunk=o.ssm_chunk, dtype=self.dtype)
+        gated = y.reshape(b, s, inner).astype(jnp.float32) \
+            * nn.silu(z.astype(jnp.float32))
+        return dense(d, name="out_proj")(
+            rms_norm(gated, gain, o.norm_eps, self.dtype))
+
+
 class TpMlpBlock(nn.Module):
     """Megatron MLP: column-parallel up-projection -> gelu ->
     row-parallel down-projection — exactly one psum per block."""
@@ -439,24 +591,42 @@ class TransformerBlock(nn.Module):
     # no cell runs (LayerNorm rides the matmul fusions: PERF.md section 5)
     ln_dtype: Any = jnp.float32
     options: BlockOptions = BlockOptions()
+    # the sequence mixer: "attention" or "mamba" (BlockOptions.layer_type)
+    kind: str = "attention"
 
     @nn.compact
     def __call__(self, x):
-        ln = lambda: make_norm(self.options, self.ln_dtype)
+        o = self.options
+        ln = lambda: make_norm(o, self.ln_dtype)
 
         def drop(h):
-            return _stream_dropout(
+            h = _stream_dropout(
                 self, h, self.dropout_rate, self.deterministic,
                 self.seq_axis, self.tp_axis,
             )
+            if o.residual_multiplier != 1.0:
+                h = (h.astype(jnp.float32)
+                     * o.residual_multiplier).astype(h.dtype)
+            return h
 
-        x = x + drop(SelfAttention(
-            self.n_heads, dtype=self.dtype, seq_axis=self.seq_axis,
-            tp_axis=self.tp_axis, sp_impl=self.sp_impl,
-            decode=self.decode, cache_len=self.cache_len,
-            attention_fn=self.attention_fn, options=self.options,
-        )(ln()(x).astype(self.dtype)))
-        if self.tp_axis is not None:
+        if self.kind == "mamba":
+            mixer = Mamba2Mixer(
+                o, dtype=self.dtype, seq_axis=self.seq_axis,
+                tp_axis=self.tp_axis, decode=self.decode)
+        else:
+            mixer = SelfAttention(
+                self.n_heads, dtype=self.dtype, seq_axis=self.seq_axis,
+                tp_axis=self.tp_axis, sp_impl=self.sp_impl,
+                decode=self.decode, cache_len=self.cache_len,
+                attention_fn=self.attention_fn, options=o,
+            )
+        x = x + drop(mixer(ln()(x).astype(self.dtype)))
+        if o.gated_mlp:
+            if self.tp_axis is not None:
+                raise ValueError("the gated MLP has no tensor-parallel "
+                                 "form: no tp_axis")
+            mlp = GatedMlp(self.d_ff, dtype=self.dtype)
+        elif self.tp_axis is not None:
             mlp = TpMlpBlock(self.d_ff, tp_axis=self.tp_axis,
                              dtype=self.dtype)
         else:
@@ -541,15 +711,15 @@ class TransformerLM(nn.Module):
     # fp32 LayerNorm is the numerics-safe default; bf16 is a perf knob
     # no cell runs (LayerNorm rides the matmul fusions: PERF.md section 5)
     ln_dtype: Any = jnp.float32
+    # the block's options (norm, attention, state-space mixer, gated MLP,
+    # the stream's multipliers, per-block recomputation):
+    # BlockOptions.  With ``rope_theta`` or ``no_positions`` the model
+    # holds no position table.
+    options: BlockOptions = BlockOptions()
 
-    @nn.compact
-    def __call__(self, tokens):
-        b, s = tokens.shape
-        d_ff = self.d_ff or 4 * self.d_model
-        embed = make_lm_embed(
-            self, self.vocab_size, self.d_model, self.tp_axis,
-            self.vocab_parallel,
-        )
+    def _positions(self, s: int):
+        """The learned position table's rows of this call's ``s``
+        tokens, ``(s, d_model)``."""
         pos_table = self.param(
             "pos_embed", nn.initializers.normal(0.02),
             (self.max_len, self.d_model), jnp.float32,
@@ -581,15 +751,37 @@ class TransformerLM(nn.Module):
             )
             offset = pos_idx.value
             pos_idx.value = offset + s
-        pos = lax.dynamic_slice_in_dim(pos_table, offset, s, axis=0)
+        return lax.dynamic_slice_in_dim(pos_table, offset, s, axis=0)
 
-        x = (embed(tokens) + pos[None]).astype(self.dtype)
+    @nn.compact
+    def __call__(self, tokens):
+        b, s = tokens.shape
+        o = self.options
+        d_ff = self.d_ff or 4 * self.d_model
+        embed = make_lm_embed(
+            self, self.vocab_size, self.d_model, self.tp_axis,
+            self.vocab_parallel,
+        )
+        pos = None if o.rope_theta or o.no_positions \
+            else self._positions(s)
+        x = embed(tokens)
+        if pos is not None:
+            x = x + pos[None]
+        if o.embedding_multiplier != 1.0:
+            x = x * o.embedding_multiplier
+        x = x.astype(self.dtype)
         x = _stream_dropout(
             self, x, self.dropout_rate, self.deterministic, self.seq_axis,
             self.tp_axis,
         )
-        for _ in range(self.n_layers):
-            x = TransformerBlock(
+        for i in range(self.n_layers):
+            block, named = TransformerBlock, {}
+            if o.remat_blocks:
+                # under the name the block has without recomputation: one
+                # parameter tree either way
+                block = nn.remat(TransformerBlock)
+                named = {"name": f"TransformerBlock_{i}"}
+            x = block(
                 self.n_heads, d_ff, dtype=self.dtype,
                 seq_axis=self.seq_axis, tp_axis=self.tp_axis,
                 sp_impl=self.sp_impl, decode=self.decode,
@@ -597,17 +789,22 @@ class TransformerLM(nn.Module):
                 dropout_rate=self.dropout_rate,
                 deterministic=self.deterministic,
                 attention_fn=self.attention_fn,
-                ln_dtype=self.ln_dtype,
+                ln_dtype=self.ln_dtype, options=o,
+                kind=o.layer_type(i), **named,
             )(x)
-        x = nn.LayerNorm(dtype=self.ln_dtype)(x)
+        x = make_norm(o, self.ln_dtype)(x).astype(jnp.float32)
+        if o.logits_scaling != 1.0:
+            # the head is linear: dividing what it reads divides the
+            # logits, for the ``return_hidden`` twin's callers too
+            x = x / o.logits_scaling
         if self.return_hidden:
-            return x.astype(jnp.float32)
+            return x
         # Weight-tied head, under the device scope the losses share
         with jax.named_scope(HEAD_CE_SCOPE):
             if self.vocab_parallel:
                 # local vocab block
-                return embed.attend(x.astype(jnp.float32))
-            return x.astype(jnp.float32) @ embed.embedding.T
+                return embed.attend(x)
+            return x @ embed.embedding.T
 
 
 @jax.named_scope(HEAD_CE_SCOPE)

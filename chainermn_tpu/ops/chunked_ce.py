@@ -83,10 +83,15 @@ def _ce_fwd_impl(h, table, targets, n_chunks):
         tl = tl + jnp.where(in_c, picked, 0.0)
         return (cm, s, tl), None
 
-    init = (
+    from .grouped_matmul import vary_alike
+
+    # inside a vma-checked shard_map a scan's first carry has the type
+    # of the carries that follow
+    init = vary_alike(
         jnp.full((n,), -jnp.inf, jnp.float32),
         jnp.zeros((n,), jnp.float32),
         jnp.zeros((n,), jnp.float32),
+        like=(h, table, targets),
     )
     (m, s, tl), _ = lax.scan(body, init, (e, chunk_ids))
     lse = m + jnp.log(s)
@@ -130,11 +135,15 @@ def _ce_bwd(n_chunks, res, g):
             g[:, None]
         return dh, de_c
 
-    dh, de = lax.scan(body, jnp.zeros((n, d), jnp.float32),
-                      (e, jnp.arange(n_chunks)))
+    from .grouped_matmul import sum_to_vma, vary_alike
+
+    dh0, = vary_alike(jnp.zeros((n, d), jnp.float32), like=(h, table, g))
+    dh, de = lax.scan(body, dh0, (e, jnp.arange(n_chunks)))
+    # a table replicated over the data axes gets the sum of its shards'
+    # gradients, as autodiff gives a plain operation's
     return (
         dh.astype(h.dtype),
-        de.reshape(v, d).astype(table.dtype),
+        sum_to_vma(de.reshape(v, d).astype(table.dtype), table),
         None,
     )
 

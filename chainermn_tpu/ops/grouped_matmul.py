@@ -56,11 +56,13 @@ def sum_to_vma(cotangent, primal):
     return _cc.psum(cotangent, extra) if extra else cotangent
 
 
-def _vary_alike(*operands):
-    """The operands of a ``pallas_call`` inside ``shard_map``, each made
-    to vary over every mesh axis any of them varies over (a matter of
-    types: nothing moves)."""
-    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+def vary_alike(*operands, like=()):
+    """The operands of a ``pallas_call`` or the first carries of a scan
+    inside ``shard_map``, each made to vary over every mesh axis any of
+    them, or of ``like``, varies over (a matter of types: nothing
+    moves)."""
+    vma = frozenset().union(
+        *(jax.typeof(x).vma for x in (*operands, *like)))
     return tuple(
         lax.pcast(x, tuple(sorted(vma - jax.typeof(x).vma)), to="varying")
         if vma - jax.typeof(x).vma else x for x in operands)
@@ -101,7 +103,7 @@ def _rows_product(x, w, block_group, block_rows, transpose_w, interpret):
                          blocks, w[block_group],
                          preferred_element_type=jnp.float32)
         return out.astype(x.dtype).reshape(x.shape[0], -1)
-    block_group, x, w = _vary_alike(block_group, x, w)
+    block_group, x, w = vary_alike(block_group, x, w)
     r, k = x.shape
     n = w.shape[1] if transpose_w else w.shape[2]
     w_block = (1, n, k) if transpose_w else (1, k, n)
@@ -140,7 +142,7 @@ def _weight_gradient(x, dy, block_group, groups, block_rows, interpret):
             preferred_element_type=jnp.float32)
         return jax.ops.segment_sum(per_block, block_group,
                                    num_segments=groups)
-    block_group, x, dy = _vary_alike(block_group, x, dy)
+    block_group, x, dy = vary_alike(block_group, x, dy)
     r, k = x.shape
     n = dy.shape[1]
     tk = _dw_tile(k, n)

@@ -1,0 +1,196 @@
+"""Chunked state-space scan (Mamba-2's SSD form) and the causal
+depthwise convolution in front of it, in plain XLA.
+
+A Mamba-2 head carries a state ``S (p, n)`` over the positions of a
+sequence (``p`` the head's width, ``n`` the state size), driven by a
+positive step ``dt_t``, a negative rate ``A`` (one a head), an input
+``x_t (p,)`` and two vectors ``B_t``, ``C_t (n,)`` that all heads share
+(one group):
+
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T        (S_{-1} = 0)
+    y_t = S_t C_t + D x_t
+
+:func:`ssd_scan` computes exactly that, ``chunk`` positions at a time
+(arXiv:2405.21060, section 6).  With ``a_t = dt_t A`` and ``cum`` its
+running sum inside a chunk:
+
+* inside a chunk, ``y_i += sum_{j <= i} (C_i . B_j) L_ij dt_j x_j`` with
+  ``L_ij = exp(cum_i - cum_j)``: one ``(chunk, chunk)`` score product a
+  chunk for all heads, and a masked product of it with ``x`` a head;
+* the state a chunk alone would leave, ``sum_j exp(cum_end - cum_j) dt_j
+  x_j B_j^T``, one product a head and chunk;
+* the recurrence over those chunk states, ``S <- exp(cum_end) S +
+  state``, a loop of ``s / chunk`` element-wise steps in float32;
+* what the state carried into a chunk adds: ``y_i += exp(cum_i) S C_i``.
+
+Products take bfloat16 operands and accumulate in float32; ``dt``, the
+running sums, ``L`` and the carried states are float32.  The part
+inside the chunks runs :data:`CHUNKS_PER_PASS` chunks at a time under
+``jax.checkpoint``: a ``(chunks, heads, chunk, chunk)`` float32 tensor
+is 537 MB a layer at 8192 positions and 64 heads, so neither pass holds
+more than a pass's worth of it and the backward computes it again.
+
+The scan is single-device in the sequence and the heads: it has no
+sequence-parallel, tensor-parallel or decode form.
+
+:func:`ssd_census` is the static count of the algorithm's work (chunks,
+matmul FLOPs by part, least bytes), as ``launch_census`` is for the
+flash kernels.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .grouped_matmul import vary_alike
+
+#: chunks whose ``(heads, chunk, chunk)`` decay tensors are live at once
+#: (on the chip 8 read 468.1 ms a step of the cell against 470.0 at 4:
+#: fewer, larger fusions; ``PERF.md`` section 6, PR 37)
+CHUNKS_PER_PASS = 8
+
+#: device scopes of the convolution and of the scan
+SSM_CONV_SCOPE = "ssm_conv"
+SSM_SCAN_SCOPE = "ssm_scan"
+
+
+@jax.named_scope(SSM_CONV_SCOPE)
+def causal_conv1d(x, taps, bias=None):
+    """Depthwise causal convolution over the sequence: ``x (b, s, c)``,
+    ``taps (k, c)``, ``bias (c,)``;
+
+        y_t = bias + sum_{j < k} taps[j] x_{t - (k - 1) + j}
+
+    with zeros before the sequence (``taps[k - 1]`` meets ``x_t``: a
+    ``torch.nn.Conv1d(c, c, k, groups=c, padding=k - 1)`` cut to ``s``).
+    Float32 sums of ``k`` shifted copies, the result in ``x``'s dtype."""
+    k, s = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(padded[:, j:j + s].astype(jnp.float32)
+            * taps[j].astype(jnp.float32) for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(x.dtype)
+
+
+def _dot(spec, a, b, dtype):
+    """A product with operands in ``dtype`` and a float32 result."""
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _weighted(x, weights):
+    """``x (g, q, h, p)`` times ``weights (g, q, h)`` in ``x``'s dtype,
+    the weights rounded to it first: left in float32, XLA:TPU makes a
+    float32 copy of ``x`` and lays that out again for the product (two
+    passes over twice the bytes, 8.7 ms of the cell's step: ``PERF.md``
+    section 6, PR 37); the product is a bfloat16 operand either way."""
+    return x * weights.astype(x.dtype)[..., None]
+
+
+@functools.partial(jax.checkpoint, static_argnums=(7,))
+def _within_chunks(x, dt, cum, B, C, carried, D, dtype):
+    """``y`` of a few chunks: ``x (g, q, h, p)``, ``dt`` / ``cum (g, q,
+    h)``, ``B`` / ``C (g, q, n)``, ``carried (g, h, p, n)`` the state
+    entering each chunk, ``D (h,)``.  ``(g, q, h, p)`` in ``x``'s dtype,
+    summed in float32."""
+    q = x.shape[1]
+    dot = functools.partial(_dot, dtype=dtype)
+    scores = dot("gin,gjn->gij", C, B)
+    # exp of a masked difference: above the diagonal cum_i - cum_j is
+    # positive and may overflow
+    live = jnp.tril(jnp.ones((q, q), bool))[None, :, :, None]
+    decay = jnp.exp(jnp.where(
+        live, cum[:, :, None, :] - cum[:, None, :, :], -jnp.inf))
+    weights = scores[..., None] * decay * dt[:, None, :, :]  # (g, i, j, h)
+    inside = dot("gijh,gjhp->gihp", weights, x)
+    from_state = dot("gin,ghpn->gihp", C, carried) \
+        * jnp.exp(cum)[..., None]
+    skip = D[:, None] * x.astype(jnp.float32)
+    return (inside + from_state + skip).astype(x.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "dtype"))
+@jax.named_scope(SSM_SCAN_SCOPE)
+def ssd_scan(x, dt, A, B, C, D, chunk: int = 256, dtype=jnp.bfloat16):
+    """The recurrence of the module docstring in its chunked form.
+
+    ``x (b, s, h, p)``; ``dt (b, s, h)``, positive; ``A (h,)``,
+    negative; ``B``, ``C (b, s, n)``, shared by the heads; ``D (h,)``.
+    Returns ``y (b, s, h, p)`` in ``x``'s dtype; ``dtype`` is that of
+    the products' operands.  A length that is no multiple of ``chunk``
+    is padded with ``dt = 0`` rows (decay 1, no input), which leave
+    every state as it was."""
+    b, s, h, p = x.shape
+    n = B.shape[-1]
+    pad = -s % chunk
+    if pad:
+        x, dt, B, C = (jnp.pad(t, ((0, 0), (0, pad))
+                               + ((0, 0),) * (t.ndim - 2))
+                       for t in (x, dt, B, C))
+    c = (s + pad) // chunk
+    dt = dt.astype(jnp.float32)
+    A = A.astype(jnp.float32)
+    # chunks of all sequences on one axis: they differ only in the state
+    # they are handed
+    xc = x.reshape(b * c, chunk, h, p)
+    dtc = dt.reshape(b * c, chunk, h)
+    Bc, Cc = B.reshape(b * c, chunk, n), C.reshape(b * c, chunk, n)
+    cum = jnp.cumsum(dtc * A, axis=1)  # (bc, q, h), inclusive
+
+    # the state each chunk alone would leave at its end
+    to_end = jnp.exp(cum[:, -1:, :] - cum) * dtc
+    own = _dot("gjhp,gjn->ghpn", _weighted(xc, to_end), Bc, dtype)
+
+    def carry_on(state, chunk_of):
+        own_c, decay_c = chunk_of
+        return decay_c[..., None, None] * state + own_c, state
+
+    own = own.reshape(b, c, h, p, n).swapaxes(0, 1)
+    through = jnp.exp(cum[:, -1, :]).reshape(b, c, h).swapaxes(0, 1)
+    # (inside shard_map the first carry varies as the chunk states do)
+    state0, = vary_alike(jnp.zeros((b, h, p, n), jnp.float32), like=(own,))
+    _, carried = lax.scan(carry_on, state0, (own, through))
+    carried = carried.swapaxes(0, 1).reshape(b * c, h, p, n)
+
+    g = math.gcd(b * c, CHUNKS_PER_PASS)
+    passes = lambda t: t.reshape(b * c // g, g, *t.shape[1:])
+    D = D.astype(jnp.float32)
+    y = lax.map(lambda args: _within_chunks(*args, D, dtype),
+                tuple(map(passes, (xc, dtc, cum, Bc, Cc, carried))))
+    return y.reshape(b, s + pad, h, p)[:, :s]
+
+
+def ssd_census(s: int, chunk: int, heads: int, head_dim: int,
+               state: int, itemsize: int = 2) -> dict:
+    """What :func:`ssd_scan` computes for one sequence of ``s``
+    positions, from the algorithm alone: ``chunks`` (and the padded
+    length), the forward pass's matmul FLOPs by part -- ``scores`` (``C
+    B^T``, once a chunk for all heads), ``inside`` (the masked scores
+    against ``x``), ``states`` (each chunk's own end state) and
+    ``carried`` (``C`` against the state handed in) -- their sum
+    ``flops_forward``, ``flops_backward`` (two products for each of the
+    forward's, and the scores once more: they are computed again), and
+    ``bytes_forward``: ``x`` and ``y`` once each in ``itemsize`` bytes,
+    ``B`` and ``C`` once, ``dt`` in float32."""
+    chunks = -(-s // chunk)
+    inner = heads * head_dim
+    parts = {
+        "scores": 2.0 * chunks * chunk * chunk * state,
+        "inside": 2.0 * chunks * chunk * chunk * inner,
+        "states": 2.0 * chunks * chunk * inner * state,
+        "carried": 2.0 * chunks * chunk * inner * state,
+    }
+    forward = sum(parts.values())
+    return {
+        "chunks": chunks, "padded": chunks * chunk, "flops": parts,
+        "flops_forward": forward,
+        "flops_backward": 2.0 * forward + parts["scores"],
+        "bytes_forward": float(s) * (2 * inner * itemsize
+                                     + 2 * state * itemsize + 4 * heads),
+    }

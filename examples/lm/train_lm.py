@@ -25,9 +25,21 @@ Virtual-mesh smoke run (2 data x 2 seq x 2 model):
 
 On one real TPU chip, flash attention kicks in automatically for long
 sequences: ``python examples/lm/train_lm.py --seq-len 2048 --flash``.
+
+The block's options (``models.transformer.BlockOptions``) have flags of
+their own: ``--rmsnorm``, grouped-query heads, ``--no-positions``, a
+``--gated-mlp``, the stream's multipliers, ``--layer-types`` with
+``mamba`` entries for Mamba-2 layers (``--ssm-*`` size their mixer),
+``--remat-blocks`` and ``--chunked-ce``.  A Mamba-2 / attention hybrid
+on the CPU:
+
+    python examples/lm/train_lm.py --cpu-mesh --rmsnorm --gated-mlp \
+      --no-positions --n-kv-heads 2 --layer-types mamba,mamba,attention \
+      --ssm-heads 8 --ssm-head-dim 32 --ssm-state 32 --ssm-chunk 64
 """
 
 import argparse
+import dataclasses
 import os
 import sys
 import time
@@ -62,6 +74,39 @@ def main(argv=None):
     p.add_argument("--n-layers", type=int, default=4)
     p.add_argument("--n-heads", type=int, default=4)
     p.add_argument("--dropout", type=float, default=0.0)
+    p.add_argument("--d-ff", type=int, default=None,
+                   help="MLP width (default 4 x d-model)")
+    # the block's options: models.transformer.BlockOptions
+    p.add_argument("--rmsnorm", action="store_true",
+                   help="RMSNorm (gain only) instead of LayerNorm")
+    p.add_argument("--norm-eps", type=float, default=1e-6)
+    p.add_argument("--n-kv-heads", type=int, default=None,
+                   help="key/value heads (grouped-query attention)")
+    p.add_argument("--attention-scale", type=float, default=None,
+                   help="factor on q k^T where not head width ** -0.5")
+    p.add_argument("--no-positions", action="store_true",
+                   help="no position table and no positions in attention")
+    p.add_argument("--gated-mlp", action="store_true",
+                   help="W_out(SiLU(g) * u), [g | u] = W_in x, no biases")
+    p.add_argument("--layer-types", default=None,
+                   help="comma-separated 'attention' / 'mamba', a layer "
+                        "each, repeated over the depth")
+    p.add_argument("--ssm-heads", type=int, default=0,
+                   help="heads of a Mamba-2 mixer")
+    p.add_argument("--ssm-head-dim", type=int, default=64)
+    p.add_argument("--ssm-state", type=int, default=128)
+    p.add_argument("--ssm-conv", type=int, default=4)
+    p.add_argument("--ssm-chunk", type=int, default=256)
+    p.add_argument("--embedding-multiplier", type=float, default=1.0)
+    p.add_argument("--residual-multiplier", type=float, default=1.0)
+    p.add_argument("--logits-scaling", type=float, default=1.0)
+    p.add_argument("--remat-blocks", action="store_true",
+                   help="compute each block's forward again in the "
+                        "backward pass: only the blocks' inputs are kept")
+    p.add_argument("--chunked-ce", type=int, default=0, metavar="CHUNKS",
+                   help="head + cross-entropy over this many vocabulary "
+                        "chunks (ops.chunked_lm_loss): the logits are "
+                        "never whole; dense model, --sp 1 --tp 1")
     p.add_argument("--steps", type=int, default=200)
     p.add_argument("--lr", type=float, default=1e-3)
     p.add_argument("--report-every", type=int, default=20)
@@ -108,6 +153,7 @@ def main(argv=None):
     from jax.sharding import PartitionSpec as P
 
     from chainermn_tpu.models.transformer import (
+        BlockOptions,
         TransformerLM,
         generate,
         lm_loss,
@@ -124,27 +170,51 @@ def main(argv=None):
         print(f"mesh: dp={comm.dp_size} x sp={comm.sp_size} x "
               f"tp={comm.tp_size}  {comm!r}")
 
+    options = BlockOptions(
+        norm="rmsnorm" if args.rmsnorm else "layernorm",
+        norm_eps=args.norm_eps, n_kv_heads=args.n_kv_heads,
+        attention_scale=args.attention_scale,
+        no_positions=args.no_positions, gated_mlp=args.gated_mlp,
+        layer_types=tuple(args.layer_types.split(","))
+        if args.layer_types else None,
+        ssm_heads=args.ssm_heads, ssm_head_dim=args.ssm_head_dim,
+        ssm_state=args.ssm_state, ssm_conv=args.ssm_conv,
+        ssm_chunk=args.ssm_chunk,
+        embedding_multiplier=args.embedding_multiplier,
+        residual_multiplier=args.residual_multiplier,
+        logits_scaling=args.logits_scaling,
+        remat_blocks=args.remat_blocks,
+    )
+    # the general attention path takes the kernels by option, the fused
+    # qkv path as a function
     attention_fn = None
-    if args.flash:
+    if args.flash and options.general_attention:
+        options = dataclasses.replace(options, use_flash=True)
+    elif args.flash:
         from chainermn_tpu.ops.pallas_attention import flash_attention_fn
 
         attention_fn = flash_attention_fn()
+    # only the fused-qkv block of GPT-2 has a KV-cache decode form
+    cached_decode = not (options.general_attention or options.layer_types)
 
-    def make_model(seq_axis, tp_axis, deterministic=False):
+    def make_model(seq_axis, tp_axis, deterministic=False,
+                   options=options):
         return TransformerLM(
             vocab_size=args.vocab, d_model=args.d_model,
-            n_heads=args.n_heads, n_layers=args.n_layers,
+            n_heads=args.n_heads, n_layers=args.n_layers, d_ff=args.d_ff,
             max_len=args.seq_len, dropout_rate=args.dropout,
             deterministic=deterministic, seq_axis=seq_axis,
             tp_axis=tp_axis, sp_impl=args.sp_impl,
             vocab_parallel=args.vocab_parallel,
-            attention_fn=attention_fn,
+            attention_fn=attention_fn, options=options,
         )
 
     seq_axis = "mn_seq" if args.sp > 1 else None
     tp_axis = "mn_model" if args.tp > 1 else None
     if args.vocab_parallel and tp_axis is None:
         p.error("--vocab-parallel requires --tp > 1")
+    if args.chunked_ce and (seq_axis or tp_axis):
+        p.error("--chunked-ce is the dense model's: --sp 1 --tp 1")
     model = make_model(seq_axis, tp_axis)
 
     batch = args.batchsize or 2 * comm.dp_size
@@ -171,7 +241,13 @@ def main(argv=None):
         optax.adamw(args.lr, weight_decay=0.01), comm
     )
 
-    def loss_fn(p, b):
+    def main_loss(p, b):
+        if args.chunked_ce:
+            # the tied head a vocabulary chunk at a time: the (b, s, V)
+            # logits never materialize
+            from chainermn_tpu.ops import chunked_lm_loss
+
+            return chunked_lm_loss(model, p, b, args.chunked_ce)
         logits = model.apply(
             p, b, rngs={"dropout": jax.random.PRNGKey(0)}
         )
@@ -179,11 +255,13 @@ def main(argv=None):
             # vocab-sharded logits: softmax statistics assembled with
             # collectives, the full-vocab row never materializes (the
             # psums also make the loss mn_model-invariant)
-            main = vp_lm_loss(logits, b, tp_axis, seq_axis=seq_axis)
-        elif seq_axis is not None:
-            main = sp_lm_loss(logits, b, seq_axis)
-        else:
-            main = lm_loss(logits, b)
+            return vp_lm_loss(logits, b, tp_axis, seq_axis=seq_axis)
+        if seq_axis is not None:
+            return sp_lm_loss(logits, b, seq_axis)
+        return lm_loss(logits, b)
+
+    def loss_fn(p, b):
+        main = main_loss(p, b)
         # Certify replication to vma-checked autodiff over every mesh
         # axis the loss wasn't reduced over: unused (size-1) axes still
         # shard the batch spec, so vma tracks them as varying — the
@@ -231,7 +309,12 @@ def main(argv=None):
         # Sampling: SP is training-only — materialize the dense twin
         # (identical param tree for seq_axis=None); TP generates
         # natively under its mesh.
-        gen_model = make_model(None, tp_axis, deterministic=True)
+        # (the block-causal kernels want whole blocks of positions: a
+        # prompt's odd lengths go through the dense masked softmax)
+        gen_model = make_model(
+            None, tp_axis, deterministic=True,
+            options=dataclasses.replace(options, use_flash=False,
+                                        remat_blocks=False))
         prompt = jnp.asarray(corpus[:2, :8])
         kw = {}
         if tp_axis is not None:
@@ -239,7 +322,8 @@ def main(argv=None):
         out = generate(
             gen_model, params, prompt, args.generate,
             temperature=args.temperature,
-            rng=jax.random.PRNGKey(7), **kw,
+            rng=jax.random.PRNGKey(7),
+            use_cache=None if cached_decode else False, **kw,
         )
         out = np.asarray(out)
         if chief:
@@ -247,8 +331,8 @@ def main(argv=None):
                 "vocab-parallel" if args.vocab_parallel
                 else "tp-sharded" if tp_axis is not None
                 else "dense"
-            )
-            print(f"sampled ({tier} KV-cache decode): "
+            ) + (" KV-cache" if cached_decode else " recompute")
+            print(f"sampled ({tier} decode): "
                   f"{out[0].tolist()}")
 
     served = None
@@ -260,6 +344,9 @@ def main(argv=None):
         if args.vocab_parallel:
             p.error("--serve does not support --vocab-parallel yet "
                     "(serve the dense-head twin)")
+        if not cached_decode:
+            p.error("--serve decodes through the KV cache, which only "
+                    "the GPT-2 block has")
         from chainermn_tpu.serving.batcher import (
             ContinuousBatcher,
             Request,

@@ -283,3 +283,42 @@ def test_grouped_matmul_compiles_at_the_cell_shape(one_chip):
     # second), two input gradients, two weight gradients
     assert text.count('custom_call_target="tpu_custom_call"') >= 5
     assert "_grouped_matmul_dw" in text
+
+
+def test_block_causal_attention_compiles_at_head_width_64(one_chip):
+    """The causal launch of ``granite4hmicro_train_s8192``: one
+    8192-token sequence, 32 query and 8 key/value heads of **64** (the
+    other cells' heads are 128 wide), ``scale = 1 / 64`` handed to the
+    kernels: forward, dq and dk/dv."""
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def attend(q, k, v):
+        return pa.block_causal_attention_with_lse(
+            q, k, v, 1, scale=1.0 / 64, interpret=False)[0]
+
+    text = _compiled_text(_grad_of(attend), q, kv, kv)
+    for kernel in ("_bdflash_forward", "_bdflash_backward_dq",
+                   "_bdflash_backward_dkdv"):
+        assert text.count(f"{kernel}/pallas_call") >= 1, kernel
+
+
+def test_chunked_scan_compiles_at_the_cell_shape(one_chip):
+    """``ops.ssd_scan`` at one Mamba-2 layer of the cell: 8192
+    positions, 64 heads of 64, state 128, chunk 256, forward and all
+    gradients; the step's temporaries stay a pass's worth (no
+    ``(chunks, heads, 256, 256)`` float32 tensor is kept: 537 MB)."""
+    from chainermn_tpu.ops.ssd_scan import ssd_scan
+
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    args = (sd((1, 8192, 64, 64), jnp.bfloat16),
+            sd((1, 8192, 64), jnp.float32), sd((64,), jnp.float32),
+            sd((1, 8192, 128), jnp.bfloat16),
+            sd((1, 8192, 128), jnp.bfloat16), sd((64,), jnp.float32))
+    compiled = jax.jit(jax.grad(
+        lambda *a: ssd_scan(*a).astype(jnp.float32).sum(),
+        argnums=range(6))).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 537e6 * 2

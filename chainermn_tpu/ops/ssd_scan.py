@@ -1,5 +1,6 @@
 """Chunked state-space scan (Mamba-2's SSD form) and the causal
-depthwise convolution in front of it, in plain XLA.
+depthwise convolution in front of it: the scan in two forms, Pallas
+kernels on a TPU and plain XLA everywhere else.
 
 A Mamba-2 head carries a state ``S (p, n)`` over the positions of a
 sequence (``p`` the head's width, ``n`` the state size), driven by a
@@ -20,33 +21,60 @@ running sum inside a chunk:
 * the state a chunk alone would leave, ``sum_j exp(cum_end - cum_j) dt_j
   x_j B_j^T``, one product a head and chunk;
 * the recurrence over those chunk states, ``S <- exp(cum_end) S +
-  state``, a loop of ``s / chunk`` element-wise steps in float32;
+  state``, ``s / chunk`` element-wise steps in float32;
 * what the state carried into a chunk adds: ``y_i += exp(cum_i) S C_i``.
 
 Products take bfloat16 operands and accumulate in float32; ``dt``, the
-running sums, ``L`` and the carried states are float32.  The part
-inside the chunks runs :data:`CHUNKS_PER_PASS` chunks at a time under
-``jax.checkpoint``: a ``(chunks, heads, chunk, chunk)`` float32 tensor
-is 537 MB a layer at 8192 positions and 64 heads, so neither pass holds
-more than a pass's worth of it and the backward computes it again.
+running sums, ``L`` and the carried states are float32, in both forms.
+
+**Which form runs** is read off the input and the platform
+(:func:`_use_kernels`).  The kernels (:mod:`.ssd_kernels`) run on a TPU
+when the sizes tile -- heads 64 wide, their number a multiple of 8, a
+state of 128, a chunk of 128 or 256 -- and ``x`` and the products'
+operands are bfloat16 (the cell's launch: chunk 256, 64 heads of 64,
+state 128); with ``interpret=True`` they run interpreted at any such
+sizes, in either precision (the unit tests).  Every other call runs the
+XLA form: off the TPU, float32 operands on it, a head width, head count,
+state or chunk the kernels do not tile.
+
+* **The kernels** walk a sequence's chunks in order with a group of 8
+  heads' states in VMEM, reading and writing the mixer's own token-major
+  ``(s, heads * 64)`` tensors: no operand is transposed, widened to
+  float32 or zero-filled in HBM.  ``ssd_scan`` is then a
+  ``jax.custom_vjp``: a forward that is not differentiated keeps
+  nothing; the differentiated one keeps its operands, the two layouts of
+  ``cum`` and ``dt`` and the state entering every chunk (``(chunks,
+  heads / 2, 128, 128)`` float32 a sequence, 67 MB at the cell's shape);
+  the backward kernel walks the chunks last to first with the states'
+  cotangent in VMEM and computes scores and decays again per tile.
+* **The XLA form** computes each chunk's own end state for all chunks,
+  carries them through a ``lax.scan`` and runs the part inside the
+  chunks :data:`CHUNKS_PER_PASS` chunks at a time under
+  ``jax.checkpoint``: a ``(chunks, heads, chunk, chunk)`` float32 tensor
+  is 537 MB a layer at 8192 positions and 64 heads, so neither pass
+  holds more than a pass's worth of it and the backward (autodiff's)
+  computes it again.  It is what the kernels are tested against.
 
 The scan is single-device in the sequence and the heads: it has no
 sequence-parallel, tensor-parallel or decode form.
 
 :func:`ssd_census` is the static count of the algorithm's work (chunks,
-matmul FLOPs by part, least bytes), as ``launch_census`` is for the
-flash kernels.
+matmul FLOPs by part, least bytes) and of the kernel path's launches
+(grid, tiles, VMEM a grid point, bytes moved), as ``launch_census`` is
+for the flash kernels.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import ssd_kernels
 from .grouped_matmul import vary_alike
 
 #: chunks whose ``(heads, chunk, chunk)`` decay tensors are live at once
@@ -115,9 +143,24 @@ def _within_chunks(x, dt, cum, B, C, carried, D, dtype):
     return (inside + from_state + skip).astype(x.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "dtype"))
+def _use_kernels(x, B, chunk, dtype, interpret) -> bool:
+    """Whether :func:`ssd_scan` runs the Pallas kernels: the sizes tile
+    (:func:`ssd_kernels.tiles`) and either the kernels are asked for
+    interpreted, or this is a TPU and ``x`` and the products' operands
+    are bfloat16 (what the tiles' VMEM is sized for)."""
+    _, _, h, p = x.shape
+    if not ssd_kernels.tiles(chunk, h, p, B.shape[-1]):
+        return False
+    if interpret is not None:
+        return True
+    return jax.default_backend() == "tpu" \
+        and x.dtype == jnp.bfloat16 and dtype == jnp.bfloat16
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "dtype", "interpret"))
 @jax.named_scope(SSM_SCAN_SCOPE)
-def ssd_scan(x, dt, A, B, C, D, chunk: int = 256, dtype=jnp.bfloat16):
+def ssd_scan(x, dt, A, B, C, D, chunk: int = 256, dtype=jnp.bfloat16,
+             interpret: Optional[bool] = None):
     """The recurrence of the module docstring in its chunked form.
 
     ``x (b, s, h, p)``; ``dt (b, s, h)``, positive; ``A (h,)``,
@@ -125,7 +168,41 @@ def ssd_scan(x, dt, A, B, C, D, chunk: int = 256, dtype=jnp.bfloat16):
     Returns ``y (b, s, h, p)`` in ``x``'s dtype; ``dtype`` is that of
     the products' operands.  A length that is no multiple of ``chunk``
     is padded with ``dt = 0`` rows (decay 1, no input), which leave
-    every state as it was."""
+    every state as it was.  ``interpret=True`` runs the kernels
+    interpreted wherever the sizes tile (the unit tests do)."""
+    if _use_kernels(x, B, chunk, dtype, interpret):
+        return _scan_kernels(x, dt, A, B, C, D, chunk, dtype,
+                             bool(interpret))
+    return _scan_xla(x, dt, A, B, C, D, chunk, dtype)
+
+
+def _padded(x, dt, B, C, chunk):
+    """The operands with ``dt = 0`` rows up to a whole chunk."""
+    pad = -x.shape[1] % chunk
+    if pad:
+        x, dt, B, C = (jnp.pad(t, ((0, 0), (0, pad))
+                               + ((0, 0),) * (t.ndim - 2))
+                       for t in (x, dt, B, C))
+    return x, dt, B, C
+
+
+def _scan_kernels(x, dt, A, B, C, D, chunk, dtype, interpret):
+    """The kernel path: padding and the running sums here, the passes
+    over the chunks in :func:`ssd_kernels.ssd_chunks`."""
+    b, s, h, p = x.shape
+    x, dt, B, C = _padded(x, dt, B, C, chunk)
+    padded = x.shape[1]
+    dt = dt.astype(jnp.float32)
+    cum = jnp.cumsum(
+        (dt * A.astype(jnp.float32)).reshape(b, padded // chunk, chunk, h),
+        axis=2).reshape(b, padded, h)
+    y = ssd_kernels.ssd_chunks(x.reshape(b, padded, h * p), dt, cum, B, C,
+                               D, chunk, dtype, interpret)
+    return y.reshape(b, padded, h, p)[:, :s]
+
+
+def _scan_xla(x, dt, A, B, C, D, chunk, dtype):
+    """The XLA form."""
     b, s, h, p = x.shape
     n = B.shape[-1]
     pad = -s % chunk
@@ -177,7 +254,14 @@ def ssd_census(s: int, chunk: int, heads: int, head_dim: int,
     ``flops_forward``, ``flops_backward`` (two products for each of the
     forward's, and the scores once more: they are computed again), and
     ``bytes_forward``: ``x`` and ``y`` once each in ``itemsize`` bytes,
-    ``B`` and ``C`` once, ``dt`` in float32."""
+    ``B`` and ``C`` once, ``dt`` in float32.  ``kernels`` is the static
+    account of the kernel path, ``None`` where the sizes do not tile
+    (:func:`ssd_kernels.tiles`: the XLA form runs): for the ``forward``
+    launch that keeps the entering states and for the ``backward``
+    launch, ``grid`` (sequences, head groups, chunks), ``tiles`` a
+    launch, ``vmem_bytes`` a grid point (scratch and double-buffered
+    blocks), ``hbm_bytes`` read and written, and ``hbm_over_least``,
+    those over ``bytes_forward``."""
     chunks = -(-s // chunk)
     inner = heads * head_dim
     parts = {
@@ -187,10 +271,18 @@ def ssd_census(s: int, chunk: int, heads: int, head_dim: int,
         "carried": 2.0 * chunks * chunk * inner * state,
     }
     forward = sum(parts.values())
+    least = float(s) * (2 * inner * itemsize + 2 * state * itemsize
+                        + 4 * heads)
+    kernels = None
+    if ssd_kernels.tiles(chunk, heads, head_dim, state):
+        kernels = ssd_kernels.launch_account(chunks * chunk, chunk, heads,
+                                             state, itemsize)
+        for launch in kernels.values():
+            launch["hbm_over_least"] = launch["hbm_bytes"] / least
     return {
         "chunks": chunks, "padded": chunks * chunk, "flops": parts,
         "flops_forward": forward,
         "flops_backward": 2.0 * forward + parts["scores"],
-        "bytes_forward": float(s) * (2 * inner * itemsize
-                                     + 2 * state * itemsize + 4 * heads),
+        "bytes_forward": least,
+        "kernels": kernels,
     }

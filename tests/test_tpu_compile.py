@@ -15,6 +15,7 @@ everything compiles in the test's own process.
 
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -305,20 +306,64 @@ def test_block_causal_attention_compiles_at_head_width_64(one_chip):
         assert text.count(f"{kernel}/pallas_call") >= 1, kernel
 
 
+def _scan_shapes(one_chip):
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    return (sd((1, 8192, 64, 64), jnp.bfloat16),
+            sd((1, 8192, 64), jnp.float32), sd((64,), jnp.float32),
+            sd((1, 8192, 128), jnp.bfloat16),
+            sd((1, 8192, 128), jnp.bfloat16), sd((64,), jnp.float32))
+
+
 def test_chunked_scan_compiles_at_the_cell_shape(one_chip):
-    """``ops.ssd_scan`` at one Mamba-2 layer of the cell: 8192
+    """``ops.ssd_scan``'s XLA form at one Mamba-2 layer of the cell: 8192
     positions, 64 heads of 64, state 128, chunk 256, forward and all
     gradients; the step's temporaries stay a pass's worth (no
     ``(chunks, heads, 256, 256)`` float32 tensor is kept: 537 MB)."""
     from chainermn_tpu.ops.ssd_scan import ssd_scan
 
-    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
-                                                   sharding=one_chip)
-    args = (sd((1, 8192, 64, 64), jnp.bfloat16),
-            sd((1, 8192, 64), jnp.float32), sd((64,), jnp.float32),
-            sd((1, 8192, 128), jnp.bfloat16),
-            sd((1, 8192, 128), jnp.bfloat16), sd((64,), jnp.float32))
     compiled = jax.jit(jax.grad(
         lambda *a: ssd_scan(*a).astype(jnp.float32).sum(),
-        argnums=range(6))).lower(*args).compile()
+        argnums=range(6))).lower(*_scan_shapes(one_chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()  # traced off a TPU
     assert compiled.memory_analysis().temp_size_in_bytes < 537e6 * 2
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_chunked_scan_kernels_compile_at_the_cell_shape(one_chip, grad):
+    """The same layer through the Pallas kernels (``ops/ssd_kernels.py``,
+    asked for with ``interpret=False`` as a TPU takes them by itself):
+    the forward launch alone, and with the gradient the forward that
+    keeps the entering states and the backward launch, each traced
+    under the ``ssm_scan`` scope; the residual states (67 MB) and the
+    per-group ``dB`` / ``dC`` (2 x 34 MB) are the temporaries."""
+    from chainermn_tpu.ops.ssd_scan import ssd_scan
+
+    scan = functools.partial(ssd_scan, interpret=False)
+    fn = jax.grad(lambda *a: scan(*a).astype(jnp.float32).sum(),
+                  argnums=range(6)) if grad else scan
+    compiled = jax.jit(fn).lower(*_scan_shapes(one_chip)).compile()
+    kernels = re.findall(r'op_name="([^"]*/(_ssd_\w+)/pallas_call)"',
+                         compiled.as_text())
+    assert {name for _, name in kernels} == (
+        {"_ssd_forward", "_ssd_backward"} if grad else {"_ssd_forward"})
+    assert all("/ssm_scan/" in op_name for op_name, _ in kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < 537e6
+
+
+def test_chunked_scan_kernels_compile_at_the_other_sizes_they_tile(one_chip):
+    """What ``ssd_kernels.tiles`` admits besides the cell's launch: a
+    chunk of 128, one group of 8 heads, two sequences whose length is
+    no multiple of the chunk; forward and gradient."""
+    from chainermn_tpu.ops.ssd_scan import ssd_scan
+
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    args = (sd((2, 1000, 8, 64), jnp.bfloat16),
+            sd((2, 1000, 8), jnp.float32), sd((8,), jnp.float32),
+            sd((2, 1000, 128), jnp.bfloat16),
+            sd((2, 1000, 128), jnp.bfloat16), sd((8,), jnp.float32))
+    text = _compiled_text(jax.grad(
+        lambda *a: ssd_scan(*a, chunk=128, interpret=False).astype(
+            jnp.float32).sum(), argnums=range(6)), *args)
+    assert text.count('custom_call_target="tpu_custom_call"') == 2

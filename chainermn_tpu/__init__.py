@@ -13,7 +13,15 @@ Facade parity: ``chainermn/__init__.py`` re-exports (component #1 in
 SURVEY.md section 2).
 """
 
-from chainermn_tpu.communicators import (  # noqa: F401
+import time as _time
+
+_t_first_line = _time.monotonic()  # where setup.before_program ends
+
+from chainermn_tpu.observability import timeline as _timeline  # noqa: E402
+
+_import_phase = _timeline.PROCESS.program_starts(_t_first_line)
+
+from chainermn_tpu.communicators import (  # noqa: F401,E402
     CommunicatorBase,
     create_communicator,
 )
@@ -33,6 +41,10 @@ from chainermn_tpu.extensions import (  # noqa: F401
 from chainermn_tpu import global_except_hook  # noqa: F401
 
 __version__ = "0.2.0"
+
+_import_phase.__exit__(None, None, None)  # setup.import ends here
+_timeline.PROCESS.listen()  # JAX's trace / lower / compile events
+del _import_phase, _t_first_line
 
 
 def __getattr__(name):

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
+from ..observability.timeline import phased as _phased
 from .communicator_base import CommunicatorBase
 from .xla_communicator_base import XlaCommunicatorBase
 from ._topology import Topology
@@ -51,6 +52,7 @@ _COMMUNICATORS = {
 }
 
 
+@_phased("setup.communicator")
 def create_communicator(
     communicator_name: str = "tpu",
     devices: Optional[Sequence] = None,

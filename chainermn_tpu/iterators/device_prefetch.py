@@ -21,10 +21,9 @@ Typical wiring (the ``--native-loader`` path)::
         params, opt_state, m = step(params, opt_state, batch)
 
 Telemetry (``observability.timeline``; nothing of it exists while
-telemetry is off): ``feed.collate`` spans ``next(host iterator)``,
-``feed.place`` the ``place_fn`` call (the ``device_put`` *enqueue*),
-and ``feed.h2d`` runs from that enqueue to the placed batch being
-ready.  The main thread must not wait for the copy, so each placed batch
+telemetry is off): ``feed.collate`` spans ``next(host iterator)``, and
+``feed.h2d`` runs from the ``place_fn`` call (the ``device_put``
+*enqueue*) to the placed batch being ready.  The main thread must not wait for the copy, so each placed batch
 gets a short-lived daemon thread that ``block_until_ready``s it inside
 the span and drops it: the span sits on that thread's line of the trace.
 """
@@ -94,8 +93,7 @@ class _DevicePrefetcher:
                     return
             # async dispatch: returns a jax.Array immediately, the copy
             # proceeds while the caller's current step computes
-            with _obs.span("feed.place"):
-                placed = self._place(host)
+            placed = self._place(host)
             self._watch(placed)
             self._buf.append(placed)
             self._states.append(state)

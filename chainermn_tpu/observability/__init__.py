@@ -6,8 +6,8 @@ repo can account for every collective before it runs (``analysis``'s
 pins) — this package records what actually happened at runtime and
 joins the two.
 
-* :mod:`.metrics` — counters/gauges/histograms in a get-or-create
-  registry; ``Histogram`` shares the min-of-N protocol helpers with
+* :mod:`.metrics` — histograms in a get-or-create registry;
+  ``Histogram`` shares the min-of-N protocol helpers with
   ``utils.benchmarking`` so bench rows and telemetry reports compute
   spreads identically.
 * :mod:`.timeline` — nestable ``span()`` context managers on the
@@ -15,6 +15,10 @@ joins the two.
   ``ResilienceLog`` events merge into the same stream.  Activation
   mirrors the fault injector (``is None`` fast path when disabled,
   ``CHAINERMN_TPU_TELEMETRY`` env activation for spawned workers).
+  Its process record (``phase()``, ``process_record()``) holds the
+  once-only set-up phases from the process's start, JAX's trace /
+  lower / compile events under them and a step's recompiles, telemetry
+  on or off.
 * :mod:`.attribute` — the static-vs-measured join:
   :func:`attribute(timeline, trace)` matches measured collective spans
   to ``CollectiveRecord``\\ s and prices achieved bytes/sec against the
@@ -30,20 +34,22 @@ pinned by ``tests/test_observability.py``).
 """
 
 from .metrics import (  # noqa: F401
-    Counter,
-    Gauge,
     Histogram,
     MetricsRegistry,
 )
 from .timeline import (  # noqa: F401
     ENV_TELEMETRY,
     NULL_SPAN,
+    ProcessRecord,
     Telemetry,
     Timeline,
     active,
     install,
     instant,
     observe,
+    phase,
+    process_record,
+    setup_line,
     span,
 )
 from .attribute import (  # noqa: F401

@@ -1,4 +1,4 @@
-"""Metrics registry: counters, gauges, histograms.
+"""Metrics registry: histograms of span durations.
 
 The runtime half of the repo's measurement story.  The static analyzer
 (``analysis``) prices every collective before it runs; these metrics
@@ -27,46 +27,6 @@ from __future__ import annotations
 from typing import Dict, List, Optional
 
 import numpy as np
-
-
-class Counter:
-    """Monotonically increasing count (events, retries, faults)."""
-
-    __slots__ = ("name", "_value")
-
-    def __init__(self, name: str):
-        self.name = name
-        self._value = 0
-
-    def inc(self, n: int = 1) -> None:
-        self._value += n
-
-    @property
-    def value(self) -> int:
-        return self._value
-
-    def __repr__(self):
-        return f"<Counter {self.name}={self._value}>"
-
-
-class Gauge:
-    """Last-written value (queue depth, current world size)."""
-
-    __slots__ = ("name", "_value")
-
-    def __init__(self, name: str):
-        self.name = name
-        self._value: Optional[float] = None
-
-    def set(self, v: float) -> None:
-        self._value = float(v)
-
-    @property
-    def value(self) -> Optional[float]:
-        return self._value
-
-    def __repr__(self):
-        return f"<Gauge {self.name}={self._value}>"
 
 
 class Histogram:
@@ -158,21 +118,7 @@ class MetricsRegistry:
     """
 
     def __init__(self):
-        self._counters: Dict[str, Counter] = {}
-        self._gauges: Dict[str, Gauge] = {}
         self._histograms: Dict[str, Histogram] = {}
-
-    def counter(self, name: str) -> Counter:
-        c = self._counters.get(name)
-        if c is None:
-            c = self._counters[name] = Counter(name)
-        return c
-
-    def gauge(self, name: str) -> Gauge:
-        g = self._gauges.get(name)
-        if g is None:
-            g = self._gauges[name] = Gauge(name)
-        return g
 
     def histogram(self, name: str) -> Histogram:
         h = self._histograms.get(name)
@@ -186,8 +132,6 @@ class MetricsRegistry:
     def snapshot(self) -> dict:
         """JSON-serializable view of everything recorded so far."""
         return {
-            "counters": {k: c.value for k, c in self._counters.items()},
-            "gauges": {k: g.value for k, g in self._gauges.items()},
             "histograms": {
                 k: {
                     "count": h.count,

@@ -46,6 +46,8 @@ import numpy as np
 import optax
 from jax import lax
 
+from .observability import timeline as _timeline
+
 
 def _axes_bound(axis_names) -> bool:
     """True when called under a trace with ``axis_names`` bound (shard_map).
@@ -426,6 +428,7 @@ class _MultiNodeOptimizer:
                 )
         _cw.plan_agreement(comm, plan)
 
+    @_timeline.phased("setup.optimizer")
     def init(self, params):
         self._check_plan_agreement(params)
         return MultiNodeOptimizerState(
@@ -523,6 +526,7 @@ class _DoubleBufferingOptimizer(_MultiNodeOptimizer):
             for b, spec in zip(buckets, wplan.buckets)
         )
 
+    @_timeline.phased("setup.optimizer")
     def init(self, params):
         self._check_plan_agreement(params)
         if self._wire is None:  # legacy per-leaf wire: param-shaped tree
@@ -614,6 +618,7 @@ class _ZeroRedundancyOptimizer(_MultiNodeOptimizer):
         n = self._comm.size
         return jax.tree_util.tree_map(lambda x: _to_blocks(x, n), tree)
 
+    @_timeline.phased("setup.optimizer")
     def init(self, params):
         self._check_plan_agreement(params)
         return MultiNodeOptimizerState(
@@ -911,6 +916,7 @@ class _ZeroRedundancyOptimizer(_MultiNodeOptimizer):
         return updates, MultiNodeOptimizerState(inner, state.step + 1)
 
 
+@_timeline.phased("setup.optimizer")
 def create_multi_node_optimizer(
     actual_optimizer: optax.GradientTransformation,
     communicator,
@@ -1128,6 +1134,7 @@ def _grad_reduce_compiler_options(mesh, axes):
 # reference reached via Trainer + _MultiNodeOptimizer (SURVEY.md section
 # 3.2: "the entire box under optimizer.update becomes ONE jitted function").
 # ----------------------------------------------------------------------
+@_timeline.phased("setup.build_step")
 def build_train_step(
     comm,
     loss_fn,
@@ -1284,6 +1291,26 @@ def build_train_step(
         if hybrid and overlap_mode != "bucket" else None
     )
 
+    # What the process record (observability.timeline) knows of this
+    # step object.  A cached call runs none of it but the count: the
+    # body below runs only while JAX traces the program, which is the
+    # slow path of a call and nothing else, and JAX's own trace / lower
+    # / compile events (which a cached call never reaches) do the rest.
+    n_calls = 0
+    n_programs = [0]  # programs compiled for this step object so far
+
+    def _tell_record(body):
+        """``body`` as it is jitted: traced, it opens the step call that
+        the compile after the trace closes as ``step.first_call`` (and,
+        after the step object's first program, ``step.recompile``)."""
+        @functools.wraps(body)
+        def traced(params, opt_state, batch):
+            _timeline.PROCESS.step_traced(body.__name__, n_calls,
+                                          n_programs)
+            return body(params, opt_state, batch)
+
+        return traced
+
     def _finish_build(sharded, in_shardings):
         """jit (or overlap-schedule) one built shard_map step.  The jit
         pins ``in_shardings`` (the shard_map's ``in_specs`` on the mesh,
@@ -1305,7 +1332,7 @@ def build_train_step(
                 label="train_step",
             )
         return jax.jit(
-            sharded,
+            _tell_record(sharded),
             donate_argnums=(0, 1) if donate else (),
             in_shardings=in_shardings,
             compiler_options=compiler_options,
@@ -1600,7 +1627,7 @@ def build_train_step(
         def _build(state_shardings, pshardings=None):
             pshardings = rep if pshardings is None else pshardings
             return jax.jit(
-                _step,
+                _tell_record(_step),
                 donate_argnums=(0, 1) if donate else (),
                 in_shardings=(pshardings, state_shardings, batch_sharding),
                 out_shardings=(pshardings, state_shardings, rep),
@@ -1778,6 +1805,8 @@ def build_train_step(
             return False
 
     def checked_step(params, opt_state, batch):
+        nonlocal n_calls
+        n_calls += 1  # the ordinal a recompile is reported with
         if not _is_placed(batch):
             batch = _place_batch(batch)
         # ``jit`` keys its trace on the arguments' types, and an array's
@@ -1789,7 +1818,7 @@ def build_train_step(
         # Multi-process worlds keep jit's own handling of host values.
         leaves, structure = jax.tree_util.tree_flatten((params, opt_state))
         if n_procs == 1 and not all(map(_on_mesh, leaves)):
-            params, opt_state = place(params, opt_state)
+            params, opt_state = _place(params, opt_state)
         if _guard_enabled[0]:
             key = _guard_key(params, opt_state, batch)
             if key not in _guard_verified:
@@ -1797,7 +1826,7 @@ def build_train_step(
         return _get_step(params, opt_state, structure)(
             params, opt_state, batch)
 
-    def place(params, opt_state=None, batch=None):
+    def _place(params, opt_state=None, batch=None):
         """Device-put helper: lay out params per their partition specs
         (replicated unless hybrid), optimizer state per its spec (sharded
         for ZeRO / hybrid), shard a batch."""
@@ -1813,6 +1842,8 @@ def build_train_step(
             out.append(_place_batch(batch))
         return out[0] if len(out) == 1 else tuple(out)
 
+    # the public call is a set-up phase; the step's own use above is not
+    place = _timeline.phased("setup.place_state")(_place)
     place_batch = _place_batch
 
     checked_step.place = place

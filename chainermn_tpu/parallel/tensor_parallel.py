@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
+from ..observability.timeline import phased as _phased
+
 
 class ColumnParallelDense(nn.Module):
     """Dense whose output features are sharded across ``axis_name``.
@@ -270,6 +272,7 @@ def tp_flow_specs(params, model_axis: str = "tp",
     }
 
 
+@_phased("setup.init_params")
 def sharded_init(init_fn: Callable, mesh, in_specs, param_specs_fn,
                  *args):
     """Initialize a model whose parameters live sharded on ``mesh``.

@@ -279,13 +279,16 @@ def main(argv=None):
 
     sample = jnp.zeros((1, args.image_size, args.image_size, 3),
                        jnp.bfloat16)
-    variables = model.init(
-        {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)},
-        sample,
-    )
-    params = {"params": variables["params"],
-              "batch_stats": variables.get("batch_stats", {})}
-    params = comm.bcast_data(params)
+    # the set-up phase parallel.sharded_init records for the LM examples
+    with cmn.observability.phase("setup.init_params"):
+        variables = model.init(
+            {"params": jax.random.PRNGKey(0),
+             "dropout": jax.random.PRNGKey(1)},
+            sample,
+        )
+        params = {"params": variables["params"],
+                  "batch_stats": variables.get("batch_stats", {})}
+        params = comm.bcast_data(params)
 
     opt = cmn.create_multi_node_optimizer(
         optax.sgd(args.lr, momentum=args.momentum), comm
@@ -358,6 +361,7 @@ def main(argv=None):
     if chief:
         print("final:", {k: round(v, 4) for k, v in final.items()
                          if isinstance(v, float)})
+        print(cmn.observability.setup_line())
     return {"final": final, "losses": [float(l) for l in step_losses],
             "comm": comm, "step": step, "trainer": trainer}
 

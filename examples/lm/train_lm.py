@@ -304,6 +304,7 @@ def main(argv=None):
     if chief:
         print(f"final: loss={last_loss:.4f} "
               f"(uniform {np.log(args.vocab):.3f}, corpus floor 1.386)")
+        print(cmn.observability.setup_line())
 
     if args.generate > 0:
         # Sampling: SP is training-only — materialize the dense twin

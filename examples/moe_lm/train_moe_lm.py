@@ -364,6 +364,7 @@ def main(argv=None):
         print(f"final: loss={last_loss:.4f} "
               f"(uniform would be {np.log(args.vocab):.3f}; the Markov "
               "corpus floor is log 4 = 1.386)")
+        print(cmn.observability.setup_line())
 
     if args.generate > 0:
         # Sample from the SAME sharded parameter tree: sequence

@@ -62,7 +62,14 @@ def _no_leaked_telemetry():
     obs.install(None)
 
 
-def _mlp_trainer(comm, n_units=50, stop=(3, "iteration")):
+def _mlp_batch(rows=16):
+    x = np.random.RandomState(0).rand(rows, 28, 28).astype(np.float32)
+    y = np.random.RandomState(1).randint(0, 10, (rows,)).astype(np.int32)
+    return x, y
+
+
+def _mlp_step(comm, n_units=50):
+    """A fresh step object over an MLP, with its placed state."""
     from chainermn_tpu.models import MLP
 
     model = MLP(n_units=n_units)
@@ -77,9 +84,12 @@ def _mlp_trainer(comm, n_units=50, stop=(3, "iteration")):
     opt = cmn.create_multi_node_optimizer(optax.sgd(0.05), comm)
     step = cmn.build_train_step(comm, loss_fn, opt, donate=False)
     p, o = step.place(params, opt.init(params))
-    x = np.random.RandomState(0).rand(16, 28, 28).astype(np.float32)
-    y = np.random.RandomState(1).randint(0, 10, (16,)).astype(np.int32)
-    it = itertools.cycle([(x, y)])
+    return step, p, o
+
+
+def _mlp_trainer(comm, n_units=50, stop=(3, "iteration")):
+    step, p, o = _mlp_step(comm, n_units)
+    it = itertools.cycle([_mlp_batch()])
     return Trainer(Updater(it, step, p, o), stop_trigger=stop)
 
 
@@ -87,13 +97,8 @@ def _mlp_trainer(comm, n_units=50, stop=(3, "iteration")):
 # metrics registry
 # ----------------------------------------------------------------------
 class TestMetrics:
-    def test_counter_gauge_histogram(self):
+    def test_histogram_and_snapshot(self):
         reg = obs.MetricsRegistry()
-        reg.counter("c").inc()
-        reg.counter("c").inc(2)
-        assert reg.counter("c").value == 3
-        reg.gauge("g").set(1.5)
-        assert reg.gauge("g").value == 1.5
         h = reg.histogram("h")
         for v in (1.0, 2.0, 3.0, 4.0):
             h.observe(v)
@@ -102,7 +107,6 @@ class TestMetrics:
         assert h.percentile(50) == 2.5
         assert h.max == 4.0
         snap = reg.snapshot()
-        assert snap["counters"]["c"] == 3
         assert snap["histograms"]["h"]["count"] == 4
 
     def test_get_or_create_is_stable(self):
@@ -380,11 +384,17 @@ class TestTrainerInstrumentation:
         trainer = _mlp_trainer(comm, stop=(12, "iteration"))
         trainer.run()  # warm compile + a few iterations
         upd = trainer.updater
+        before = obs.process_record()
         t0 = time.monotonic()
         for _ in range(10):
             upd.update()
         jax.block_until_ready(upd.last_metrics["loss"])
         step_s = (time.monotonic() - t0) / 10
+        # the always-on process record is once a process, never a step:
+        # ten cached steps add no span and reach no JAX listener
+        after = obs.process_record()
+        assert len(after["spans"]) == len(before["spans"])
+        assert after["counters"] == before["counters"]
 
         spans_per_step = 8  # 4 taxonomy sites + generous headroom
         assert spans_per_step * per_span <= 0.01 * step_s, (
@@ -880,6 +890,8 @@ def profiled(comm, tmp_path_factory):
     — and the timeline that recorded the same spans."""
     trainer = _mlp_trainer(comm, stop=(2, "iteration"))
     trainer.updater.update()  # compile outside the trace
+    step, p, o = _mlp_step(comm)
+    step(p, o, _mlp_batch(16))  # its first program, outside the trace
     trace_dir = str(tmp_path_factory.mktemp("profile"))
     options = jax.profiler.ProfileOptions()
     options.host_tracer_level = 1
@@ -889,6 +901,9 @@ def profiled(comm, tmp_path_factory):
         with obs.observe() as tel:
             with obs.span("feed.test", bytes=77):
                 pass
+            with obs.phase("setup.test_phase", rows=32):
+                pass
+            step(p, o, _mlp_batch(32))  # a second shape: recompiles
             trainer.updater.update()
             trainer.updater.update()
             trainer.run()
@@ -925,15 +940,312 @@ class TestProfilerAnnotations:
         assert len(events["update"]) == 2
         assert len(events["train"]) == 4
 
+    def test_a_phase_under_a_profile_is_a_trace_annotation(self, profiled):
+        events, _ = profiled
+        assert [int(s["rows"]) for s in events["setup.test_phase"]] == [32]
+
+    def test_a_recompile_leaves_its_annotation_on_the_host_plane(
+            self, profiled):
+        events, tel = profiled
+        note, = events["step.recompile"]
+        assert "_step" in note["fun_name"] and int(note["ordinal"]) == 2
+        assert float(note["seconds"]) > 0
+        here, = tel.timeline.events("step.recompile")
+        assert here["args"]["ordinal"] == 2
+
     def test_span_events_carry_no_wall_field(self, profiled):
         _, tel = profiled
         spans = tel.timeline.spans()
         assert spans and all("wall" not in s for s in spans)
         assert tel.timeline.wall0 > 0  # the anchor stays
 
-    def test_disabled_span_enters_no_annotation(self):
+    def test_disabled_span_enters_no_annotation(self, comm, monkeypatch):
         assert obs.active() is None
         assert obs.span("update", step_num=3) is tl_mod.NULL_SPAN
+        # and a cached step through Updater.update and the step object
+        # enters no span, makes no annotation and reads no clock in
+        # the telemetry's code
+        upd = _mlp_trainer(comm).updater
+        upd.update()  # first call: compiles
+        upd.update()
+        seen = []
+
+        class _Clock:
+            def __getattr__(self, name):
+                seen.append("time." + name)
+                return getattr(time, name)
+
+        monkeypatch.setattr(tl_mod, "time", _Clock())
+        monkeypatch.setattr(
+            jax.profiler, "TraceAnnotation",
+            lambda *a, **k: seen.append("annotation"))
+        monkeypatch.setattr(
+            tl_mod.Timeline, "span",
+            lambda *a, **k: seen.append("span"))
+        for _ in range(3):
+            upd.update()
+        jax.block_until_ready(upd.last_metrics["loss"])
+        assert seen == []
+
+
+_CHILD = r"""
+import json, time
+t_script = time.time()
+import chainermn_tpu
+from chainermn_tpu import observability as obs
+rec = obs.process_record()
+to_wall = time.time() - time.monotonic()
+print(json.dumps({
+    "origin": rec["origin"], "t_script": t_script,
+    "start_wall": rec["start"] + to_wall,
+    "spans": [[e["name"], e["t"] - rec["start"], e["dur"], e["sid"],
+               e["parent"]] for e in rec["spans"]]}))
+"""
+
+
+class TestProcessRecord:
+    def test_zero_is_the_os_process_start_and_import_follows_it(self):
+        """In a fresh process: the record's zero lies between the
+        parent's clock before the spawn and the child's first line,
+        ``setup.before_program`` runs from it to where ``setup.import``
+        begins, and both are children of the root ``setup``."""
+        import subprocess
+        import sys
+
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        t_before = time.time()
+        out = subprocess.run(
+            [sys.executable, "-c", _CHILD], env=env, check=True,
+            capture_output=True, text=True, timeout=300,
+            cwd=os.path.dirname(os.path.dirname(
+                os.path.abspath(cmn.__file__))))
+        got = json.loads(out.stdout.strip().splitlines()[-1])
+        assert got["origin"] == "os"
+        tick = 1.0 / os.sysconf("SC_CLK_TCK")
+        assert t_before - 2 * tick <= got["start_wall"] \
+            <= got["t_script"] + 2 * tick
+        root, before, imp = got["spans"][:3]
+        assert root[0] == "setup" and root[1] == 0.0 and root[4] == 0
+        assert before[0] == "setup.before_program" and before[1] == 0.0
+        assert imp[0] == "setup.import"
+        assert before[1] + before[2] == pytest.approx(imp[1], abs=1e-9)
+        assert before[4] == imp[4] == root[3]
+        # the root ends where the last phase under it ended
+        assert root[2] == pytest.approx(imp[1] + imp[2], abs=1e-9)
+
+    def test_phases_nest_under_setup_and_do_not_overlap(self, comm):
+        _mlp_step(comm)  # optimizer, build_step, place_state at least
+        with obs.phase("setup.outer", k=1):
+            with obs.phase("setup.inner"):
+                pass
+        rec = obs.process_record()
+        root = rec["spans"][0]
+        assert root["name"] == "setup" and root["t"] == rec["start"]
+        by_id = {e["sid"]: e for e in rec["spans"]}
+        phases = [e for e in rec["spans"][1:]
+                  if e["name"].startswith(("setup.", "step."))]
+        names = {e["name"] for e in phases}
+        assert {"setup.before_program", "setup.import",
+                "setup.communicator", "setup.optimizer",
+                "setup.build_step", "setup.place_state",
+                "setup.outer", "setup.inner"} <= names
+        inner = [e for e in phases if e["name"] == "setup.inner"][-1]
+        assert by_id[inner["parent"]]["name"] == "setup.outer"
+        assert by_id[inner["parent"]]["args"] == {"k": 1}
+        for e in phases:  # every phase lies inside its parent
+            parent = by_id[e["parent"]]
+            assert parent["t"] <= e["t"]
+            if not e["args"].get("recompile"):
+                assert e["t"] + e["dur"] <= \
+                    parent["t"] + parent["dur"] + 1e-9
+        # and the phases of one parent on one thread follow each other
+        main = threading.main_thread().ident
+        kids = sorted((e for e in phases if e["parent"] == root["sid"]
+                       and e["ident"] == main), key=lambda e: e["t"])
+        for a, b in zip(kids, kids[1:]):
+            assert a["t"] + a["dur"] <= b["t"] + 1e-9, (a, b)
+
+    def test_a_jit_inside_a_phase_is_its_children_and_is_counted(self):
+        def fresh_program_of_this_test(x):
+            return jnp.cos(x) * 3
+
+        x = jnp.ones((5,))
+        before = obs.process_record()
+        with obs.phase("setup.test_jit"):
+            jax.block_until_ready(jax.jit(fresh_program_of_this_test)(x))
+        rec = obs.process_record()
+        phase = [e for e in rec["spans"]
+                 if e["name"] == "setup.test_jit"][-1]
+        kids = [e for e in rec["spans"] if e["parent"] == phase["sid"]]
+        assert [e["name"] for e in kids] == ["jax.trace", "jax.lower",
+                                             "jax.compile"]
+        for e in kids:
+            assert "fresh_program_of_this_test" in e["args"]["fun_name"]
+            assert phase["t"] <= e["t"] and e["t"] + e["dur"] \
+                <= phase["t"] + phase["dur"] + 1e-9
+        assert kids[2]["args"]["cache"] in ("hit", "miss", "uncached")
+        assert rec["counters"]["compile.programs"] \
+            == before["counters"]["compile.programs"] + 1
+        assert rec["by_phase"]["setup.test_jit"]["compile.programs"] \
+            == before["by_phase"].get("setup.test_jit", {}).get(
+                "compile.programs", 0) + 1
+        # a program compiled outside every phase is the root's child
+        # and does not move the root's end: phases alone do
+        jax.block_until_ready(jax.jit(lambda x: x * 7 - 1)(x))
+        after = obs.process_record()
+        loose = after["spans"][-1]
+        assert loose["name"] == "jax.compile" and loose["parent"] == -1
+        assert after["spans"][0]["dur"] == rec["spans"][0]["dur"]
+
+    def test_a_second_shape_after_a_cached_call_is_one_recompile(
+            self, comm):
+        step, p, o = _mlp_step(comm)
+        before = obs.process_record()
+        p, o, _ = step(p, o, _mlp_batch(16))   # call 1: first program
+        p, o, _ = step(p, o, _mlp_batch(16))   # call 2: cached
+        with obs.observe() as tel:
+            p, o, _ = step(p, o, _mlp_batch(32))  # call 3: recompiles
+            p, o, _ = step(p, o, _mlp_batch(32))  # call 4: cached
+        rec = obs.process_record()
+        new = rec["recompiles"][len(before["recompiles"]):]
+        assert len(new) == 1
+        note = new[0]["args"]
+        assert note["ordinal"] == 3 and "_step" in note["fun_name"]
+        assert note["seconds"] > 0
+        assert note["cache"] in ("hit", "miss", "uncached")
+        shown, = tel.timeline.events("step.recompile")
+        assert shown["args"] == note and shown["t"] == new[0]["t"]
+        calls = [e for e in rec["spans"] if e["name"] == "step.first_call"
+                 ][-2:]
+        assert [c["args"]["ordinal"] for c in calls] == [1, 3]
+        assert "recompile" not in calls[0]["args"]
+        assert calls[1]["args"]["recompile"] is True
+        for c in calls:  # trace, lower, compile of the step under each
+            kids = [e["name"] for e in rec["spans"]
+                    if e["parent"] == c["sid"]]
+            assert kids == ["jax.trace", "jax.lower", "jax.compile"]
+        # a recompile is no part of set-up: the root does not reach it
+        root = rec["spans"][0]
+        assert root["t"] + root["dur"] <= calls[1]["t"]
+
+    def test_a_step_traced_but_not_compiled_is_no_first_call(self, comm):
+        step, p, o = _mlp_step(comm)
+        before = len(obs.process_record()["spans"])
+        batch = step.place_batch(_mlp_batch(16))
+        step.collective_trace(p, o, batch)  # a jaxpr walk only
+        step.get_jitted(p, o).lower(p, o, batch)  # lowered, not compiled
+        with obs.phase("setup.after_the_walk"):
+            pass
+        new = obs.process_record()["spans"][before:]
+        names = [e["name"] for e in new]
+        assert "step.first_call" not in names
+        traced, = [e for e in new if e["name"] == "step.trace"]
+        assert traced["args"] == {"fun_name": "_step", "ordinal": 0}
+        # the walk's trace, the lowering's (which finds it cached), and
+        # the lowering
+        assert [e["name"] for e in new if e["parent"] == traced["sid"]] \
+            == ["jax.trace", "jax.trace", "jax.lower"]
+
+    def test_telemetry_installed_after_setup_shows_the_earlier_phases(
+            self):
+        with obs.phase("setup.earlier", k=2):
+            pass
+        with obs.observe() as tel:
+            with obs.span("later"):
+                pass
+        rec = obs.process_record()
+        mine = [e for e in rec["spans"] if e["name"] == "setup.earlier"][-1]
+        doc = tel.timeline.chrome_trace()
+        by_name = {}
+        for e in doc["traceEvents"]:
+            if e["ph"] == "X":
+                by_name.setdefault(e["name"], []).append(e)
+        assert by_name["setup"][0]["ts"] == 0.0  # the process's start
+        assert by_name["setup.before_program"][0]["ts"] == 0.0
+        shown = by_name["setup.earlier"][-1]
+        assert shown["ts"] == pytest.approx(
+            (mine["t"] - rec["start"]) * 1e6)
+        assert shown["args"] == {"k": 2} and shown["cat"] == "process"
+        later, = by_name["later"]
+        assert later["ts"] >= shown["ts"] + shown["dur"]
+        assert all(e["ts"] >= 0 for es in by_name.values() for e in es)
+        # the timeline's own queries stay the telemetry's own spans
+        assert [e["name"] for e in tel.timeline.events()] == ["later"]
+
+    def test_inner_traces_are_a_count_on_their_stage(self):
+        """JAX's events as the listeners get them: the trace of an inner
+        jit inside a program's trace is no span of its own, a compile
+        inside it (an eager operation at trace time) is."""
+        rec = tl_mod.ProcessRecord(start=time.monotonic())
+        trace = "/jax/core/compile/jaxpr_trace_duration"
+        compile_ = "/jax/core/compile/backend_compile_duration"
+        with rec.phase("setup.test"):
+            rec._stage_starts(trace, 0.0, fun_name="outer")
+            for _ in range(3):
+                rec._stage_starts(trace, 0.0, fun_name="inner")
+                rec._stage_ends(trace, 10.0, 10.25, fun_name="inner")
+            rec._stage_starts(compile_, 0.0, fun_name="jit(iota)")
+            rec._stage_ends(compile_, 11.0, 11.5, fun_name="jit(iota)")
+            rec._stage_ends(trace, 10.0, 12.0, fun_name="outer")
+        spans = rec.snapshot()["spans"]
+        assert [e["name"] for e in spans] == [
+            "setup", "jax.compile", "jax.trace", "setup.test"]
+        _, inner_compile, outer, phase = spans
+        assert outer["args"] == {"fun_name": "outer", "nested_traces": 3,
+                                 "nested_s": pytest.approx(0.75)}
+        assert outer["dur"] == pytest.approx(2.0)
+        assert inner_compile["parent"] == outer["sid"]
+        assert outer["parent"] == phase["sid"]
+        assert rec.snapshot()["counters"]["compile.programs"] == 1
+
+    def test_the_record_is_bounded(self):
+        rec = tl_mod.ProcessRecord(start=time.monotonic())
+        rec.MAX_SPANS = 4
+        for _ in range(6):
+            with rec.phase("setup.again"):
+                pass
+        snap = rec.snapshot()
+        assert len(snap["spans"]) == 1 + 4 and snap["dropped"] == 2
+        assert snap["origin"] == "given"
+
+
+class TestSetupMetrics:
+    """The benchmark's set-up metrics (``BENCHMARK.json``, all of them
+    ``moves: setup_s``) read what the program records under the names it
+    records it: each reader on this process's record, after a step's
+    first call."""
+
+    @pytest.mark.parametrize("name", [
+        "setup_before_program_s", "setup_import_s", "setup_init_params_s",
+        "setup_step_trace_s", "setup_step_load_s", "setup_programs",
+        "setup_cache_misses"])
+    def test_reader_finds_the_programs_phases(self, comm, name):
+        import importlib
+        import types
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "BENCHMARK.json")) as f:
+            entry, = [m for m in json.load(f)["per_layer"]
+                      if m["name"] == name]
+        assert entry["moves"] == "setup_s"
+        with open(os.path.join(root, "cellbench", "layer_metrics",
+                               name + ".json")) as f:
+            how = json.load(f)
+        reader = importlib.import_module(
+            "cellbench.readers." + how["reader"])
+        with obs.phase("setup.init_params"):
+            step, p, o = _mlp_step(comm)
+        step(p, o, _mlp_batch())
+        ctx = types.SimpleNamespace(
+            spec=types.SimpleNamespace(rehearse=False))
+        value = reader.read(ctx, **how["args"])
+        if entry["unit"] == "count":
+            # the CPU runs get no persistent cache: nothing is a miss
+            assert value == 0 if "misses" in name else value >= 1
+        else:
+            assert value > 0
+        ctx.spec.rehearse = True
+        assert reader.read(ctx, **how["args"]) is None
 
 
 def _host_batches(n):
@@ -951,7 +1263,8 @@ class TestFeedSpans:
 
     @pytest.mark.parametrize("name,count", [
         ("feed.collate", 6),  # the sixth finds the iterator exhausted
-        ("feed.place", 5), ("feed.h2d", 5)])
+        ("feed.place", 0),  # taken out: it timed the enqueue, read by none
+        ("feed.h2d", 5)])
     def test_on_records_the_feed_spans(self, name, count):
         # built BEFORE telemetry is installed, as the runner does
         it = prefetch_to_device(_host_batches(5), jax.device_put, 2)
@@ -965,7 +1278,7 @@ class TestFeedSpans:
         if name == "feed.h2d":
             sp = tel.timeline.spans(name)
             assert {s["args"]["bytes"] for s in sp} == {4 * 8 * 4}
-            main = tel.timeline.spans("feed.place")[0]["tid"]
+            main = tel.timeline.spans("feed.collate")[0]["tid"]
             assert all(s["tid"] != main for s in sp)  # off the main line
         np.testing.assert_array_equal(
             np.stack(got), np.stack(list(_host_batches(5))))
@@ -978,9 +1291,8 @@ class TestFeedSpans:
         with obs.observe() as tel:
             up.update()
         wait, = tel.timeline.spans("data.wait")
-        for name in ("feed.collate", "feed.place"):
-            assert {s["parent"] for s in tel.timeline.spans(name)} \
-                == {wait["sid"]}
+        assert {s["parent"] for s in tel.timeline.spans("feed.collate")} \
+            == {wait["sid"]}
 
     def test_each_copy_has_its_own_observer_which_ends_with_it(self):
         """A span per batch that opens at the enqueue, whatever earlier

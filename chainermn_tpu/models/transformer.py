@@ -25,12 +25,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 # Communication goes through the audited wrappers — raw lax collectives
 # outside the sanctioned comm modules are a lint error (analysis.lint).
@@ -88,7 +90,14 @@ class BlockOptions:
     ``residual_multiplier`` on what a mixer or an MLP adds to the
     stream, ``logits_scaling`` dividing the logits.  ``remat_blocks``:
     the backward pass computes each block's forward again from the
-    block's input, the only activation kept a block."""
+    block's input.  Besides that input a block keeps what
+    :func:`remat_plan` fits into ``remat_budget_bytes`` of one device's
+    memory: the gated MLP's ``in_proj`` result (``mlp_in``) and the
+    state-space mixer's (``ssm_in``), the two widest tensors of a block,
+    whose matmuls the backward then does not run again.  The budget is
+    the program's to fill from what it observes
+    (:func:`remat_budget`: the device's memory less the state the step
+    holds less a reserve); 0, the default, keeps the input alone."""
 
     norm: str = "layernorm"
     norm_eps: float = 1e-6
@@ -111,6 +120,7 @@ class BlockOptions:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     remat_blocks: bool = False
+    remat_budget_bytes: int = 0
 
     @property
     def general_attention(self) -> bool:
@@ -127,6 +137,104 @@ class BlockOptions:
             raise ValueError(f"layer_types holds 'attention' and 'mamba', "
                              f"got {kind!r}")
         return kind
+
+    def remat_widths(self, d_ff: int) -> dict:
+        """Width (last axis) of each result of :data:`REMAT_NAMES` that
+        a model of these options has: ``[g | u]`` of the gated MLP, ``[z
+        | xBC | dt]`` of the state-space mixer."""
+        widths = {}
+        if self.gated_mlp:
+            widths["mlp_in"] = 2 * d_ff
+        if "mamba" in (self.layer_types or ()):
+            widths["ssm_in"] = 2 * self.ssm_heads * self.ssm_head_dim \
+                + 2 * self.ssm_state + self.ssm_heads
+        return widths
+
+
+#: the results a block can keep across its recomputation
+#: (``jax.ad_checkpoint.checkpoint_name``), in the order a budget is
+#: spent on them: :class:`GatedMlp`'s ``in_proj`` result in every layer,
+#: :class:`Mamba2Mixer`'s in the ``mamba`` layers (each saves one matmul
+#: over ``d_model`` a layer; what each paid on the chip: ``PERF.md``
+#: section 6, PR 40).
+REMAT_NAMES = ("mlp_in", "ssm_in")
+
+#: what :func:`remat_budget` leaves the step besides its state: its own
+#: temporaries, as so many tensors of the widest kept result (the cell
+#: of ``PERF.md`` reads 6.6 of them ahead of time at one 8192-token
+#: sequence and 6.3 at two: the blocks' inputs, one block's backward,
+#: the head's chunks; known from shapes only this roughly), and bytes
+#: clear of the device's limit besides
+REMAT_TEMPORARIES = 8
+REMAT_CLEAR_BYTES = 1 << 30
+
+
+def remat_plan(layer_kinds, tokens: int, widths: dict, budget_bytes: int,
+               itemsize: int = 2) -> Tuple[Tuple[str, ...], ...]:
+    """What each block keeps besides its input under per-block
+    recomputation, a tuple of :data:`REMAT_NAMES` a layer: names are
+    added while their bytes (``tokens x widths[name] x itemsize`` a
+    layer) fit into ``budget_bytes``, ``mlp_in`` before ``ssm_in``, the
+    first layers first.  ``layer_kinds``: each layer's mixer
+    (:meth:`BlockOptions.layer_type`); ``tokens``: the positions one
+    device holds a step; ``widths``: :meth:`BlockOptions.remat_widths`.
+    No budget, nothing kept."""
+    kept = [() for _ in layer_kinds]
+    left = budget_bytes
+    for name in REMAT_NAMES:
+        if name not in widths:
+            continue
+        cost = tokens * widths[name] * itemsize
+        for i, kind in enumerate(layer_kinds):
+            if name == "ssm_in" and kind != "mamba":
+                continue
+            if cost > left:
+                break
+            kept[i] += (name,)
+            left -= cost
+    return tuple(kept)
+
+
+@functools.lru_cache(maxsize=None)
+def _keep(names: Tuple[str, ...]):
+    """The ``jax.checkpoint`` policy that keeps the results called
+    ``names`` (none: no policy).  One object a set of names: JAX caches
+    what it derives from a block's inner programs under the policy it
+    was given, and a fresh one a layer has every layer derive and trace
+    them again (the scan's backward kernel nine times)."""
+    return jax.checkpoint_policies.save_only_these_names(*names) \
+        if names else None
+
+
+def remat_kept(plan, tokens: int, widths: dict, itemsize: int = 2):
+    """How far a :func:`remat_plan` engaged: ``(names x layers, bytes)``,
+    e.g. ``("mlp_in x10, ssm_in x9", 3939500032)``; ``("", 0)`` for a
+    plan that keeps nothing."""
+    counts = {name: sum(name in names for names in plan)
+              for name in REMAT_NAMES}
+    return (", ".join(f"{name} x{n}" for name, n in counts.items() if n),
+            sum(n * tokens * widths.get(name, 0) * itemsize
+                for name, n in counts.items()))
+
+
+def remat_budget(device, state, tokens: int, widths: dict,
+                 itemsize: int = 2) -> int:
+    """The bytes of ``device``'s memory that blocks may fill with kept
+    results: the limit the device reports (``memory_stats()
+    ["bytes_limit"]``) less the bytes it holds of ``state`` (a tree of
+    arrays: parameters and the optimizer's state), less
+    :data:`REMAT_TEMPORARIES` tensors of the widest result for the
+    step's own temporaries, less :data:`REMAT_CLEAR_BYTES`.  0 where the
+    device reports no limit (off the TPU) or nothing is left."""
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit or not widths:
+        return 0
+    held = sum(
+        math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+        for x in jax.tree_util.tree_leaves(state))
+    reserve = REMAT_TEMPORARIES * tokens * max(widths.values()) * itemsize \
+        + REMAT_CLEAR_BYTES
+    return max(0, int(limit) - held - reserve)
 
 
 def rms_norm(x, scale, eps: float, dtype):
@@ -463,8 +571,9 @@ class GatedMlp(nn.Module):
     def __call__(self, x):
         dense = functools.partial(nn.Dense, use_bias=False,
                                   dtype=self.dtype)
-        gate, up = jnp.split(dense(2 * self.d_ff, name="in_proj")(x), 2,
-                             axis=-1)
+        # named for the block's recomputation (an identity elsewhere)
+        gate, up = jnp.split(checkpoint_name(
+            dense(2 * self.d_ff, name="in_proj")(x), "mlp_in"), 2, axis=-1)
         return dense(x.shape[-1], name="out_proj")(nn.silu(gate) * up)
 
 
@@ -536,8 +645,9 @@ class Mamba2Mixer(nn.Module):
         skip = f32("D", nn.initializers.ones, (h,))
         gain = f32("norm", nn.initializers.ones, (inner,))
 
-        z, xbc, dt = jnp.split(
-            dense(2 * inner + 2 * n + h, name="in_proj")(x),
+        # named for the block's recomputation (an identity elsewhere)
+        z, xbc, dt = jnp.split(checkpoint_name(
+            dense(2 * inner + 2 * n + h, name="in_proj")(x), "ssm_in"),
             [inner, 2 * inner + 2 * n], axis=-1)
         xbc = nn.silu(causal_conv1d(xbc, taps, conv_bias))
         xs, B, C = jnp.split(xbc, [inner, inner + n], axis=-1)
@@ -753,6 +863,15 @@ class TransformerLM(nn.Module):
             pos_idx.value = offset + s
         return lax.dynamic_slice_in_dim(pos_table, offset, s, axis=0)
 
+    def remat_plan(self, tokens: int):
+        """:func:`remat_plan` of this model's layers for ``tokens``
+        positions a device and step, under ``options``' budget."""
+        o = self.options
+        return remat_plan(
+            [o.layer_type(i) for i in range(self.n_layers)], tokens,
+            o.remat_widths(self.d_ff or 4 * self.d_model),
+            o.remat_budget_bytes, jnp.dtype(self.dtype).itemsize)
+
     @nn.compact
     def __call__(self, tokens):
         b, s = tokens.shape
@@ -774,12 +893,15 @@ class TransformerLM(nn.Module):
             self, x, self.dropout_rate, self.deterministic, self.seq_axis,
             self.tp_axis,
         )
+        if o.remat_blocks:
+            plan = self.remat_plan(b * s)
         for i in range(self.n_layers):
             block, named = TransformerBlock, {}
             if o.remat_blocks:
                 # under the name the block has without recomputation: one
-                # parameter tree either way
-                block = nn.remat(TransformerBlock)
+                # parameter tree either way; what the plan keeps of this
+                # block besides its input is the policy's
+                block = nn.remat(TransformerBlock, policy=_keep(plan[i]))
                 named = {"name": f"TransformerBlock_{i}"}
             x = block(
                 self.n_heads, d_ff, dtype=self.dtype,

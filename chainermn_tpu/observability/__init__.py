@@ -48,6 +48,7 @@ from .timeline import (  # noqa: F401
     instant,
     observe,
     phase,
+    phase_attributes,
     process_record,
     setup_line,
     span,

@@ -469,6 +469,8 @@ class _Phase:
         rec = self._rec
         local = rec._thread()
         rec._end_step_trace(local)
+        with rec._lock:
+            self.args.update(rec._attributes.pop(self.name, {}))
         self._parent = local.phases[-1].id if local.phases else _ROOT_ID
         self.id = -next(rec._ids)
         local.phases.append(self)
@@ -530,6 +532,8 @@ class ProcessRecord:
         self.by_phase: Dict[str, dict] = {}
         self.dropped = 0
         self._listening = False
+        # attributes waiting for the next phase of a name
+        self._attributes: Dict[str, dict] = {}
 
     # -- recording -----------------------------------------------------
     def _thread(self):
@@ -574,6 +578,14 @@ class ProcessRecord:
         """``with record.phase(name, **args):`` records one phase under
         the phase open on this thread (else under the root)."""
         return _Phase(self, name, args, t0)
+
+    def phase_attributes(self, name: str, **args) -> None:
+        """Attributes for the next phase called ``name``, from a caller
+        that knows them and does not open the phase itself (the example
+        that knows what its model keeps, for ``build_train_step``'s
+        ``setup.build_step``)."""
+        with self._lock:
+            self._attributes.setdefault(name, {}).update(args)
 
     # -- the program's first lines -------------------------------------
     def program_starts(self, t: float) -> _Phase:
@@ -751,6 +763,12 @@ def phase(name: str, **args) -> _Phase:
     return PROCESS.phase(name, **args)
 
 
+def phase_attributes(name: str, **args) -> None:
+    """:meth:`ProcessRecord.phase_attributes` of this process's record:
+    ``args`` become attributes of the next phase called ``name``."""
+    PROCESS.phase_attributes(name, **args)
+
+
 def phased(name: str):
     """Decorator form of :func:`phase`: every call of the function is
     one phase ``name``."""
@@ -779,12 +797,16 @@ def setup_line() -> str:
     rec = PROCESS.snapshot()
     by_name: Dict[str, float] = {}
     calls = set()
+    said = []  # the set-up phases' attributes, e.g. remat.kept
     for e in rec["spans"][1:]:
         if e["name"].startswith(("setup.", "step.")) \
                 and not e["args"].get("recompile"):
             by_name[e["name"]] = by_name.get(e["name"], 0.0) + e["dur"]
             if e["name"] == "step.first_call":
                 calls.add(e["sid"])
+            elif e["name"].startswith("setup.") and e["args"]:
+                said.append(e["name"].split(".", 1)[1] + ": " + ", ".join(
+                    f"{k} {v}" for k, v in e["args"].items()))
     stages = {"jax.trace": 0.0, "jax.lower": 0.0, "jax.compile": 0.0}
     for e in rec["spans"]:
         if e["parent"] in calls and e["name"] in stages:
@@ -800,7 +822,8 @@ def setup_line() -> str:
         f"{c['compile.programs']} programs, {c['compile.cache_hits']} "
         f"from the cache in {c['compile.cache_load_s']:.2f} s, "
         f"{c['compile.cache_misses']} written to it, "
-        f"{len(rec['recompiles'])} recompiles of a step")
+        f"{len(rec['recompiles'])} recompiles of a step"
+        + "".join(f"; {note}" for note in said))
 
 
 # ----------------------------------------------------------------------

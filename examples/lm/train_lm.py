@@ -102,7 +102,13 @@ def main(argv=None):
     p.add_argument("--logits-scaling", type=float, default=1.0)
     p.add_argument("--remat-blocks", action="store_true",
                    help="compute each block's forward again in the "
-                        "backward pass: only the blocks' inputs are kept")
+                        "backward pass.  Kept: the blocks' inputs and, "
+                        "as far as the device's memory goes (its "
+                        "reported limit less parameters and optimizer "
+                        "state less a reserve: "
+                        "models.transformer.remat_budget; nothing off "
+                        "the TPU), the in_proj results of the gated MLP "
+                        "(mlp_in) and of the Mamba-2 mixer (ssm_in)")
     p.add_argument("--chunked-ce", type=int, default=0, metavar="CHUNKS",
                    help="head + cross-entropy over this many vocabulary "
                         "chunks (ops.chunked_lm_loss): the logits are "
@@ -157,6 +163,8 @@ def main(argv=None):
         TransformerLM,
         generate,
         lm_loss,
+        remat_budget,
+        remat_kept,
         sp_lm_loss,
         vp_lm_loss,
     )
@@ -240,6 +248,24 @@ def main(argv=None):
     opt = cmn.create_multi_node_optimizer(
         optax.adamw(args.lr, weight_decay=0.01), comm
     )
+    opt_state = opt.init(params)
+    if options.remat_blocks:
+        # what the blocks keep besides their inputs, as far as the memory
+        # left beside the state goes (the parameter tree is the same
+        # under any plan: the step is traced once, with this one)
+        tokens = batch // comm.dp_size * args.seq_len // comm.sp_size
+        widths = options.remat_widths(args.d_ff or 4 * args.d_model)
+        options = dataclasses.replace(
+            options, remat_budget_bytes=remat_budget(
+                comm.mesh.local_devices[0], (params, opt_state), tokens,
+                widths))
+        model = make_model(seq_axis, tp_axis, options=options)
+        kept, kept_bytes = remat_kept(
+            model.remat_plan(tokens), tokens, widths)
+        if kept:
+            cmn.observability.phase_attributes(
+                "setup.build_step",
+                **{"remat.kept": kept, "remat.kept_bytes": kept_bytes})
 
     def main_loss(p, b):
         if args.chunked_ce:
@@ -283,7 +309,7 @@ def main(argv=None):
         comm, loss_fn, opt, data_axes=comm.data_axis_names,
         param_specs=specs, batch_specs=P("mn_data", "mn_seq"),
     )
-    params, opt_state = step.place(params, opt.init(params))
+    params, opt_state = step.place(params, opt_state)
 
     rng = np.random.RandomState(1)
     t0, tokens_done, last_loss = time.perf_counter(), 0, float("nan")

@@ -25,12 +25,15 @@ if ROOT not in sys.path:
 from cellbench import compare, flops_granite  # noqa: E402
 from cellbench.reference import granite_hybrid as ref  # noqa: E402
 from cellbench.runners import train_hybrid  # noqa: E402
+from chainermn_tpu.models import transformer  # noqa: E402
 from chainermn_tpu.models.transformer import (  # noqa: E402
     BlockOptions,
     GatedMlp,
     Mamba2Mixer,
     TransformerLM,
     lm_loss,
+    remat_kept,
+    remat_plan,
 )
 from chainermn_tpu.ops import chunked_lm_loss  # noqa: E402
 from chainermn_tpu.ops import pallas_attention as pa  # noqa: E402
@@ -360,6 +363,158 @@ def test_recomputation_changes_neither_the_tree_nor_the_result(seeded):
     for a, b in zip(jax.tree_util.tree_leaves(g0),
                     jax.tree_util.tree_leaves(g1)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-8)
+
+
+# -- what a block keeps across its recomputation -----------------------------
+#: the cell's sizes: one 8192-token sequence, ``[g | u]`` 2 x 8192 wide,
+#: ``[z | xBC | dt]`` 2 x 4096 + 2 x 128 + 64
+_CELL_WIDTHS = {"mlp_in": 16384, "ssm_in": 8512}
+_MAMBA = [i for i, kind in enumerate(CONFIG["layer_types"])
+          if kind == "mamba"]
+
+
+@pytest.mark.parametrize("tokens,budget,mlp_layers,ssm_layers", [
+    (8192, 0, [], []),
+    (8192, 139_460_607, [], []),           # a byte short of one ssm_in
+    (8192, 268_435_455, [], [0]),          # ... of one mlp_in: one ssm_in
+    (8192, 2_700_000_000, range(10), []),  # 10 x 268 MB, 15.6 MB over
+    (8192, 3_600_000_000, range(10), _MAMBA[:6]),  # + 6 x 139 MB
+    (8192, 4_000_000_000, range(10), _MAMBA),      # all 19: 3.94 GB
+    (16384, 2_700_000_000, range(5), []),  # two sequences: half of them
+    (16384, 4_100_000_000, range(7), [0]),  # 7 x 537 MB, then one 279 MB
+], ids=["none", "a_byte_short", "one_mixer", "mlp", "mlp_and_six", "all",
+        "batch2", "batch2_more"])
+def test_remat_plan_by_hand(tokens, budget, mlp_layers, ssm_layers):
+    plan = remat_plan(CONFIG["layer_types"], tokens, _CELL_WIDTHS, budget)
+    assert [i for i, names in enumerate(plan) if "mlp_in" in names] \
+        == list(mlp_layers)
+    assert [i for i, names in enumerate(plan) if "ssm_in" in names] \
+        == list(ssm_layers)
+    assert all(set(names) <= {"mlp_in", "ssm_in"} for names in plan)
+    assert plan[5] in ((), ("mlp_in",))  # the attention layer has no ssm_in
+    said, nbytes = remat_kept(plan, tokens, _CELL_WIDTHS)
+    assert nbytes == tokens * 2 * (16384 * len(mlp_layers)
+                                   + 8512 * len(ssm_layers)) <= budget
+    assert said == ", ".join(
+        f"{name} x{len(layers)}" for name, layers in
+        (("mlp_in", mlp_layers), ("ssm_in", ssm_layers)) if len(layers))
+
+
+def test_remat_plan_names_only_what_the_model_has():
+    """No gated MLP: no ``mlp_in``; no mamba layer: nothing at all."""
+    kinds = ["mamba", "attention"]
+    assert remat_plan(kinds, 64, {"ssm_in": 100}, 1 << 30) \
+        == (("ssm_in",), ())
+    assert remat_plan(["attention"] * 2, 64, {}, 1 << 30) == ((), ())
+    assert BlockOptions(gated_mlp=True).remat_widths(32) == {"mlp_in": 64}
+    assert BlockOptions().remat_widths(32) == {}
+    assert options_of(CONFIG).remat_widths(CONFIG["intermediate_size"]) \
+        == _CELL_WIDTHS
+    # layers that keep the same names share one policy object (JAX caches
+    # a block's derived inner programs under it: a fresh one a layer
+    # cost 3.2 s of the cell's set-up), and no names is no policy
+    assert transformer._keep(("mlp_in", "ssm_in")) \
+        is transformer._keep(("mlp_in", "ssm_in"))
+    assert transformer._keep(()) is None
+
+
+def _loss_and_gradient(model, tree, tokens):
+    """Operation by operation: a compiled program's fusions, and with
+    them its float32 roundings, depend on what else is in it."""
+    with jax.disable_jit():
+        return jax.value_and_grad(
+            lambda p: lm_loss(model.apply(p, tokens), tokens))(tree)
+
+
+@pytest.fixture(scope="module")
+def plainly_recomputed(seeded):
+    cfg, _, tree, tokens = seeded
+    return _loss_and_gradient(model_of(cfg, remat_blocks=True), tree,
+                              tokens)
+
+
+def _in_proj_products(model, tree, tokens) -> dict:
+    """``dot_general``s of the gradient's jaxpr (every level of it)
+    under the gated MLP's and the mixer's scopes with a ``(b, s, width
+    of the in_proj result)`` result: the forward ``in_proj`` products,
+    each twice where a block's forward is computed again."""
+    widths = model.options.remat_widths(model.d_ff)
+    scopes = {"mlp_in": transformer.GATED_MLP_SCOPE,
+              "ssm_in": transformer.SSM_MIXER_SCOPE}
+    found = dict.fromkeys(widths, 0)
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            shape = eqn.outvars[0].aval.shape
+            for name, width in widths.items():
+                found[name] += eqn.primitive.name == "dot_general" \
+                    and len(shape) == 3 and shape[-1] == width \
+                    and scopes[name] in str(eqn.source_info.name_stack)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(
+        lambda p: lm_loss(model.apply(p, tokens), tokens)))(tree).jaxpr)
+    return found
+
+
+#: ``seeded``'s sizes: 192 tokens in float32, ``mlp_in`` 256 wide in all
+#: three layers, ``ssm_in`` 328 wide in the two mamba layers
+_MLP_IN, _SSM_IN = 192 * 256 * 4, 192 * 328 * 4
+
+
+@pytest.mark.parametrize("budget,plan", [
+    (_MLP_IN, (("mlp_in",), (), ())),
+    (3 * _MLP_IN + _SSM_IN, (("mlp_in", "ssm_in"), ("mlp_in",),
+                             ("mlp_in",))),
+    (1 << 30, (("mlp_in", "ssm_in"), ("mlp_in",), ("mlp_in", "ssm_in"))),
+], ids=["one_layer", "mlp_and_one_mixer", "all"])
+def test_kept_results_change_no_bit_and_save_their_products(
+        seeded, plainly_recomputed, budget, plan):
+    """With a plan that keeps something: the parameter tree, the loss
+    and every gradient are the plain recomputation's to the last bit
+    (the same operations on the same values, run one by one), and the
+    gradient runs one ``in_proj`` product a kept layer where the plain
+    recomputation runs two."""
+    cfg, _, tree, tokens = seeded
+    plain = model_of(cfg, remat_blocks=True)
+    kept = model_of(cfg, remat_blocks=True, remat_budget_bytes=budget)
+    assert kept.remat_plan(tokens.size) == plan
+    shape = lambda m: jax.eval_shape(m.init, jax.random.PRNGKey(0), tokens)
+    assert jax.tree_util.tree_structure(shape(kept)) \
+        == jax.tree_util.tree_structure(shape(plain))
+    (l0, g0), (l1, g1) = plainly_recomputed, _loss_and_gradient(
+        kept, tree, tokens)
+    assert float(l0) == float(l1)
+    for a, b in zip(jax.tree_util.tree_leaves(g0),
+                    jax.tree_util.tree_leaves(g1)):
+        np.testing.assert_array_equal(a, b)
+    assert _in_proj_products(plain, tree, tokens) \
+        == {"mlp_in": 2 * 3, "ssm_in": 2 * 2}
+    assert _in_proj_products(kept, tree, tokens) == {
+        name: 2 * n - sum(name in names for names in plan)
+        for name, n in (("mlp_in", 3), ("ssm_in", 2))}
+
+
+def test_no_budget_lowers_to_the_plain_recomputation(seeded, monkeypatch):
+    """Budget 0 hands ``nn.remat`` no policy, and a name is an identity
+    outside one: the lowered text is that of a model without the names
+    (but for the ordinals JAX numbers its private functions with), with
+    recomputation and without it."""
+    import re
+
+    cfg, _, tree, tokens = seeded
+
+    def texts():
+        return [re.sub(r"@(\w+?)_\d+\b", r"@\1", jax.jit(jax.grad(
+            lambda p: lm_loss(model_of(cfg, **kw).apply(p, tokens), tokens)
+        )).lower(tree).as_text()) for kw in ({"remat_blocks": True}, {})]
+
+    named = texts()
+    monkeypatch.setattr(transformer, "checkpoint_name",
+                        lambda x, name: x)
+    assert named == texts()
+    assert named[0] != named[1]
 
 
 def test_the_mixer_and_the_gated_mlp_hold_the_published_leaves():

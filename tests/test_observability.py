@@ -1209,6 +1209,95 @@ class TestProcessRecord:
         assert snap["origin"] == "given"
 
 
+class TestWhatTheBlocksKeep:
+    """``examples/lm/train_lm.py --remat-blocks`` says how far its plan
+    engaged (``models.transformer.remat_plan``) on the process record's
+    ``setup.build_step`` and in its set-up line."""
+
+    _ARGV = ["--cpu-mesh", "--rmsnorm", "--gated-mlp", "--no-positions",
+             "--n-kv-heads", "2", "--layer-types", "mamba,attention,mamba",
+             "--ssm-heads", "4", "--ssm-head-dim", "8", "--ssm-state",
+             "16", "--ssm-chunk", "16", "--d-model", "32", "--n-layers",
+             "3", "--seq-len", "32", "--vocab", "64", "--steps", "2",
+             "--report-every", "1", "--generate", "0"]
+
+    @staticmethod
+    def _example():
+        import importlib.util
+
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "examples", "lm", "train_lm.py")
+        spec = importlib.util.spec_from_file_location("train_lm", path)
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        return module
+
+    @staticmethod
+    def _last_build_step():
+        return [e for e in obs.process_record()["spans"]
+                if e["name"] == "setup.build_step"][-1]["args"]
+
+    def test_build_step_carries_the_plan_and_the_cached_step_no_clock(
+            self, monkeypatch, capsys):
+        import types
+
+        from chainermn_tpu.models import transformer
+
+        # a device that reports a limit, as a TPU does: 1 GiB over the
+        # reserve is room for every result of these sizes
+        budget = transformer.remat_budget
+        monkeypatch.setattr(
+            transformer, "remat_budget",
+            lambda device, *a: budget(types.SimpleNamespace(
+                memory_stats=lambda: {
+                    "bytes_limit": 2 * transformer.REMAT_CLEAR_BYTES}), *a))
+        out = self._example().main(self._ARGV + ["--remat-blocks"])
+        # 2 rows of 32 tokens a device: mlp_in 2 x 128 wide in three
+        # layers, ssm_in 2 x 32 + 2 x 16 + 4 in the two mamba layers
+        assert self._last_build_step() == {
+            "remat.kept": "mlp_in x3, ssm_in x2",
+            "remat.kept_bytes": 64 * 2 * (3 * 256 + 2 * 100)}
+        assert "build_step: remat.kept mlp_in x3, ssm_in x2, " \
+            "remat.kept_bytes 123904" in capsys.readouterr().out
+        assert out["model"].options.remat_budget_bytes > 0
+        # the step that keeps them, cached: no span, no annotation, no
+        # clock read in the telemetry's code
+        seen = []
+
+        class _Clock:
+            def __getattr__(self, name):
+                seen.append("time." + name)
+                return getattr(time, name)
+
+        monkeypatch.setattr(tl_mod, "time", _Clock())
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                            lambda *a, **k: seen.append("annotation"))
+        step, p, o = out["step"], out["params"], out["opt_state"]
+        for _ in range(2):
+            p, o, metrics = step(p, o, out["batch"])
+        jax.block_until_ready(metrics["loss"])
+        assert seen == []
+
+    @pytest.mark.parametrize("flags", [[], ["--remat-blocks"]],
+                             ids=["no_recomputation", "no_limit_reported"])
+    def test_nothing_kept_says_nothing(self, flags):
+        """Without ``--remat-blocks``, and with it on a device that
+        reports no limit (the CPU: budget 0)."""
+        out = self._example().main(self._ARGV + flags)
+        assert self._last_build_step() == {}
+        assert out["model"].options.remat_budget_bytes == 0
+
+    def test_attributes_wait_for_the_phase_of_their_name(self):
+        rec = tl_mod.ProcessRecord(start=time.monotonic())
+        rec.phase_attributes("setup.b", k=1)
+        rec.phase_attributes("setup.b", j="x")
+        for name in ("setup.a", "setup.b", "setup.b"):
+            with rec.phase(name, own=0):
+                pass
+        assert [e["args"] for e in rec.snapshot()["spans"][1:]] == [
+            {"own": 0}, {"own": 0, "k": 1, "j": "x"}, {"own": 0}]
+
+
 class TestSetupMetrics:
     """The benchmark's set-up metrics (``BENCHMARK.json``, all of them
     ``moves: setup_s``) read what the program records under the names it

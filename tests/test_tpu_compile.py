@@ -13,6 +13,7 @@ so inside the ``topo`` fixture: nothing is described at import time, and
 everything compiles in the test's own process.
 """
 
+import dataclasses
 import functools
 import os
 import re
@@ -367,3 +368,77 @@ def test_chunked_scan_kernels_compile_at_the_other_sizes_they_tile(one_chip):
         lambda *a: ssd_scan(*a, chunk=128, interpret=False).astype(
             jnp.float32).sum(), argnums=range(6)), *args)
     assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+# -- the hybrid cell's step under the plan its example would choose ----------
+#: what a v5e reports as ``memory_stats()["bytes_limit"]`` (15.75 GiB less
+#: 2 MiB; read on the chip, PERF.md section 6, PR 40)
+_V5E_BYTES_LIMIT = 16_909_336_064
+#: a Mamba-2 layer of the cell: 76 182 976 float32 parameters with ``mu``
+#: and ``nu`` beside them
+_MAMBA_LAYER_STATE = 76_182_976 * 12
+_CELL_LAYERS = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
+
+
+@pytest.mark.parametrize("rows,layer_types,kept", [
+    # the cell: one 8192-token sequence, all ten layers, every result
+    pytest.param(1, _CELL_LAYERS, "mlp_in x10, ssm_in x9", id="cell"),
+    # two sequences a step, a shorter plan from the same code; five of
+    # the layers, on a device that reports the other five's state less
+    pytest.param(2, _CELL_LAYERS[3:8], "mlp_in x4", id="two_sequences"),
+])
+def test_hybrid_step_under_the_examples_plan_fits_the_chip(
+        lm_step_builder, monkeypatch, rows, layer_types, kept):
+    """``granite4hmicro_train_s8192``'s step (``cellbench/configs/
+    granite-4.0-h-micro.json`` through ``examples/lm/train_lm.py``'s
+    options) with what its blocks keep across their recomputation
+    chosen as the example chooses it on a v5e (``remat_budget`` of the
+    reported limit and the abstract state, ``remat_plan``): compiles,
+    the scan's and the attention's kernels in it, and arguments and
+    temporaries stay 0.8 GB under the limit."""
+    import types
+
+    from chainermn_tpu.models.transformer import (
+        BlockOptions,
+        remat_budget,
+        remat_kept,
+        remat_plan,
+    )
+
+    # the program asks the backend which form of the scan to trace
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    limit = _V5E_BYTES_LIMIT \
+        - (len(_CELL_LAYERS) - len(layer_types)) * _MAMBA_LAYER_STATE
+    options = BlockOptions(
+        norm="rmsnorm", norm_eps=1e-5, n_kv_heads=8,
+        attention_scale=1 / 64, layer_types=layer_types, ssm_heads=64,
+        ssm_head_dim=64, ssm_state=128, ssm_conv=4, ssm_chunk=256,
+        gated_mlp=True, no_positions=True, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=8.0, use_flash=True,
+        remat_blocks=True)
+    sizes = dict(n_layers=len(layer_types), d_model=2048, n_heads=32,
+                 vocab=12544, seq_len=8192, per_chip_batch=rows, d_ff=8192,
+                 chunked_ce=7, lr=1e-4)
+    tokens, widths = rows * 8192, options.remat_widths(8192)
+    _, state = lm_step_builder(1, options=options, **sizes)
+    options = dataclasses.replace(options, remat_budget_bytes=remat_budget(
+        types.SimpleNamespace(memory_stats=lambda: {"bytes_limit": limit}),
+        state[:2], tokens, widths))
+    step, abstract = lm_step_builder(1, options=options, **sizes)
+    said, kept_bytes = remat_kept(
+        remat_plan(layer_types, tokens, widths,
+                   options.remat_budget_bytes), tokens, widths)
+    assert said == kept
+    compiled = step.get_jitted(*abstract[:2]).lower(*abstract).compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert held + 0.8e9 <= limit, (held, limit)
+    # kept for real: the temporaries hold them
+    assert memory.temp_size_in_bytes > kept_bytes
+    text = compiled.as_text()
+    for kernel in ("_ssd_forward", "_ssd_backward", "_bdflash_forward"):
+        assert f"{kernel}/pallas_call" in text, kernel
+    if rows == 1:  # one forward in_proj product a layer, each kept
+        for width, layers in ((16384, 10), (8512, 9)):
+            assert len(re.findall(rf"= bf16\[1,8192,{width}\]\S* fusion\(",
+                                  text)) == layers

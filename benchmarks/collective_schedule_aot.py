@@ -60,6 +60,23 @@ def one_process():
         yield
 
 
+def _abstract_arguments(mesh, opt, params, specs, tokens, batch_spec):
+    """``(params, opt_state, batch)`` as shapes with their shardings on
+    ``mesh``: what a step over described devices is lowered with."""
+    def placed(tree, spec_tree):
+        return jax.tree_util.tree_map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
+            tree, spec_tree)
+
+    opt_state = jax.eval_shape(opt.init, params)
+    state_specs = optax.tree_map_params(
+        opt, lambda _leaf, spec: spec, opt_state, specs,
+        transform_non_params=lambda _leaf: P())
+    return (placed(params, specs), placed(opt_state, state_specs),
+            placed(tokens, batch_spec))
+
+
 def build_lm_step(devices, *, n_layers=18, d_model=1536, n_heads=12,
                   vocab=50257, seq_len=2048, per_chip_batch=4, d_ff=None,
                   options=None, chunked_ce=0, lr=1e-3):
@@ -117,18 +134,77 @@ def build_lm_step(devices, *, n_layers=18, d_model=1536, n_heads=12,
         comm, loss_fn, opt, data_axes=comm.data_axis_names,
         param_specs=specs, batch_specs=batch_spec)
 
-    def placed(tree, spec_tree):
-        return jax.tree_util.tree_map(
-            lambda x, s: jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=NamedSharding(mesh, s)),
-            tree, spec_tree)
+    return step, _abstract_arguments(mesh, opt, params, specs, tokens,
+                                     batch_spec)
 
-    opt_state = jax.eval_shape(opt.init, params)
-    state_specs = optax.tree_map_params(
-        opt, lambda _leaf, spec: spec, opt_state, specs,
-        transform_non_params=lambda _leaf: P())
-    return step, (placed(params, specs), placed(opt_state, state_specs),
-                  placed(tokens, batch_spec))
+
+def build_moe_lm_step(devices, *, options, vocab, d_model, n_heads,
+                      n_layers, d_ff, n_experts, top_k, held, shared_d_ff,
+                      seq_len, per_chip_batch, chunked_ce, lr=1e-5,
+                      aux_coef=1e-3):
+    """The step ``examples/moe_lm/train_moe_lm.py`` builds on its general
+    path at ``--sp 1 --tp 1`` (``--dropless --untied-head --moe-every 1
+    --chunked-ce N``: the next-token loss a vocabulary chunk at a time,
+    the expert layers' counters as ``aux``) over ``devices`` (described
+    or attached), and its abstract arguments ``(params, opt_state,
+    batch)``, shardings on.  ``options``: the model's ``BlockOptions``."""
+    import chainermn_tpu as cmn
+    from chainermn_tpu.functions import collectives as cc
+    from chainermn_tpu.models.moe_transformer import (
+        COUNTERS,
+        MoeTransformerLM,
+        moe_param_specs,
+    )
+    from chainermn_tpu.models.transformer import HEAD_CE_SCOPE
+    from chainermn_tpu.ops.chunked_ce import chunked_softmax_cross_entropy
+
+    comm = cmn.create_communicator(
+        "mesh", devices=list(devices), sp_size=1, tp_size=1)
+    mesh = comm.mesh
+    model = MoeTransformerLM(
+        vocab_size=vocab, d_model=d_model, n_heads=n_heads,
+        n_layers=n_layers, n_experts=n_experts, d_ff=d_ff, moe_every=1,
+        k=top_k, max_len=seq_len, aux_stat_axes=("mn_data", "mn_seq"),
+        options=options, routing="dropless", held=held,
+        shared_d_ff=shared_d_ff, tie_head=False, return_hidden=True)
+    batch_spec = P("mn_data", "mn_seq")
+    tokens = jax.ShapeDtypeStruct(
+        (per_chip_batch * len(devices), seq_len), jnp.int32)
+    params = jax.eval_shape(
+        jax.shard_map(
+            lambda t: {"params": model.init(jax.random.PRNGKey(0),
+                                            t)["params"]},
+            mesh=mesh, in_specs=(batch_spec,), out_specs=P(),
+            check_vma=False),
+        tokens)
+    specs = moe_param_specs(params)
+    opt = cmn.create_multi_node_optimizer(
+        optax.adamw(lr, weight_decay=0.01), comm)
+
+    def loss_fn(p, b):
+        (hidden, aux), sown = model.apply(p, b, mutable=[COUNTERS])
+        with jax.named_scope(HEAD_CE_SCOPE):
+            main = chunked_softmax_cross_entropy(
+                hidden[:, :-1].reshape(-1, d_model), p["params"]["lm_head"],
+                b[:, 1:].reshape(-1), chunked_ce).mean()
+        total = main + aux_coef * aux
+        for axis in (comm.seq_axis_name, comm.model_axis_name):
+            total = cc.pmean(total, axis)  # width 1: certifies replication
+        counters = {}
+        for path, values in jax.tree_util.tree_leaves_with_path(
+                sown.get(COUNTERS, {})):
+            axes = tuple(a for a in comm.axis_names
+                         if a in jax.typeof(values).vma)
+            counters[path[-2].key] = counters.get(path[-2].key, 0) + (
+                cc.psum(values, axes) if axes else values)
+        return total, counters
+
+    step = cmn.build_train_step(
+        comm, loss_fn, opt, data_axes=comm.data_axis_names,
+        param_specs=specs, batch_specs=batch_spec, has_aux=True)
+
+    return step, _abstract_arguments(mesh, opt, params, specs, tokens,
+                                     batch_spec)
 
 
 def main(argv=None):

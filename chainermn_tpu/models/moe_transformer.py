@@ -40,11 +40,13 @@ from chainermn_tpu.functions import collectives as _cc
 from chainermn_tpu.models.transformer import (
     HEAD_CE_SCOPE,
     BlockOptions,
-    SelfAttention,
     TransformerBlock,
+    block_under_plan,
     lm_loss,
     make_lm_embed,
+    make_mixer,
     make_norm,
+    model_remat_plan,
     sp_lm_loss,
     vp_lm_loss,
 )
@@ -66,6 +68,8 @@ COUNTERS = "counters"
 #: and the one for their routing decisions: ``chosen (tokens, k)``, the
 #: experts each token's routes went to
 ROUTES = "routes"
+#: device scope of the shared expert and its gate
+MOE_SHARED_SCOPE = "moe_shared"
 
 
 class MoeMlp(nn.Module):
@@ -95,7 +99,13 @@ class MoeMlp(nn.Module):
     of the axis runs this same layer on its own ``n_experts /
     axis_size`` experts and the parts are summed over the axis.  Its
     counters are sown into the ``"counters"`` collection, its routing
-    decisions into ``"routes"``.
+    decisions into ``"routes"``.  ``shared_d_ff``: beside the routed
+    experts a shared one of that width that every token goes through,
+    under a sigmoid gate of its own, ``sigmoid(w_s . x) W_d(silu(W_g x)
+    * W_u x)`` (parameters ``shared_wg`` / ``shared_wu`` / ``shared_wd``
+    / ``shared_gate``, held whole by every chip): computed by every
+    chip alike, outside the sum over ``expert_axis``, so it is counted
+    once.
     """
 
     n_experts: int
@@ -120,6 +130,28 @@ class MoeMlp(nn.Module):
     dtype: Any = jnp.bfloat16
     routing: str = "capacity"  # capacity | dropless
     held: Optional[Tuple[int, int]] = None  # dropless: (first, count)
+    shared_d_ff: int = 0  # dropless: width of the shared expert, 0 none
+
+    @jax.named_scope(MOE_SHARED_SCOPE)
+    def _shared(self, x):
+        """The shared expert's result on ``x (tokens, d)``, float32:
+        the gate's logit in float32, the three products in ``x``'s
+        dtype."""
+        d = x.shape[-1]
+        init = nn.initializers.normal(0.02)
+        wg = self.param("shared_wg", init, (d, self.shared_d_ff),
+                        jnp.float32)
+        wu = self.param("shared_wu", init, (d, self.shared_d_ff),
+                        jnp.float32)
+        wd = self.param("shared_wd", init, (self.shared_d_ff, d),
+                        jnp.float32)
+        gate = self.param("shared_gate", init, (d, 1), jnp.float32)
+        mm = lambda a, w: jnp.dot(a, w.astype(a.dtype),
+                                  preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(mm(x, wg)) * mm(x, wu)).astype(x.dtype)
+        return jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), gate,
+            precision=lax.Precision.HIGHEST)) * mm(hidden, wd)
 
     def _dropless(self, x, router, local_e: int):
         """The held experts' part of the layer, without a drop."""
@@ -150,6 +182,9 @@ class MoeMlp(nn.Module):
         )
         if self.expert_axis is not None:
             y = _cc.psum(y, self.expert_axis)
+        if self.shared_d_ff:
+            # every chip's own, the same on each: added after the sum
+            y = y.astype(jnp.float32) + self._shared(x.reshape(-1, d))
         for name, value in counters.items():
             self.sow(COUNTERS, name, value)
         self.sow(ROUTES, "chosen", chosen)
@@ -176,6 +211,9 @@ class MoeMlp(nn.Module):
         if self.routing != "capacity":
             raise ValueError(f"routing must be capacity or dropless, got "
                              f"{self.routing!r}")
+        if self.shared_d_ff:
+            raise ValueError("the shared expert comes with dropless "
+                             "routing")
         # Per-chip expert blocks are independent draws (fold the axis
         # index into the RNG — tensor_parallel's sharded-init convention).
         from chainermn_tpu.parallel.tensor_parallel import _sharded_init
@@ -247,7 +285,9 @@ class MoeMlp(nn.Module):
 
 
 class MoeTransformerBlock(nn.Module):
-    """Pre-LN block: TP/SP-aware attention + MoE MLP."""
+    """Pre-LN block: TP/SP-aware attention, or with ``options``'
+    ``layer_types`` the mixer of the layer's ``kind``
+    (:func:`~chainermn_tpu.models.transformer.make_mixer`), + MoE MLP."""
 
     n_heads: int
     d_ff: int
@@ -269,15 +309,18 @@ class MoeTransformerBlock(nn.Module):
     options: BlockOptions = BlockOptions()
     routing: str = "capacity"
     held: Optional[Tuple[int, int]] = None
+    shared_d_ff: int = 0
+    # the sequence mixer, one of transformer.LAYER_KINDS
+    kind: str = "attention"
 
     @nn.compact
     def __call__(self, x):
         ln = lambda: make_norm(self.options)
-        x = x + SelfAttention(
-            self.n_heads, dtype=self.dtype, seq_axis=self.seq_axis,
-            tp_axis=self.tp_axis, sp_impl=self.sp_impl,
-            decode=self.decode, cache_len=self.cache_len,
-            attention_fn=self.attention_fn, options=self.options,
+        x = x + make_mixer(
+            self.kind, self.n_heads, self.options, self.dtype,
+            seq_axis=self.seq_axis, tp_axis=self.tp_axis,
+            sp_impl=self.sp_impl, decode=self.decode,
+            cache_len=self.cache_len, attention_fn=self.attention_fn,
         )(ln()(x).astype(self.dtype))
         y, aux = MoeMlp(
             self.n_experts, self.d_ff, k=self.k,
@@ -287,6 +330,7 @@ class MoeTransformerBlock(nn.Module):
             aux_stat_axes=self.aux_stat_axes,
             dispatch_impl=self.dispatch_impl, dtype=self.dtype,
             routing=self.routing, held=self.held,
+            shared_d_ff=self.shared_d_ff,
         )(ln()(x).astype(self.dtype))
         return x + y, aux
 
@@ -332,12 +376,15 @@ class MoeTransformerLM(nn.Module):
     cache_len: int = 0
     attention_fn: Optional[Callable] = None
     # the block's options (norm, grouped-query heads, rotary positions,
-    # q/k norm, block diffusion): transformer.BlockOptions.  With
+    # q/k norm, block diffusion, the kind of each layer's mixer,
+    # per-block recomputation): transformer.BlockOptions.  With
     # ``rope_theta`` the model holds no position table.
     options: BlockOptions = BlockOptions()
-    # expert layers without drops over the held experts: MoeMlp
+    # expert layers without drops over the held experts, and their
+    # shared expert: MoeMlp
     routing: str = "capacity"
     held: Optional[Tuple[int, int]] = None
+    shared_d_ff: int = 0
     # an output head of its own (``lm_head (vocab, d)``) instead of the
     # embedding's transpose
     tie_head: bool = True
@@ -345,9 +392,18 @@ class MoeTransformerLM(nn.Module):
     # block-diffusion loss applies the head to the noised copy alone
     return_hidden: bool = False
 
+    def remat_plan(self, tokens: int):
+        """What each block keeps across its recomputation, for
+        ``tokens`` positions a device and step
+        (:func:`~chainermn_tpu.models.transformer.remat_plan`)."""
+        return model_remat_plan(self, tokens)
+
     def _rotary(self, tokens):
         """The model on the general attention path: no position table,
-        every block ``options``' block."""
+        every block ``options``' block, its mixer of the layer's kind
+        and, with ``remat_blocks``, computed again in the backward pass
+        (both from the code ``TransformerLM`` takes them from)."""
+        o = self.options
         d_ff = self.d_ff or 4 * self.d_model
         embed = make_lm_embed(
             self, self.vocab_size, self.d_model, self.tp_axis,
@@ -358,24 +414,33 @@ class MoeTransformerLM(nn.Module):
             (self.vocab_size, self.d_model), jnp.float32)
         x = embed(tokens).astype(self.dtype)
         aux = jnp.zeros((), jnp.float32)
+        if o.remat_blocks:
+            plan = self.remat_plan(tokens.size)
+        serial = {MoeTransformerBlock: 0, TransformerBlock: 0}
         for i in range(self.n_layers):
-            if (i + 1) % self.moe_every == 0:
-                x, a = MoeTransformerBlock(
+            sparse = (i + 1) % self.moe_every == 0
+            cls = MoeTransformerBlock if sparse else TransformerBlock
+            block = block_under_plan(cls, plan[i], serial[cls]) \
+                if o.remat_blocks else cls
+            serial[cls] += 1
+            if sparse:
+                x, a = block(
                     self.n_heads, d_ff, self.n_experts, k=self.k,
                     capacity_factor=self.capacity_factor,
                     capacity=self.capacity, no_drop=self.no_drop,
                     aux_stat_axes=self.aux_stat_axes,
                     dispatch_impl=self.dispatch_impl, dtype=self.dtype,
-                    expert_axis=self.expert_axis, options=self.options,
+                    expert_axis=self.expert_axis, options=o,
                     routing=self.routing, held=self.held,
+                    shared_d_ff=self.shared_d_ff, kind=o.layer_type(i),
                 )(x)
                 aux = aux + a
             else:
-                x = TransformerBlock(
-                    self.n_heads, d_ff, dtype=self.dtype,
-                    options=self.options,
+                x = block(
+                    self.n_heads, d_ff, dtype=self.dtype, options=o,
+                    kind=o.layer_type(i),
                 )(x)
-        x = make_norm(self.options)(x)
+        x = make_norm(o)(x)
         if self.return_hidden:
             return x, aux
         with jax.named_scope(HEAD_CE_SCOPE):
@@ -386,11 +451,12 @@ class MoeTransformerLM(nn.Module):
         if self.options.rope_theta:
             return self._rotary(tokens)
         if self.options != BlockOptions() or not self.tie_head \
-                or self.return_hidden or self.routing != "capacity":
+                or self.return_hidden or self.routing != "capacity" \
+                or self.shared_d_ff:
             raise ValueError(
-                "the block's options, dropless routing, an untied head "
-                "and return_hidden come with rotary positions "
-                "(options.rope_theta)")
+                "the block's options, dropless routing, a shared expert, "
+                "an untied head and return_hidden come with rotary "
+                "positions (options.rope_theta)")
         b, s = tokens.shape
         d_ff = self.d_ff or 4 * self.d_model
         embed = make_lm_embed(

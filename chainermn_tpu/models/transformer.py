@@ -51,6 +51,13 @@ class MlpBlock(nn.Module):
         return nn.Dense(d, dtype=self.dtype)(h)
 
 
+#: the kinds of sequence mixer a layer can have
+#: (``BlockOptions.layer_types``; the examples' ``--layer-types`` and
+#: :func:`remat_plan` read them here): :class:`SelfAttention`,
+#: :class:`Mamba2Mixer`, :class:`GatedDeltaMixer`
+LAYER_KINDS = ("attention", "mamba", "linear_attention")
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockOptions:
     """What the one transformer block can be besides GPT-2's (the
@@ -71,18 +78,30 @@ class BlockOptions:
     ``attention_scale``: the factor on ``q k^T`` where it is not
     ``head_dim ** -0.5``, handed to the kernels as their ``scale``.
     ``use_flash``: the Pallas kernels instead of a dense masked softmax.
+    ``rotary_fraction``: the leading share of each head's channels that
+    the rotation turns (the rest pass unrotated).  ``attn_output_gate``:
+    ``q_proj`` is twice as wide, a head's second half a gate, and the
+    output projection reads ``attention * sigmoid(gate)``.
+    ``zero_centered_norm``: every RMSNorm gain of the block and of the
+    model (the q/k norms too, not a mixer's own gated norm) is ``1 +
+    w`` with ``w`` initialised 0.
     Any of the attention options takes :class:`SelfAttention` off its
     fused-qkv path onto separate ``q_proj`` / ``k_proj`` / ``v_proj`` /
     ``o_proj`` kernels; that path is single-device in the sequence and
     head axes (no ``seq_axis``, ``tp_axis`` or ``decode``).
 
-    ``layer_types``: the kind of each layer's sequence mixer,
-    ``"attention"`` or ``"mamba"`` (:class:`Mamba2Mixer`), layer ``i``
+    ``layer_types``: the kind of each layer's sequence mixer, one of
+    :data:`LAYER_KINDS` (``"mamba"``: :class:`Mamba2Mixer`,
+    ``"linear_attention"``: :class:`GatedDeltaMixer`), layer ``i``
     taking entry ``i % len(layer_types)``; ``None``: attention in every
-    layer.  The mixer's sizes: ``ssm_heads`` heads of ``ssm_head_dim``
-    (its inner width is their product), a state of ``ssm_state`` a head
-    channel, ``ssm_conv`` taps of the causal convolution, the scan's
-    ``ssm_chunk``.  ``gated_mlp``: ``W_out(SiLU(g) * u)`` with ``[g | u]
+    layer.  The Mamba-2 mixer's sizes: ``ssm_heads`` heads of
+    ``ssm_head_dim`` (its inner width is their product), a state of
+    ``ssm_state`` a head channel, ``ssm_conv`` taps of the causal
+    convolution, the scan's ``ssm_chunk``.  The Gated DeltaNet mixer's:
+    ``gdn_key_heads`` key heads of ``gdn_key_dim``, each serving
+    ``gdn_value_heads / gdn_key_heads`` of the ``gdn_value_heads``
+    value heads of ``gdn_value_dim``, ``gdn_conv`` taps, the scan's
+    ``gdn_chunk``.  ``gated_mlp``: ``W_out(SiLU(g) * u)`` with ``[g | u]
     = W_in x``, no biases (:class:`GatedMlp`), instead of GELU with
     biases.  ``no_positions``: the model holds no position table and
     attention sees no position at all.  The stream's multipliers:
@@ -92,8 +111,9 @@ class BlockOptions:
     the backward pass computes each block's forward again from the
     block's input.  Besides that input a block keeps what
     :func:`remat_plan` fits into ``remat_budget_bytes`` of one device's
-    memory: the gated MLP's ``in_proj`` result (``mlp_in``) and the
-    state-space mixer's (``ssm_in``), the two widest tensors of a block,
+    memory: the gated MLP's ``in_proj`` result (``mlp_in``), the
+    state-space mixer's (``ssm_in``), the widest tensors of a block,
+    and the Gated DeltaNet mixer's ``[q | k | v | z]`` (``gdn_in``),
     whose matmuls the backward then does not run again.  The budget is
     the program's to fill from what it observes
     (:func:`remat_budget`: the device's memory less the state the step
@@ -108,12 +128,21 @@ class BlockOptions:
     block_diffusion: int = 0
     use_flash: bool = False
     attention_scale: Optional[float] = None
+    rotary_fraction: float = 1.0
+    attn_output_gate: bool = False
+    zero_centered_norm: bool = False
     layer_types: Optional[Tuple[str, ...]] = None
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 128
+    gdn_value_dim: int = 128
+    gdn_conv: int = 4
+    gdn_chunk: int = 64
     gated_mlp: bool = False
     no_positions: bool = False
     embedding_multiplier: float = 1.0
@@ -126,38 +155,48 @@ class BlockOptions:
     def general_attention(self) -> bool:
         return bool(self.n_kv_heads or self.head_dim or self.rope_theta
                     or self.qk_norm or self.block_diffusion
-                    or self.attention_scale or self.no_positions)
+                    or self.attention_scale or self.no_positions
+                    or self.attn_output_gate
+                    or self.rotary_fraction != 1.0)
 
     def layer_type(self, layer: int) -> str:
         """The kind of layer ``layer``'s sequence mixer."""
         if not self.layer_types:
             return "attention"
         kind = self.layer_types[layer % len(self.layer_types)]
-        if kind not in ("attention", "mamba"):
-            raise ValueError(f"layer_types holds 'attention' and 'mamba', "
+        if kind not in LAYER_KINDS:
+            raise ValueError(f"layer_types holds {', '.join(LAYER_KINDS)}; "
                              f"got {kind!r}")
         return kind
 
     def remat_widths(self, d_ff: int) -> dict:
         """Width (last axis) of each result of :data:`REMAT_NAMES` that
         a model of these options has: ``[g | u]`` of the gated MLP, ``[z
-        | xBC | dt]`` of the state-space mixer."""
+        | xBC | dt]`` of the state-space mixer, ``[q | k | v | z]`` of
+        the Gated DeltaNet mixer."""
         widths = {}
         if self.gated_mlp:
             widths["mlp_in"] = 2 * d_ff
         if "mamba" in (self.layer_types or ()):
             widths["ssm_in"] = 2 * self.ssm_heads * self.ssm_head_dim \
                 + 2 * self.ssm_state + self.ssm_heads
+        if "linear_attention" in (self.layer_types or ()):
+            widths["gdn_in"] = 2 * self.gdn_key_heads * self.gdn_key_dim \
+                + 2 * self.gdn_value_heads * self.gdn_value_dim
         return widths
 
 
 #: the results a block can keep across its recomputation
 #: (``jax.ad_checkpoint.checkpoint_name``), in the order a budget is
 #: spent on them: :class:`GatedMlp`'s ``in_proj`` result in every layer,
-#: :class:`Mamba2Mixer`'s in the ``mamba`` layers (each saves one matmul
-#: over ``d_model`` a layer; what each paid on the chip: ``PERF.md``
-#: section 6, PR 40).
-REMAT_NAMES = ("mlp_in", "ssm_in")
+#: :class:`Mamba2Mixer`'s in the ``mamba`` layers, :class:`GatedDeltaMixer`'s
+#: ``in_proj_qkvz`` result in the ``linear_attention`` layers (each saves
+#: one matmul over ``d_model`` a layer; what the first two paid on the
+#: chip: ``PERF.md`` section 6, PR 40).
+REMAT_NAMES = ("mlp_in", "ssm_in", "gdn_in")
+#: the one kind of layer (:data:`LAYER_KINDS`) that has a result of that
+#: name; a name not here is every layer's
+_REMAT_KIND = {"ssm_in": LAYER_KINDS[1], "gdn_in": LAYER_KINDS[2]}
 
 #: what :func:`remat_budget` leaves the step besides its state: its own
 #: temporaries, as so many tensors of the widest kept result (the cell
@@ -174,7 +213,7 @@ def remat_plan(layer_kinds, tokens: int, widths: dict, budget_bytes: int,
     """What each block keeps besides its input under per-block
     recomputation, a tuple of :data:`REMAT_NAMES` a layer: names are
     added while their bytes (``tokens x widths[name] x itemsize`` a
-    layer) fit into ``budget_bytes``, ``mlp_in`` before ``ssm_in``, the
+    layer) fit into ``budget_bytes``, in :data:`REMAT_NAMES`' order, the
     first layers first.  ``layer_kinds``: each layer's mixer
     (:meth:`BlockOptions.layer_type`); ``tokens``: the positions one
     device holds a step; ``widths``: :meth:`BlockOptions.remat_widths`.
@@ -186,13 +225,25 @@ def remat_plan(layer_kinds, tokens: int, widths: dict, budget_bytes: int,
             continue
         cost = tokens * widths[name] * itemsize
         for i, kind in enumerate(layer_kinds):
-            if name == "ssm_in" and kind != "mamba":
+            if _REMAT_KIND.get(name, kind) != kind:
                 continue
             if cost > left:
                 break
             kept[i] += (name,)
             left -= cost
     return tuple(kept)
+
+
+def model_remat_plan(model, tokens: int):
+    """:func:`remat_plan` of the layers of ``model`` (either LM: its
+    ``options``, ``n_layers``, ``d_ff`` / ``d_model`` and ``dtype``) for
+    ``tokens`` positions a device and step, under the options'
+    budget."""
+    o = model.options
+    return remat_plan(
+        [o.layer_type(i) for i in range(model.n_layers)], tokens,
+        o.remat_widths(model.d_ff or 4 * model.d_model),
+        o.remat_budget_bytes, jnp.dtype(model.dtype).itemsize)
 
 
 @functools.lru_cache(maxsize=None)
@@ -245,18 +296,30 @@ def rms_norm(x, scale, eps: float, dtype):
     return (y * scale).astype(dtype)
 
 
+def norm_gain(module: nn.Module, name: str, width: int,
+              zero_centered: bool):
+    """The learned gain ``name (width,)`` of an RMSNorm: the parameter
+    itself, initialised 1, or zero-centred, ``1 + w`` with ``w``
+    initialised 0."""
+    if zero_centered:
+        return 1.0 + module.param(name, nn.initializers.zeros, (width,),
+                                  jnp.float32)
+    return module.param(name, nn.initializers.ones, (width,), jnp.float32)
+
+
 class RMSNorm(nn.Module):
-    """:func:`rms_norm` with a learned gain.  The backward pass computes
-    the float32 intermediates again from ``x`` (``jax.checkpoint``):
-    none of them is kept beside the activation it normalises."""
+    """:func:`rms_norm` with a learned gain (:func:`norm_gain`).  The
+    backward pass computes the float32 intermediates again from ``x``
+    (``jax.checkpoint``): none of them is kept beside the activation it
+    normalises."""
 
     eps: float = 1e-6
     dtype: Any = jnp.float32
+    zero_centered: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones,
-                           (x.shape[-1],), jnp.float32)
+        scale = norm_gain(self, "scale", x.shape[-1], self.zero_centered)
         return jax.checkpoint(
             functools.partial(rms_norm, eps=self.eps, dtype=self.dtype)
         )(x, scale)
@@ -266,16 +329,24 @@ def make_norm(options: BlockOptions, dtype=jnp.float32, **kw):
     """The block's norm: flax ``LayerNorm`` (its epsilon 1e-6, as every
     model before the option) or :class:`RMSNorm`."""
     if options.norm == "rmsnorm":
-        return RMSNorm(eps=options.norm_eps, dtype=dtype, **kw)
+        return RMSNorm(eps=options.norm_eps, dtype=dtype,
+                       zero_centered=options.zero_centered_norm, **kw)
     if options.norm != "layernorm":
         raise ValueError(f"norm must be layernorm or rmsnorm, got "
                          f"{options.norm!r}")
     return nn.LayerNorm(dtype=dtype, **kw)
 
 
-def apply_rope(x, positions, theta: float):
+def apply_rope(x, positions, theta: float, fraction: float = 1.0):
     """Rotary positions on ``x (b, s, heads, dh)``, the halves
-    convention (``rotate_half``), angles in float32."""
+    convention (``rotate_half``), angles in float32.  ``fraction``: the
+    leading ``fraction * dh`` channels of a head are rotated (among
+    themselves), the rest pass as they are."""
+    if fraction != 1.0:
+        turned = int(x.shape[-1] * fraction)
+        return jnp.concatenate(
+            [apply_rope(x[..., :turned], positions, theta),
+             x[..., turned:]], axis=-1)
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[:, None] * freq[None, :]
@@ -289,7 +360,8 @@ def apply_rope(x, positions, theta: float):
 
 
 #: device scope of the attention projections (q, k, v, their norms and
-#: rotation, and the output projection) on the general path
+#: rotation, the output gate and the output projection) on the general
+#: path
 ATTN_PROJ_SCOPE = "attn_proj"
 
 
@@ -389,14 +461,20 @@ class SelfAttention(nn.Module):
             if gain is not None:
                 t = rms_norm(t, gain, o.norm_eps, self.dtype)
             if o.rope_theta:
-                t = apply_rope(t, pos, o.rope_theta)
+                t = apply_rope(t, pos, o.rope_theta, o.rotary_fraction)
             return t
 
         with jax.named_scope(ATTN_PROJ_SCOPE):
-            q = dense(hq * dh, name="q_proj")(x).reshape(b, s, hq, dh)
+            gate = None
+            if o.attn_output_gate:
+                # a head's columns: its query, then its gate
+                q, gate = jnp.split(dense(2 * hq * dh, name="q_proj")(
+                    x).reshape(b, s, hq, 2 * dh), 2, axis=-1)
+            else:
+                q = dense(hq * dh, name="q_proj")(x).reshape(b, s, hq, dh)
             k = dense(hkv * dh, name="k_proj")(x).reshape(b, s, hkv, dh)
             v = dense(hkv * dh, name="v_proj")(x).reshape(b, s, hkv, dh)
-            gains = [self.param(n, nn.initializers.ones, (dh,), jnp.float32)
+            gains = [norm_gain(self, n, dh, o.zero_centered_norm)
                      if o.qk_norm else None for n in ("q_norm", "k_norm")]
             # float32 inside, recomputed in the backward pass: only the
             # projections' outputs and the kernels' inputs are kept
@@ -418,6 +496,12 @@ class SelfAttention(nn.Module):
             out = multi_head_attention(q, rep(k), rep(v), causal=causal,
                                        scale=o.attention_scale)
         with jax.named_scope(ATTN_PROJ_SCOPE):
+            if gate is not None:
+                # float32 inside, recomputed in the backward pass
+                out = jax.checkpoint(lambda out, gate: (
+                    out.astype(jnp.float32) * jax.nn.sigmoid(
+                        gate.astype(jnp.float32))).astype(out.dtype))(
+                    out, gate)
             return dense(d, name="o_proj")(out.reshape(b, s, hq * dh))
 
     def _decode_attend(self, q, k, v, b, heads, dh, scale):
@@ -552,10 +636,14 @@ class SelfAttention(nn.Module):
         return nn.Dense(d, use_bias=False, dtype=self.dtype)(out)
 
 
-#: device scopes of the gated MLP and of the state-space mixer (the
+#: device scopes of the gated MLP, of the state-space mixer (the
 #: convolution's and the scan's lie inside the mixer's: ops.ssd_scan)
+#: and of the Gated DeltaNet mixer (its convolution's and, from
+#: ops.gated_delta, its scan's inside it)
 GATED_MLP_SCOPE = "gated_mlp"
 SSM_MIXER_SCOPE = "ssm_mixer"
+GDN_MIXER_SCOPE = "gdn_mixer"
+GDN_CONV_SCOPE = "gdn_conv"
 
 
 class GatedMlp(nn.Module):
@@ -660,6 +748,127 @@ class Mamba2Mixer(nn.Module):
             rms_norm(gated, gain, o.norm_eps, self.dtype))
 
 
+def _gdn_rates(key, shape, dtype=jnp.float32):
+    """``A_log``: the log of a rate drawn uniformly from (0, 16]."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-4, 16.0))
+
+
+def _unit_heads(t, scale: float, dtype):
+    """Each head of ``t (..., dh)`` over its L2 norm (epsilon 1e-6 under
+    the root), times ``scale``; float32 inside, the result in
+    ``dtype``."""
+    t32 = t.astype(jnp.float32)
+    return (t32 * lax.rsqrt(jnp.sum(t32 * t32, axis=-1, keepdims=True)
+                            + 1e-6) * scale).astype(dtype)
+
+
+class GatedDeltaMixer(nn.Module):
+    """The sequence mixer of a Gated DeltaNet layer (arXiv:2412.06464;
+    HF ``Qwen3NextGatedDeltaNet``), sized by ``options``' ``gdn_*``
+    fields: ``hk`` key heads of ``dk``, ``hv`` value heads of ``dv``,
+    key head ``j`` serving value heads ``j hv / hk ...``.  On ``x (b,
+    s, d)``:
+
+        [q | k | v | z] = in_proj_qkvz(x)    widths hk dk | hk dk | hv dv | hv dv
+        [b | a] = in_proj_ba(x)              widths hv | hv
+        [q | k | v] = SiLU(conv1d([q | k | v]))   causal, depthwise, no bias
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)
+        q = q / |q| / sqrt(dk);  k = k / |k|      a head
+        o = gated_delta_scan(q, k, v, g, beta)
+        out_proj(RMSNorm_head(o) * norm * SiLU(z))
+
+    the last norm over each value head's ``dv`` with one plain gain
+    ``norm (dv,)`` for all heads.  (HF lays the columns of the two
+    in-projections out by key head; the same columns in another order.)
+    Products in ``dtype``; ``beta``, ``g``, the normalisation of q and
+    k, the scan's decays and states and the norm in float32.
+    Single-device in the sequence and the heads, as the scan is
+    (:mod:`chainermn_tpu.ops.gated_delta`): raises under ``seq_axis``,
+    ``tp_axis`` or ``decode``."""
+
+    options: BlockOptions
+    dtype: Any = jnp.bfloat16
+    seq_axis: Optional[str] = None
+    tp_axis: Optional[str] = None
+    decode: bool = False
+
+    @nn.compact
+    @jax.named_scope(GDN_MIXER_SCOPE)
+    def __call__(self, x):
+        if self.tp_axis is not None or self.seq_axis is not None \
+                or self.decode:
+            raise ValueError(
+                "the Gated DeltaNet mixer is single-device in sequence "
+                "and heads: no seq_axis, tp_axis or decode")
+        from chainermn_tpu.ops.gated_delta import gated_delta_scan
+        from chainermn_tpu.ops.ssd_scan import causal_conv1d
+
+        o = self.options
+        b, s, d = x.shape
+        hk, hv = o.gdn_key_heads, o.gdn_value_heads
+        dk, dv = o.gdn_key_dim, o.gdn_value_dim
+        keys, values = hk * dk, hv * dv
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=self.dtype)
+        f32 = lambda name, init, shape: self.param(name, init, shape,
+                                                   jnp.float32)
+        taps = f32("conv_kernel", _ssm_taps, (o.gdn_conv, 2 * keys + values))
+        rates = -jnp.exp(f32("A_log", _gdn_rates, (hv,)))
+        step_bias = f32("dt_bias", _ssm_step_bias, (hv,))
+        gain = f32("norm", nn.initializers.ones, (dv,))
+
+        # named for the block's recomputation (an identity elsewhere)
+        qkv, z = jnp.split(checkpoint_name(
+            dense(2 * keys + 2 * values, name="in_proj_qkvz")(x),
+            "gdn_in"), [2 * keys + values], axis=-1)
+        write, step = jnp.split(
+            dense(2 * hv, name="in_proj_ba")(x).astype(jnp.float32),
+            2, axis=-1)
+        q, k, v = jnp.split(
+            nn.silu(causal_conv1d(qkv, taps, scope=GDN_CONV_SCOPE)),
+            [keys, 2 * keys], axis=-1)
+        # float32 inside, recomputed in the backward pass
+        unit = jax.checkpoint(_unit_heads, static_argnums=(1, 2))
+        out = gated_delta_scan(
+            unit(q.reshape(b, s, hk, dk), dk ** -0.5, self.dtype),
+            unit(k.reshape(b, s, hk, dk), 1.0, self.dtype),
+            v.reshape(b, s, hv, dv),
+            rates * jax.nn.softplus(step + step_bias),
+            jax.nn.sigmoid(write), chunk=o.gdn_chunk, dtype=self.dtype)
+        gated = jax.checkpoint(lambda out, z, gain: (
+            rms_norm(out, gain, o.norm_eps, jnp.float32)
+            * nn.silu(z.astype(jnp.float32))).astype(self.dtype))(
+            out, z.reshape(b, s, hv, dv), gain)
+        return dense(d, name="out_proj")(gated.reshape(b, s, values))
+
+
+def make_mixer(kind: str, n_heads: int, options: BlockOptions, dtype,
+               **attention):
+    """The sequence mixer of a layer of ``kind`` (one of
+    :data:`LAYER_KINDS`): where both LMs' blocks get theirs.
+    ``attention``: :class:`SelfAttention`'s other fields; the two
+    recurrent mixers take of them what they refuse (``seq_axis``,
+    ``tp_axis``, ``decode``)."""
+    if kind == "attention":
+        return SelfAttention(n_heads, dtype=dtype, options=options,
+                             **attention)
+    recurrent = {"mamba": Mamba2Mixer, "linear_attention": GatedDeltaMixer}
+    return recurrent[kind](
+        options, dtype=dtype, **{name: attention[name] for name in (
+            "seq_axis", "tp_axis", "decode") if name in attention})
+
+
+def block_under_plan(block_cls, keep: Tuple[str, ...], serial: int):
+    """``block_cls`` as a layer under per-block recomputation, for both
+    LMs: the backward pass computes the block's forward again from its
+    input and from what the plan keeps of it (``keep``, names of
+    :data:`REMAT_NAMES`: the policy's), under the name the ``serial``-th
+    block of its class has without recomputation: one parameter tree
+    either way."""
+    return functools.partial(nn.remat(block_cls, policy=_keep(keep)),
+                             name=f"{block_cls.__name__}_{serial}")
+
+
 class TpMlpBlock(nn.Module):
     """Megatron MLP: column-parallel up-projection -> gelu ->
     row-parallel down-projection — exactly one psum per block."""
@@ -701,7 +910,7 @@ class TransformerBlock(nn.Module):
     # no cell runs (LayerNorm rides the matmul fusions: PERF.md section 5)
     ln_dtype: Any = jnp.float32
     options: BlockOptions = BlockOptions()
-    # the sequence mixer: "attention" or "mamba" (BlockOptions.layer_type)
+    # the sequence mixer, one of LAYER_KINDS (BlockOptions.layer_type)
     kind: str = "attention"
 
     @nn.compact
@@ -719,17 +928,11 @@ class TransformerBlock(nn.Module):
                      * o.residual_multiplier).astype(h.dtype)
             return h
 
-        if self.kind == "mamba":
-            mixer = Mamba2Mixer(
-                o, dtype=self.dtype, seq_axis=self.seq_axis,
-                tp_axis=self.tp_axis, decode=self.decode)
-        else:
-            mixer = SelfAttention(
-                self.n_heads, dtype=self.dtype, seq_axis=self.seq_axis,
-                tp_axis=self.tp_axis, sp_impl=self.sp_impl,
-                decode=self.decode, cache_len=self.cache_len,
-                attention_fn=self.attention_fn, options=o,
-            )
+        mixer = make_mixer(
+            self.kind, self.n_heads, o, self.dtype, seq_axis=self.seq_axis,
+            tp_axis=self.tp_axis, sp_impl=self.sp_impl,
+            decode=self.decode, cache_len=self.cache_len,
+            attention_fn=self.attention_fn)
         x = x + drop(mixer(ln()(x).astype(self.dtype)))
         if o.gated_mlp:
             if self.tp_axis is not None:
@@ -866,11 +1069,7 @@ class TransformerLM(nn.Module):
     def remat_plan(self, tokens: int):
         """:func:`remat_plan` of this model's layers for ``tokens``
         positions a device and step, under ``options``' budget."""
-        o = self.options
-        return remat_plan(
-            [o.layer_type(i) for i in range(self.n_layers)], tokens,
-            o.remat_widths(self.d_ff or 4 * self.d_model),
-            o.remat_budget_bytes, jnp.dtype(self.dtype).itemsize)
+        return model_remat_plan(self, tokens)
 
     @nn.compact
     def __call__(self, tokens):
@@ -896,13 +1095,8 @@ class TransformerLM(nn.Module):
         if o.remat_blocks:
             plan = self.remat_plan(b * s)
         for i in range(self.n_layers):
-            block, named = TransformerBlock, {}
-            if o.remat_blocks:
-                # under the name the block has without recomputation: one
-                # parameter tree either way; what the plan keeps of this
-                # block besides its input is the policy's
-                block = nn.remat(TransformerBlock, policy=_keep(plan[i]))
-                named = {"name": f"TransformerBlock_{i}"}
+            block = block_under_plan(TransformerBlock, plan[i], i) \
+                if o.remat_blocks else TransformerBlock
             x = block(
                 self.n_heads, d_ff, dtype=self.dtype,
                 seq_axis=self.seq_axis, tp_axis=self.tp_axis,
@@ -912,7 +1106,7 @@ class TransformerLM(nn.Module):
                 deterministic=self.deterministic,
                 attention_fn=self.attention_fn,
                 ln_dtype=self.ln_dtype, options=o,
-                kind=o.layer_type(i), **named,
+                kind=o.layer_type(i),
             )(x)
         x = make_norm(o, self.ln_dtype)(x).astype(jnp.float32)
         if o.logits_scaling != 1.0:

@@ -1879,9 +1879,15 @@ def _bc_geometry(q, k, block, block_size, interpret, kind, tile):
         raise ValueError(
             f"block-causal attention needs q (b, s, hq, d) and k/v "
             f"(b, s, hkv, d) with hq % hkv == 0; got {q.shape}, {k.shape}")
-    bs = _effective_q_block(
-        _clamp_blocks_for_dim(block_size, block_size, d, warn=False)[0],
-        s, interpret)
+    size = _clamp_blocks_for_dim(block_size, block_size, d, warn=False)[0]
+    if block_size is None and d > 128:
+        # these bodies keep a (bs, d) float32 accumulator beside whole
+        # strips of scores: at d = 256 a 1024 block asks for 16.9 MB of
+        # the 16 MB of scoped VMEM inside a whole train step (refused
+        # ahead of time for a described v5e; the launch alone
+        # compiles); half the block fits
+        size = min(size, _DEFAULT_BLOCK // 2)
+    bs = _effective_q_block(size, s, interpret)
     if s % bs or bs % block:
         raise ValueError(
             f"sequence length {s} must be a whole number of {bs}-blocks, "

@@ -87,8 +87,7 @@ SSM_CONV_SCOPE = "ssm_conv"
 SSM_SCAN_SCOPE = "ssm_scan"
 
 
-@jax.named_scope(SSM_CONV_SCOPE)
-def causal_conv1d(x, taps, bias=None):
+def causal_conv1d(x, taps, bias=None, scope: str = SSM_CONV_SCOPE):
     """Depthwise causal convolution over the sequence: ``x (b, s, c)``,
     ``taps (k, c)``, ``bias (c,)``;
 
@@ -96,14 +95,17 @@ def causal_conv1d(x, taps, bias=None):
 
     with zeros before the sequence (``taps[k - 1]`` meets ``x_t``: a
     ``torch.nn.Conv1d(c, c, k, groups=c, padding=k - 1)`` cut to ``s``).
-    Float32 sums of ``k`` shifted copies, the result in ``x``'s dtype."""
+    Float32 sums of ``k`` shifted copies, the result in ``x``'s dtype.
+    ``scope``: the device scope it is traced under (a mixer of another
+    kind gives its own)."""
     k, s = taps.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    y = sum(padded[:, j:j + s].astype(jnp.float32)
-            * taps[j].astype(jnp.float32) for j in range(k))
-    if bias is not None:
-        y = y + bias.astype(jnp.float32)
-    return y.astype(x.dtype)
+    with jax.named_scope(scope):
+        padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+        y = sum(padded[:, j:j + s].astype(jnp.float32)
+                * taps[j].astype(jnp.float32) for j in range(k))
+        if bias is not None:
+            y = y + bias.astype(jnp.float32)
+        return y.astype(x.dtype)
 
 
 def _dot(spec, a, b, dtype):

@@ -89,14 +89,22 @@ def main(argv=None):
     p.add_argument("--gated-mlp", action="store_true",
                    help="W_out(SiLU(g) * u), [g | u] = W_in x, no biases")
     p.add_argument("--layer-types", default=None,
-                   help="comma-separated 'attention' / 'mamba', a layer "
-                        "each, repeated over the depth")
+                   help="comma-separated kinds of sequence mixer "
+                        "(models.transformer.LAYER_KINDS), a layer each, "
+                        "repeated over the depth")
     p.add_argument("--ssm-heads", type=int, default=0,
                    help="heads of a Mamba-2 mixer")
     p.add_argument("--ssm-head-dim", type=int, default=64)
     p.add_argument("--ssm-state", type=int, default=128)
     p.add_argument("--ssm-conv", type=int, default=4)
     p.add_argument("--ssm-chunk", type=int, default=256)
+    p.add_argument("--gdn-key-heads", type=int, default=0,
+                   help="key heads of a Gated DeltaNet mixer")
+    p.add_argument("--gdn-value-heads", type=int, default=0)
+    p.add_argument("--gdn-key-dim", type=int, default=128)
+    p.add_argument("--gdn-value-dim", type=int, default=128)
+    p.add_argument("--gdn-conv", type=int, default=4)
+    p.add_argument("--gdn-chunk", type=int, default=64)
     p.add_argument("--embedding-multiplier", type=float, default=1.0)
     p.add_argument("--residual-multiplier", type=float, default=1.0)
     p.add_argument("--logits-scaling", type=float, default=1.0)
@@ -159,6 +167,7 @@ def main(argv=None):
     from jax.sharding import PartitionSpec as P
 
     from chainermn_tpu.models.transformer import (
+        LAYER_KINDS,
         BlockOptions,
         TransformerLM,
         generate,
@@ -178,6 +187,9 @@ def main(argv=None):
         print(f"mesh: dp={comm.dp_size} x sp={comm.sp_size} x "
               f"tp={comm.tp_size}  {comm!r}")
 
+    if args.layer_types and set(args.layer_types.split(",")) \
+            - set(LAYER_KINDS):
+        p.error(f"--layer-types holds {', '.join(LAYER_KINDS)}")
     options = BlockOptions(
         norm="rmsnorm" if args.rmsnorm else "layernorm",
         norm_eps=args.norm_eps, n_kv_heads=args.n_kv_heads,
@@ -188,6 +200,10 @@ def main(argv=None):
         ssm_heads=args.ssm_heads, ssm_head_dim=args.ssm_head_dim,
         ssm_state=args.ssm_state, ssm_conv=args.ssm_conv,
         ssm_chunk=args.ssm_chunk,
+        gdn_key_heads=args.gdn_key_heads,
+        gdn_value_heads=args.gdn_value_heads,
+        gdn_key_dim=args.gdn_key_dim, gdn_value_dim=args.gdn_value_dim,
+        gdn_conv=args.gdn_conv, gdn_chunk=args.gdn_chunk,
         embedding_multiplier=args.embedding_multiplier,
         residual_multiplier=args.residual_multiplier,
         logits_scaling=args.logits_scaling,

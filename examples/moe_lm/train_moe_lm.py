@@ -39,9 +39,30 @@ of the vocabulary; ``cellbench/configs/sdar-30b-a3b.json``):
       --held 0,16 --moe-every 1 --n-layers 4 --vocab 18992 --seq-len 8192 \
       --batchsize 1 --rope-theta 1e6 --rmsnorm --qk-norm --untied-head \
       --dropless --block-diffusion 4 --flash --lr 1e-5 --aux-coef 1e-3
+
+``--layer-types`` gives each layer's sequence mixer its kind
+(``linear_attention``: Gated DeltaNet, sized by ``--gdn-*``),
+``--shared-d-ff`` every expert layer a gated shared expert,
+``--attn-output-gate`` / ``--rotary-fraction`` / ``--zero-centered-norm``
+the attention and norms of Qwen3-Next, ``--remat-blocks`` per-block
+recomputation and ``--chunked-ce`` the head a vocabulary chunk at a
+time.  One chip's share of Qwen3-Next-80B-A3B-Instruct (experts 0..31
+of 512, an eighth of the vocabulary, one period of the layer pattern;
+``cellbench/configs/qwen3-next-80b-a3b.json``):
+
+    python examples/moe_lm/train_moe_lm.py --d-model 2048 --n-heads 16 \
+      --n-kv-heads 2 --head-dim 256 --d-ff 512 --shared-d-ff 512 \
+      --n-experts 512 --top-k 10 --held 0,32 --moe-every 1 --n-layers 4 \
+      --layer-types linear_attention,linear_attention,linear_attention,attention \
+      --gdn-key-heads 16 --gdn-value-heads 32 --vocab 18992 \
+      --seq-len 8192 --batchsize 2 --rope-theta 1e7 --rotary-fraction 0.25 \
+      --rmsnorm --zero-centered-norm --qk-norm --attn-output-gate \
+      --untied-head --dropless --flash --remat-blocks --chunked-ce 8 \
+      --lr 1e-5 --aux-coef 1e-3
 """
 
 import argparse
+import dataclasses
 import os
 import sys
 import time
@@ -146,13 +167,49 @@ def main(argv=None):
     g.add_argument("--block-diffusion", type=int, default=0, metavar="B",
                    help="train on the block-diffusion objective with "
                         "blocks of B positions (mask id: --vocab - 1)")
+    g.add_argument("--norm-eps", type=float, default=1e-6)
+    g.add_argument("--zero-centered-norm", action="store_true",
+                   help="RMSNorm gains 1 + w, w initialised 0")
+    g.add_argument("--rotary-fraction", type=float, default=1.0,
+                   help="the leading share of a head that is rotated")
+    g.add_argument("--attn-output-gate", action="store_true",
+                   help="q_proj twice as wide: a sigmoid gate a head on "
+                        "what the output projection reads")
+    g.add_argument("--layer-types", default=None,
+                   help="comma-separated kinds of sequence mixer, a "
+                        "layer each, repeated over the depth")
+    g.add_argument("--gdn-key-heads", type=int, default=0,
+                   help="key heads of a Gated DeltaNet mixer")
+    g.add_argument("--gdn-value-heads", type=int, default=0)
+    g.add_argument("--gdn-key-dim", type=int, default=128)
+    g.add_argument("--gdn-value-dim", type=int, default=128)
+    g.add_argument("--gdn-conv", type=int, default=4)
+    g.add_argument("--gdn-chunk", type=int, default=64)
+    g.add_argument("--shared-d-ff", type=int, default=0,
+                   help="width of the gated shared expert beside the "
+                        "routed ones (--dropless)")
+    g.add_argument("--remat-blocks", action="store_true",
+                   help="compute each block's forward again in the "
+                        "backward pass; kept besides the blocks' inputs, "
+                        "as far as the device's memory goes "
+                        "(models.transformer.remat_budget): the Gated "
+                        "DeltaNet in-projection's result (gdn_in)")
+    g.add_argument("--chunked-ce", type=int, default=0, metavar="CHUNKS",
+                   help="head + cross-entropy over this many vocabulary "
+                        "chunks: the logits are never whole")
     args = p.parse_args(argv)
     general = args.rope_theta is not None
     if not general and (args.rmsnorm or args.n_kv_heads or args.head_dim
                         or args.qk_norm or args.untied_head or args.flash
                         or args.dropless or args.held
-                        or args.return_routes or args.block_diffusion):
+                        or args.return_routes or args.block_diffusion
+                        or args.zero_centered_norm or args.attn_output_gate
+                        or args.rotary_fraction != 1.0 or args.layer_types
+                        or args.shared_d_ff or args.remat_blocks
+                        or args.chunked_ce):
         p.error("the block's options come with --rope-theta")
+    if args.chunked_ce and args.block_diffusion:
+        p.error("--chunked-ce is the next-token loss's")
     if general and (args.sp != 1 or args.generate or args.vocab_parallel):
         p.error("--rope-theta needs --sp 1, --generate 0 and a dense "
                 "vocabulary: the general block has no sequence-parallel, "
@@ -189,11 +246,20 @@ def main(argv=None):
         moe_param_specs,
     )
     from chainermn_tpu.models.transformer import (
+        HEAD_CE_SCOPE,
+        LAYER_KINDS,
         BlockOptions,
         block_diffusion_loss,
         noised_copy,
+        remat_budget,
+        remat_kept,
     )
     from chainermn_tpu.parallel import sharded_init
+
+    layer_types = tuple(args.layer_types.split(",")) \
+        if args.layer_types else None
+    if set(layer_types or ()) - set(LAYER_KINDS):
+        p.error(f"--layer-types holds {', '.join(LAYER_KINDS)}")
 
     comm = cmn.create_communicator(
         "mesh", devices=devices, sp_size=args.sp, tp_size=args.tp
@@ -215,24 +281,39 @@ def main(argv=None):
                                                 else ("mn_model",)),
     )
     mask_id = args.vocab - 1
-    if general:
-        # attention whole on every chip over its own sequences, the
+    options = BlockOptions(
+        norm="rmsnorm" if args.rmsnorm else "layernorm",
+        norm_eps=args.norm_eps,
+        n_kv_heads=args.n_kv_heads, head_dim=args.head_dim,
+        rope_theta=args.rope_theta, qk_norm=args.qk_norm,
+        block_diffusion=args.block_diffusion, use_flash=args.flash,
+        rotary_fraction=args.rotary_fraction,
+        attn_output_gate=args.attn_output_gate,
+        zero_centered_norm=args.zero_centered_norm,
+        layer_types=layer_types,
+        gdn_key_heads=args.gdn_key_heads,
+        gdn_value_heads=args.gdn_value_heads,
+        gdn_key_dim=args.gdn_key_dim, gdn_value_dim=args.gdn_value_dim,
+        gdn_conv=args.gdn_conv, gdn_chunk=args.gdn_chunk,
+        remat_blocks=args.remat_blocks,
+    )
+
+    def make_general(options):
+        # the mixers whole on every chip over its own sequences, the
         # experts over the model axis (or this chip's share of them)
-        model = MoeTransformerLM(
+        return MoeTransformerLM(
             **sizes, expert_axis="mn_model" if args.tp > 1 else None,
-            options=BlockOptions(
-                norm="rmsnorm" if args.rmsnorm else "layernorm",
-                n_kv_heads=args.n_kv_heads, head_dim=args.head_dim,
-                rope_theta=args.rope_theta, qk_norm=args.qk_norm,
-                block_diffusion=args.block_diffusion,
-                use_flash=args.flash,
-            ),
+            options=options,
             routing="dropless" if args.dropless else "capacity",
             held=tuple(int(x) for x in args.held.split(","))
             if args.held else None,
+            shared_d_ff=args.shared_d_ff,
             tie_head=not args.untied_head,
-            return_hidden=bool(args.block_diffusion),
+            return_hidden=bool(args.block_diffusion or args.chunked_ce),
         )
+
+    if general:
+        model = make_general(options)
     else:
         model = MoeTransformerLM(
             **sizes, seq_axis="mn_seq", tp_axis="mn_model",
@@ -262,6 +343,23 @@ def main(argv=None):
     opt = cmn.create_multi_node_optimizer(
         optax.adamw(args.lr, weight_decay=0.01), comm
     )
+    opt_state = opt.init(params)
+    if options.remat_blocks:
+        # what the blocks keep besides their inputs, as far as the memory
+        # left beside the state goes (the parameter tree is the same
+        # under any plan: the step is traced once, with this one)
+        tokens = batch // comm.dp_size * args.seq_len
+        widths = options.remat_widths(args.d_ff or 4 * args.d_model)
+        model = make_general(dataclasses.replace(
+            options, remat_budget_bytes=remat_budget(
+                comm.mesh.local_devices[0], (params, opt_state), tokens,
+                widths)))
+        kept, kept_bytes = remat_kept(
+            model.remat_plan(tokens), tokens, widths)
+        if kept:
+            cmn.observability.phase_attributes(
+                "setup.build_step",
+                **{"remat.kept": kept, "remat.kept_bytes": kept_bytes})
 
     def loss_fn(p, b):
         return moe_lm_loss(
@@ -282,6 +380,18 @@ def main(argv=None):
             head = p["params"]["lm_head"] if args.untied_head \
                 else p["params"]["embed"]["embedding"]
             main = block_diffusion_loss(out, head, tokens, b[2])
+            total = main + args.aux_coef * aux
+        elif args.chunked_ce:
+            from chainermn_tpu.ops.chunked_ce import (
+                chunked_softmax_cross_entropy,
+            )
+
+            head = p["params"]["lm_head"] if args.untied_head \
+                else p["params"]["embed"]["embedding"]
+            with jax.named_scope(HEAD_CE_SCOPE):
+                main = chunked_softmax_cross_entropy(
+                    out[:, :-1].reshape(-1, out.shape[-1]), head,
+                    tokens[:, 1:].reshape(-1), args.chunked_ce).mean()
             total = main + args.aux_coef * aux
         else:
             total = moe_lm_loss((out, aux), tokens, aux_coef=args.aux_coef)
@@ -320,7 +430,7 @@ def main(argv=None):
         data_axes=comm.data_axis_names, param_specs=specs,
         batch_specs=P("mn_data", "mn_seq"), has_aux=general,
     )
-    params, opt_state = step.place(params, opt.init(params))
+    params, opt_state = step.place(params, opt_state)
 
     loader = None
     if args.native_loader:

@@ -288,6 +288,9 @@ class TestGlobalExceptHook:
 
         from chainermn_tpu import global_except_hook as geh
 
+        # an example's main() run earlier in this process (which files a
+        # worker gets is the scheduler's choice) leaves its hook in
+        geh.remove_hook()
         old = sys.excepthook
         geh.add_hook()
         assert sys.excepthook is not old

@@ -175,24 +175,29 @@ _WEIGHT_BYTES = 1 << 19
 
 
 @pytest.fixture(scope="module")
-def lm_step_builder(topo):
-    """``build(chips, **sizes) -> (step, abstract args)`` over described
-    devices:
-    ``benchmarks/collective_schedule_aot.py``'s builder at small sizes."""
+def aot(topo):
+    """``benchmarks/collective_schedule_aot.py``, the builders of the
+    cells' steps over described devices, inside its one-process
+    patch."""
     import importlib.util
-    from contextlib import ExitStack
 
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks",
         "collective_schedule_aot.py")
     spec = importlib.util.spec_from_file_location(
         "collective_schedule_aot", path)
-    aot = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(aot)
-    with ExitStack() as stack:
-        stack.enter_context(aot.one_process())
-        yield lambda chips, **sizes: aot.build_lm_step(
-            topo.devices[:chips], **{**_SMALL_LM, **sizes})
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    with module.one_process():
+        yield module
+
+
+@pytest.fixture(scope="module")
+def lm_step_builder(topo, aot):
+    """``build(chips, **sizes) -> (step, abstract args)`` over described
+    devices: ``build_lm_step`` at small sizes."""
+    return lambda chips, **sizes: aot.build_lm_step(
+        topo.devices[:chips], **{**_SMALL_LM, **sizes})
 
 
 def test_dp_step_reduces_weight_gradients_asynchronously(lm_step_builder):
@@ -305,6 +310,31 @@ def test_block_causal_attention_compiles_at_head_width_64(one_chip):
     for kernel in ("_bdflash_forward", "_bdflash_backward_dq",
                    "_bdflash_backward_dkdv"):
         assert text.count(f"{kernel}/pallas_call") >= 1, kernel
+
+
+def test_block_causal_attention_compiles_at_head_width_256(one_chip):
+    """The causal launch of ``qwen3next80b_train_s8192``: 8192-token
+    sequences, 16 query and 2 key/value heads of **256**: forward, dq
+    and dk/dv.  At this width the family's default block is 512 (inside
+    the cell's step a 1024 block asks for 16.9 MB of the 16 MB of scoped
+    VMEM and is refused; alone it compiles)."""
+    q = jax.ShapeDtypeStruct((1, 8192, 16, 256), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 2, 256), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def attend(q, k, v):
+        return pa.block_causal_attention_with_lse(
+            q, k, v, 1, interpret=False)[0]
+
+    text = _compiled_text(_grad_of(attend), q, kv, kv)
+    for kernel in ("_bdflash_forward", "_bdflash_backward_dq",
+                   "_bdflash_backward_dkdv"):
+        assert text.count(f"{kernel}/pallas_call") >= 1, kernel
+    assert pa._bc_geometry(q, kv, 1, None, False, "fwd", None)[1] == 512
+    narrow = jax.ShapeDtypeStruct((1, 8192, 16, 128), jnp.bfloat16)
+    assert pa._bc_geometry(narrow, narrow, 1, None, False, "fwd",
+                           None)[1] == 1024
 
 
 def _scan_shapes(one_chip):
@@ -442,3 +472,95 @@ def test_hybrid_step_under_the_examples_plan_fits_the_chip(
         for width, layers in ((16384, 10), (8512, 9)):
             assert len(re.findall(rf"= bf16\[1,8192,{width}\]\S* fusion\(",
                                   text)) == layers
+
+
+# ----------------------------------------------------------------------
+# qwen3next80b_train_s8192's whole step, as the MoE example builds it
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def moe_step_builder(topo, aot):
+    """``build(**sizes) -> (step, abstract args)`` over one described
+    chip: ``build_moe_lm_step``."""
+    return lambda **sizes: aot.build_moe_lm_step(topo.devices[:1], **sizes)
+
+
+def test_qwen3next_step_under_the_examples_plan_compiles_for_the_chip(
+        moe_step_builder, monkeypatch):
+    """``qwen3next80b_train_s8192``'s step (``cellbench/configs/
+    qwen3-next-80b-a3b.json`` and ``cellbench/traffic/
+    train_moe_s8192.json`` through ``examples/moe_lm/train_moe_lm.py``'s
+    options) with what its blocks keep chosen as the example chooses it
+    on a v5e: compiles, the causal kernels at head width 256 and the
+    grouped products in it, the plan ``gdn_in x3``, the arguments the
+    7.51 GB of float32 state.  ``memory_analysis()`` counts 11.16 GB of
+    temporaries where the chip reserves 8.61 (``PERF.md`` section 6,
+    PR 41), so what is held against the limit here is what the chip
+    read plus that over-count, not the limit itself."""
+    import json
+    import types
+
+    from chainermn_tpu.models.transformer import (
+        BlockOptions,
+        remat_budget,
+        remat_kept,
+        remat_plan,
+    )
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "cellbench", "configs",
+                           "qwen3-next-80b-a3b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "cellbench", "traffic",
+                           "train_moe_s8192.json")) as f:
+        traffic = json.load(f)
+    rows, seq = traffic["per_chip_batch"], traffic["seq_len"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kinds = ("linear_attention",) * 3 + ("attention",)
+    options = BlockOptions(
+        norm="rmsnorm", norm_eps=cfg["rms_norm_eps"],
+        n_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+        rope_theta=float(cfg["rope_theta"]), qk_norm=True,
+        rotary_fraction=cfg["partial_rotary_factor"],
+        attn_output_gate=True, zero_centered_norm=True, layer_types=kinds,
+        gdn_key_heads=cfg["linear_num_key_heads"],
+        gdn_value_heads=cfg["linear_num_value_heads"],
+        gdn_key_dim=cfg["linear_key_head_dim"],
+        gdn_value_dim=cfg["linear_value_head_dim"],
+        gdn_conv=cfg["linear_conv_kernel_dim"],
+        gdn_chunk=cfg["linear_chunk_size"], use_flash=True,
+        remat_blocks=True)
+    sizes = dict(
+        vocab=cfg["vocab_size"], d_model=cfg["hidden_size"],
+        n_heads=cfg["num_attention_heads"],
+        n_layers=cfg["num_hidden_layers"],
+        d_ff=cfg["moe_intermediate_size"], n_experts=cfg["router_experts"],
+        top_k=cfg["num_experts_per_tok"],
+        held=(cfg["first_expert"], cfg["num_experts"]),
+        shared_d_ff=cfg["shared_expert_intermediate_size"], seq_len=seq,
+        per_chip_batch=rows, chunked_ce=cfg["head_chunks"],
+        lr=cfg["optimizer"]["lr"], aux_coef=cfg["aux_loss_coef"])
+    tokens = rows * seq
+    widths = options.remat_widths(cfg["moe_intermediate_size"])
+    _, state = moe_step_builder(options=options, **sizes)
+    budget = remat_budget(
+        types.SimpleNamespace(
+            memory_stats=lambda: {"bytes_limit": _V5E_BYTES_LIMIT}),
+        state[:2], tokens, widths)
+    options = dataclasses.replace(options, remat_budget_bytes=budget)
+    assert remat_kept(remat_plan(kinds, tokens, widths, budget), tokens,
+                      widths) == ("gdn_in x3", 3 * tokens * 12288 * 2)
+    step, abstract = moe_step_builder(options=options, **sizes)
+    compiled = step.get_jitted(*abstract[:2]).lower(*abstract).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(
+        625_667_136 * 12, rel=1e-3)
+    # 16.31 GB on the chip + the 2.55 GB memory_analysis() counts over it
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        <= 16.31e9 + 2.55e9
+    text = compiled.as_text()
+    for kernel in ("_bdflash_forward", "_bdflash_backward_dq",
+                   "_bdflash_backward_dkdv", "_grouped_matmul",
+                   "_grouped_matmul_dw"):
+        assert f"{kernel}/pallas_call" in text, kernel
+    for scope in ("gdn_mixer", "gdn_conv", "gdn_scan", "moe_shared"):
+        assert scope in text, scope

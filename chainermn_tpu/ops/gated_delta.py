@@ -1,5 +1,6 @@
 """Chunked gated delta rule (Gated DeltaNet, arXiv:2412.06464; the
-linear-attention layers of Qwen3-Next), in plain XLA.
+linear-attention layers of Qwen3-Next) in two forms: Pallas kernels on a
+TPU and plain XLA everywhere else.
 
 A value head carries a state ``S (dk, dv)`` over the positions of a
 sequence, driven by a key ``k_t`` and a query ``q_t (dk,)`` (both
@@ -15,10 +16,7 @@ strength ``beta_t`` in (0, 1):
 time.  With ``G`` the running sum of ``g`` inside a chunk, a head:
 
 * ``A_ij = beta_i (k_i . k_j) e^{G_i - G_j}`` for ``j < i``, and ``T =
-  (I + A)^-1``: ``A`` is strictly lower triangular, so nilpotent, and
-  ``T = (I - A)(I + A^2)(I + A^4)...`` is ``log2(chunk)`` squarings and
-  as many products (:func:`_inverse_unit_lower`), in float32 at the
-  highest precision;
+  (I + A)^-1``, in float32 (``A`` is strictly lower triangular);
 * ``U = T (beta V)`` and ``W = T (beta e^G K)``: what the chunk would
   write into an empty state, and what it reads of the state it is
   handed;
@@ -26,32 +24,63 @@ time.  With ``G`` the running sum of ``g`` inside a chunk, a head:
   ((Q K^T) e^{G_i - G_j} [j <= i]) V'`` and ``S' = e^{G_C} S + (K
   e^{G_C - G})^T V'``.
 
-Three stages: what needs no state, :data:`CHUNKS_PER_PASS` chunks at a
-time under ``jax.checkpoint`` (the ``(chunk, chunk)`` float32 tensors of
-a head and chunk are 134 MB each at 2 x 8192 positions and 32 heads;
-neither pass holds more than a pass's worth and the backward computes
-them again); the recurrence over chunks, a ``lax.scan`` whose step is
-three small products a head, under ``jax.checkpoint`` too, so that the
-backward keeps the carried states alone; and the products inside the
-chunks with ``V'``.  Products take ``dtype`` operands and accumulate in
-float32; the decays, ``T`` and the carried state are float32.  Every
-decay is the exponential of a non-positive number.
+Products take ``dtype`` operands and accumulate in float32; ``U``,
+``W``, ``V'``, ``Q e^G``, ``K e^{G_C - G}`` and the decayed ``Q K^T``
+are rounded to ``dtype``; the decays, ``T`` and the carried state are
+float32, in both forms.  Every decay is the exponential of a
+non-positive number.
+
+**Which form runs** is read off the input and the platform
+(:func:`_use_kernels`).  The kernels (:mod:`.gated_delta_kernels`) run
+on a TPU when the sizes tile -- keys and values 128 wide, a chunk of 64,
+whole groups of at most four value heads a key head -- and ``k``, ``v``
+and the products' operands are bfloat16 (the cell's launch: 16 key and
+32 value heads of 128); with ``interpret=True`` they run interpreted at
+any such sizes, in either precision (the unit tests).  Every other call
+runs the XLA form: off the TPU, float32 operands on it, a head width, a
+chunk or a grouping the kernels do not tile.
+
+* **The kernels** walk a sequence's chunks in order with a key head's
+  value heads' states in VMEM, reading ``q``, ``k``, ``v`` and writing
+  ``o`` in the mixer's own token-major layout; ``K K^T``, the decays and
+  ``A`` are tiles made on the chip, and ``T`` is solved exactly in the
+  tile (substitution in the diagonal blocks, then merges of pairs).
+  ``gated_delta_scan`` is then a ``jax.custom_vjp``: a forward that is
+  not differentiated keeps nothing; the differentiated one keeps its
+  operands, the two layouts of ``G`` and ``beta``, the state entering
+  every chunk (``(chunks, heads, dk, dv)`` float32 a sequence, 268 MB at
+  the cell's shape) and every chunk's ``T`` (67 MB); the backward kernel
+  walks the chunks last to first with the states' cotangent in VMEM and
+  computes decays, ``U``, ``W`` and ``V'`` again per tile.
+* **The XLA form** has three stages: what needs no state,
+  :data:`CHUNKS_PER_PASS` chunks at a time under ``jax.checkpoint`` with
+  ``T = (I - A)(I + A^2)(I + A^4)...`` (``A`` is nilpotent:
+  ``log2(chunk)`` squarings and as many products,
+  :func:`_inverse_unit_lower`, at the highest precision; the ``(chunk,
+  chunk)`` float32 tensors of a head and chunk are 134 MB each at 2 x
+  8192 positions and 32 heads, and neither pass holds more than a pass's
+  worth); the recurrence over chunks, a ``lax.scan`` whose step is three
+  small products a head, under ``jax.checkpoint`` too, so that the
+  backward keeps the carried states alone; and the products inside the
+  chunks with ``V'``.  It is what the kernels are tested against.
 
 Single-device in the sequence and the heads: no sequence-parallel,
 tensor-parallel or decode form.  :func:`gated_delta_census` is the
-static count of the algorithm's work, as ``ssd_census`` is for the
-state-space scan.
+static count of the algorithm's work and of the kernel path's launches,
+as ``ssd_census`` is for the state-space scan.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import gated_delta_kernels
 from .grouped_matmul import vary_alike
 
 #: chunks whose ``(heads, chunk, chunk)`` float32 tensors are live at
@@ -141,10 +170,24 @@ def _carry_on(state, chunk_of, dtype):
     return state, (new.astype(dtype), from_state.astype(dtype))
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "dtype"))
+def _use_kernels(k, v, chunk, dtype, interpret) -> bool:
+    """Whether :func:`gated_delta_scan` runs the Pallas kernels: the
+    sizes tile (:func:`gated_delta_kernels.tiles`) and either the
+    kernels are asked for interpreted, or this is a TPU and ``k``, ``v``
+    and the products' operands are bfloat16."""
+    (hk, dk), (h, dv) = k.shape[2:], v.shape[2:]
+    if not gated_delta_kernels.tiles(chunk, h, hk, dk, dv):
+        return False
+    if interpret is not None:
+        return True
+    return jax.default_backend() == "tpu" and dtype == jnp.bfloat16 \
+        and k.dtype == v.dtype == jnp.bfloat16
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "dtype", "interpret"))
 @jax.named_scope(GDN_SCAN_SCOPE)
 def gated_delta_scan(q, k, v, g, beta, chunk: int = 64,
-                     dtype=jnp.bfloat16):
+                     dtype=jnp.bfloat16, interpret: Optional[bool] = None):
     """The recurrence of the module docstring in its chunked form.
 
     ``q``, ``k (b, s, key_heads, dk)``, normalised as the layer wants
@@ -154,17 +197,42 @@ def gated_delta_scan(q, k, v, g, beta, chunk: int = 64,
     heads)``.  Returns ``o (b, s, heads, dv)`` in ``v``'s dtype;
     ``dtype`` is that of the products' operands.  A length that is no
     multiple of ``chunk`` is padded with ``g = 0, beta = 0`` rows of
-    zero keys, which leave every state as it was."""
+    zero keys, which leave every state as it was.  ``interpret=True``
+    runs the kernels interpreted wherever the sizes tile (the unit
+    tests do)."""
     b, s, hk, dk = k.shape
     h, dv = v.shape[2:]
     if h % hk:
         raise ValueError(f"{h} value heads over {hk} key heads")
-    pad = -s % chunk
+    scan, whole = _scan_xla, chunk
+    if _use_kernels(k, v, chunk, dtype, interpret):
+        scan = functools.partial(_scan_kernels, interpret=bool(interpret))
+        whole = chunk * gated_delta_kernels.CHUNKS_PER_POINT
+    pad = -s % whole
     if pad:
         q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad))
                                     + ((0, 0),) * (t.ndim - 2))
                             for t in (q, k, v, g, beta))
-    c = (s + pad) // chunk
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    return scan(q, k, v, g, beta, chunk, dtype)[:, :s]
+
+
+def _scan_kernels(q, k, v, g, beta, chunk, dtype, interpret):
+    """The kernel path over whole chunks: the running sums here, the
+    passes in :func:`gated_delta_kernels.gated_delta_chunks`."""
+    b, s, h, dv = v.shape
+    cum = jnp.cumsum(g.reshape(b, s // chunk, chunk, h), axis=2)
+    o = gated_delta_kernels.gated_delta_chunks(
+        q.reshape(b, s, -1), k.reshape(b, s, -1), v.reshape(b, s, -1),
+        cum.reshape(b, s, h), beta, chunk, dtype, interpret)
+    return o.reshape(b, s, h, dv)
+
+
+def _scan_xla(q, k, v, g, beta, chunk, dtype):
+    """The XLA form over whole chunks."""
+    b, s, hk, dk = k.shape
+    h, dv = v.shape[2:]
+    c = s // chunk
     # chunks of all sequences on one axis: outside the recurrence they
     # differ in nothing
     cut = lambda t: t.reshape(b * c, chunk, *t.shape[2:])
@@ -172,8 +240,7 @@ def gated_delta_scan(q, k, v, g, beta, chunk: int = 64,
     parts = lax.map(
         lambda args: _without_state(*args, dtype),
         tuple(cut(t).reshape(b * c // passes, passes, chunk, *t.shape[2:])
-              for t in (q, k, v, g.astype(jnp.float32),
-                        beta.astype(jnp.float32))))
+              for t in (q, k, v, g, beta)))
     # (b, c, h, ...) -> the recurrence's (c, b, h, ...)
     by_chunk = lambda t: jnp.moveaxis(
         t.reshape(b, c, h, *t.shape[4:]), 1, 0)
@@ -190,7 +257,7 @@ def gated_delta_scan(q, k, v, g, beta, chunk: int = 64,
     out = inside + jnp.moveaxis(from_state, 0, 1).reshape(
         b * c, h, chunk, dv).astype(jnp.float32)
     out = jnp.moveaxis(out.reshape(b, c, h, chunk, dv), 2, 3)
-    return out.reshape(b, s + pad, h, dv)[:, :s].astype(v.dtype)
+    return out.reshape(b, s, h, dv).astype(v.dtype)
 
 
 def gated_delta_census(s: int, chunk: int, heads: int, dk: int, dv: int,
@@ -208,7 +275,15 @@ def gated_delta_census(s: int, chunk: int, heads: int, dk: int, dv: int,
     ``flops_backward`` (two products for each of the forward's, and
     ``kk`` and ``qk`` once more: they are computed again), and
     ``bytes_forward``: ``q``, ``k``, ``v`` and ``o`` once each in
-    ``itemsize`` bytes, ``g`` and ``beta`` in float32."""
+    ``itemsize`` bytes, ``g`` and ``beta`` in float32.  ``kernels`` is
+    the static account of the kernel path, ``None`` where the sizes do
+    not tile (:func:`gated_delta_kernels.tiles`: the XLA form runs): for
+    the ``forward`` launch that keeps the entering states and each
+    chunk's ``T``, and for the ``backward`` launch, ``grid`` (sequences,
+    key heads, grid points of ``CHUNKS_PER_POINT`` chunks), ``tiles`` a
+    launch, ``vmem_bytes`` a grid point (scratch and double-buffered
+    blocks), ``hbm_bytes`` read and written, and ``hbm_over_least``,
+    those over ``bytes_forward``."""
     key_heads = key_heads or heads
     chunks = -(-s // chunk)
     per_head = float(chunks * heads)
@@ -224,9 +299,18 @@ def gated_delta_census(s: int, chunk: int, heads: int, dk: int, dv: int,
     forward = sum(parts.values())
     least = float(s) * ((2 * key_heads * dk + 2 * heads * dv) * itemsize
                         + 2 * 4 * heads)
+    kernels = None
+    if gated_delta_kernels.tiles(chunk, heads, key_heads, dk, dv):
+        per_point = chunk * gated_delta_kernels.CHUNKS_PER_POINT
+        kernels = gated_delta_kernels.launch_account(
+            -(-s // per_point) * per_point, chunk, heads, key_heads,
+            itemsize)
+        for launch in kernels.values():
+            launch["hbm_over_least"] = launch["hbm_bytes"] / least
     return {
         "chunks": chunks, "padded": chunks * chunk, "flops": parts,
         "flops_forward": forward,
         "flops_backward": 2.0 * forward + parts["kk"] + parts["qk"],
         "bytes_forward": least,
+        "kernels": kernels,
     }

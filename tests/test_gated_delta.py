@@ -125,3 +125,48 @@ def test_census_at_the_cells_shape_by_hand():
         (2 * 16 * 128 + 2 * 32 * 128) * 2 + 2 * 4 * 32)
     # off a boundary the last chunk is padded
     assert gated_delta_census(100, 64, 4, 16, 8)["padded"] == 128
+
+
+def test_census_accounts_for_the_kernels_at_the_cells_shape():
+    """Hand-worked: 16 key heads over 32 grid points of 4 chunks; a grid
+    point's tiles of ``q`` and ``k`` are 256 x 128 bfloat16 = 64 KiB,
+    of ``v`` and ``o`` 256 x 256 = 128 KiB, the two heads' entering
+    states of its four chunks 4 x 2 x 128 x 128 float32 = 512 KiB, their
+    ``T`` 4 x 2 x 64 x 64 = 128 KiB (256 in VMEM, 64 lanes padded to
+    128), the running sums and ``beta`` 4 x 8 x 64 float32 = 8 KiB (16
+    in VMEM); the two states in scratch 128 KiB."""
+    from chainermn_tpu.ops import gated_delta_kernels
+
+    kib = 1024
+    census = gated_delta_census(8192, 64, 32, 128, 128, key_heads=16)
+    got = census["kernels"]
+    points = 16 * 32
+    assert got["forward"]["grid"] == got["backward"]["grid"] == (1, 16, 32)
+    assert got["forward"]["tiles"] == got["backward"]["tiles"] == points
+    # forward: q, k | v, o | rows | states | inverses
+    assert got["forward"]["vmem_bytes"] == (
+        2 * (2 * 64 + 2 * 128 + 16 + 512 + 256) + 128) * kib
+    assert got["forward"]["hbm_bytes"] == points * (
+        2 * 64 + 2 * 128 + 8 + 512 + 128) * kib
+    # backward: q, k, dq, dk | v, do, dv | rows, drows | states | inverses
+    assert got["backward"]["vmem_bytes"] == (
+        2 * (4 * 64 + 3 * 128 + 2 * 16 + 512 + 256) + 128) * kib
+    assert got["backward"]["hbm_bytes"] == points * (
+        4 * 64 + 3 * 128 + 2 * 8 + 512 + 128) * kib
+    for launch in got.values():
+        assert launch["hbm_over_least"] == pytest.approx(
+            launch["hbm_bytes"] / census["bytes_forward"])
+    # the entering states and T are the traffic: 2.7x and 3.3x the least
+    assert 2.6 < got["forward"]["hbm_over_least"] < 2.7
+    assert 3.3 < got["backward"]["hbm_over_least"] < 3.4
+    # far under the 16 MiB a kernel may use by default
+    assert got["backward"]["vmem_bytes"] < 4 * 2 ** 20
+    # a length that is no whole grid point is padded to one
+    assert gated_delta_census(8200, 64, 32, 128, 128, key_heads=16)[
+        "kernels"]["forward"]["grid"] == (1, 16, 33)
+    # sizes the kernels do not tile have no account
+    assert gated_delta_census(100, 16, 4, 16, 8, key_heads=2)[
+        "kernels"] is None
+    assert gated_delta_kernels.tiles(64, 32, 16, 128, 128)
+    assert not gated_delta_kernels.tiles(16, 4, 2, 16, 8)
+    assert not gated_delta_kernels.tiles(64, 32, 4, 128, 128)

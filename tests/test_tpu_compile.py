@@ -400,6 +400,55 @@ def test_chunked_scan_kernels_compile_at_the_other_sizes_they_tile(one_chip):
     assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
+def _delta_shapes(one_chip, b=2, s=8192, key_heads=16, heads=32):
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    return (sd((b, s, key_heads, 128), jnp.bfloat16),
+            sd((b, s, key_heads, 128), jnp.bfloat16),
+            sd((b, s, heads, 128), jnp.bfloat16),
+            sd((b, s, heads), jnp.float32), sd((b, s, heads), jnp.float32))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_gated_delta_kernels_compile_at_the_cell_shape(one_chip, grad):
+    """A Gated DeltaNet layer of ``qwen3next80b_train_s8192`` through the
+    Pallas kernels (``ops/gated_delta_kernels.py``, asked for with
+    ``interpret=False`` as a TPU takes them by itself): two sequences of
+    8192 positions, 16 key and 32 value heads of 128, chunk 64; the
+    forward launch alone, and with the gradient the forward that keeps
+    the entering states and each chunk's ``T`` and the backward launch,
+    each traced under the ``gdn_scan`` scope with no loop beside them;
+    the residuals (537 + 134 MB) are the temporaries."""
+    from chainermn_tpu.ops.gated_delta import gated_delta_scan
+
+    scan = functools.partial(gated_delta_scan, interpret=False)
+    fn = jax.grad(lambda *a: scan(*a).astype(jnp.float32).sum(),
+                  argnums=range(5)) if grad else scan
+    compiled = jax.jit(fn).lower(*_delta_shapes(one_chip)).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r'op_name="([^"]*/(_gdn_\w+)/pallas_call)"', text)
+    assert {name for _, name in kernels} == (
+        {"_gdn_forward", "_gdn_backward"} if grad else {"_gdn_forward"})
+    assert all("/gdn_scan/" in op_name for op_name, _ in kernels)
+    assert not re.search(r'op_name="[^"]*gdn_scan[^"]*while', text)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        1.25e9 if grad else 0.25e9)
+
+
+def test_gated_delta_kernels_compile_at_the_other_sizes_they_tile(one_chip):
+    """What ``gated_delta_kernels.tiles`` admits besides the cell's
+    launch: one value head a key head and four, a length that is no
+    multiple of a grid point's four chunks; forward and gradient."""
+    from chainermn_tpu.ops.gated_delta import gated_delta_scan
+
+    for key_heads, heads in ((2, 2), (1, 4)):
+        text = _compiled_text(jax.grad(
+            lambda *a: gated_delta_scan(*a, interpret=False).astype(
+                jnp.float32).sum(), argnums=range(5)),
+            *_delta_shapes(one_chip, 2, 1000, key_heads, heads))
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
 # -- the hybrid cell's step under the plan its example would choose ----------
 #: what a v5e reports as ``memory_stats()["bytes_limit"]`` (15.75 GiB less
 #: 2 MiB; read on the chip, PERF.md section 6, PR 40)
@@ -490,12 +539,14 @@ def test_qwen3next_step_under_the_examples_plan_compiles_for_the_chip(
     qwen3-next-80b-a3b.json`` and ``cellbench/traffic/
     train_moe_s8192.json`` through ``examples/moe_lm/train_moe_lm.py``'s
     options) with what its blocks keep chosen as the example chooses it
-    on a v5e: compiles, the causal kernels at head width 256 and the
-    grouped products in it, the plan ``gdn_in x3``, the arguments the
-    7.51 GB of float32 state.  ``memory_analysis()`` counts 11.16 GB of
-    temporaries where the chip reserves 8.61 (``PERF.md`` section 6,
-    PR 41), so what is held against the limit here is what the chip
-    read plus that over-count, not the limit itself."""
+    on a v5e: compiles, the causal kernels at head width 256, the
+    grouped products and the delta rule's kernels in it, the plan
+    ``gdn_in x3``, the arguments the 7.51 GB of float32 state.
+    ``memory_analysis()`` counted 11.16 GB of temporaries where the chip
+    reserved 8.61 while the delta rule ran in XLA (``PERF.md`` section
+    6, PR 41); with its kernels (PR 42) it counts 7.09 GB where the
+    chip reserves 6.11.  Every ``pallas_call`` of the mixers lies under
+    ``gdn_scan`` and no ``while`` is left there."""
     import json
     import types
 
@@ -554,13 +605,22 @@ def test_qwen3next_step_under_the_examples_plan_compiles_for_the_chip(
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes == pytest.approx(
         625_667_136 * 12, rel=1e-3)
-    # 16.31 GB on the chip + the 2.55 GB memory_analysis() counts over it
-    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
-        <= 16.31e9 + 2.55e9
+    # not above the parent's temporaries (11.16 GB with the XLA form;
+    # the kernels keep no (chunk, chunk) tensor or (c, b, h, ...) copy)
+    assert memory.temp_size_in_bytes <= 11_164_387_328
+    assert memory.temp_size_in_bytes <= 7.3e9
     text = compiled.as_text()
     for kernel in ("_bdflash_forward", "_bdflash_backward_dq",
                    "_bdflash_backward_dkdv", "_grouped_matmul",
-                   "_grouped_matmul_dw"):
+                   "_grouped_matmul_dw", "_gdn_forward", "_gdn_backward"):
         assert f"{kernel}/pallas_call" in text, kernel
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    under_mixer = [name for name in op_names if "gdn_mixer" in name]
+    delta_rule = [name for name in under_mixer if name.endswith(
+        "/pallas_call")]
+    assert delta_rule and all("/gdn_scan/_gdn_" in name
+                              for name in delta_rule), delta_rule
+    assert not [name for name in op_names
+                if "gdn_scan" in name and "while" in name]
     for scope in ("gdn_mixer", "gdn_conv", "gdn_scan", "moe_shared"):
         assert scope in text, scope

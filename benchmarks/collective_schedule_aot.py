@@ -141,13 +141,15 @@ def build_lm_step(devices, *, n_layers=18, d_model=1536, n_heads=12,
 def build_moe_lm_step(devices, *, options, vocab, d_model, n_heads,
                       n_layers, d_ff, n_experts, top_k, held, shared_d_ff,
                       seq_len, per_chip_batch, chunked_ce, lr=1e-5,
-                      aux_coef=1e-3):
+                      aux_coef=1e-3, **model_fields):
     """The step ``examples/moe_lm/train_moe_lm.py`` builds on its general
     path at ``--sp 1 --tp 1`` (``--dropless --untied-head --moe-every 1
     --chunked-ce N``: the next-token loss a vocabulary chunk at a time,
     the expert layers' counters as ``aux``) over ``devices`` (described
     or attached), and its abstract arguments ``(params, opt_state,
-    batch)``, shardings on.  ``options``: the model's ``BlockOptions``."""
+    batch)``, shardings on.  ``options``: the model's ``BlockOptions``;
+    ``model_fields``: further fields of ``MoeTransformerLM``
+    (``router_options``, ``first_dense``, ``dense_d_ff``)."""
     import chainermn_tpu as cmn
     from chainermn_tpu.functions import collectives as cc
     from chainermn_tpu.models.moe_transformer import (
@@ -166,7 +168,8 @@ def build_moe_lm_step(devices, *, options, vocab, d_model, n_heads,
         n_layers=n_layers, n_experts=n_experts, d_ff=d_ff, moe_every=1,
         k=top_k, max_len=seq_len, aux_stat_axes=("mn_data", "mn_seq"),
         options=options, routing="dropless", held=held,
-        shared_d_ff=shared_d_ff, tie_head=False, return_hidden=True)
+        shared_d_ff=shared_d_ff, tie_head=False, return_hidden=True,
+        **model_fields)
     batch_spec = P("mn_data", "mn_seq")
     tokens = jax.ShapeDtypeStruct(
         (per_chip_batch * len(devices), seq_len), jnp.int32)
@@ -178,8 +181,12 @@ def build_moe_lm_step(devices, *, options, vocab, d_model, n_heads,
             check_vma=False),
         tokens)
     specs = moe_param_specs(params)
+    # the example's: a selection bias is kept out of the decay
+    decayed = (lambda tree: jax.tree_util.tree_map_with_path(
+        lambda path, _: path[-1].key != "router_bias", tree)) \
+        if model.router_options.selection_bias else None
     opt = cmn.create_multi_node_optimizer(
-        optax.adamw(lr, weight_decay=0.01), comm)
+        optax.adamw(lr, weight_decay=0.01, mask=decayed), comm)
 
     def loss_fn(p, b):
         (hidden, aux), sown = model.apply(p, b, mutable=[COUNTERS])

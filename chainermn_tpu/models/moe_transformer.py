@@ -28,6 +28,7 @@ exercises every collective the composition inserts.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable, Optional, Tuple
 
 import jax
@@ -72,6 +73,30 @@ ROUTES = "routes"
 MOE_SHARED_SCOPE = "moe_shared"
 
 
+@dataclasses.dataclass(frozen=True)
+class RouterOptions:
+    """What a dropless expert layer's router and shared expert can be
+    besides the defaults (a softmax over the experts, weights
+    renormalised over the chosen, a shared expert under a sigmoid gate);
+    one hashable field, ``router_options``, of the layer, the block and
+    the LM.
+
+    ``score="sigmoid"``: a sigmoid an expert in the softmax's place.
+    ``selection_bias``: a number an expert (``router_bias (n_experts,)``,
+    initialised 0) added to the scores for the choice of the ``k``
+    alone, outside the weights and with no gradient; the optimizer is to
+    leave it alone too (a balancing rule outside the loss moves it,
+    where one runs).  ``routed_scale``: a factor on the renormalised
+    weights.  ``shared_gated=False``: the shared expert without its
+    gate, ``W_d(silu(W_g x) * W_u x)`` added as it is (no
+    ``shared_gate``)."""
+
+    score: str = "softmax"
+    selection_bias: bool = False
+    routed_scale: float = 1.0
+    shared_gated: bool = True
+
+
 class MoeMlp(nn.Module):
     """Routed expert MLP replacing a transformer's dense MLP block.
 
@@ -105,7 +130,9 @@ class MoeMlp(nn.Module):
     * W_u x)`` (parameters ``shared_wg`` / ``shared_wu`` / ``shared_wd``
     / ``shared_gate``, held whole by every chip): computed by every
     chip alike, outside the sum over ``expert_axis``, so it is counted
-    once.
+    once.  ``router_options`` (:class:`RouterOptions`, dropless only):
+    sigmoid scores, a selection bias outside the weights, a scaling
+    factor on the routed weights, the shared expert without its gate.
     """
 
     n_experts: int
@@ -131,12 +158,13 @@ class MoeMlp(nn.Module):
     routing: str = "capacity"  # capacity | dropless
     held: Optional[Tuple[int, int]] = None  # dropless: (first, count)
     shared_d_ff: int = 0  # dropless: width of the shared expert, 0 none
+    router_options: RouterOptions = RouterOptions()  # dropless
 
     @jax.named_scope(MOE_SHARED_SCOPE)
     def _shared(self, x):
         """The shared expert's result on ``x (tokens, d)``, float32:
-        the gate's logit in float32, the three products in ``x``'s
-        dtype."""
+        the gate's logit (where it is gated) in float32, the three
+        products in ``x``'s dtype."""
         d = x.shape[-1]
         init = nn.initializers.normal(0.02)
         wg = self.param("shared_wg", init, (d, self.shared_d_ff),
@@ -145,10 +173,12 @@ class MoeMlp(nn.Module):
                         jnp.float32)
         wd = self.param("shared_wd", init, (self.shared_d_ff, d),
                         jnp.float32)
-        gate = self.param("shared_gate", init, (d, 1), jnp.float32)
         mm = lambda a, w: jnp.dot(a, w.astype(a.dtype),
                                   preferred_element_type=jnp.float32)
         hidden = (jax.nn.silu(mm(x, wg)) * mm(x, wu)).astype(x.dtype)
+        if not self.router_options.shared_gated:
+            return mm(hidden, wd)
+        gate = self.param("shared_gate", init, (d, 1), jnp.float32)
         return jax.nn.sigmoid(jnp.dot(
             x.astype(jnp.float32), gate,
             precision=lax.Precision.HIGHEST)) * mm(hidden, wd)
@@ -175,10 +205,15 @@ class MoeMlp(nn.Module):
                         jnp.float32)
         wd = self.param("expert_wd", init, (count, self.d_ff, d),
                         jnp.float32)
+        ro = self.router_options
+        bias = self.param("router_bias", nn.initializers.zeros,
+                          (self.n_experts,), jnp.float32) \
+            if ro.selection_bias else None
         y, aux, counters, chosen = held_experts_moe(
             x.reshape(-1, d), router, wg, wu, wd,
             num_experts=self.n_experts, k=self.k, first=first,
-            aux_stat_axes=self.aux_stat_axes,
+            aux_stat_axes=self.aux_stat_axes, score=ro.score,
+            selection_bias=bias, routed_scale=ro.routed_scale,
         )
         if self.expert_axis is not None:
             y = _cc.psum(y, self.expert_axis)
@@ -211,9 +246,11 @@ class MoeMlp(nn.Module):
         if self.routing != "capacity":
             raise ValueError(f"routing must be capacity or dropless, got "
                              f"{self.routing!r}")
-        if self.shared_d_ff:
-            raise ValueError("the shared expert comes with dropless "
-                             "routing")
+        if self.shared_d_ff or self.router_options != RouterOptions():
+            raise ValueError(
+                "the shared expert (gated or ungated), sigmoid scores, "
+                "the selection bias and the scaling factor on the routed "
+                "weights (router_options) come with dropless routing")
         # Per-chip expert blocks are independent draws (fold the axis
         # index into the RNG — tensor_parallel's sharded-init convention).
         from chainermn_tpu.parallel.tensor_parallel import _sharded_init
@@ -310,6 +347,7 @@ class MoeTransformerBlock(nn.Module):
     routing: str = "capacity"
     held: Optional[Tuple[int, int]] = None
     shared_d_ff: int = 0
+    router_options: RouterOptions = RouterOptions()
     # the sequence mixer, one of transformer.LAYER_KINDS
     kind: str = "attention"
 
@@ -331,6 +369,7 @@ class MoeTransformerBlock(nn.Module):
             dispatch_impl=self.dispatch_impl, dtype=self.dtype,
             routing=self.routing, held=self.held,
             shared_d_ff=self.shared_d_ff,
+            router_options=self.router_options,
         )(ln()(x).astype(self.dtype))
         return x + y, aux
 
@@ -340,9 +379,11 @@ class MoeTransformerLM(nn.Module):
 
     Every ``moe_every``-th block's MLP is a routed :class:`MoeMlp`
     (GShard-style interleaving; ``moe_every=1`` makes every block MoE);
-    the rest are dense (TP-sharded when ``tp_axis`` is set).  ``aux_loss``
-    is the summed load-balancing loss — feed the pair to
-    :func:`moe_lm_loss`.
+    the rest are dense (TP-sharded when ``tp_axis`` is set).  On the
+    general path the first ``first_dense`` layers' MLPs are dense
+    whatever ``moe_every`` says, ``dense_d_ff`` wide where that is not
+    the experts' ``d_ff``.  ``aux_loss`` is the summed load-balancing
+    loss — feed the pair to :func:`moe_lm_loss`.
     """
 
     vocab_size: int
@@ -378,13 +419,18 @@ class MoeTransformerLM(nn.Module):
     # the block's options (norm, grouped-query heads, rotary positions,
     # q/k norm, block diffusion, the kind of each layer's mixer,
     # per-block recomputation): transformer.BlockOptions.  With
-    # ``rope_theta`` the model holds no position table.
+    # ``rope_theta`` or ``no_positions`` the model holds no position
+    # table.
     options: BlockOptions = BlockOptions()
-    # expert layers without drops over the held experts, and their
-    # shared expert: MoeMlp
+    # expert layers without drops over the held experts, their shared
+    # expert and their router's other forms: MoeMlp
     routing: str = "capacity"
     held: Optional[Tuple[int, int]] = None
     shared_d_ff: int = 0
+    router_options: RouterOptions = RouterOptions()
+    # leading layers whose MLP is dense, and the dense MLP's width
+    first_dense: int = 0
+    dense_d_ff: Optional[int] = None
     # an output head of its own (``lm_head (vocab, d)``) instead of the
     # embedding's transpose
     tie_head: bool = True
@@ -398,7 +444,11 @@ class MoeTransformerLM(nn.Module):
         (:func:`~chainermn_tpu.models.transformer.remat_plan`)."""
         return model_remat_plan(self, tokens)
 
-    def _rotary(self, tokens):
+    def sparse_layer(self, i: int) -> bool:
+        """Whether layer ``i``'s MLP is an expert layer."""
+        return (i + 1) % self.moe_every == 0 and i >= self.first_dense
+
+    def _general(self, tokens):
         """The model on the general attention path: no position table,
         every block ``options``' block, its mixer of the layer's kind
         and, with ``remat_blocks``, computed again in the backward pass
@@ -418,7 +468,7 @@ class MoeTransformerLM(nn.Module):
             plan = self.remat_plan(tokens.size)
         serial = {MoeTransformerBlock: 0, TransformerBlock: 0}
         for i in range(self.n_layers):
-            sparse = (i + 1) % self.moe_every == 0
+            sparse = self.sparse_layer(i)
             cls = MoeTransformerBlock if sparse else TransformerBlock
             block = block_under_plan(cls, plan[i], serial[cls]) \
                 if o.remat_blocks else cls
@@ -432,13 +482,15 @@ class MoeTransformerLM(nn.Module):
                     dispatch_impl=self.dispatch_impl, dtype=self.dtype,
                     expert_axis=self.expert_axis, options=o,
                     routing=self.routing, held=self.held,
-                    shared_d_ff=self.shared_d_ff, kind=o.layer_type(i),
+                    shared_d_ff=self.shared_d_ff,
+                    router_options=self.router_options,
+                    kind=o.layer_type(i),
                 )(x)
                 aux = aux + a
             else:
                 x = block(
-                    self.n_heads, d_ff, dtype=self.dtype, options=o,
-                    kind=o.layer_type(i),
+                    self.n_heads, self.dense_d_ff or d_ff, dtype=self.dtype,
+                    options=o, kind=o.layer_type(i),
                 )(x)
         x = make_norm(o)(x)
         if self.return_hidden:
@@ -448,15 +500,17 @@ class MoeTransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens):
-        if self.options.rope_theta:
-            return self._rotary(tokens)
+        if self.options.rope_theta or self.options.no_positions:
+            return self._general(tokens)
         if self.options != BlockOptions() or not self.tie_head \
                 or self.return_hidden or self.routing != "capacity" \
-                or self.shared_d_ff:
+                or self.shared_d_ff or self.first_dense or self.dense_d_ff \
+                or self.router_options != RouterOptions():
             raise ValueError(
                 "the block's options, dropless routing, a shared expert, "
-                "an untied head and return_hidden come with rotary "
-                "positions (options.rope_theta)")
+                "the router's options, leading dense layers, an untied "
+                "head and return_hidden come with rotary positions "
+                "(options.rope_theta) or none (options.no_positions)")
         b, s = tokens.shape
         d_ff = self.d_ff or 4 * self.d_model
         embed = make_lm_embed(

@@ -54,8 +54,10 @@ class MlpBlock(nn.Module):
 #: the kinds of sequence mixer a layer can have
 #: (``BlockOptions.layer_types``; the examples' ``--layer-types`` and
 #: :func:`remat_plan` read them here): :class:`SelfAttention`,
-#: :class:`Mamba2Mixer`, :class:`GatedDeltaMixer`
-LAYER_KINDS = ("attention", "mamba", "linear_attention")
+#: :class:`Mamba2Mixer`, :class:`GatedDeltaMixer`, :class:`KdaMixer`,
+#: :class:`LatentAttention`
+LAYER_KINDS = ("attention", "mamba", "linear_attention", "kda",
+               "latent_attention")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,9 +103,16 @@ class BlockOptions:
     ``gdn_key_heads`` key heads of ``gdn_key_dim``, each serving
     ``gdn_value_heads / gdn_key_heads`` of the ``gdn_value_heads``
     value heads of ``gdn_value_dim``, ``gdn_conv`` taps, the scan's
-    ``gdn_chunk``.  ``gated_mlp``: ``W_out(SiLU(g) * u)`` with ``[g | u]
-    = W_in x``, no biases (:class:`GatedMlp`), instead of GELU with
-    biases.  ``no_positions``: the model holds no position table and
+    ``gdn_chunk``.  ``"kda"``: :class:`KdaMixer`, a delta rule with a
+    decay a key channel, sized by the same fields (``gdn_value_heads``
+    heads, a key head each).  ``"latent_attention"``:
+    :class:`LatentAttention`, whose keys and values all heads expand
+    from one compression of ``latent_kv_rank`` channels; a head's
+    query and key are ``latent_nope_dim`` channels of its own beside
+    ``latent_shared_dim`` that all heads share, its value
+    ``latent_value_dim``.  ``gated_mlp``: ``W_out(SiLU(g) * u)`` with
+    ``[g | u] = W_in x``, no biases (:class:`GatedMlp`), instead of GELU
+    with biases.  ``no_positions``: the model holds no position table and
     attention sees no position at all.  The stream's multipliers:
     ``embedding_multiplier`` on the token embedding,
     ``residual_multiplier`` on what a mixer or an MLP adds to the
@@ -113,8 +122,10 @@ class BlockOptions:
     :func:`remat_plan` fits into ``remat_budget_bytes`` of one device's
     memory: the gated MLP's ``in_proj`` result (``mlp_in``), the
     state-space mixer's (``ssm_in``), the widest tensors of a block,
-    and the Gated DeltaNet mixer's ``[q | k | v | z]`` (``gdn_in``),
-    whose matmuls the backward then does not run again.  The budget is
+    the Gated DeltaNet mixer's ``[q | k | v | z]`` (``gdn_in``), the
+    KDA mixer's ``[q | k | v]`` (``kda_in``) and latent attention's
+    queries (``latent_in``), whose matmuls the backward then does not
+    run again.  The budget is
     the program's to fill from what it observes
     (:func:`remat_budget`: the device's memory less the state the step
     holds less a reserve); 0, the default, keeps the input alone."""
@@ -143,6 +154,10 @@ class BlockOptions:
     gdn_value_dim: int = 128
     gdn_conv: int = 4
     gdn_chunk: int = 64
+    latent_kv_rank: int = 0
+    latent_nope_dim: int = 128
+    latent_shared_dim: int = 64
+    latent_value_dim: int = 128
     gated_mlp: bool = False
     no_positions: bool = False
     embedding_multiplier: float = 1.0
@@ -169,11 +184,14 @@ class BlockOptions:
                              f"got {kind!r}")
         return kind
 
-    def remat_widths(self, d_ff: int) -> dict:
+    def remat_widths(self, d_ff: int, n_heads: int = 0) -> dict:
         """Width (last axis) of each result of :data:`REMAT_NAMES` that
         a model of these options has: ``[g | u]`` of the gated MLP, ``[z
         | xBC | dt]`` of the state-space mixer, ``[q | k | v | z]`` of
-        the Gated DeltaNet mixer."""
+        the Gated DeltaNet mixer, ``[q | k | v]`` of the KDA mixer, the
+        ``n_heads`` queries of latent attention.  With KDA layers also
+        :data:`KDA_WORK`, no result of a name: it only widens what
+        :func:`remat_budget` leaves the step."""
         widths = {}
         if self.gated_mlp:
             widths["mlp_in"] = 2 * d_ff
@@ -183,6 +201,14 @@ class BlockOptions:
         if "linear_attention" in (self.layer_types or ()):
             widths["gdn_in"] = 2 * self.gdn_key_heads * self.gdn_key_dim \
                 + 2 * self.gdn_value_heads * self.gdn_value_dim
+        if "kda" in (self.layer_types or ()):
+            widths["kda_in"] = self.gdn_value_heads * (
+                2 * self.gdn_key_dim + self.gdn_value_dim)
+            widths[KDA_WORK] = 2 * KDA_WORK_TENSORS \
+                * self.gdn_value_heads * self.gdn_key_dim
+        if "latent_attention" in (self.layer_types or ()):
+            widths["latent_in"] = n_heads * (
+                self.latent_nope_dim + self.latent_shared_dim)
         return widths
 
 
@@ -190,13 +216,31 @@ class BlockOptions:
 #: (``jax.ad_checkpoint.checkpoint_name``), in the order a budget is
 #: spent on them: :class:`GatedMlp`'s ``in_proj`` result in every layer,
 #: :class:`Mamba2Mixer`'s in the ``mamba`` layers, :class:`GatedDeltaMixer`'s
-#: ``in_proj_qkvz`` result in the ``linear_attention`` layers (each saves
+#: ``in_proj_qkvz`` result in the ``linear_attention`` layers,
+#: :class:`KdaMixer`'s ``in_proj_qkv`` result in the ``kda`` layers,
+#: :class:`LatentAttention`'s ``q_proj`` result in its layers (each saves
 #: one matmul over ``d_model`` a layer; what the first two paid on the
 #: chip: ``PERF.md`` section 6, PR 40).
-REMAT_NAMES = ("mlp_in", "ssm_in", "gdn_in")
+REMAT_NAMES = ("mlp_in", "ssm_in", "gdn_in", "kda_in", "latent_in")
 #: the one kind of layer (:data:`LAYER_KINDS`) that has a result of that
-#: name; a name not here is every layer's
-_REMAT_KIND = {"ssm_in": LAYER_KINDS[1], "gdn_in": LAYER_KINDS[2]}
+#: name; a name not here is every layer's with a dense MLP
+_REMAT_KIND = {"ssm_in": LAYER_KINDS[1], "gdn_in": LAYER_KINDS[2],
+               "kda_in": LAYER_KINDS[3], "latent_in": LAYER_KINDS[4]}
+
+#: a width among :meth:`BlockOptions.remat_widths` that is no kept
+#: result's: what the channel-wise delta rule's XLA form holds a token in
+#: float32 while a KDA block's backward runs (the log decays, their
+#: copy cut into chunks, the running sums and the cotangents of the
+#: first two: :data:`KDA_WORK_TENSORS` tensors of ``heads x dk``), in
+#: units of the model's two-byte activations.  :func:`remat_budget` reserves :data:`REMAT_TEMPORARIES`
+#: of the widest width, so this keeps the blocks of a model with such
+#: layers from keeping results the scan's working set leaves no room
+#: for (ahead of time, two 8192-token sequences of 32 heads of 128
+#: beside 7.2 GB of state compile with nothing kept and with any one
+#: result kept do not: ``PERF.md`` section 6, PR 43).  Goes with the
+#: XLA form.
+KDA_WORK = "kda_work"
+KDA_WORK_TENSORS = 5
 
 #: what :func:`remat_budget` leaves the step besides its state: its own
 #: temporaries, as so many tensors of the widest kept result (the cell
@@ -209,15 +253,17 @@ REMAT_CLEAR_BYTES = 1 << 30
 
 
 def remat_plan(layer_kinds, tokens: int, widths: dict, budget_bytes: int,
-               itemsize: int = 2) -> Tuple[Tuple[str, ...], ...]:
+               itemsize: int = 2, dense=None) -> Tuple[Tuple[str, ...], ...]:
     """What each block keeps besides its input under per-block
     recomputation, a tuple of :data:`REMAT_NAMES` a layer: names are
     added while their bytes (``tokens x widths[name] x itemsize`` a
     layer) fit into ``budget_bytes``, in :data:`REMAT_NAMES`' order, the
     first layers first.  ``layer_kinds``: each layer's mixer
     (:meth:`BlockOptions.layer_type`); ``tokens``: the positions one
-    device holds a step; ``widths``: :meth:`BlockOptions.remat_widths`.
-    No budget, nothing kept."""
+    device holds a step; ``widths``: :meth:`BlockOptions.remat_widths`;
+    ``dense``: whether each layer's MLP is the dense one (``None``:
+    every layer's; an expert layer has no ``mlp_in``).  No budget,
+    nothing kept."""
     kept = [() for _ in layer_kinds]
     left = budget_bytes
     for name in REMAT_NAMES:
@@ -225,7 +271,8 @@ def remat_plan(layer_kinds, tokens: int, widths: dict, budget_bytes: int,
             continue
         cost = tokens * widths[name] * itemsize
         for i, kind in enumerate(layer_kinds):
-            if _REMAT_KIND.get(name, kind) != kind:
+            if _REMAT_KIND.get(name, kind) != kind or (
+                    name == "mlp_in" and dense and not dense[i]):
                 continue
             if cost > left:
                 break
@@ -234,16 +281,28 @@ def remat_plan(layer_kinds, tokens: int, widths: dict, budget_bytes: int,
     return tuple(kept)
 
 
+def model_remat_widths(model) -> dict:
+    """:meth:`BlockOptions.remat_widths` of ``model`` (either LM): its
+    dense MLP's width (``dense_d_ff`` where the experts' ``d_ff`` is
+    another) and its heads."""
+    return model.options.remat_widths(
+        getattr(model, "dense_d_ff", None) or model.d_ff
+        or 4 * model.d_model, model.n_heads)
+
+
 def model_remat_plan(model, tokens: int):
     """:func:`remat_plan` of the layers of ``model`` (either LM: its
-    ``options``, ``n_layers``, ``d_ff`` / ``d_model`` and ``dtype``) for
-    ``tokens`` positions a device and step, under the options'
-    budget."""
+    ``options``, ``n_layers``, ``n_heads``, ``d_ff`` / ``d_model`` and
+    ``dtype``; an LM with expert layers says which are sparse,
+    ``sparse_layer``) for ``tokens`` positions a device and step, under
+    the options' budget."""
     o = model.options
+    sparse = getattr(model, "sparse_layer", lambda i: False)
     return remat_plan(
         [o.layer_type(i) for i in range(model.n_layers)], tokens,
-        o.remat_widths(model.d_ff or 4 * model.d_model),
-        o.remat_budget_bytes, jnp.dtype(model.dtype).itemsize)
+        model_remat_widths(model), o.remat_budget_bytes,
+        jnp.dtype(model.dtype).itemsize,
+        dense=[not sparse(i) for i in range(model.n_layers)])
 
 
 @functools.lru_cache(maxsize=None)
@@ -639,11 +698,18 @@ class SelfAttention(nn.Module):
 #: device scopes of the gated MLP, of the state-space mixer (the
 #: convolution's and the scan's lie inside the mixer's: ops.ssd_scan)
 #: and of the Gated DeltaNet mixer (its convolution's and, from
-#: ops.gated_delta, its scan's inside it)
+#: ops.gated_delta, its scan's inside it); of the KDA mixer, its
+#: convolution and its scan (ops.gated_delta's own scope inside it), and
+#: of latent attention's projections (the compression, its norm and
+#: expansion, the queries and the output projection)
 GATED_MLP_SCOPE = "gated_mlp"
 SSM_MIXER_SCOPE = "ssm_mixer"
 GDN_MIXER_SCOPE = "gdn_mixer"
 GDN_CONV_SCOPE = "gdn_conv"
+KDA_MIXER_SCOPE = "kda_mixer"
+KDA_CONV_SCOPE = "kda_conv"
+KDA_SCAN_SCOPE = "kda_scan"
+LATENT_PROJ_SCOPE = "latent_proj"
 
 
 class GatedMlp(nn.Module):
@@ -842,20 +908,181 @@ class GatedDeltaMixer(nn.Module):
         return dense(d, name="out_proj")(gated.reshape(b, s, values))
 
 
+class KdaMixer(nn.Module):
+    """The sequence mixer of a Kimi Delta Attention layer
+    (arXiv:2510.26692; HF ``KimiDeltaAttention``): a delta rule whose
+    state decays by a factor of its own in each key channel.  Sized by
+    ``options``' ``gdn_*`` fields: ``h = gdn_value_heads`` heads, a key
+    head each, of ``dk`` / ``dv``, ``gdn_conv`` taps, the scan's
+    ``gdn_chunk``; the two low-rank projections go through ``dk``
+    channels.  On ``x (b, s, d)``:
+
+        [q | k | v] = SiLU(conv1d(in_proj_qkv(x)))   widths h dk | h dk | h dv
+        g = -exp(A_log) softplus(f_b(f_a(x)) + dt_bias)   (s, h, dk): d -> dk -> h dk
+        beta = sigmoid(b_proj(x))                    a head
+        q = q / |q| / sqrt(dk);  k = k / |k|         a head
+        o = gated_delta_scan(q, k, v, g, beta)       g a key channel's
+        out_proj(RMSNorm_head(o) * norm * sigmoid(g_b(g_a(x))))   d -> dk -> h dv
+
+    ``A_log`` a head, ``dt_bias`` a channel of every head, ``g_b`` with
+    a bias, no other; the last norm over each head's ``dv`` with one
+    plain gain ``norm (dv,)``.  Products in ``dtype``; ``beta``, ``g``,
+    the normalisation of q and k, the scan's decays and states and the
+    norm in float32.  Single-device in the sequence and the heads, as
+    the scan is (:mod:`chainermn_tpu.ops.gated_delta`): raises under
+    ``seq_axis``, ``tp_axis`` or ``decode``."""
+
+    options: BlockOptions
+    dtype: Any = jnp.bfloat16
+    seq_axis: Optional[str] = None
+    tp_axis: Optional[str] = None
+    decode: bool = False
+
+    @nn.compact
+    @jax.named_scope(KDA_MIXER_SCOPE)
+    def __call__(self, x):
+        if self.tp_axis is not None or self.seq_axis is not None \
+                or self.decode:
+            raise ValueError(
+                "the KDA mixer is single-device in sequence and heads: "
+                "no seq_axis, tp_axis or decode")
+        from chainermn_tpu.ops.gated_delta import gated_delta_scan
+        from chainermn_tpu.ops.ssd_scan import causal_conv1d
+
+        o = self.options
+        b, s, d = x.shape
+        h, dk, dv = o.gdn_value_heads, o.gdn_key_dim, o.gdn_value_dim
+        keys, values = h * dk, h * dv
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=self.dtype)
+        f32 = lambda name, init, shape: self.param(name, init, shape,
+                                                   jnp.float32)
+        taps = f32("conv_kernel", _ssm_taps, (o.gdn_conv, 2 * keys + values))
+        rates = -jnp.exp(f32("A_log", _ssm_rates, (h,)))
+        step_bias = f32("dt_bias", _ssm_step_bias, (h, dk))
+        gain = f32("norm", nn.initializers.ones, (dv,))
+
+        # named for the block's recomputation (an identity elsewhere)
+        qkv = checkpoint_name(
+            dense(2 * keys + values, name="in_proj_qkv")(x), "kda_in")
+        q, k, v = jnp.split(
+            nn.silu(causal_conv1d(qkv, taps, scope=KDA_CONV_SCOPE)),
+            [keys, 2 * keys], axis=-1)
+        step = dense(keys, name="f_b_proj")(dense(dk, name="f_a_proj")(x))
+        write = dense(h, name="b_proj")(x)
+        gate = nn.Dense(values, dtype=self.dtype, name="g_b_proj")(
+            dense(dk, name="g_a_proj")(x))
+        # float32 inside, recomputed in the backward pass
+        unit = jax.checkpoint(_unit_heads, static_argnums=(1, 2))
+        decay = jax.checkpoint(lambda step, rates, step_bias: (
+            rates[:, None] * jax.nn.softplus(
+                step.reshape(b, s, h, dk).astype(jnp.float32) + step_bias)))
+        with jax.named_scope(KDA_SCAN_SCOPE):
+            out = gated_delta_scan(
+                unit(q.reshape(b, s, h, dk), dk ** -0.5, self.dtype),
+                unit(k.reshape(b, s, h, dk), 1.0, self.dtype),
+                v.reshape(b, s, h, dv), decay(step, rates, step_bias),
+                jax.nn.sigmoid(write.astype(jnp.float32)),
+                chunk=o.gdn_chunk, dtype=self.dtype)
+        gated = jax.checkpoint(lambda out, gate, gain: (
+            rms_norm(out, gain, o.norm_eps, jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(self.dtype))(
+            out, gate.reshape(b, s, h, dv), gain)
+        return dense(d, name="out_proj")(gated.reshape(b, s, values))
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention without a position (DeepSeek-V2,
+    arXiv:2405.04434, as Kimi Linear's full-attention layers run it):
+    keys and values are expanded, a head its own, from one compression
+    of ``latent_kv_rank`` channels that all heads share.  With ``dn =
+    latent_nope_dim``, ``ds = latent_shared_dim``, ``dv =
+    latent_value_dim``, on ``x (b, s, d)``:
+
+        [q_a | q_b] = q_proj(x)                 a head: dn | ds
+        [c | k_b] = kv_a_proj(x)                latent_kv_rank | ds
+        [k_a | v] = kv_b_proj(RMSNorm(c))       a head: dn | dv
+        q = [q_a | q_b];  k = [k_a | k_b]       k_b the same for all heads
+        o_proj(causal_softmax_attention(q, k, v))   at (dn + ds) ** -0.5
+
+    No channel is rotated (the ``ds`` channels are where other models of
+    the family put a rotation: ``rope_theta`` is refused here).  Keys
+    are wider than values: with ``use_flash`` the block-causal kernels
+    take the two widths apart, else a dense masked softmax runs.
+    ``attention_scale`` replaces the scale.  Single-device in the
+    sequence and the heads, no cache: raises under ``seq_axis``,
+    ``tp_axis`` or ``decode``."""
+
+    n_heads: int
+    options: BlockOptions
+    dtype: Any = jnp.bfloat16
+    seq_axis: Optional[str] = None
+    tp_axis: Optional[str] = None
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        o = self.options
+        if self.tp_axis is not None or self.seq_axis is not None \
+                or self.decode:
+            raise ValueError(
+                "latent attention is single-device in sequence and heads "
+                "and has no cache: no seq_axis, tp_axis or decode")
+        if o.rope_theta:
+            raise ValueError("latent attention has no rotary form: "
+                             "no_positions, not rope_theta")
+        b, s, d = x.shape
+        h, rank = self.n_heads, o.latent_kv_rank
+        dn, ds, dv = o.latent_nope_dim, o.latent_shared_dim, \
+            o.latent_value_dim
+        dense = functools.partial(nn.Dense, use_bias=False,
+                                  dtype=self.dtype)
+        with jax.named_scope(LATENT_PROJ_SCOPE):
+            # named for the block's recomputation (an identity elsewhere)
+            q = checkpoint_name(dense(h * (dn + ds), name="q_proj")(x),
+                                "latent_in").reshape(b, s, h, dn + ds)
+            latent, shared = jnp.split(
+                dense(rank + ds, name="kv_a_proj")(x), [rank], axis=-1)
+            gain = norm_gain(self, "kv_a_norm", rank, o.zero_centered_norm)
+            own, v = jnp.split(
+                dense(h * (dn + dv), name="kv_b_proj")(jax.checkpoint(
+                    functools.partial(rms_norm, eps=o.norm_eps,
+                                      dtype=self.dtype))(latent, gain)
+                ).reshape(b, s, h, dn + dv), [dn], axis=-1)
+            k = jnp.concatenate([own, jnp.broadcast_to(
+                shared[:, :, None], (b, s, h, ds))], axis=-1)
+        scale = o.attention_scale or (dn + ds) ** -0.5
+        if o.use_flash:
+            from chainermn_tpu.ops import pallas_attention as pa
+
+            # causal is block-causal at block length 1
+            out, _ = pa.block_causal_attention_with_lse(q, k, v, 1,
+                                                        scale=scale)
+        else:
+            from chainermn_tpu.ops import multi_head_attention
+
+            out = multi_head_attention(q, k, v, causal=True, scale=scale)
+        with jax.named_scope(LATENT_PROJ_SCOPE):
+            return dense(d, name="o_proj")(out.reshape(b, s, h * dv))
+
+
 def make_mixer(kind: str, n_heads: int, options: BlockOptions, dtype,
                **attention):
     """The sequence mixer of a layer of ``kind`` (one of
     :data:`LAYER_KINDS`): where both LMs' blocks get theirs.
-    ``attention``: :class:`SelfAttention`'s other fields; the two
-    recurrent mixers take of them what they refuse (``seq_axis``,
-    ``tp_axis``, ``decode``)."""
+    ``attention``: :class:`SelfAttention`'s other fields; the other
+    mixers take of them what they refuse (``seq_axis``, ``tp_axis``,
+    ``decode``)."""
     if kind == "attention":
         return SelfAttention(n_heads, dtype=dtype, options=options,
                              **attention)
-    recurrent = {"mamba": Mamba2Mixer, "linear_attention": GatedDeltaMixer}
-    return recurrent[kind](
-        options, dtype=dtype, **{name: attention[name] for name in (
-            "seq_axis", "tp_axis", "decode") if name in attention})
+    refused = {name: attention[name] for name in (
+        "seq_axis", "tp_axis", "decode") if name in attention}
+    if kind == "latent_attention":
+        return LatentAttention(n_heads, options, dtype=dtype, **refused)
+    recurrent = {"mamba": Mamba2Mixer, "linear_attention": GatedDeltaMixer,
+                 "kda": KdaMixer}
+    return recurrent[kind](options, dtype=dtype, **refused)
 
 
 def block_under_plan(block_cls, keep: Tuple[str, ...], serial: int):

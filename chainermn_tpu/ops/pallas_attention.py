@@ -1877,7 +1877,7 @@ def _bc_geometry(q, k, block, block_size, interpret, kind, tile):
     hkv = k.shape[2]
     if k.shape != (b, s, hkv, d) or hq % hkv:
         raise ValueError(
-            f"block-causal attention needs q (b, s, hq, d) and k/v "
+            f"block-causal attention needs q (b, s, hq, d) and k "
             f"(b, s, hkv, d) with hq % hkv == 0; got {q.shape}, {k.shape}")
     size = _clamp_blocks_for_dim(block_size, block_size, d, warn=False)[0]
     if block_size is None and d > 128:
@@ -1914,35 +1914,36 @@ def _bc_forward(q, k, v, block, strict, scale, block_size, interpret,
     b, s, hq, d = q.shape
     group, bs, t = _bc_geometry(q, k, block, block_size, interpret, "fwd",
                                 tile)
+    dv = v.shape[-1]
     n = s // bs
     kv_index = lambda i, j, kb: (i // group, jnp.minimum(kb, j), 0)
     out, lse = pl.pallas_call(
         functools.partial(_bc_fwd_kernel, s=s, scale=scale, bs=bs,
                           block=block, strict=strict, tile=t),
         out_shape=[
-            _out_struct((b * hq, s, d), q.dtype, q, k, v),
+            _out_struct((b * hq, s, dv), q.dtype, q, k, v),
             _out_struct((b * hq, 8, s), jnp.float32, q, k, v),
         ],
         grid=(b * hq, n, n),
         in_specs=[
             pl.BlockSpec((1, bs, d), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, bs, d), kv_index),
-            pl.BlockSpec((1, bs, d), kv_index),
+            pl.BlockSpec((1, bs, dv), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, bs, d), lambda i, j, kb: (i, j, 0)),
+            pl.BlockSpec((1, bs, dv), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, 8, bs), lambda i, j, kb: (i, 0, j)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bs, d), jnp.float32),
+            pltpu.VMEM((bs, dv), jnp.float32),
             pltpu.VMEM((bs, 128), jnp.float32),
             pltpu.VMEM((bs, 128), jnp.float32),
         ],
         interpret=interpret,
         name="_bdflash_forward",
     )(_to_bh(q), _to_bh(k), _to_bh(v))
-    return (jnp.moveaxis(out.reshape(b, hq, s, d), 1, 2),
-            lse[:, 0])  # (b, s, hq, d), (b * hq, s)
+    return (jnp.moveaxis(out.reshape(b, hq, s, dv), 1, 2),
+            lse[:, 0])  # (b, s, hq, dv), (b * hq, s)
 
 
 @functools.partial(
@@ -1959,6 +1960,7 @@ def _bc_backward(q, k, v, out, lse, g, g_lse, block, strict, scale,
     hkv = k.shape[2]
     group, bs, t = _bc_geometry(q, k, block, block_size, interpret, "bwd",
                                 tile)
+    dv = v.shape[-1]
     n = s // bs
     qb, kb_, vb, dob = _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(g)
     delta = jnp.sum(dob.astype(jnp.float32)
@@ -1979,8 +1981,8 @@ def _bc_backward(q, k, v, out, lse, g, g_lse, block, strict, scale,
         in_specs=[
             pl.BlockSpec((1, bs, d), row),
             pl.BlockSpec((1, bs, d), kv_index),
-            pl.BlockSpec((1, bs, d), kv_index),
-            pl.BlockSpec((1, bs, d), row),
+            pl.BlockSpec((1, bs, dv), kv_index),
+            pl.BlockSpec((1, bs, dv), row),
             pl.BlockSpec((1, 8, bs), stat),
             pl.BlockSpec((1, 8, bs), stat),
         ],
@@ -2000,37 +2002,37 @@ def _bc_backward(q, k, v, out, lse, g, g_lse, block, strict, scale,
     q_row = lambda i, kb, t: (*q_of(i, kb, t), 0)
     q_stat = lambda i, kb, t: (q_of(i, kb, t)[0], 0, q_of(i, kb, t)[1])
     kv_row = lambda i, kb, t: (i, kb, 0)
-    dk, dv = pl.pallas_call(
+    dk, dv_out = pl.pallas_call(
         functools.partial(_bc_bwd_dkv_kernel, n_q=n, **kwargs),
         out_shape=[
             _out_struct((b * hkv, s, d), k.dtype, q, k, v, g),
-            _out_struct((b * hkv, s, d), v.dtype, q, k, v, g),
+            _out_struct((b * hkv, s, dv), v.dtype, q, k, v, g),
         ],
         grid=(b * hkv, n, group * n),
         in_specs=[
             pl.BlockSpec((1, bs, d), q_row),
             pl.BlockSpec((1, bs, d), kv_row),
-            pl.BlockSpec((1, bs, d), kv_row),
-            pl.BlockSpec((1, bs, d), q_row),
+            pl.BlockSpec((1, bs, dv), kv_row),
+            pl.BlockSpec((1, bs, dv), q_row),
             pl.BlockSpec((1, 8, bs), q_stat),
             pl.BlockSpec((1, 8, bs), q_stat),
         ],
         out_specs=[
             pl.BlockSpec((1, bs, d), kv_row),
-            pl.BlockSpec((1, bs, d), kv_row),
+            pl.BlockSpec((1, bs, dv), kv_row),
         ],
         scratch_shapes=[
             pltpu.VMEM((bs, d), jnp.float32),
-            pltpu.VMEM((bs, d), jnp.float32),
+            pltpu.VMEM((bs, dv), jnp.float32),
         ],
         interpret=interpret,
         name="_bdflash_backward_dkdv",
     )(qb, kb_, vb, dob, lse8, delta)
 
     def from_bh(x, h):
-        return jnp.moveaxis(x.reshape(b, h, s, d), 1, 2)
+        return jnp.moveaxis(x.reshape(b, h, s, -1), 1, 2)
 
-    return from_bh(dq, hq), from_bh(dk, hkv), from_bh(dv, hkv)
+    return from_bh(dq, hq), from_bh(dk, hkv), from_bh(dv_out, hkv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -2040,9 +2042,12 @@ def block_causal_attention_with_lse(q, k, v, block, strict=False,
     """Block-causal grouped-query flash attention returning ``(out,
     lse)``, both differentiable.
 
-    ``q`` is (b, s, hq, d), ``k`` / ``v`` (b, s, hkv, d) with ``hq`` a
-    multiple of ``hkv``: query head ``h`` reads key/value head ``h //
-    (hq // hkv)``, which is never repeated in HBM.  Key ``j`` is live
+    ``q`` is (b, s, hq, d), ``k`` (b, s, hkv, d) and ``v`` (b, s, hkv,
+    dv) with ``hq`` a multiple of ``hkv``: query head ``h`` reads
+    key/value head ``h // (hq // hkv)``, which is never repeated in HBM.
+    The values' width ``dv`` is their own (``out`` is (b, s, hq, dv):
+    keys of 192 against values of 128 run as they are).  Key ``j`` is
+    live
     for query ``i`` iff ``j < block * (i // block + 1)``, or with
     ``strict`` iff ``j < block * (i // block)`` (a strict launch's first
     ``block`` queries see no key: ``lse`` is ``-1e30`` there and ``out``

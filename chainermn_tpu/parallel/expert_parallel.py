@@ -638,31 +638,59 @@ MOE_EXPERTS_SCOPE = "moe_experts"
 
 def held_experts_moe(x, router_w, w_gate, w_up, w_down, *,
                      num_experts: int, k: int, first=0,
-                     aux_stat_axes=None):
+                     aux_stat_axes=None, score: str = "softmax",
+                     selection_bias=None, routed_scale: float = 1.0):
     """One chip's part of a dropless mixture-of-experts layer.
 
     ``x (tokens, d)``; ``router_w (d, num_experts)``; ``w_gate`` /
     ``w_up (count, d, f)`` and ``w_down (count, f, d)``: the SiLU-gated
     experts ``first .. first + count - 1`` held here, ``W_d(silu(W_g x)
-    * W_u x)``.  The router's softmax is float32 over all
-    ``num_experts``; the top ``k`` are kept with weights renormalised
-    over them.  Returns ``(y, aux, counters, chosen)``: the held
-    experts' part of the result; the load-balancing loss over all ``num_experts``
-    (:func:`load_balancing_loss`); and ``moe_rows_routed`` (routes to
-    held experts), ``moe_rows_computed`` (rows the expert products ran
-    over) and ``moe_dropped`` (held routes no path computed: always 0),
-    each an int32 scalar."""
+    * W_u x)``.  The router's scores are float32 over all
+    ``num_experts``, a softmax or (``score="sigmoid"``) a sigmoid an
+    expert; the top ``k`` are kept with weights renormalised over them
+    (a sigmoid's with 1e-20 under the sum) and multiplied by
+    ``routed_scale``.  ``selection_bias (num_experts,)``: added to the
+    scores for the choice of the ``k`` alone, outside the weights and
+    outside the gradient.  Returns ``(y, aux, counters, chosen)``: the
+    held experts' part of the result; the load-balancing loss over all
+    ``num_experts`` (:func:`load_balancing_loss`, on the scores over
+    their sum where they are sigmoids); and ``moe_rows_routed`` (routes
+    to held experts), ``moe_rows_computed`` (rows the expert products
+    ran over), ``moe_dropped`` (held routes no path computed: always 0)
+    and, under a bias, ``moe_routes_biased`` (routes, of all ``tokens x
+    k``, to an expert that is not among the ``k`` best scores: what the
+    bias changed), each an int32 scalar."""
     t, d = x.shape
     count = w_gate.shape[0]
     block_rows = HELD_BLOCK_ROWS
     n_blocks = held_buffer_blocks(t, num_experts, k, count)
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"score must be softmax or sigmoid, got {score!r}")
+    biased = None
     with jax.named_scope(MOE_ROUTE_SCOPE):
-        probs = jax.nn.softmax(jnp.einsum(
+        logits = jnp.einsum(
             "td,de->te", x.astype(jnp.float32),
-            router_w.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST), axis=-1)
-        top, chosen = lax.top_k(probs, k)
-        weights = top / jnp.sum(top, axis=-1, keepdims=True)
+            router_w.astype(jnp.float32), precision=lax.Precision.HIGHEST)
+        if score == "softmax":
+            probs = scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        if selection_bias is None:
+            top, chosen = lax.top_k(scores, k)
+        else:
+            _, chosen = lax.top_k(
+                scores + lax.stop_gradient(selection_bias), k)
+            top = jnp.take_along_axis(scores, chosen, axis=-1)
+            # a route whose score k others or more beat
+            better = jnp.sum(scores[:, None, :] > top[:, :, None], axis=-1)
+            biased = jnp.sum(better >= k).astype(jnp.int32)
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        if score == "sigmoid":
+            total = total + 1e-20
+        weights = top / total
+        if routed_scale != 1.0:
+            weights = weights * routed_scale
         raw_routes = jnp.sum(
             jax.nn.one_hot(chosen, num_experts, dtype=probs.dtype), axis=1)
         aux = load_balancing_loss(probs, raw_routes, axes=aux_stat_axes)
@@ -719,4 +747,6 @@ def held_experts_moe(x, router_w, w_gate, w_up, w_down, *,
         "moe_dropped": jnp.where(
             plan.overflow, 0, plan.routed.astype(jnp.int32) - on_fast),
     }
+    if biased is not None:
+        counters["moe_routes_biased"] = biased
     return y, aux, counters, chosen

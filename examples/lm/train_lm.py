@@ -270,7 +270,8 @@ def main(argv=None):
         # left beside the state goes (the parameter tree is the same
         # under any plan: the step is traced once, with this one)
         tokens = batch // comm.dp_size * args.seq_len // comm.sp_size
-        widths = options.remat_widths(args.d_ff or 4 * args.d_model)
+        widths = options.remat_widths(args.d_ff or 4 * args.d_model,
+                                      args.n_heads)
         options = dataclasses.replace(
             options, remat_budget_bytes=remat_budget(
                 comm.mesh.local_devices[0], (params, opt_state), tokens,

@@ -59,6 +59,27 @@ of 512, an eighth of the vocabulary, one period of the layer pattern;
       --rmsnorm --zero-centered-norm --qk-norm --attn-output-gate \
       --untied-head --dropless --flash --remat-blocks --chunked-ce 8 \
       --lr 1e-5 --aux-coef 1e-3
+
+``--no-positions`` takes the general block without any position;
+``--layer-types`` also knows ``kda`` (a delta rule with a decay a key
+channel, sized by ``--gdn-*``) and ``latent_attention`` (keys and values
+expanded from one compression, ``--latent-*``); ``--first-dense`` makes
+the leading layers' MLPs dense (``--gated-mlp``, ``--dense-d-ff``);
+``--router-score sigmoid``, ``--router-bias``, ``--routed-scale`` and
+``--shared-ungated`` are the router's and the shared expert's other
+forms.  One chip's share of Kimi-Linear-48B-A3B-Instruct (experts 0..7
+of 256, an eighth of the vocabulary, the leading dense layer and the
+four that follow; ``cellbench/configs/kimi-linear-48b-a3b.json``):
+
+    python examples/moe_lm/train_moe_lm.py --d-model 2304 --n-heads 32 \
+      --no-positions --latent-kv-rank 512 --d-ff 1024 --shared-d-ff 1024 \
+      --shared-ungated --n-experts 256 --top-k 8 --held 0,8 --moe-every 1 \
+      --first-dense 1 --dense-d-ff 9216 --gated-mlp --n-layers 5 \
+      --layer-types kda,kda,kda,latent_attention --gdn-value-heads 32 \
+      --router-score sigmoid --router-bias --routed-scale 2.446 \
+      --vocab 20480 --seq-len 8192 --batchsize 2 --rmsnorm --norm-eps 1e-5 \
+      --untied-head --dropless --flash --remat-blocks --chunked-ce 8 \
+      --lr 1e-5 --aux-coef 1e-3
 """
 
 import argparse
@@ -143,6 +164,9 @@ def main(argv=None):
     g.add_argument("--rope-theta", type=float, default=None,
                    help="rotary positions of this base: the general "
                         "block, no position table (needs --sp 1)")
+    g.add_argument("--no-positions", action="store_true",
+                   help="no position anywhere: the general block, no "
+                        "position table, no rotation (needs --sp 1)")
     g.add_argument("--rmsnorm", action="store_true")
     g.add_argument("--n-kv-heads", type=int, default=None,
                    help="key/value heads shared by groups of query heads")
@@ -185,20 +209,49 @@ def main(argv=None):
     g.add_argument("--gdn-value-dim", type=int, default=128)
     g.add_argument("--gdn-conv", type=int, default=4)
     g.add_argument("--gdn-chunk", type=int, default=64)
+    g.add_argument("--latent-kv-rank", type=int, default=0,
+                   help="latent attention: channels of the compression "
+                        "keys and values are expanded from")
+    g.add_argument("--latent-nope-dim", type=int, default=128,
+                   help="a head's own channels of a query and a key")
+    g.add_argument("--latent-shared-dim", type=int, default=64,
+                   help="a key's channels that all heads share")
+    g.add_argument("--latent-value-dim", type=int, default=128)
     g.add_argument("--shared-d-ff", type=int, default=0,
                    help="width of the gated shared expert beside the "
                         "routed ones (--dropless)")
+    g.add_argument("--shared-ungated", action="store_true",
+                   help="the shared expert without its sigmoid gate")
+    g.add_argument("--router-score", default="softmax",
+                   choices=("softmax", "sigmoid"),
+                   help="the router's scores (--dropless)")
+    g.add_argument("--router-bias", action="store_true",
+                   help="a bias an expert on the choice of the top k "
+                        "alone: outside the weights, no gradient, left "
+                        "alone by the optimizer")
+    g.add_argument("--routed-scale", type=float, default=1.0,
+                   help="factor on the renormalised routed weights")
+    g.add_argument("--first-dense", type=int, default=0, metavar="K",
+                   help="the first K layers' MLPs are dense")
+    g.add_argument("--dense-d-ff", type=int, default=None,
+                   help="width of a dense layer's MLP where it is not "
+                        "--d-ff")
+    g.add_argument("--gated-mlp", action="store_true",
+                   help="a dense layer's MLP is W_out(SiLU(g) * u)")
     g.add_argument("--remat-blocks", action="store_true",
                    help="compute each block's forward again in the "
                         "backward pass; kept besides the blocks' inputs, "
                         "as far as the device's memory goes "
-                        "(models.transformer.remat_budget): the Gated "
-                        "DeltaNet in-projection's result (gdn_in)")
+                        "(models.transformer.remat_budget): the dense "
+                        "MLP's and the mixers' in-projections' results "
+                        "(models.transformer.REMAT_NAMES)")
     g.add_argument("--chunked-ce", type=int, default=0, metavar="CHUNKS",
                    help="head + cross-entropy over this many vocabulary "
                         "chunks: the logits are never whole")
     args = p.parse_args(argv)
-    general = args.rope_theta is not None
+    general = args.rope_theta is not None or args.no_positions
+    if args.rope_theta is not None and args.no_positions:
+        p.error("--rope-theta or --no-positions")
     if not general and (args.rmsnorm or args.n_kv_heads or args.head_dim
                         or args.qk_norm or args.untied_head or args.flash
                         or args.dropless or args.held
@@ -206,14 +259,19 @@ def main(argv=None):
                         or args.zero_centered_norm or args.attn_output_gate
                         or args.rotary_fraction != 1.0 or args.layer_types
                         or args.shared_d_ff or args.remat_blocks
-                        or args.chunked_ce):
-        p.error("the block's options come with --rope-theta")
+                        or args.chunked_ce or args.latent_kv_rank
+                        or args.shared_ungated or args.router_bias
+                        or args.router_score != "softmax"
+                        or args.routed_scale != 1.0 or args.first_dense
+                        or args.dense_d_ff or args.gated_mlp):
+        p.error("the block's options come with --rope-theta or "
+                "--no-positions")
     if args.chunked_ce and args.block_diffusion:
         p.error("--chunked-ce is the next-token loss's")
     if general and (args.sp != 1 or args.generate or args.vocab_parallel):
-        p.error("--rope-theta needs --sp 1, --generate 0 and a dense "
-                "vocabulary: the general block has no sequence-parallel, "
-                "decode or vocab-parallel path yet")
+        p.error("--rope-theta / --no-positions need --sp 1, --generate 0 "
+                "and a dense vocabulary: the general block has no "
+                "sequence-parallel, decode or vocab-parallel path yet")
     if args.held and (args.tp != 1 or not args.dropless):
         p.error("--held is one chip's share: --tp 1 and --dropless")
 
@@ -242,6 +300,7 @@ def main(argv=None):
         COUNTERS,
         ROUTES,
         MoeTransformerLM,
+        RouterOptions,
         moe_lm_loss,
         moe_param_specs,
     )
@@ -250,6 +309,7 @@ def main(argv=None):
         LAYER_KINDS,
         BlockOptions,
         block_diffusion_loss,
+        model_remat_widths,
         noised_copy,
         remat_budget,
         remat_kept,
@@ -295,6 +355,11 @@ def main(argv=None):
         gdn_value_heads=args.gdn_value_heads,
         gdn_key_dim=args.gdn_key_dim, gdn_value_dim=args.gdn_value_dim,
         gdn_conv=args.gdn_conv, gdn_chunk=args.gdn_chunk,
+        latent_kv_rank=args.latent_kv_rank,
+        latent_nope_dim=args.latent_nope_dim,
+        latent_shared_dim=args.latent_shared_dim,
+        latent_value_dim=args.latent_value_dim,
+        gated_mlp=args.gated_mlp, no_positions=args.no_positions,
         remat_blocks=args.remat_blocks,
     )
 
@@ -308,6 +373,11 @@ def main(argv=None):
             held=tuple(int(x) for x in args.held.split(","))
             if args.held else None,
             shared_d_ff=args.shared_d_ff,
+            router_options=RouterOptions(
+                score=args.router_score, selection_bias=args.router_bias,
+                routed_scale=args.routed_scale,
+                shared_gated=not args.shared_ungated),
+            first_dense=args.first_dense, dense_d_ff=args.dense_d_ff,
             tie_head=not args.untied_head,
             return_hidden=bool(args.block_diffusion or args.chunked_ce),
         )
@@ -340,8 +410,13 @@ def main(argv=None):
         print(f"params: {n_params / 1e6:.2f} M  "
               f"(expert blocks sharded over mn_model)")
 
+    # the selection bias is no weight: it has no gradient (Adam then
+    # leaves it where it is) and is kept out of the decay
+    decayed = (lambda tree: jax.tree_util.tree_map_with_path(
+        lambda path, _: path[-1].key != "router_bias", tree)) \
+        if args.router_bias else None
     opt = cmn.create_multi_node_optimizer(
-        optax.adamw(args.lr, weight_decay=0.01), comm
+        optax.adamw(args.lr, weight_decay=0.01, mask=decayed), comm
     )
     opt_state = opt.init(params)
     if options.remat_blocks:
@@ -349,7 +424,7 @@ def main(argv=None):
         # left beside the state goes (the parameter tree is the same
         # under any plan: the step is traced once, with this one)
         tokens = batch // comm.dp_size * args.seq_len
-        widths = options.remat_widths(args.d_ff or 4 * args.d_model)
+        widths = model_remat_widths(model)
         model = make_general(dataclasses.replace(
             options, remat_budget_bytes=remat_budget(
                 comm.mesh.local_devices[0], (params, opt_state), tokens,

@@ -337,6 +337,54 @@ def test_block_causal_attention_compiles_at_head_width_256(one_chip):
                            None)[1] == 1024
 
 
+def test_block_causal_attention_compiles_at_key_width_192_value_width_128(
+        one_chip):
+    """The causal launch of ``kimilinear48b_train_s8192``: two
+    8192-token sequences, 32 heads, keys **192** wide against values
+    **128**, each at its own width (Mosaic takes the 192: on the chip
+    the same launch with the keys padded to 256 lanes was 5 % slower,
+    ``PERF.md`` section 6, PR 43): forward, dq and dk/dv."""
+    qk = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16,
+                              sharding=one_chip)
+    v = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def attend(q, k, v):
+        return pa.block_causal_attention_with_lse(
+            q, k, v, 1, scale=192 ** -0.5, interpret=False)[0]
+
+    compiled = jax.jit(_grad_of(attend)).lower(qk, qk, v).compile()
+    text = compiled.as_text()
+    for kernel in ("_bdflash_forward", "_bdflash_backward_dq",
+                   "_bdflash_backward_dkdv"):
+        assert text.count(f"{kernel}/pallas_call") >= 1, kernel
+    assert [x.shape for x in compiled.out_info] == [
+        qk.shape, qk.shape, v.shape]
+    assert pa._bc_geometry(qk, qk, 1, None, False, "fwd", None)[1] == 512
+
+
+def test_channel_decay_scan_compiles_at_the_cell_shape(one_chip):
+    """The channel-wise delta rule of ``kimilinear48b_train_s8192`` (two
+    8192-token sequences, 32 heads of 128, chunk 64, ``g`` a float32 a
+    key channel), forward and backward: the XLA form, no kernel, for a
+    TPU too."""
+    from chainermn_tpu.ops.gated_delta import gated_delta_scan
+
+    head = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
+                                sharding=one_chip)
+    g = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.float32,
+                             sharding=one_chip)
+    beta = jax.ShapeDtypeStruct((2, 8192, 32), jnp.float32,
+                                sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        return gated_delta_scan(q, k, v, g, beta).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                          head, head, head, g, beta)
+    assert "tpu_custom_call" not in text
+
+
 def _scan_shapes(one_chip):
     sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                    sharding=one_chip)

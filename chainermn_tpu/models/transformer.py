@@ -184,13 +184,15 @@ class BlockOptions:
                              f"got {kind!r}")
         return kind
 
-    def remat_widths(self, d_ff: int, n_heads: int = 0) -> dict:
+    def remat_widths(self, d_ff: int, n_heads: int = 0,
+                     dtype=jnp.bfloat16) -> dict:
         """Width (last axis) of each result of :data:`REMAT_NAMES` that
-        a model of these options has: ``[g | u]`` of the gated MLP, ``[z
-        | xBC | dt]`` of the state-space mixer, ``[q | k | v | z]`` of
-        the Gated DeltaNet mixer, ``[q | k | v]`` of the KDA mixer, the
-        ``n_heads`` queries of latent attention.  With KDA layers also
-        :data:`KDA_WORK`, no result of a name: it only widens what
+        a model of these options and activations of ``dtype`` has: ``[g
+        | u]`` of the gated MLP, ``[z | xBC | dt]`` of the state-space
+        mixer, ``[q | k | v | z]`` of the Gated DeltaNet mixer, ``[q | k
+        | v]`` of the KDA mixer, the ``n_heads`` queries of latent
+        attention.  With KDA layers whose scan runs its XLA form here
+        also :data:`KDA_WORK`, no result of a name: it only widens what
         :func:`remat_budget` leaves the step."""
         widths = {}
         if self.gated_mlp:
@@ -204,8 +206,14 @@ class BlockOptions:
         if "kda" in (self.layer_types or ()):
             widths["kda_in"] = self.gdn_value_heads * (
                 2 * self.gdn_key_dim + self.gdn_value_dim)
-            widths[KDA_WORK] = 2 * KDA_WORK_TENSORS \
-                * self.gdn_value_heads * self.gdn_key_dim
+            from chainermn_tpu.ops.gated_delta import runs_kernels
+
+            if not runs_kernels(
+                    self.gdn_chunk, self.gdn_value_heads,
+                    self.gdn_value_heads, self.gdn_key_dim,
+                    self.gdn_value_dim, dtype, channels=True):
+                widths[KDA_WORK] = 2 * KDA_WORK_TENSORS \
+                    * self.gdn_value_heads * self.gdn_key_dim
         if "latent_attention" in (self.layer_types or ()):
             widths["latent_in"] = n_heads * (
                 self.latent_nope_dim + self.latent_shared_dim)
@@ -238,7 +246,11 @@ _REMAT_KIND = {"ssm_in": LAYER_KINDS[1], "gdn_in": LAYER_KINDS[2],
 #: for (ahead of time, two 8192-token sequences of 32 heads of 128
 #: beside 7.2 GB of state compile with nothing kept and with any one
 #: result kept do not: ``PERF.md`` section 6, PR 43).  Goes with the
-#: XLA form.
+#: XLA form: where the scan runs its kernels (``ops.gated_delta.
+#: runs_kernels``) :meth:`BlockOptions.remat_widths` leaves it out, and
+#: what the kernels hold (the entering states and ``T`` of one block's
+#: backward, 671 MB at that shape) lies inside :data:`REMAT_TEMPORARIES`
+#: (``PERF.md`` section 6, PR 44).
 KDA_WORK = "kda_work"
 KDA_WORK_TENSORS = 5
 
@@ -287,7 +299,7 @@ def model_remat_widths(model) -> dict:
     another) and its heads."""
     return model.options.remat_widths(
         getattr(model, "dense_d_ff", None) or model.d_ff
-        or 4 * model.d_model, model.n_heads)
+        or 4 * model.d_model, model.n_heads, model.dtype)
 
 
 def model_remat_plan(model, tokens: int):

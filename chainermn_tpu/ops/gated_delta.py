@@ -1,8 +1,8 @@
 """Chunked gated delta rule in two variants: a scalar decay a head and
 position (Gated DeltaNet, arXiv:2412.06464), and a decay of its own for
-each key channel (Kimi Delta Attention, arXiv:2510.26692).  The scalar
+each key channel (Kimi Delta Attention, arXiv:2510.26692).  Either
 variant has two forms, Pallas kernels on a TPU and plain XLA everywhere
-else; the channel-wise variant has the XLA form alone.
+else.
 
 A value head carries a state ``S (dk, dv)`` over the positions of a
 sequence, driven by a key ``k_t`` and a query ``q_t (dk,)`` (both
@@ -47,25 +47,31 @@ K^T``, ``W = T (beta (K * e^G))``, ``O = (Q * e^G) S + P V'``, ``S' =
 Diag(e^{G_C}) S + (K * e^{G_C - G})^T V'``.  Splitting ``A`` as ``(K
 e^G)(K e^{-G})^T`` would evaluate ``e^{-G}``, which overflows float32
 after a few strongly decayed positions; the rule above, every decay the
-exponential of a non-positive number, is kept by cutting a chunk into
-blocks of :data:`DECAY_BLOCK` positions (:func:`_channel_decays`): for
+exponential of a non-positive number, is kept by cutting the chunk.  The
+XLA form cuts it into blocks of :data:`DECAY_BLOCK` positions
+(:func:`_channel_decays`): for
 ``i`` in block ``r`` and ``j`` in an earlier block, ``(K_i * e^{G_i -
 G_r0}) . (K_j * e^{G_r0 - G_j})`` with ``r0`` the position before the
 block's first (both exponents non-positive, a product in ``dtype``
 operands); inside a block the ``block x block x dk`` differences
-directly, in float32.
+directly, in float32.  The kernels cut it by halves
+(:mod:`.kda_kernels`: for ``i`` above a cut ``m`` and ``j`` at or below
+it ``G_i - G_j = (G_i - G_m) + (G_m - G_j)``, one product of decayed
+operands a level, ``dtype`` operands where the cut parts
+:data:`DECAY_BLOCK` positions or more and float32 ones, to 16 bits of
+mantissa, inside a block).
 
 **Which form runs** is read off the input and the platform
-(:func:`_use_kernels`).  The kernels (:mod:`.gated_delta_kernels`) run
-the scalar rule on a TPU when the sizes tile -- keys and values 128
-wide, a chunk of 64, whole groups of at most four value heads a key head
--- and ``k``, ``v`` and the products' operands are bfloat16 (the cell's
-launch: 16 key and 32 value heads of 128); with ``interpret=True`` they
-run interpreted at any such sizes, in either precision (the unit tests).
-Every other call runs the XLA form: off the TPU, float32 operands on it,
-a head width, a chunk or a grouping the kernels do not tile, and the
-channel-wise rule everywhere (the kernels take ``G`` as one row a head
-and chunk).
+(:func:`_use_kernels`).  The kernels run on a TPU when the sizes tile --
+keys and values 128 wide, a chunk of 64; under the scalar rule
+(:mod:`.gated_delta_kernels`) whole groups of at most four value heads a
+key head, under the channel-wise rule (:mod:`.kda_kernels`) a key head a
+value head -- and ``k``, ``v`` and the products' operands are bfloat16
+(the cells' launches: 16 key and 32 value heads of 128; 32 of each);
+with ``interpret=True`` they run interpreted at any such sizes, in
+either precision (the unit tests).  Every other call runs the XLA form:
+off the TPU, float32 operands on it, a head width, a chunk or a grouping
+the kernels do not tile.
 
 * **The kernels** walk a sequence's chunks in order with a key head's
   value heads' states in VMEM, reading ``q``, ``k``, ``v`` and writing
@@ -78,7 +84,12 @@ and chunk).
   every chunk (``(chunks, heads, dk, dv)`` float32 a sequence, 268 MB at
   the cell's shape) and every chunk's ``T`` (67 MB); the backward kernel
   walks the chunks last to first with the states' cotangent in VMEM and
-  computes decays, ``U``, ``W`` and ``V'`` again per tile.
+  computes decays, ``U``, ``W`` and ``V'`` again per tile.  The
+  channel-wise rule's do the same a value head, with the ``(chunk, dk)``
+  tile of ``g`` read as the mixer left it, its running sum and every
+  decayed operand made on the chip, and ``dg`` a channel written in
+  ``g``'s layout (twice the residuals: 537 and 134 MB at the cell's
+  shape).
 * **The XLA form** has three stages: what needs no state,
   :data:`CHUNKS_PER_PASS` chunks at a time (:data:`CHUNKS_PER_PASS_CHANNELS`
   under the channel-wise rule) under ``jax.checkpoint`` with
@@ -108,7 +119,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import gated_delta_kernels
+from . import gated_delta_kernels, kda_kernels
 from .grouped_matmul import vary_alike
 
 #: chunks whose ``(heads, chunk, chunk)`` float32 tensors are live at
@@ -284,18 +295,31 @@ def _carry_on(state, chunk_of, dtype):
     return state, (new.astype(dtype), from_state.astype(dtype))
 
 
-def _use_kernels(k, v, chunk, dtype, interpret) -> bool:
-    """Whether :func:`gated_delta_scan` runs the Pallas kernels under
-    the scalar rule: the sizes tile (:func:`gated_delta_kernels.tiles`)
+def _use_kernels(k, v, chunk, dtype, interpret, channels=False) -> bool:
+    """Whether :func:`gated_delta_scan` runs the Pallas kernels, the
+    scalar rule's or (``channels``) the channel-wise rule's: the sizes
+    tile (:func:`gated_delta_kernels.tiles`, :func:`kda_kernels.tiles`)
     and either the kernels are asked for interpreted, or this is a TPU
     and ``k``, ``v`` and the products' operands are bfloat16."""
     (hk, dk), (h, dv) = k.shape[2:], v.shape[2:]
-    if not gated_delta_kernels.tiles(chunk, h, hk, dk, dv):
+    family = kda_kernels if channels else gated_delta_kernels
+    if not family.tiles(chunk, h, hk, dk, dv):
         return False
     if interpret is not None:
         return True
     return jax.default_backend() == "tpu" and dtype == jnp.bfloat16 \
         and k.dtype == v.dtype == jnp.bfloat16
+
+
+def runs_kernels(chunk: int, heads: int, key_heads: int, dk: int, dv: int,
+                 dtype, channels: bool = False) -> bool:
+    """Whether :func:`gated_delta_scan` left to itself (``interpret``
+    ``None``) runs its kernels here on ``dtype`` operands of these
+    sizes, under the scalar rule or (``channels``) the channel-wise
+    one: :func:`_use_kernels`' answer, from sizes alone."""
+    of = lambda h, d: jax.ShapeDtypeStruct((1, chunk, h, d), dtype)
+    return _use_kernels(of(key_heads, dk), of(heads, dv), chunk, dtype,
+                        None, channels)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "dtype", "interpret"))
@@ -323,8 +347,10 @@ def gated_delta_scan(q, k, v, g, beta, chunk: int = 64,
         raise ValueError(f"g is (b, s, heads) or (b, s, heads, dk): got "
                          f"{g.shape} for {h} heads of {dk}")
     scan, whole = _scan_xla, chunk
-    if g.ndim == 3 and _use_kernels(k, v, chunk, dtype, interpret):
-        scan = functools.partial(_scan_kernels, interpret=bool(interpret))
+    if _use_kernels(k, v, chunk, dtype, interpret, g.ndim == 4):
+        scan = functools.partial(
+            _scan_kernels if g.ndim == 3 else _scan_channel_kernels,
+            interpret=bool(interpret))
         whole = chunk * gated_delta_kernels.CHUNKS_PER_POINT
     pad = -s % whole
     if pad:
@@ -344,6 +370,16 @@ def _scan_kernels(q, k, v, g, beta, chunk, dtype, interpret):
         q.reshape(b, s, -1), k.reshape(b, s, -1), v.reshape(b, s, -1),
         cum.reshape(b, s, h), beta, chunk, dtype, interpret)
     return o.reshape(b, s, h, dv)
+
+
+def _scan_channel_kernels(q, k, v, g, beta, chunk, dtype, interpret):
+    """The channel-wise rule's kernel path over whole grid points
+    (:func:`kda_kernels.kda_chunks`: the running sums are made in the
+    tile)."""
+    flat = lambda t: t.reshape(*t.shape[:2], -1)
+    o = kda_kernels.kda_chunks(flat(q), flat(k), flat(v), flat(g), beta,
+                               chunk, dtype, interpret)
+    return o.reshape(v.shape)
 
 
 def _scan_xla(q, k, v, g, beta, chunk, dtype):
@@ -426,9 +462,10 @@ def gated_delta_census(s: int, chunk: int, heads: int, dk: int, dv: int,
     ``qk`` are then a value head's (no two heads share a decayed key),
     ``g`` is ``dk`` float32 numbers a head and position in
     ``bytes_forward`` (as many bytes as ``q``, ``k``, ``v`` and ``o``
-    together at 32 heads of 128 in bfloat16), ``kernels`` is ``None``
-    (the XLA form runs), and ``exponentials`` counts what the blocked
-    form evaluates in a forward pass, a head and chunk: ``chunk x
+    together at 32 heads of 128 in bfloat16), ``kernels`` is the account
+    of :mod:`.kda_kernels`' launches (``None`` where :func:`kda_kernels.
+    tiles` refuses the sizes), and ``exponentials`` counts what the XLA
+    form's blocks evaluate in a forward pass, a head and chunk: ``chunk x
     DECAY_BLOCK x dk`` inside the blocks and ``chunk x (chunk /
     DECAY_BLOCK + 3) x dk`` for the factors between them, ``e^G`` and
     ``e^{G_C - G}``."""
@@ -441,12 +478,12 @@ def gated_delta_census(s: int, chunk: int, heads: int, dk: int, dv: int,
     least = float(s) * ((2 * key_heads * dk + 2 * heads * dv) * itemsize
                         + 4 * heads * ((dk if channel_decay else 1) + 1))
     kernels = None
-    if not channel_decay \
-            and gated_delta_kernels.tiles(chunk, heads, key_heads, dk, dv):
+    family = kda_kernels if channel_decay else gated_delta_kernels
+    if family.tiles(chunk, heads, key_heads, dk, dv):
         per_point = chunk * gated_delta_kernels.CHUNKS_PER_POINT
         kernels = gated_delta_kernels.launch_account(
             -(-s // per_point) * per_point, chunk, heads, key_heads,
-            itemsize)
+            itemsize, plan=family.launch_plan)
         for launch in kernels.values():
             launch["hbm_over_least"] = launch["hbm_bytes"] / least
     census = {

@@ -395,10 +395,11 @@ def launch_plan(b, s, chunk, heads, key_heads, backward: bool):
     return (b, key_heads, points), ins, outs, [(r, d, d)]
 
 
-def _launch(kernel, name, operands, out_dtypes, dims, backward, interpret):
-    """One ``pallas_call`` over ``launch_plan(*dims)``, with the outputs
+def _launch(kernel, name, operands, out_dtypes, dims, backward, interpret,
+            plan=launch_plan):
+    """One ``pallas_call`` over ``plan(*dims)``, with the outputs
     ``out_dtypes`` names."""
-    grid, ins, outs, scratch = launch_plan(*dims, backward)
+    grid, ins, outs, scratch = plan(*dims, backward)
     spec = lambda entry: pl.BlockSpec(entry[1], entry[2])
     operands = vary_alike(*operands)
     return pl.pallas_call(
@@ -463,8 +464,9 @@ gated_delta_chunks.defvjp(_gdn_fwd, _gdn_bwd)
 
 
 def launch_account(s: int, chunk: int, heads: int, key_heads: int,
-                   itemsize: int = 2) -> dict:
-    """The static account of the two launches for one sequence of ``s``
+                   itemsize: int = 2, plan=launch_plan) -> dict:
+    """The static account of the two launches (``plan``'s: these
+    kernels', or the channel-wise rule's) for one sequence of ``s``
     positions (a multiple of ``chunk``), ``forward`` the one that keeps
     the entering states: ``grid``, ``tiles`` a launch, ``vmem_bytes`` a
     grid point (the scratch, and every block twice, as VMEM holds it:
@@ -478,8 +480,8 @@ def launch_account(s: int, chunk: int, heads: int, key_heads: int,
 
     out = {}
     for kind, backward in (("forward", False), ("backward", True)):
-        grid, ins, outs, scratch = launch_plan(1, s, chunk, heads,
-                                               key_heads, backward)
+        grid, ins, outs, scratch = plan(1, s, chunk, heads, key_heads,
+                                        backward)
         operands = [(blk, size or itemsize)
                     for _, blk, _, size in (*ins.values(), *outs.values())]
         points = math.prod(grid)

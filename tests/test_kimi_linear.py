@@ -175,23 +175,39 @@ def test_channel_decay_bfloat16_products_stay_near_the_recurrence():
         < 0.02 * float(jnp.abs(want).max())
 
 
-def test_channel_decay_never_runs_the_kernels(monkeypatch):
-    """The kernels take ``G`` as one row a head and chunk: a decay a
-    channel runs the XLA form at the sizes they tile, even when they are
-    asked for interpreted; ``g`` of another shape is refused."""
-    from chainermn_tpu.ops import gated_delta_kernels
+def test_channel_decay_runs_kernels_of_its_own(monkeypatch):
+    """The scalar rule's kernels take ``G`` as one row a head and chunk:
+    a decay a channel never reaches them; asked for interpreted at the
+    sizes that tile it runs its own (``ops/kda_kernels.py``), left to
+    itself off the TPU its XLA form; ``g`` of another shape is
+    refused."""
+    from chainermn_tpu.ops import gated_delta_kernels, kda_kernels
 
     def refuse(*args, **kw):
-        raise AssertionError("the kernels ran")
+        raise AssertionError("the scalar rule's kernels ran")
+
+    ran = []
+    sound = kda_kernels.kda_chunks
+
+    def counted(*args):
+        ran.append(args[3].shape)
+        return sound(*args)
 
     q, k, v, g, beta = _operands(256, b=1, hk=2, h=2, dk=128, dv=128)
     assert gated_delta._use_kernels(k, v, 64, jnp.float32, True)
+    assert gated_delta._use_kernels(k, v, 64, jnp.float32, True, True)
     monkeypatch.setattr(gated_delta_kernels, "gated_delta_chunks", refuse)
+    monkeypatch.setattr(kda_kernels, "kda_chunks", counted)
     jax.clear_caches()
-    got = gated_delta_scan(q, k, v, g, beta, chunk=64, dtype=jnp.float32,
-                           interpret=True)
-    np.testing.assert_allclose(got, recurrence(q, k, v, g, beta),
-                               atol=2e-6)
+    want = recurrence(q, k, v, g, beta)
+    np.testing.assert_allclose(
+        gated_delta_scan(q, k, v, g, beta, chunk=64, dtype=jnp.float32),
+        want, atol=2e-6)
+    assert not ran
+    np.testing.assert_allclose(
+        gated_delta_scan(q, k, v, g, beta, chunk=64, dtype=jnp.float32,
+                         interpret=True), want, atol=2e-6)
+    assert ran == [(1, 256, 2 * 128)]
     with pytest.raises(AssertionError, match="kernels ran"):
         gated_delta_scan(q, k, v, g[..., 0], beta, chunk=64,
                          dtype=jnp.float32, interpret=True)
@@ -210,7 +226,8 @@ def test_channel_decay_census_at_the_cells_shape_by_hand():
     # g: as many bytes as q, k, v and o together
     assert census["bytes_forward"] == 8192 * 32 * (4 * 128 * 2
                                                    + 4 * 128 + 4)
-    assert census["kernels"] is None
+    # the kernel path's account: tests/test_kda_kernels.py
+    assert census["kernels"]["forward"]["grid"] == (1, 32, 32)
     # 16 x 16 x 128 in each of 4 blocks, 4 + 1 factors of 64 x 128
     # between them, e^G and e^{G_C - G}: 23 x 64 x 128 a head and chunk
     assert census["exponentials"] == 128 * 32 * 64 * 128 * (16 + 4 + 3)

@@ -363,11 +363,17 @@ def test_block_causal_attention_compiles_at_key_width_192_value_width_128(
     assert pa._bc_geometry(qk, qk, 1, None, False, "fwd", None)[1] == 512
 
 
-def test_channel_decay_scan_compiles_at_the_cell_shape(one_chip):
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+def test_channel_decay_scan_compiles_at_the_cell_shape(one_chip, grad):
     """The channel-wise delta rule of ``kimilinear48b_train_s8192`` (two
     8192-token sequences, 32 heads of 128, chunk 64, ``g`` a float32 a
-    key channel), forward and backward: the XLA form, no kernel, for a
-    TPU too."""
+    key channel) through its Pallas kernels (``ops/kda_kernels.py``,
+    asked for with ``interpret=False`` as a TPU takes them by itself):
+    the forward launch alone, and with the gradient the forward that
+    keeps the entering states and each chunk's ``T`` and the backward
+    launch, each traced under the ``gdn_scan`` scope with no loop beside
+    them; the residuals (537 + 134 MB) and ``dg`` (268 MB) are the
+    temporaries, with the operands' copies into the kernels' layout."""
     from chainermn_tpu.ops.gated_delta import gated_delta_scan
 
     head = jax.ShapeDtypeStruct((2, 8192, 32, 128), jnp.bfloat16,
@@ -376,13 +382,18 @@ def test_channel_decay_scan_compiles_at_the_cell_shape(one_chip):
                              sharding=one_chip)
     beta = jax.ShapeDtypeStruct((2, 8192, 32), jnp.float32,
                                 sharding=one_chip)
-
-    def loss(q, k, v, g, beta):
-        return gated_delta_scan(q, k, v, g, beta).astype(jnp.float32).sum()
-
-    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
-                          head, head, head, g, beta)
-    assert "tpu_custom_call" not in text
+    scan = functools.partial(gated_delta_scan, interpret=False)
+    fn = jax.grad(lambda *a: scan(*a).astype(jnp.float32).sum(),
+                  argnums=range(5)) if grad else scan
+    compiled = jax.jit(fn).lower(head, head, head, g, beta).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r'op_name="([^"]*/(_kda_\w+)/pallas_call)"', text)
+    assert {name for _, name in kernels} == (
+        {"_kda_forward", "_kda_backward"} if grad else {"_kda_forward"})
+    assert all("/gdn_scan/" in op_name for op_name, _ in kernels)
+    assert not re.search(r'op_name="[^"]*gdn_scan[^"]*while', text)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        1.7e9 if grad else 0.7e9)
 
 
 def _scan_shapes(one_chip):
@@ -671,4 +682,117 @@ def test_qwen3next_step_under_the_examples_plan_compiles_for_the_chip(
     assert not [name for name in op_names
                 if "gdn_scan" in name and "while" in name]
     for scope in ("gdn_mixer", "gdn_conv", "gdn_scan", "moe_shared"):
+        assert scope in text, scope
+
+
+# ----------------------------------------------------------------------
+# kimilinear48b_train_s8192's whole step, as the MoE example builds it
+# ----------------------------------------------------------------------
+def test_kimilinear_step_under_the_examples_plan_compiles_for_the_chip(
+        moe_step_builder, monkeypatch):
+    """``kimilinear48b_train_s8192``'s step (``cellbench/configs/
+    kimi-linear-48b-a3b.json`` and ``cellbench/traffic/
+    train_kda_s8192.json`` through ``examples/moe_lm/train_moe_lm.py``'s
+    options) with what its blocks keep chosen as the example chooses it
+    on a v5e: with the channel-wise rule's kernels ``remat_widths`` holds
+    no ``KDA_WORK`` and the plan is ``mlp_in x1, kda_in x4, latent_in
+    x1`` (2.42 GB; with the XLA form nothing could be kept: ``PERF.md``
+    section 6, PR 43); the step compiles, arguments and temporaries
+    (15.41 GB counted ahead of time) stay 1 GB under the limit the chip
+    reports, the causal kernels at 192 / 128, the grouped products and
+    the delta rule's kernels are in it, every ``pallas_call`` of the
+    mixers lies under ``kda_scan`` and no ``while`` is left there."""
+    import json
+    import types
+
+    from chainermn_tpu.models.moe_transformer import RouterOptions
+    from chainermn_tpu.models.transformer import (
+        KDA_WORK,
+        BlockOptions,
+        remat_budget,
+        remat_kept,
+        remat_plan,
+    )
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "cellbench", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "cellbench", "traffic",
+                           "train_kda_s8192.json")) as f:
+        traffic = json.load(f)
+    lin = cfg["linear_attn_config"]
+    rows, seq = traffic["per_chip_batch"], traffic["seq_len"]
+    kinds = ("kda", "kda", "kda", "latent_attention")
+    options = BlockOptions(
+        norm="rmsnorm", norm_eps=cfg["rms_norm_eps"], layer_types=kinds,
+        gdn_value_heads=lin["num_heads"], gdn_key_dim=lin["head_dim"],
+        gdn_value_dim=lin["head_dim"],
+        gdn_conv=lin["short_conv_kernel_size"],
+        gdn_chunk=cfg["linear_chunk_size"],
+        latent_kv_rank=cfg["kv_lora_rank"],
+        latent_nope_dim=cfg["qk_nope_head_dim"],
+        latent_shared_dim=cfg["qk_rope_head_dim"],
+        latent_value_dim=cfg["v_head_dim"], gated_mlp=True,
+        no_positions=True, use_flash=True, remat_blocks=True)
+    n_layers, dense_layers = (cfg["num_hidden_layers"],
+                              cfg["first_k_dense_replace"])
+    sizes = dict(
+        vocab=cfg["vocab_size"], d_model=cfg["hidden_size"],
+        n_heads=cfg["num_attention_heads"], n_layers=n_layers,
+        d_ff=cfg["moe_intermediate_size"], n_experts=cfg["router_experts"],
+        top_k=cfg["num_experts_per_token"],
+        held=(cfg["first_expert"], cfg["num_experts"]),
+        shared_d_ff=cfg["moe_intermediate_size"]
+        * cfg["num_shared_experts"], seq_len=seq, per_chip_batch=rows,
+        chunked_ce=cfg["head_chunks"], lr=cfg["optimizer"]["lr"],
+        aux_coef=cfg["aux_loss_coef"],
+        router_options=RouterOptions(
+            score=cfg["moe_router_activation_func"], selection_bias=True,
+            routed_scale=cfg["routed_scaling_factor"], shared_gated=False),
+        first_dense=dense_layers, dense_d_ff=cfg["intermediate_size"])
+    tokens = rows * seq
+    widths_of = lambda: options.remat_widths(cfg["intermediate_size"],
+                                             cfg["num_attention_heads"])
+    # off the TPU the XLA form runs, and its reserve with it
+    assert KDA_WORK in widths_of()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    widths = widths_of()
+    assert widths == {"mlp_in": 18432, "kda_in": 12288, "latent_in": 6144}
+    _, state = moe_step_builder(options=options, **sizes)
+    budget = remat_budget(
+        types.SimpleNamespace(
+            memory_stats=lambda: {"bytes_limit": _V5E_BYTES_LIMIT}),
+        state[:2], tokens, widths)
+    options = dataclasses.replace(options, remat_budget_bytes=budget)
+    plan = remat_plan(
+        [options.layer_type(i) for i in range(n_layers)], tokens, widths,
+        budget, dense=[i < dense_layers for i in range(n_layers)])
+    said, kept_bytes = remat_kept(plan, tokens, widths)
+    assert said == "mlp_in x1, kda_in x4, latent_in x1"
+    assert kept_bytes == tokens * 2 * (18432 + 4 * 12288 + 6144)
+    step, abstract = moe_step_builder(options=options, **sizes)
+    compiled = step.get_jitted(*abstract[:2]).lower(*abstract).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(
+        602_450_816 * 12, rel=1e-3)
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert held + 1.0e9 <= _V5E_BYTES_LIMIT, (held, _V5E_BYTES_LIMIT)
+    assert memory.temp_size_in_bytes > kept_bytes  # kept for real
+    text = compiled.as_text()
+    for kernel in ("_bdflash_forward", "_bdflash_backward_dq",
+                   "_bdflash_backward_dkdv", "_grouped_matmul",
+                   "_grouped_matmul_dw", "_kda_forward", "_kda_backward"):
+        assert f"{kernel}/pallas_call" in text, kernel
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    delta_rule = [name for name in op_names if "kda_mixer" in name
+                  and name.endswith("/pallas_call")]
+    # four layers: a forward, its recomputation and a backward each
+    assert len(delta_rule) == 12
+    assert all("/kda_scan/" in name and "/gdn_scan/_kda_" in name
+               for name in delta_rule), delta_rule
+    assert not [name for name in op_names
+                if "kda_scan" in name and "while" in name]
+    for scope in ("kda_mixer", "kda_conv", "kda_scan", "latent_proj",
+                  "moe_shared", "gated_mlp"):
         assert scope in text, scope

@@ -812,10 +812,11 @@ class Mamba2Mixer(nn.Module):
         gain = f32("norm", nn.initializers.ones, (inner,))
 
         # named for the block's recomputation (an identity elsewhere)
-        z, xbc, dt = jnp.split(checkpoint_name(
-            dense(2 * inner + 2 * n + h, name="in_proj")(x), "ssm_in"),
-            [inner, 2 * inner + 2 * n], axis=-1)
-        xbc = nn.silu(causal_conv1d(xbc, taps, conv_bias))
+        proj = checkpoint_name(
+            dense(2 * inner + 2 * n + h, name="in_proj")(x), "ssm_in")
+        z, _, dt = jnp.split(proj, [inner, 2 * inner + 2 * n], axis=-1)
+        xbc = causal_conv1d(proj, taps, conv_bias, silu=True,
+                            first_column=inner)
         xs, B, C = jnp.split(xbc, [inner, inner + n], axis=-1)
         dt = jax.nn.softplus(dt.astype(jnp.float32) + step_bias)
         y = ssd_scan(xs.reshape(b, s, h, p), dt, rates, B, C, skip,
@@ -896,14 +897,16 @@ class GatedDeltaMixer(nn.Module):
         gain = f32("norm", nn.initializers.ones, (dv,))
 
         # named for the block's recomputation (an identity elsewhere)
-        qkv, z = jnp.split(checkpoint_name(
-            dense(2 * keys + 2 * values, name="in_proj_qkvz")(x),
-            "gdn_in"), [2 * keys + values], axis=-1)
+        proj = checkpoint_name(
+            dense(2 * keys + 2 * values, name="in_proj_qkvz")(x), "gdn_in")
+        z = proj[..., 2 * keys + values:]
         write, step = jnp.split(
             dense(2 * hv, name="in_proj_ba")(x).astype(jnp.float32),
             2, axis=-1)
         q, k, v = jnp.split(
-            nn.silu(causal_conv1d(qkv, taps, scope=GDN_CONV_SCOPE)),
+            # q | k | v where they lie in the projection's q | k | v | z
+            causal_conv1d(proj, taps, scope=GDN_CONV_SCOPE, silu=True,
+                          first_column=0),
             [keys, 2 * keys], axis=-1)
         # float32 inside, recomputed in the backward pass
         unit = jax.checkpoint(_unit_heads, static_argnums=(1, 2))
@@ -978,7 +981,7 @@ class KdaMixer(nn.Module):
         qkv = checkpoint_name(
             dense(2 * keys + values, name="in_proj_qkv")(x), "kda_in")
         q, k, v = jnp.split(
-            nn.silu(causal_conv1d(qkv, taps, scope=KDA_CONV_SCOPE)),
+            causal_conv1d(qkv, taps, scope=KDA_CONV_SCOPE, silu=True),
             [keys, 2 * keys], axis=-1)
         step = dense(keys, name="f_b_proj")(dense(dk, name="f_a_proj")(x))
         write = dense(h, name="b_proj")(x)

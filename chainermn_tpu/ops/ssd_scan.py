@@ -1,6 +1,12 @@
 """Chunked state-space scan (Mamba-2's SSD form) and the causal
 depthwise convolution in front of it: the scan in two forms, Pallas
-kernels on a TPU and plain XLA everywhere else.
+kernels on a TPU and plain XLA everywhere else; the convolution's
+forward one XLA form everywhere, and the gradient of its activated form
+(:func:`causal_conv1d` with ``silu=True``, which every mixer calls) one
+Pallas pass over ``x`` and ``dy`` on a TPU (:mod:`.conv_kernels`, PR 45:
+bfloat16 ``x``, channels and first column multiples of 128, a length
+that is a multiple of 16, at most 8 taps) and autodiff of the XLA form
+everywhere else (:func:`_use_conv_kernel`).
 
 A Mamba-2 head carries a state ``S (p, n)`` over the positions of a
 sequence (``p`` the head's width, ``n`` the state size), driven by a
@@ -74,8 +80,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import ssd_kernels
-from .grouped_matmul import vary_alike
+from . import conv_kernels, ssd_kernels
+from .grouped_matmul import sum_to_vma, vary_alike
 
 #: chunks whose ``(heads, chunk, chunk)`` decay tensors are live at once
 #: (on the chip 8 read 468.1 ms a step of the cell against 470.0 at 4:
@@ -87,7 +93,72 @@ SSM_CONV_SCOPE = "ssm_conv"
 SSM_SCAN_SCOPE = "ssm_scan"
 
 
-def causal_conv1d(x, taps, bias=None, scope: str = SSM_CONV_SCOPE):
+def _conv_xla(x, taps, bias, silu):
+    """:func:`causal_conv1d`'s XLA form: float32 sums of ``k`` shifted
+    copies, rounded to ``x``'s dtype, then the activation."""
+    k, s = taps.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(padded[:, j:j + s].astype(jnp.float32)
+            * taps[j].astype(jnp.float32) for j in range(k))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    y = y.astype(x.dtype)
+    return jax.nn.silu(y) if silu else y
+
+
+def _columns(x, taps, start):
+    """Columns ``start .. start + c`` of ``x``, ``c`` the taps'.  (Cut
+    out of each shifted copy instead, XLA:TPU reads the range in place:
+    4.9 ms a step less in one cell, 5.0 more in another; ``PERF.md``
+    section 6, PR 45.)"""
+    return x[..., start:start + taps.shape[1]]
+
+
+def _use_conv_kernel(x, taps, start, interpret) -> bool:
+    """Whether the gradient of the activated convolution runs the Pallas
+    kernel: the sizes tile (:func:`conv_kernels.tiles`) and either the
+    kernel is asked for (``interpret`` given), or this is a TPU and
+    ``x`` is bfloat16 (what the tile's VMEM is sized for)."""
+    if not conv_kernels.tiles(x.shape[1], taps.shape[1], start,
+                              taps.shape[0]):
+        return False
+    if interpret is not None:
+        return True
+    return jax.default_backend() == "tpu" and x.dtype == jnp.bfloat16
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _conv_silu(x, taps, bias, start, interpret):
+    """``SiLU(conv(x[..., start:start + c]))`` with the kernel for its
+    gradient; the forward is the XLA form, as everywhere."""
+    return _conv_xla(_columns(x, taps, start), taps, bias, True)
+
+
+def _conv_silu_fwd(x, taps, bias, start, interpret):
+    # nothing float32 and nothing the size of x but x, which the blocks
+    # keep already
+    return (_conv_xla(_columns(x, taps, start), taps, bias, True),
+            (x, taps, bias))
+
+
+def _conv_silu_bwd(start, interpret, residuals, dy):
+    x, taps, bias = residuals
+    dx, dtaps, dbias = conv_kernels.conv_backward(
+        x, dy, taps, bias, start, interpret)
+    after = x.shape[-1] - start - taps.shape[1]
+    if start or after:  # what autodiff makes of the column slice
+        dx = jnp.pad(dx, ((0, 0), (0, 0), (start, after)))
+    return (dx, sum_to_vma(dtaps, taps).astype(taps.dtype),
+            None if bias is None
+            else sum_to_vma(dbias, bias).astype(bias.dtype))
+
+
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def causal_conv1d(x, taps, bias=None, scope: str = SSM_CONV_SCOPE,
+                  silu: bool = False, first_column: int = 0,
+                  interpret: Optional[bool] = None):
     """Depthwise causal convolution over the sequence: ``x (b, s, c)``,
     ``taps (k, c)``, ``bias (c,)``;
 
@@ -95,17 +166,32 @@ def causal_conv1d(x, taps, bias=None, scope: str = SSM_CONV_SCOPE):
 
     with zeros before the sequence (``taps[k - 1]`` meets ``x_t``: a
     ``torch.nn.Conv1d(c, c, k, groups=c, padding=k - 1)`` cut to ``s``).
-    Float32 sums of ``k`` shifted copies, the result in ``x``'s dtype.
-    ``scope``: the device scope it is traced under (a mixer of another
-    kind gives its own)."""
-    k, s = taps.shape[0], x.shape[1]
+    Float32 sums of ``k`` shifted copies, the result in ``x``'s dtype;
+    ``silu``: ``SiLU`` of that result, as every mixer wants it.  ``x``
+    may be wider than the taps: columns ``first_column .. first_column +
+    c`` of it are convolved (a mixer hands its in-projection's whole
+    result, so that the gradient's kernel reads the range where it
+    lies).  ``scope``: the device scope it is traced under (a mixer of
+    another kind gives its own).
+
+    The forward is one XLA form everywhere.  **Which gradient runs** is
+    read off the input and the platform (:func:`_use_conv_kernel`): with
+    the activation inside, on a TPU, for a bfloat16 ``x`` whose sizes
+    tile (channels and ``first_column`` multiples of 128, a length that is a
+    multiple of 16, at most 8 taps), the backward is one pass of
+    :mod:`.conv_kernels` over ``x`` and ``dy`` under a
+    ``jax.custom_vjp`` whose residuals are the operands; every other
+    call -- off the TPU, float32 operands, sizes that do not tile, no
+    activation -- is differentiated by autodiff as it was.
+    ``interpret=True`` runs the kernel interpreted wherever the sizes
+    tile, in either precision (the unit tests do)."""
+    if first_column + taps.shape[1] > x.shape[-1]:
+        raise ValueError(f"{taps.shape[1]} taps from column {first_column} "
+                         f"of {x.shape[-1]}")
     with jax.named_scope(scope):
-        padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-        y = sum(padded[:, j:j + s].astype(jnp.float32)
-                * taps[j].astype(jnp.float32) for j in range(k))
-        if bias is not None:
-            y = y + bias.astype(jnp.float32)
-        return y.astype(x.dtype)
+        if silu and _use_conv_kernel(x, taps, first_column, interpret):
+            return _conv_silu(x, taps, bias, first_column, bool(interpret))
+        return _conv_xla(_columns(x, taps, first_column), taps, bias, silu)
 
 
 def _dot(spec, a, b, dtype):

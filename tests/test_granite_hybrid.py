@@ -183,6 +183,16 @@ def test_causal_convolution_is_the_shifted_sum_and_xlas_grouped_one():
     # causal: a later position changes no earlier result
     moved = ssd.causal_conv1d(x.at[:, 20].add(1.0), taps, bias)
     np.testing.assert_array_equal(moved[:, :20], got[:, :20])
+    # with the activation inside (PR 45), and over a column range of a
+    # wider x: the same values, bit for bit
+    np.testing.assert_array_equal(
+        ssd.causal_conv1d(x, taps, bias, silu=True), jax.nn.silu(got))
+    wide = jnp.concatenate([x[..., :5] + 1.0, x, x[..., :3] - 1.0], axis=-1)
+    np.testing.assert_array_equal(
+        ssd.causal_conv1d(wide, taps, bias, silu=True, first_column=5),
+        jax.nn.silu(got))
+    np.testing.assert_array_equal(
+        ssd.causal_conv1d(wide, taps, bias, first_column=5), got)
 
 
 # -- the kernels at head width 64 --------------------------------------------

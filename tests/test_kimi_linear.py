@@ -751,7 +751,8 @@ def test_the_cells_files_say_what_the_issue_asked_for():
     assert traffic["seq_len"] == 8192
     mine = [m for m in bench["per_layer"]
             if m["name"].endswith(".kimilinear")]
-    assert len(mine) == 16 and all(
+    # PR 43's sixteen and the convolution's time (PR 45)
+    assert len(mine) == 17 and all(
         m["workloads"] == ["kimilinear48b_train_s8192"] for m in mine)
     for m in mine:
         assert os.path.exists(os.path.join(

@@ -396,6 +396,46 @@ def test_channel_decay_scan_compiles_at_the_cell_shape(one_chip, grad):
         1.7e9 if grad else 0.7e9)
 
 
+@pytest.mark.parametrize("shape", [
+    # (b, s, channels, first column, columns of x, bias): a layer of
+    # kimilinear48b, of qwen3next80b (q | k | v of the in-projection's q
+    # | k | v | z) and of granite4hmicro (xBC of z | xBC | dt)
+    pytest.param((2, 8192, 12288, 0, 12288, False), id="kimilinear48b"),
+    pytest.param((2, 8192, 8192, 0, 12288, False), id="qwen3next80b"),
+    pytest.param((1, 8192, 4352, 4096, 8512, True), id="granite4hmicro"),
+])
+def test_convolution_backward_compiles_at_the_cells_shapes(one_chip, shape):
+    """The gradient of ``SiLU(causal_conv1d(...))`` through its Pallas
+    kernel (``ops/conv_kernels.py``, asked for with ``interpret=False``
+    as a TPU takes it by itself): one ``tpu_custom_call`` traced under
+    the caller's scope that reads ``x``'s column range where it lies,
+    nothing of autodiff's form left beside it (its pads of the four
+    taps' cotangents to ``s + 3`` rows and the forward's padded ``x``:
+    the forward is not computed for a gradient), and no temporary the
+    size of ``x`` but ``dx`` and the cotangent handed in."""
+    from chainermn_tpu.ops.ssd_scan import causal_conv1d
+
+    b, s, c, start, total, has_bias = shape
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    args = (sd((b, s, total), jnp.bfloat16), sd((4, c), jnp.float32)) \
+        + ((sd((c,), jnp.float32),) if has_bias else ())
+    conv = functools.partial(causal_conv1d, scope="a_mixers_conv",
+                             silu=True, first_column=start, interpret=False)
+    compiled = jax.jit(jax.grad(
+        lambda *a: conv(*a).astype(jnp.float32).sum(),
+        argnums=range(len(args)))).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    kernel, = set(re.findall(r'op_name="([^"]*/pallas_call)"', text))
+    # (outermost, the scope is wrapped: "transpose(jvp(a_mixers_conv))")
+    assert re.search(r"a_mixers_conv\)*/_conv_backward/pallas_call$", kernel)
+    assert f"[{b},{s + 3}," not in text
+    assert [x.shape for x in compiled.out_info] == [a.shape for a in args]
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2.1 * b * s * c * 2
+
+
 def _scan_shapes(one_chip):
     sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                    sharding=one_chip)
@@ -574,7 +614,8 @@ def test_hybrid_step_under_the_examples_plan_fits_the_chip(
     # kept for real: the temporaries hold them
     assert memory.temp_size_in_bytes > kept_bytes
     text = compiled.as_text()
-    for kernel in ("_ssd_forward", "_ssd_backward", "_bdflash_forward"):
+    for kernel in ("_ssd_forward", "_ssd_backward", "_bdflash_forward",
+                   "ssm_conv/_conv_backward"):
         assert f"{kernel}/pallas_call" in text, kernel
     if rows == 1:  # one forward in_proj product a layer, each kept
         for width, layers in ((16384, 10), (8512, 9)):
@@ -674,9 +715,14 @@ def test_qwen3next_step_under_the_examples_plan_compiles_for_the_chip(
                    "_grouped_matmul_dw", "_gdn_forward", "_gdn_backward"):
         assert f"{kernel}/pallas_call" in text, kernel
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
-    under_mixer = [name for name in op_names if "gdn_mixer" in name]
-    delta_rule = [name for name in under_mixer if name.endswith(
-        "/pallas_call")]
+    kernels = [name for name in op_names if "gdn_mixer" in name
+               and name.endswith("/pallas_call")]
+    # three layers: the convolution's backward (PR 45) under its scope
+    conv = [name for name in kernels if "/gdn_conv/" in name]
+    assert len(conv) == 3 and all(
+        name.endswith("/gdn_conv/_conv_backward/pallas_call")
+        for name in conv), conv
+    delta_rule = sorted(set(kernels) - set(conv))
     assert delta_rule and all("/gdn_scan/_gdn_" in name
                               for name in delta_rule), delta_rule
     assert not [name for name in op_names
@@ -785,9 +831,15 @@ def test_kimilinear_step_under_the_examples_plan_compiles_for_the_chip(
                    "_grouped_matmul_dw", "_kda_forward", "_kda_backward"):
         assert f"{kernel}/pallas_call" in text, kernel
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
-    delta_rule = [name for name in op_names if "kda_mixer" in name
-                  and name.endswith("/pallas_call")]
-    # four layers: a forward, its recomputation and a backward each
+    kernels = [name for name in op_names if "kda_mixer" in name
+               and name.endswith("/pallas_call")]
+    # four layers: the convolution's backward (PR 45) under its scope,
+    conv = [name for name in kernels if "/kda_conv/" in name]
+    assert len(conv) == 4 and all(
+        name.endswith("/kda_conv/_conv_backward/pallas_call")
+        for name in conv), conv
+    # and a forward, its recomputation and a backward of the rule each
+    delta_rule = sorted(set(kernels) - set(conv))
     assert len(delta_rule) == 12
     assert all("/kda_scan/" in name and "/gdn_scan/_kda_" in name
                for name in delta_rule), delta_rule

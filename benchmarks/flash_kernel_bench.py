@@ -92,10 +92,9 @@ def main(argv=None):
 
     def step(q, k, v, g):
         out, lse = pa._flash_forward(q, k, v, True, scale, None, None,
-                                     False, "split")
+                                     False)
         return (out,) + pa._flash_backward(q, k, v, out, lse, g, True,
-                                           scale, None, None, False,
-                                           "split")
+                                           scale, None, None, False)
 
     shipped = dict(pa._COMPUTE_TILE)
     for name in a.variants:

@@ -25,8 +25,7 @@ grid point is classified dead / interior / masked, and interior blocks
 (the fully-unmasked majority at long sequence) run a fast branch with
 no iota/mask/select work — see the "Block taxonomy" section below.
 A masked block that the diagonal crosses squarely is computed in tiles,
-the ones above the diagonal skipped ("Compute tile" below).  The
-pre-split kernels are kept under ``taxonomy="legacy"`` as the reference.
+the ones above the diagonal skipped ("Compute tile" below).
 """
 
 from __future__ import annotations
@@ -146,24 +145,6 @@ def _clamp_blocks_for_dim(block_q, block_k, d: int, warn: bool = True,
 #             blocks (k or q padding) — the only blocks that pay the
 #             masked online-softmax path.  Per q row this is ~1/q_blocks
 #             of the live work at square geometry.
-#
-# ``taxonomy`` selects the kernel family:
-#   "split"    (default) classify at run time, route interior blocks
-#              down the fast branch — numerically EXACT vs "legacy"
-#              (the mask it skips is provably all-true there).
-#   "legacy"   the pre-split kernels, kept verbatim as the in-tree
-#              reference: every live block runs the masked path.
-_TAXONOMIES = ("split", "legacy")
-
-
-def _resolve_taxonomy(taxonomy):
-    t = "split" if taxonomy is None else taxonomy
-    if t not in _TAXONOMIES:
-        raise ValueError(
-            f"taxonomy must be one of {_TAXONOMIES} (or None), got "
-            f"{taxonomy!r}"
-        )
-    return t
 
 
 def _when(pred):
@@ -199,8 +180,8 @@ def _block_class(first_q, first_k, *, s_k, s_kp, causal, block_q, block_k,
     cannot drift from what the kernels execute.
 
     The forward leaves ``s_q``/``s_qp`` unset: it never masks q
-    (padded q rows are garbage the caller slices off — same contract
-    as legacy).  The backward kernels pass them, so a ragged q tail
+    (padded q rows are garbage the caller slices off).  The backward
+    kernels pass them, so a ragged q tail
     reclassifies its whole block row as masked (its recomputed p would
     otherwise contribute to dk/dv and its garbage lse to dq).  Each
     tail predicate is emitted only when the corresponding padding
@@ -397,101 +378,23 @@ def launch_census(s_q: int, s_k: int, d: int, block_q=None, block_k=None,
 # ----------------------------------------------------------------------
 # Flash attention — forward kernel
 # ----------------------------------------------------------------------
-def _flash_fwd_kernel_legacy(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
-                             m_ref, l_ref, *, s_k: int, causal: bool,
-                             scale: float, block_q: int, block_k: int):
-    """The PRE-SPLIT forward kernel, kept verbatim (``taxonomy="legacy"``)
-    as the numerics/timing reference for the diagonal split: every live
-    block pays the iota/mask/select online-softmax path.
-
-    Grid (batch*head, q_blocks, k_blocks); the k dimension is innermost
-    and sequential on TPU, so the fp32 accumulator / running max /
-    denominator live in VMEM scratch across k steps.  K/V residency is one
-    (block_k, d) tile per step."""
-    j = pl.program_id(1)
-    kb = pl.program_id(2)
-    n_kb = pl.num_programs(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    # Causal: a k block strictly above the diagonal contributes nothing —
-    # skip its matmuls entirely (static predicate per (j, kb) pair).
-    first_q = j * block_q
-    first_k = kb * block_k
-    live = (first_k <= first_q + block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _attend():
-        q = q_ref[0].astype(jnp.float32) * scale  # (bq, d)
-        k_blk = k_ref[0].astype(jnp.float32)      # (bk, d)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (bq, bk)
-        q_idx = first_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_idx = first_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = k_idx < s_k  # padded keys never contribute
-        if causal:
-            mask = mask & (k_idx <= q_idx)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_old = m_ref[:, 0:1]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_old - m_new)
-        l_new = alpha * l_ref[:, 0:1] + jnp.sum(p, axis=-1, keepdims=True)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        acc_ref[:] = alpha * acc_ref[:] + lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    @pl.when(kb == n_kb - 1)
-    def _finalize():
-        # Fully-masked rows (query padding) have l == 0.
-        o_ref[0] = (
-            acc_ref[:] / jnp.maximum(l_ref[:, 0:1], 1e-30)
-        ).astype(o_ref.dtype)
-        # Per-row log-sum-exp of the (scaled) scores — the backward's
-        # softmax statistic.  Stored broadcast over 8 sublanes because a
-        # TPU block's second-to-last dim must be a multiple of 8.
-        # Garbage on padded rows; the backward masks those by q index.
-        lse_ref[0] = jnp.broadcast_to(
-            (m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30)))[
-                None, :
-            ],
-            lse_ref.shape[1:],
-        )
-
-
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                       l_ref, *, s_k: int, s_kp: int, causal: bool,
                       scale: float, block_q: int, block_k: int, tile=None):
-    """Diagonal-split forward kernel (``taxonomy="split"``).
+    """Diagonal-split forward kernel.
 
-    Same grid/scratch contract as the legacy kernel; each (j, kb) grid
-    point routes to one of the taxonomy branches (see module section
-    "Block taxonomy").  The interior branch carries no iota/mask/select,
-    and the first k step (kb == 0, always live) writes the running
-    state directly instead of rescaling an empty accumulator — with
-    m_old = -inf the rescale factor exp(m_old - m_new) is exactly 0 in
-    fp32, so skipping it is bit-identical, and it removes the separate
-    init pass plus one (bq, d) multiply-add per q row.
-
-    Exactness vs legacy: on an interior block the legacy mask is
-    provably all-true, so ``where(mask, s, -inf)`` is the identity and
-    both branches compute the same fp32 expression tree
-    (``test_split_matches_legacy_exactly``).
+    Grid (batch*head, q_blocks, k_blocks); the k dimension is innermost
+    and sequential on TPU, so the fp32 accumulator / running max /
+    denominator live in VMEM scratch across k steps.  K/V residency is
+    one (block_k, d) tile per step.  Each (j, kb) grid point routes to
+    one of the taxonomy branches (see module section "Block taxonomy").
+    The interior branch carries no iota/mask/select (the mask is
+    provably all-true there, so ``where(mask, s, -inf)`` would be the
+    identity), and the first k step (kb == 0, always live) writes the
+    running state directly instead of rescaling an empty accumulator —
+    with m_old = -inf the rescale factor exp(m_old - m_new) is exactly
+    0 in fp32, so skipping it is bit-identical, and it removes the
+    separate init pass plus one (bq, d) multiply-add per q row.
 
     ``tile`` (:func:`_compute_tile`): a masked block is then an aligned
     diagonal block and runs strip by strip (:func:`_strips`): each q
@@ -572,9 +475,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 
     @pl.when(kb == n_kb - 1)
     def _finalize():
+        # Fully-masked rows (query padding) have l == 0.
         o_ref[0] = (
             acc_ref[:] / jnp.maximum(l_ref[:, 0:1], 1e-30)
         ).astype(o_ref.dtype)
+        # Per-row log-sum-exp of the (scaled) scores — the backward's
+        # softmax statistic.  Stored broadcast over 8 sublanes because a
+        # TPU block's second-to-last dim must be a multiple of 8.
+        # Garbage on padded rows; the backward masks those by q index.
         lse_ref[0] = jnp.broadcast_to(
             (m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30)))[
                 None, :
@@ -586,10 +494,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "scale", "block_q", "block_k", "interpret",
-                     "taxonomy", "tile"),
+                     "tile"),
 )
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
-                   taxonomy="split", tile=None):
+                   tile=None):
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
     block_q, block_k = _clamp_blocks_for_dim(block_q, block_k, d)
@@ -608,22 +516,15 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
     kb_, vb = to_bh(k, s_k, bk), to_bh(v, s_k, bk)
     s_qp, s_kp = qb.shape[1], kb_.shape[1]
 
-    if taxonomy == "legacy":
-        kernel = functools.partial(
-            _flash_fwd_kernel_legacy, s_k=s_k, causal=causal,
-            scale=scale, block_q=bq, block_k=bk,
-        )
-    else:
-        kernel = functools.partial(
-            _flash_fwd_kernel, s_k=s_k, s_kp=s_kp, causal=causal,
-            scale=scale, block_q=bq, block_k=bk,
-            tile=_compute_tile(
-                bq, bk, "fwd", causal=causal, aligned=s_k == s_kp,
-                tile=tile,
-            ),
-        )
+    kernel = functools.partial(
+        _flash_fwd_kernel, s_k=s_k, s_kp=s_kp, causal=causal,
+        scale=scale, block_q=bq, block_k=bk,
+        tile=_compute_tile(
+            bq, bk, "fwd", causal=causal, aligned=s_k == s_kp, tile=tile,
+        ),
+    )
     grid = (b * h, s_qp // bq, s_kp // bk)
-    kv_index = _kv_index(bq, bk, causal and taxonomy != "legacy")
+    kv_index = _kv_index(bq, bk, causal)
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
@@ -655,71 +556,13 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret,
 # ----------------------------------------------------------------------
 # Flash attention — backward kernels (FlashAttention-2 shape)
 # ----------------------------------------------------------------------
-def _flash_bwd_dq_kernel_legacy(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                delta_ref, dq_ref, dq_acc, *, s_q: int,
-                                s_k: int, causal: bool, scale: float,
-                                block_q: int, block_k: int):
-    """Pre-split dq kernel (``taxonomy="legacy"`` reference).
-
-    Grid (batch*head, q_blocks, k_blocks); k innermost/sequential.
-    Recomputes the (bq, bk) probability tile from q, k and the saved
-    row log-sum-exp, accumulates dq in VMEM."""
-    j = pl.program_id(1)
-    kb = pl.program_id(2)
-    n_kb = pl.num_programs(2)
-
-    @pl.when(kb == 0)
-    def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    first_q = j * block_q
-    first_k = kb * block_k
-    live = (first_k <= first_q + block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _accum():
-        q = q_ref[0].astype(jnp.float32)
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        q_idx = first_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_idx = first_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = (k_idx < s_k) & (q_idx < s_q)
-        if causal:
-            mask = mask & (k_idx <= q_idx)
-        # p from the saved statistic; explicit zeroing (padded rows carry
-        # garbage lse, so exp(s - lse) alone is not safe there)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
-        dp = lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        dq_acc[:] += lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-
-    @pl.when(kb == n_kb - 1)
-    def _finalize():
-        dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
-
-
 def _tail_mask(first_q, first_k, *, s_k, s_kp, causal, block_q, block_k,
                s_q=None, s_qp=None):
     """THE masked-branch mask, statically thinned: each padding compare
     exists only when that padding exists (s < s_padded, static), so an
     aligned causal launch's diagonal blocks pay only the causal
     compare.  Dropped compares are provably all-true there, so the
-    thinning is bit-identical to the legacy full mask.  Same
+    thinning is bit-identical to the full mask.  Same
     ``s_q``/``s_qp`` convention as :func:`_block_class`: the forward
     leaves them unset (it never masks q), the backward passes them."""
     mask_q = s_q is not None and s_q < s_qp
@@ -772,13 +615,15 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_acc, *, s_q: int, s_qp: int,
                          s_k: int, s_kp: int, causal: bool, scale: float,
                          block_q: int, block_k: int, tile=None):
-    """Diagonal-split dq kernel: interior blocks recompute p straight
-    from the saved log-sum-exp with no iota/mask/select work; only the
-    diagonal/tail blocks pay the masked path.  Same grid and numerics
-    as the legacy kernel (on interior blocks the legacy mask is all-
-    true, so ``where(mask, p, 0)`` is the identity).  With a compute
-    ``tile`` a masked block runs q strip by q strip against its live
-    keys only (:func:`_strips`)."""
+    """Diagonal-split dq kernel (grid (batch*head, q_blocks, k_blocks);
+    k innermost/sequential, dq accumulated in VMEM): interior blocks
+    recompute the (bq, bk) probability tile straight from q, k and the
+    saved row log-sum-exp with no iota/mask/select work (the mask is
+    all-true there, so ``where(mask, p, 0)`` would be the identity);
+    only the diagonal/tail blocks pay the masked path, whose explicit
+    zeroing is needed because padded rows carry garbage lse.  With a
+    compute ``tile`` a masked block runs q strip by q strip against its
+    live keys only (:func:`_strips`)."""
     j = pl.program_id(1)
     kb = pl.program_id(2)
     n_kb = pl.num_programs(2)
@@ -831,68 +676,6 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(kb == n_kb - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel_legacy(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                                 delta_ref, dk_ref, dv_ref, dk_acc,
-                                 dv_acc, *, s_q: int, s_k: int,
-                                 causal: bool, scale: float,
-                                 block_q: int, block_k: int):
-    """Pre-split dk/dv kernel (``taxonomy="legacy"`` reference).
-
-    Grid (batch*head, k_blocks, q_blocks); q innermost/sequential.
-    Accumulates dk and dv for one key block across all query blocks."""
-    kb = pl.program_id(1)
-    j = pl.program_id(2)
-    n_j = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    first_q = j * block_q
-    first_k = kb * block_k
-    live = (first_q + block_q - 1 >= first_k) if causal else True
-
-    @pl.when(live)
-    def _accum():
-        q = q_ref[0].astype(jnp.float32)
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        q_idx = first_q + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_idx = first_k + lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        mask = (k_idx < s_k) & (q_idx < s_q)
-        if causal:
-            mask = mask & (k_idx <= q_idx)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
-        dv_acc[:] += lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[0, 0][:, None])
-        dk_acc[:] += lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-
-    @pl.when(j == n_j - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -966,10 +749,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "scale", "block_q", "block_k", "interpret",
-                     "taxonomy", "tile"),
+                     "tile"),
 )
 def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
-                    interpret, taxonomy="split", g_lse=None, tile=None):
+                    interpret, g_lse=None, tile=None):
     """(b, s, h, d)-layout backward via the two kernels above.
 
     ``g_lse``: optional (b*h, s_q) cotangent of the log-sum-exp output
@@ -1013,23 +796,16 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     lse_p = jnp.broadcast_to(lse_p[:, None], (bh, 8, s_qp))
 
     n_q, n_k = s_qp // bq, s_kp // bk
-    if taxonomy == "legacy":
-        dq_kernel, dkv_kernel = (_flash_bwd_dq_kernel_legacy,
-                                 _flash_bwd_dkv_kernel_legacy)
-        kwargs = dict(s_q=s_q, s_k=s_k, causal=causal, scale=scale,
-                      block_q=bq, block_k=bk)
-    else:
-        dq_kernel, dkv_kernel = _flash_bwd_dq_kernel, _flash_bwd_dkv_kernel
-        kwargs = dict(s_q=s_q, s_qp=s_qp, s_k=s_k, s_kp=s_kp,
-                      causal=causal, scale=scale, block_q=bq, block_k=bk,
-                      tile=_compute_tile(
-                          bq, bk, "bwd", causal=causal, tile=tile,
-                          aligned=s_k == s_kp and s_q == s_qp,
-                      ))
+    kwargs = dict(s_q=s_q, s_qp=s_qp, s_k=s_k, s_kp=s_kp,
+                  causal=causal, scale=scale, block_q=bq, block_k=bk,
+                  tile=_compute_tile(
+                      bq, bk, "bwd", causal=causal, tile=tile,
+                      aligned=s_k == s_kp and s_q == s_qp,
+                  ))
 
-    kv_index = _kv_index(bq, bk, causal and taxonomy != "legacy")
+    kv_index = _kv_index(bq, bk, causal)
     dq = pl.pallas_call(
-        functools.partial(dq_kernel, **kwargs),
+        functools.partial(_flash_bwd_dq_kernel, **kwargs),
         out_shape=_out_struct((b * h, s_qp, d), q.dtype, q, k, v, g),
         grid=(b * h, n_q, n_k),
         in_specs=[
@@ -1047,7 +823,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
     )(qb, kb_, vb, dob, lse_p, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(dkv_kernel, **kwargs),
+        functools.partial(_flash_bwd_dkv_kernel, **kwargs),
         out_shape=[
             _out_struct((b * h, s_kp, d), k.dtype, q, k, v, g),
             _out_struct((b * h, s_kp, d), v.dtype, q, k, v, g),
@@ -1083,10 +859,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, scale, block_q, block_k,
 # Public API
 # ----------------------------------------------------------------------
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None, interpret=None,
-                    bwd_block_q=None, bwd_block_k=None, taxonomy=None):
+                    bwd_block_q=None, bwd_block_k=None):
     """Blocked flash attention: (b, s, h, d) x 3 -> (b, s, h, d).
 
     Numerics match :func:`chainermn_tpu.ops.multi_head_attention` (fp32
@@ -1110,12 +886,6 @@ def flash_attention(q, k, v, causal=False, scale=None,
     1024x1024 (compiles for v5e: ``tests/test_tpu_compile.py``; not
     timed on this installation).
 
-    ``taxonomy``: block-classification mode (``None`` = ``"split"``,
-    the diagonal-split kernels).  ``"legacy"`` runs the pre-split
-    kernels (every live block masked — the in-tree A/B reference).
-    Split and legacy are bit-identical wherever the compute tile does
-    not engage (``test_split_matches_legacy_exactly``).
-
     Compute tile (no argument: :func:`_compute_tile` resolves it from
     the launch's shapes): a causal launch whose diagonal blocks are
     square, aligned and at least two tiles wide computes them in q
@@ -1133,8 +903,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     if scale is None:
         scale = q.shape[-1] ** -0.5
     out, _ = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                            _should_interpret(interpret),
-                            _resolve_taxonomy(taxonomy))
+                            _should_interpret(interpret))
     return out
 
 
@@ -1164,8 +933,7 @@ def _shrink_blocks(bq: int, bk: int):
 _bwd_probe_cache: dict = {}
 
 
-def _bwd_compile_blocked(arrays, causal, scale, bq, bk,
-                         taxonomy="split") -> bool:
+def _bwd_compile_blocked(arrays, causal, scale, bq, bk) -> bool:
     """AOT-compile probe: does the backward at this geometry compile on
     the real backend?  Needed because the production path wraps the step
     in an outer ``jax.jit`` — there the Mosaic compile error would
@@ -1178,7 +946,7 @@ def _bwd_compile_blocked(arrays, causal, scale, bq, bk,
     that would have run."""
     key = (
         tuple((tuple(a.shape), str(a.dtype)) for a in arrays),
-        causal, scale, bq, bk, taxonomy,
+        causal, scale, bq, bk,
     )
     if key in _bwd_probe_cache:
         return _bwd_probe_cache[key]
@@ -1186,7 +954,7 @@ def _bwd_compile_blocked(arrays, causal, scale, bq, bk,
     try:
         sds = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrays]
         _flash_backward.lower(
-            *sds, causal, scale, bq, bk, False, taxonomy
+            *sds, causal, scale, bq, bk, False
         ).compile()
     except Exception as e:
         blocked = _is_vmem_oom(e)
@@ -1195,8 +963,7 @@ def _bwd_compile_blocked(arrays, causal, scale, bq, bk,
 
 
 def _backward_with_vmem_retry(q, k, v, out, lse, g, causal, scale,
-                              block_q, block_k, interp, g_lse=None,
-                              taxonomy="split"):
+                              block_q, block_k, interp, g_lse=None):
     """Run the backward kernels; on a scoped-VMEM compile failure retry
     with progressively ceil-shrunk block geometry (ADVICE round-5: the
     d<=256 clamp boundary was measured on v5e only — other generations
@@ -1222,15 +989,14 @@ def _backward_with_vmem_retry(q, k, v, out, lse, g, causal, scale,
         tried.add(eff)
         try:
             if probe and _bwd_compile_blocked(
-                (q, k, v, out, lse, g), causal, scale, bq, bk, taxonomy
+                (q, k, v, out, lse, g), causal, scale, bq, bk
             ):
                 raise RuntimeError(
                     f"scoped vmem limit exceeded at {eff[0]}x{eff[1]} "
                     "(AOT compile probe)"
                 )
             return _flash_backward(q, k, v, out, lse, g, causal, scale,
-                                   bq, bk, interp, taxonomy=taxonomy,
-                                   g_lse=g_lse)
+                                   bq, bk, interp, g_lse=g_lse)
         except Exception as e:
             if not _is_vmem_oom(e):
                 raise
@@ -1274,17 +1040,16 @@ def _resolve_bwd_blocks(block_q, block_k, bwd_block_q, bwd_block_k, d):
 
 
 def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
-                    bwd_block_q=None, bwd_block_k=None, taxonomy=None):
+                    bwd_block_q=None, bwd_block_k=None):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              _should_interpret(interpret),
-                              _resolve_taxonomy(taxonomy))
+                              _should_interpret(interpret))
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
-                    bwd_block_q, bwd_block_k, taxonomy, residuals, g):
+                    bwd_block_q, bwd_block_k, residuals, g):
     q, k, v, out, lse = residuals
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -1306,8 +1071,7 @@ def _flash_bwd_rule(causal, scale, block_q, block_k, interpret,
     bq, bk = _resolve_bwd_blocks(block_q, block_k, bwd_block_q,
                                  bwd_block_k, q.shape[-1])
     return _backward_with_vmem_retry(q, k, v, out, lse, g, causal,
-                                     scale, bq, bk, interp,
-                                     taxonomy=_resolve_taxonomy(taxonomy))
+                                     scale, bq, bk, interp)
 
 
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -1334,11 +1098,10 @@ def _dense_attention_with_lse(q, k, v, causal, scale):
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention_with_lse(q, k, v, causal=False, scale=None,
                              block_q=None, block_k=None, interpret=None,
-                             bwd_block_q=None, bwd_block_k=None,
-                             taxonomy=None):
+                             bwd_block_q=None, bwd_block_k=None):
     """Flash attention returning ``(out, lse)`` with BOTH outputs
     differentiable — ``lse`` is the per-row log-sum-exp of the scaled
     scores, shaped (b, s_q, h).
@@ -1351,14 +1114,13 @@ def flash_attention_with_lse(q, k, v, causal=False, scale=None,
     ``_flash_backward``'s ``g_lse``)."""
     out, lse = _flash_with_lse_fwd_rule(
         q, k, v, causal, scale, block_q, block_k, interpret,
-        taxonomy=taxonomy,
     )[0]
     return out, lse
 
 
 def _flash_with_lse_fwd_rule(q, k, v, causal, scale, block_q, block_k,
                              interpret, bwd_block_q=None,
-                             bwd_block_k=None, taxonomy=None):
+                             bwd_block_k=None):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     interp = _should_interpret(interpret)
@@ -1367,16 +1129,14 @@ def _flash_with_lse_fwd_rule(q, k, v, causal, scale, block_q, block_k,
         out, lse = _dense_attention_with_lse(q, k, v, causal, scale)
         return (out, lse), (q, k, v, None, None)
     out, lse_bh = _flash_forward(q, k, v, causal, scale, block_q,
-                                 block_k, interp,
-                                 _resolve_taxonomy(taxonomy))
+                                 block_k, interp)
     b, s_q, h, _ = q.shape
     lse = jnp.moveaxis(lse_bh.reshape(b, h, s_q), 1, 2)  # (b, s_q, h)
     return (out, lse), (q, k, v, out, lse_bh)
 
 
 def _flash_with_lse_bwd_rule(causal, scale, block_q, block_k, interpret,
-                             bwd_block_q, bwd_block_k, taxonomy,
-                             residuals, g):
+                             bwd_block_q, bwd_block_k, residuals, g):
     q, k, v, out, lse_bh = residuals
     g_out, g_lse = g
     if scale is None:
@@ -1396,7 +1156,6 @@ def _flash_with_lse_bwd_rule(causal, scale, block_q, block_k, interpret,
     return _backward_with_vmem_retry(
         q, k, v, out, lse_bh, g_out, causal, scale, bq, bk,
         _should_interpret(interpret), g_lse=g_lse_bh,
-        taxonomy=_resolve_taxonomy(taxonomy),
     )
 
 

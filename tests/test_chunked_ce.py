@@ -121,19 +121,18 @@ class TestChunkedLmLoss:
         toks = jnp.asarray(
             np.random.RandomState(0).randint(0, V, (2, 16)), jnp.int32
         )
-        params = model.init(jax.random.PRNGKey(0), toks)
-        full = lm_loss(model.apply(params, toks), toks)
-        chunked = chunked_lm_loss(model, params, toks, n_chunks=8)
+        # a program each (init, and value with gradient), not op by op
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), toks)
+        full, g_full = jax.jit(jax.value_and_grad(
+            lambda p: lm_loss(model.apply(p, toks), toks)
+        ))(params)
+        chunked, g_chunk = jax.jit(jax.value_and_grad(
+            lambda p: chunked_lm_loss(model, p, toks, n_chunks=8)
+        ))(params)
         np.testing.assert_allclose(
             float(chunked), float(full), rtol=2e-2
         )
         # gradients flow to every parameter (incl. the tied table)
-        g_full = jax.grad(
-            lambda p: lm_loss(model.apply(p, toks), toks)
-        )(params)
-        g_chunk = jax.grad(
-            lambda p: chunked_lm_loss(model, p, toks, n_chunks=8)
-        )(params)
         for a, b in zip(
             jax.tree_util.tree_leaves(g_full),
             jax.tree_util.tree_leaves(g_chunk),

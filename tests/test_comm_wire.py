@@ -479,7 +479,7 @@ class TestHLOCollectiveCensus:
         from chainermn_tpu.models import ResNet50
 
         model = ResNet50(num_classes=1000, train=False)
-        params = model.init(
+        params = jax.jit(model.init)(  # one program, not op by op
             jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3))
         )
         n_leaves = len(jax.tree_util.tree_leaves(params))

@@ -34,13 +34,13 @@ RUNTIME = {"kv_handoff/", "result/", "checkpoints/", "profile/",
            "_scratch/"}
 
 #: names the two records may use for what is gone, because their
-#: history paragraphs say so (PR 24's, and PR 32's pre-chip measurement
-#: stack).  No other document may.  Keep it short; a name leaves with
+#: history paragraphs say so (PR 24's, PR 32's pre-chip measurement
+#: stack, PR 46's hand-set count).  No other document may.  Keep it short; a name leaves with
 #: the last sentence that needs it.
 _GONE = {
     "BENCH_r*.json", "VERDICT.md", "_compat.py",
     "bench.py", "docs/performance.md", "MULTICHIP_r0*.json",
-    "benchmarks/perf_history.py",
+    "benchmarks/perf_history.py", "tests/test_readme_count.py",
 }
 DELETED = {"PERF.md": _GONE, "ROADMAP.md": _GONE}
 

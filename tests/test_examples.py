@@ -59,6 +59,10 @@ class TestExampleScripts:
         assert "loss" in out
 
     def test_imagenet_synthetic(self, tmp_path):
+        """Over 40 s in the driver's run (a process that compiles
+        ResNet-18's step and evaluator on eight devices): the one hold
+        on the example the ResNet cell runs, through ``Trainer``, the
+        iterators and ``MultiNodeEvaluator``."""
         out = _run(
             "imagenet/train_imagenet.py", "--cpu-mesh", "--epoch", "1",
             "--arch", "resnet18", "--image-size", "32",

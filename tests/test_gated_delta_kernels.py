@@ -68,7 +68,10 @@ def _value_and_gradients(f, args):
 @functools.lru_cache(maxsize=None)
 def _forms(case):
     """The case's value and gradients by the kernels (interpreted), by
-    the XLA form, and by the recurrence in float32."""
+    the XLA form, and by the recurrence in float32.  Each case's first
+    leaf pays for all six (over 40 s in the driver's run: the kernels'
+    interpreter runs a grid point at a time, the recurrence a token at
+    a time), and nothing cheaper sets the kernels against both."""
     c = CASES[case]
     args = _inputs(c["s"], c["b"], c["dtype"], c["seeded"])
     scan = lambda interpret: functools.partial(

@@ -287,7 +287,10 @@ def test_the_step_as_the_cell_runs_it_is_correct_and_the_control_is_not(
     chunked head, as the example builds them: loss, first gradient and
     first AdamW step against ``train_readings`` through the comparison
     that decides ``correct``, at the rehearsal's limits; the float8
-    control fails it."""
+    control fails it.  Over 40 s in the driver's run (the step's program
+    and the reference's control, a layer at a time): the only in-process
+    case on the step as the cell builds it, and the only one that shows
+    the comparison can fail."""
     cfg, _, tree, tokens = seeded
     model = model_of(cfg, dtype=jnp.bfloat16, use_flash=True,
                      remat_blocks=True)
@@ -485,7 +488,10 @@ def test_kept_results_change_no_bit_and_save_their_products(
     and every gradient are the plain recomputation's to the last bit
     (the same operations on the same values, run one by one), and the
     gradient runs one ``in_proj`` product a kept layer where the plain
-    recomputation runs two."""
+    recomputation runs two.  (The first case pays ``plainly_recomputed``
+    for all three, over 40 s in the driver's run: bit identity needs
+    both sides run one operation at a time, which no compiled form
+    gives.)"""
     cfg, _, tree, tokens = seeded
     plain = model_of(cfg, remat_blocks=True)
     kept = model_of(cfg, remat_blocks=True, remat_budget_bytes=budget)
@@ -624,7 +630,10 @@ def test_a_first_carry_varies_as_the_operands_it_is_like():
 def test_the_cell_rehearses_correct():
     """``cellbench.run --rehearse`` of the cell, in a process of its own
     (one CPU device, as the cell has one chip): the example's ``main``
-    under the runner's flags, three steps against the reference."""
+    under the runner's flags, three steps against the reference.  Over 40 s in the driver's
+    run (a process start, the program's compile and the reference's at
+    rehearsal size): the one tier-1 hold on the cell's own runner,
+    example and comparison end to end, which no in-process case is."""
     import subprocess
 
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}

@@ -458,10 +458,13 @@ def test_the_bias_moves_the_selection_and_not_the_weights(small_blocks):
 
 def test_shared_expert_without_a_gate_has_no_gate_parameter():
     x = jnp.zeros((1, 8, 16))
-    plain = MoeMlp(4, 8, routing="dropless", shared_d_ff=8).init(
+    # the trees' shapes: traced, nothing runs
+    plain = jax.eval_shape(
+        MoeMlp(4, 8, routing="dropless", shared_d_ff=8).init,
         jax.random.PRNGKey(0), x)["params"]
-    bare = MoeMlp(4, 8, routing="dropless", shared_d_ff=8,
-                  router_options=ROUTER).init(
+    bare = jax.eval_shape(
+        MoeMlp(4, 8, routing="dropless", shared_d_ff=8,
+               router_options=ROUTER).init,
         jax.random.PRNGKey(0), x)["params"]
     assert "shared_gate" in plain and "router_bias" not in plain
     assert "shared_gate" not in bare and bare["router_bias"].shape == (4,)
@@ -623,7 +626,11 @@ def test_model_loss_gradients_and_an_adamw_step_against_reference():
     selection biases' is 0 on both sides); the reference's
     layer-at-a-time ``train_readings`` against both, and its parameters'
     change against one step of the example's optimizer (AdamW, the
-    biases out of the decay) on the program's tree."""
+    biases out of the decay) on the program's tree.  Over 40 s in the
+    driver's run (three gradient programs at rehearsal size, five
+    layers of two mixer kinds with experts): the only case that holds
+    every leaf's gradient and update against the reference ``correct``
+    is decided by."""
     import optax
 
     from chainermn_tpu.models.moe_transformer import moe_lm_loss

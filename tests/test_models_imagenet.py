@@ -28,12 +28,31 @@ def _init_and_forward(model, batch=2, img=IMG):
     return variables, out
 
 
+def _abstract_init_and_forward(model, **apply_kwargs):
+    """Shapes and dtypes of ``init``'s variables and of ``apply``'s
+    result on a batch of two: traced by ``jax.eval_shape``, never run (a
+    shape needs no arithmetic, and op by op an Inception stack at this
+    size takes over a minute)."""
+    x = jnp.zeros((2, IMG, IMG, 3), jnp.float32)
+    variables = jax.eval_shape(
+        model.init,
+        {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)},
+        x[:1],
+    )
+    out = jax.eval_shape(
+        lambda v: model.apply(
+            v, x, rngs={"dropout": jax.random.PRNGKey(2)}, **apply_kwargs),
+        variables,
+    )
+    return variables, out
+
+
 @pytest.mark.parametrize("factory", [
     models.AlexNet, models.NIN, models.VGG16, models.GoogLeNet,
 ])
 def test_stateless_arch_forward_shape(factory):
     model = factory(num_classes=11, train=False)
-    variables, out = _init_and_forward(model)
+    variables, out = _abstract_init_and_forward(model)
     assert out.shape == (2, 11)
     assert out.dtype == jnp.float32
     assert "batch_stats" not in variables
@@ -44,16 +63,9 @@ def test_stateless_arch_forward_shape(factory):
 ])
 def test_bn_arch_forward_shape(factory):
     model = factory(num_classes=7, train=True)
-    x = jnp.zeros((2, IMG, IMG, 3), jnp.float32)
-    variables = model.init(
-        {"params": jax.random.PRNGKey(0), "dropout": jax.random.PRNGKey(1)},
-        x[:1],
-    )
+    variables, (out, mut) = _abstract_init_and_forward(
+        model, mutable=["batch_stats"])
     assert "batch_stats" in variables
-    out, mut = model.apply(
-        variables, x, mutable=["batch_stats"],
-        rngs={"dropout": jax.random.PRNGKey(2)},
-    )
     assert out.shape == (2, 7)
     assert jax.tree_util.tree_structure(
         mut["batch_stats"]
@@ -83,16 +95,19 @@ def test_bf16_bn_numerics_close_to_fp32_and_stats_stay_fp32():
     )
     bf16 = ResNet18(num_classes=5, train=True)  # default: bf16 BN
     fp32 = ResNet18(num_classes=5, train=True, norm=fp32_norm)
-    v_bf = bf16.init(jax.random.PRNGKey(0), x[:1])
-    v_fp = fp32.init(jax.random.PRNGKey(0), x[:1])
+    # a program each, not op by op
+    v_bf = jax.jit(bf16.init)(jax.random.PRNGKey(0), x[:1])
+    v_fp = jax.jit(fp32.init)(jax.random.PRNGKey(0), x[:1])
     # identical param trees (dtype is arithmetic-only, not storage)
     chex_equal = jax.tree_util.tree_all(jax.tree_util.tree_map(
         lambda a, b: bool(np.allclose(np.asarray(a), np.asarray(b))),
         v_bf["params"], v_fp["params"],
     ))
     assert chex_equal
-    out_bf, mut_bf = bf16.apply(v_bf, x, mutable=["batch_stats"])
-    out_fp, mut_fp = fp32.apply(v_fp, x, mutable=["batch_stats"])
+    out_bf, mut_bf = jax.jit(
+        lambda v: bf16.apply(v, x, mutable=["batch_stats"]))(v_bf)
+    out_fp, mut_fp = jax.jit(
+        lambda v: fp32.apply(v, x, mutable=["batch_stats"]))(v_fp)
     np.testing.assert_allclose(
         np.asarray(out_bf), np.asarray(out_fp), atol=0.15, rtol=0.1
     )
@@ -108,9 +123,10 @@ def test_bf16_bn_numerics_close_to_fp32_and_stats_stay_fp32():
 
 
 def test_dropout_is_train_gated():
+    img = 64  # the stem and pools leave nothing of 48
     model = models.AlexNet(num_classes=5, train=True)
-    variables, _ = _init_and_forward(model)
-    x = jnp.ones((4, IMG, IMG, 3))
+    variables, _ = _init_and_forward(model, img=img)
+    x = jnp.ones((4, img, img, 3))
     a = model.apply(variables, x, rngs={"dropout": jax.random.PRNGKey(3)})
     b = model.apply(variables, x, rngs={"dropout": jax.random.PRNGKey(4)})
     assert not np.allclose(np.asarray(a), np.asarray(b))

@@ -142,7 +142,11 @@ def test_encoder_decoder_components(toy):
 
 def test_model_parallel_seq2seq_matches_and_learns(devices8):
     """The MultiNodeChainList split (encoder chip 0, decoder chip 1) must
-    train end-to-end; mirrors the reference's seq2seq_mp1 topology."""
+    train end-to-end; mirrors the reference's seq2seq_mp1 topology.
+    (Over 40 s in the driver's run, 12 s alone: the stages run op by op
+    across two devices, which is what ``MultiNodeChainList`` is; the
+    only case that holds the split's routing against the one-chip
+    model and trains through it.)"""
     import chainermn_tpu as cmn
     import sys, os
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -184,10 +188,12 @@ def test_model_parallel_seq2seq_matches_and_learns(devices8):
     )
 
     step = model.value_and_grad(seq2seq_loss)
-    opt = model.optimizer(optax.adam(3e-3))
+    # the fewest steps that show it: at this rate ten take the loss from
+    # 2.78 to 1.61 (0.58 of the first)
+    opt = model.optimizer(optax.adam(2e-2))
     state = opt.init(params)
     first = None
-    for i in range(30):
+    for i in range(10):
         loss, grads = step(params, [xs, ys_in], ys_out)
         params, state = opt.update(grads, state, params)
         if first is None:

@@ -488,7 +488,10 @@ class TestServingChurn:
         at its 3rd decode step).  The drained requests stay journaled;
         the phase-2 world (size 1, via ``serve_elastic``) re-claims and
         completes every one with outputs bit-identical to the no-fault
-        run (asserted in-scenario against a fresh oracle engine)."""
+        run (asserted in-scenario against a fresh oracle engine).  (Over
+        40 s in the driver's run: two worlds of serving processes, each
+        compiling its engine; the one tier-1 hold on a replica lost
+        mid-stream.)"""
         faults = json.dumps([
             {"site": "serving.decode_step", "kind": "die", "at": [3],
              "process": 1, "exit_code": 43},

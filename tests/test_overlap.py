@@ -353,7 +353,7 @@ class TestSegmentAlignment:
         from chainermn_tpu.models import ResNet50
 
         model = ResNet50(num_classes=1000, train=False)
-        params = model.init(
+        params = jax.jit(model.init)(  # one program, not op by op
             jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3))
         )
         plan = plan_of_tree(params)

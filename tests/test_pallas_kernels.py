@@ -264,9 +264,8 @@ def _brute_census(s_q, s_k, bq, bk, causal, kind, tile=None):
 
 
 class TestDiagonalSplit:
-    """The diagonal-split kernel taxonomy: classification correctness,
-    bit-exactness vs the pre-split (legacy) kernels, and oracle checks
-    at the geometries where the classes meet."""
+    """The diagonal-split kernel taxonomy: classification correctness
+    and oracle checks at the geometries where the classes meet."""
 
     @pytest.mark.parametrize("kind", ["fwd", "bwd"])
     @pytest.mark.parametrize("s_q,s_k,bq,bk,causal", [
@@ -380,6 +379,8 @@ class TestDiagonalSplit:
         with pytest.raises(ValueError, match="fwd/bwd"):
             block_census(8, 8, 8, 8, False, kind="nope")
 
+    @pytest.mark.parametrize("entry", ["flash_attention",
+                                       "flash_attention_with_lse"])
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("s,bq,bk", [
         (32, 16, 16),   # block-boundary aligned
@@ -387,89 +388,50 @@ class TestDiagonalSplit:
         (48, 16, 8),    # fully-masked rows inside live blocks
         (40, 8, 32),    # wide k blocks
     ])
-    def test_split_matches_legacy_exactly(self, causal, s, bq, bk):
-        """The split kernels must be BIT-IDENTICAL to the pre-split
-        kernels in interpret mode, values and all three gradients: the
-        interior fast branch skips a mask that is provably all-true,
-        and the first-k-block direct write skips a rescale whose factor
-        is provably exp(-inf) = 0 — neither may change a single bit."""
-        q, k, v = _qkv(s=s, seed=7)
-
-        def run(tax):
-            def f(q, k, v):
-                return jnp.sum(
-                    flash_attention(q, k, v, causal, None, bq, bk, True,
-                                    None, None, tax) ** 2
-                )
-
-            out = flash_attention(q, k, v, causal, None, bq, bk, True,
-                                  None, None, tax)
-            grads = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-            return out, grads
-
-        out_s, g_s = run("split")
-        out_l, g_l = run("legacy")
-        np.testing.assert_array_equal(np.asarray(out_s), np.asarray(out_l))
-        for a, b in zip(g_s, g_l):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    def test_split_matches_legacy_with_lse(self):
-        """Same exactness through the (out, lse)-differentiable entry
-        point (the ring-attention building block): both outputs and the
-        folded g_lse backward."""
+    def test_split_matches_dense_oracle(self, entry, causal, s, bq, bk):
+        """Both public entry points against the dense float32 oracle at
+        the geometries where the taxonomy matters: values, ``lse`` (the
+        ring-attention building block returns it, and folds its
+        cotangent into the same backward kernels) and all three
+        gradients.  The interior fast branch skips a mask that is
+        all-true there and the first k block's direct write skips a
+        rescale whose factor is exp(-inf) = 0: neither may show."""
         from chainermn_tpu.ops.pallas_attention import (
+            _dense_attention_with_lse,
+            block_census,
             flash_attention_with_lse,
         )
 
-        q, k, v = _qkv(s=32, seed=11)
-
-        def run(tax):
-            def f(q, k, v):
-                out, lse = flash_attention_with_lse(
-                    q, k, v, True, None, 16, 16, True, None, None, tax
-                )
-                return jnp.sum(out ** 2) + jnp.sum(lse * 0.3)
-
-            out, lse = flash_attention_with_lse(
-                q, k, v, True, None, 16, 16, True, None, None, tax
-            )
-            return out, lse, jax.grad(f, argnums=(0, 1, 2))(q, k, v)
-
-        out_s, lse_s, g_s = run("split")
-        out_l, lse_l, g_l = run("legacy")
-        np.testing.assert_array_equal(np.asarray(out_s), np.asarray(out_l))
-        np.testing.assert_array_equal(np.asarray(lse_s), np.asarray(lse_l))
-        for a, b in zip(g_s, g_l):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    @pytest.mark.parametrize("s", [32, 23])
-    def test_split_gradients_match_dense_oracle(self, s):
-        """Gradients of the split path vs the dense oracle exactly at
-        the geometries where the taxonomy matters: block boundaries
-        (s = 2 blocks: the diagonal class) and ragged tails (the tail
-        class), with the census proving BOTH live branches executed."""
-        from chainermn_tpu.ops.pallas_attention import block_census
-
-        c = block_census(s, s, 16, 16, True, kind="bwd")
-        if s == 32:
+        if causal and (s, bq, bk) == (32, 16, 16):  # BOTH live branches
+            c = block_census(s, s, bq, bk, True, kind="bwd")
             assert c["interior"] >= 1 and c["masked"] >= 1
-        q, k, v = _qkv(s=s, seed=3)
+        q, k, v = _qkv(s=s, seed=7)
+        with_lse = entry == "flash_attention_with_lse"
 
-        def f_ref(q, k, v):
-            return jnp.sum(multi_head_attention(q, k, v, causal=True) ** 2)
+        def kernels(q, k, v):
+            if with_lse:
+                return flash_attention_with_lse(q, k, v, causal, None, bq,
+                                                bk, True)
+            return (flash_attention(q, k, v, causal, None, bq, bk, True),)
 
-        def f_split(q, k, v):
-            return jnp.sum(
-                flash_attention(q, k, v, True, None, 16, 16, True, None,
-                                None, "split") ** 2
-            )
+        def dense(q, k, v):
+            return _dense_attention_with_lse(
+                q, k, v, causal, q.shape[-1] ** -0.5)[:1 + with_lse]
 
-        g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-        g_split = jax.grad(f_split, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g_ref, g_split):
-            assert np.isfinite(np.asarray(b)).all()
+        def run(fn):
+            def loss(q, k, v):
+                out, *lse = fn(q, k, v)
+                return jnp.sum(out ** 2) + sum(
+                    jnp.sum(x * 0.3) for x in lse)
+
+            return fn(q, k, v) + jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        got, want = run(kernels), run(dense)
+        assert len(got) == len(want) == 4 + with_lse
+        for a, b in zip(got, want):
+            assert np.isfinite(np.asarray(a)).all()
             np.testing.assert_allclose(
-                np.asarray(b), np.asarray(a), rtol=2e-3, atol=2e-4
+                np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-4
             )
 
     def test_launch_census_applies_clamps(self):
@@ -503,19 +465,6 @@ class TestDiagonalSplit:
         c = launch_census(8192, 8192, 128, 64, 1024, interpret=True)
         assert c["fwd"]["n_q_blocks"] == 8192 // 64
 
-    @pytest.mark.parametrize("taxonomy", [
-        "diagonalize",
-        "interior",  # a block class, no longer a kernel family
-    ])
-    def test_invalid_taxonomy_raises(self, taxonomy):
-        from chainermn_tpu.ops import pallas_attention as pa
-
-        assert pa._TAXONOMIES == ("split", "legacy")
-        q, k, v = _qkv(s=16)
-        with pytest.raises(ValueError, match="taxonomy"):
-            flash_attention(q, k, v, True, None, 8, 8, True, None, None,
-                            taxonomy)
-
 
 class TestComputeTile:
     """The compute tile inside the diagonal blocks (PR 28): reached at
@@ -537,7 +486,7 @@ class TestComputeTile:
         return q, k, v, g, g_lse
 
     @staticmethod
-    def _run(q, k, v, g, g_lse, causal, bq, bk, taxonomy, tile):
+    def _run(q, k, v, g, g_lse, causal, bq, bk, tile):
         """(out, lse, dq, dk, dv) through the two private entry points,
         the lse cotangent folded in as ``flash_attention_with_lse``
         does."""
@@ -545,10 +494,9 @@ class TestComputeTile:
 
         scale = q.shape[-1] ** -0.5
         out, lse = pa._flash_forward(q, k, v, causal, scale, bq, bk, True,
-                                     taxonomy, tile=tile)
+                                     tile=tile)
         grads = pa._flash_backward(q, k, v, out, lse, g, causal, scale,
-                                   bq, bk, True, taxonomy, g_lse=g_lse,
-                                   tile=tile)
+                                   bq, bk, True, g_lse=g_lse, tile=tile)
         return (out, lse) + tuple(grads)
 
     @staticmethod
@@ -571,12 +519,11 @@ class TestComputeTile:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                              ids=["f32", "bf16"])
     @pytest.mark.parametrize("n_tiles", [2, 4, 8])
-    def test_tiled_matches_oracle_and_legacy(self, n_tiles, dtype):
+    def test_tiled_matches_oracle(self, n_tiles, dtype):
         """Forward, lse, dq, dk, dv with the diagonal blocks computed in
-        2, 4 and 8 tiles a side, against the dense oracle and against
-        the legacy kernels: tiles above the diagonal contributed exact
-        zeros, so only fp32 summation order inside a diagonal block may
-        differ -- float32 tolerance, not bit equality."""
+        2, 4 and 8 tiles a side, against the dense oracle: tiles above
+        the diagonal contributed exact zeros, so nothing of the
+        mathematics is left out."""
         from chainermn_tpu.ops.pallas_attention import block_census
 
         blk = self.TILE * n_tiles
@@ -586,34 +533,27 @@ class TestComputeTile:
         assert c["tiles_masked"] == 3 * n_tiles
         assert c["tiles_skipped"] == 3 * n_tiles * (n_tiles - 1) // 2
         args = self._inputs(s, s, dtype)
-        tiled = self._run(*args, True, blk, blk, "split", self.TILE)
-        legacy = self._run(*args, True, blk, blk, "legacy", None)
+        tiled = self._run(*args, True, blk, blk, self.TILE)
         dense = self._dense(*args, True)
         names = ("out", "lse", "dq", "dk", "dv")
         exact = dtype == jnp.float32
-        for name, t, l, w in zip(names, tiled, legacy, dense):
-            assert t.dtype == l.dtype
-            t, l, w = (np.asarray(x, np.float32) for x in (t, l, w))
+        for name, t, w in zip(names, tiled, dense):
+            assert t.dtype == (jnp.float32 if name == "lse" else dtype)
+            t, w = (np.asarray(x, np.float32) for x in (t, w))
             assert np.isfinite(t).all(), name
-            # against legacy: same operands, same precision
-            np.testing.assert_allclose(
-                t, l, err_msg=name,
-                **(dict(rtol=2e-5, atol=2e-6) if exact or name == "lse"
-                   else dict(rtol=2e-2, atol=2e-3)))
-            # against the dense float32 oracle
             np.testing.assert_allclose(
                 t, w, err_msg=name,
                 **(dict(rtol=2e-3, atol=2e-4) if exact
                    else dict(rtol=1e-1, atol=5e-2)))
 
-    def test_tile_off_is_legacy_bit_for_bit(self):
-        """With the tile off (a tile as wide as the block) the split
-        kernels are the legacy ones bit for bit on the same launch -- so
-        the tolerance above is the tile's doing and nothing else's."""
+    def test_tile_as_wide_as_the_block_is_no_tile_bit_for_bit(self):
+        """A tile as wide as the block is the launch with no tile asked
+        for, bit for bit: a diagonal block is computed in strips only
+        where it holds at least two tiles."""
         args = self._inputs(32, 32, jnp.float32)
-        whole = self._run(*args, True, 16, 16, "split", 16)
-        legacy = self._run(*args, True, 16, 16, "legacy", None)
-        for a, b in zip(whole, legacy):
+        whole = self._run(*args, True, 16, 16, 16)
+        unasked = self._run(*args, True, 16, 16, None)
+        for a, b in zip(whole, unasked):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     @pytest.mark.parametrize("s_q,s_k,bq,bk,causal", [
@@ -627,18 +567,18 @@ class TestComputeTile:
     ])
     def test_rule_leaves_other_launches_bit_identical(self, s_q, s_k, bq,
                                                       bk, causal):
-        """Launches the rule does not cover run exactly today's code
-        even when a tile is asked for: bit-identical to legacy, values,
-        lse and all three gradients."""
+        """Launches the rule does not cover run the code they run with
+        no tile asked for, even when one is: bit-identical values, lse
+        and all three gradients."""
         from chainermn_tpu.ops.pallas_attention import block_census
 
         for kind in ("fwd", "bwd"):
             assert block_census(s_q, s_k, bq, bk, causal, kind=kind,
                                 tile=self.TILE)["tile"] is None
         args = self._inputs(s_q, s_k, jnp.float32)
-        asked = self._run(*args, causal, bq, bk, "split", self.TILE)
-        legacy = self._run(*args, causal, bq, bk, "legacy", None)
-        for a, b in zip(asked, legacy):
+        asked = self._run(*args, causal, bq, bk, self.TILE)
+        unasked = self._run(*args, causal, bq, bk, None)
+        for a, b in zip(asked, unasked):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_rule_table(self):
@@ -662,9 +602,12 @@ class TestComputeTile:
     def test_public_api_resolves_the_tile_from_shapes(self):
         """No public argument: ``flash_attention`` at 512 blocks and
         seq 1024 engages the backward's default tile by itself (the
-        census says so) and agrees with legacy to float32 tolerance,
-        values and gradients."""
-        from chainermn_tpu.ops.pallas_attention import launch_census
+        census says so) and agrees with the dense oracle to float32
+        tolerance, values and gradients."""
+        from chainermn_tpu.ops.pallas_attention import (
+            _dense_attention_with_lse,
+            launch_census,
+        )
 
         c = launch_census(1024, 1024, 8, 512, 512, interpret=True)
         assert c["fwd"]["tile"] is None and c["bwd"]["tile"] == 256
@@ -675,19 +618,21 @@ class TestComputeTile:
         q, k, v = (jnp.asarray(rng.randn(1, 1024, 1, 8), jnp.float32) * 0.3
                    for _ in range(3))
 
-        def run(tax):
+        def run(attend):
             def f(q, k, v):
-                return jnp.sum(flash_attention(
-                    q, k, v, True, None, 512, 512, True, None, None,
-                    tax) ** 2)
+                return jnp.sum(attend(q, k, v) ** 2)
 
             return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
 
-        (l_s, g_s), (l_l, g_l) = run(None), run("legacy")
-        np.testing.assert_allclose(float(l_s), float(l_l), rtol=1e-5)
-        for a, b in zip(g_s, g_l):
+        (l_s, g_s), (l_d, g_d) = (
+            run(lambda q, k, v: flash_attention(
+                q, k, v, True, None, 512, 512, True)),
+            run(lambda q, k, v: _dense_attention_with_lse(
+                q, k, v, True, 8 ** -0.5)[0]))
+        np.testing.assert_allclose(float(l_s), float(l_d), rtol=1e-5)
+        for a, b in zip(g_s, g_d):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-5, atol=2e-6)
+                                       rtol=2e-3, atol=2e-4)
 
 
 class TestFlashWithSequenceParallel:
@@ -858,7 +803,7 @@ class TestVmemRetry:
         calls = []
 
         def fake_backward(q, k, v, out, lse, g, causal, scale, bq, bk,
-                          interp, taxonomy="split", g_lse=None):
+                          interp, g_lse=None):
             eff = pa._clamp_blocks_for_dim(bq, bk, q.shape[-1],
                                            warn=False)
             calls.append(eff)
@@ -896,7 +841,7 @@ class TestVmemRetry:
         from chainermn_tpu.ops import pallas_attention as pa
 
         def fake_backward(q, k, v, out, lse, g, causal, scale, bq, bk,
-                          interp, taxonomy="split", g_lse=None):
+                          interp, g_lse=None):
             raise RuntimeError("scoped vmem limit exceeded")
 
         monkeypatch.setattr(pa, "_flash_backward", fake_backward)
@@ -940,12 +885,12 @@ class TestVmemRetry:
         real = pa._flash_backward
 
         def spying(q, k, v, out, lse, g, causal, scale, bq, bk, interp,
-                   taxonomy="split", g_lse=None):
+                   g_lse=None):
             seen.append((bq, bk))
             if len(seen) == 1:
                 raise RuntimeError("scoped vmem limit exceeded")
             return real(q, k, v, out, lse, g, causal, scale, bq, bk,
-                        interp, taxonomy=taxonomy, g_lse=g_lse)
+                        interp, g_lse=g_lse)
 
         monkeypatch.setattr(pa, "_flash_backward", spying)
         q, k, v = _qkv(s=32)

@@ -124,7 +124,10 @@ def test_model_loss_gradients_and_an_adamw_step_against_reference():
     ``jax.value_and_grad`` of the reference's whole-model loss; the
     reference's layer-at-a-time ``train_readings`` against both, and
     its parameters' change against one ``optax.adamw`` step of the
-    program's tree."""
+    program's tree.  Over 40 s in the driver's run (three gradient
+    programs at rehearsal size): the only case that holds every leaf's
+    gradient and update against the reference ``correct`` is decided
+    by."""
     import optax
 
     from chainermn_tpu.models.moe_transformer import moe_lm_loss
@@ -205,7 +208,9 @@ def test_sixteen_shares_and_the_shared_expert_once_are_the_uncut_layer(
     routed part, each route counted once; those and the shared expert
     counted **once** are the uncut layer, in the reference and in the
     program (whose every share adds the shared expert: sixteen sums
-    hold it sixteen times)."""
+    hold it sixteen times).  Over 40 s in the driver's run (sixteen
+    layers with sixteen held ranges, each traced by itself): the only
+    case that holds the cut of the deployment's experts into shares."""
     ein = ref._ein(False)
     weights = ref.init_weights(ref.seed_key(21), UNCUT)
     layer = _layer_weights(weights)
@@ -357,16 +362,19 @@ def test_remat_plan_with_the_new_kind():
         == (("mlp_in", "ssm_in"), ("mlp_in", "gdn_in"))
 
 
-def _paths(tree):
+def _paths(model, tokens):
+    """Path -> shape of the parameters ``init`` would make (traced by
+    ``jax.eval_shape``: a shape needs no arithmetic)."""
+    tree = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
     return {"/".join(k.key for k in path): leaf.shape for path, leaf
-            in jax.tree_util.tree_leaves_with_path(tree)}
+            in jax.tree_util.tree_leaves_with_path(tree["params"])}
 
 
 def test_default_options_leave_both_lms_trees_as_they_are():
     tokens = jnp.zeros((1, 8), jnp.int32)
     lm = TransformerLM(vocab_size=32, d_model=16, n_heads=2, n_layers=1,
                        max_len=8)
-    assert _paths(lm.init(jax.random.PRNGKey(0), tokens)["params"]) == {
+    assert _paths(lm, tokens) == {
         "embed/embedding": (32, 16), "pos_embed": (8, 16),
         "LayerNorm_0/scale": (16,), "LayerNorm_0/bias": (16,),
         "TransformerBlock_0/LayerNorm_0/scale": (16,),
@@ -381,7 +389,7 @@ def test_default_options_leave_both_lms_trees_as_they_are():
         "TransformerBlock_0/MlpBlock_0/Dense_1/bias": (16,)}
     moe = MoeTransformerLM(vocab_size=32, d_model=16, n_heads=2,
                            n_layers=2, n_experts=2, max_len=8)
-    got = _paths(moe.init(jax.random.PRNGKey(0), tokens)["params"])
+    got = _paths(moe, tokens)
     block = "MoeTransformerBlock_0/"
     assert {k: v for k, v in got.items() if k.startswith(block)} == {
         block + "LayerNorm_0/scale": (16,),
@@ -402,7 +410,7 @@ def test_default_options_leave_both_lms_trees_as_they_are():
         moe_every=1, d_ff=8, options=BlockOptions(
             norm="rmsnorm", rope_theta=1e4, qk_norm=True),
         routing="dropless", tie_head=False)
-    got = _paths(rotary.init(jax.random.PRNGKey(0), tokens)["params"])
+    got = _paths(rotary, tokens)
     assert set(got) == {
         "embed/embedding", "lm_head", "RMSNorm_0/scale",
         "MoeTransformerBlock_0/RMSNorm_0/scale",
@@ -430,15 +438,16 @@ def test_transformer_lm_takes_the_new_kind_too():
     lm = TransformerLM(vocab_size=32, d_model=16, n_heads=2, n_layers=2,
                        max_len=24, dtype=jnp.float32, options=o)
     tokens = jnp.arange(48).reshape(2, 24) % 32
-    params = lm.init(jax.random.PRNGKey(0), tokens)
+    # one program each, not op by op
+    params = jax.jit(lm.init)(jax.random.PRNGKey(0), tokens)
     mixer = params["params"]["TransformerBlock_0"]["GatedDeltaMixer_0"]
     assert sorted(mixer) == ["A_log", "conv_kernel", "dt_bias",
                              "in_proj_ba", "in_proj_qkvz", "norm",
                              "out_proj"]
     assert mixer["conv_kernel"].shape == (4, 2 * 16 + 32)
     assert lm.remat_plan(48) == (("gdn_in",), ())
-    loss, grads = jax.value_and_grad(lambda p: transformer.lm_loss(
-        lm.apply(p, tokens), tokens))(params)
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: transformer.lm_loss(
+        lm.apply(p, tokens), tokens)))(params)
     assert np.isfinite(float(loss))
     assert all(bool(jnp.isfinite(g).all())
                for g in jax.tree_util.tree_leaves(grads))
@@ -448,8 +457,9 @@ def test_the_new_scopes_are_on_the_operations():
     cfg = SHARE
     tokens = _tokens(cfg, rows=1, s=32)
     model = _model(cfg)
-    params = {"params": model.init(jax.random.PRNGKey(0),
-                                   tokens)["params"]}
+    # lowered from the parameters' shapes: nothing runs
+    params = {"params": jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), tokens)["params"]}
     text = jax.jit(lambda p: _apply(model, p, tokens)).lower(
         params).as_text(debug_info=True)
     for scope in ("gdn_mixer", "gdn_conv", "gdn_scan", "moe_shared",
@@ -461,7 +471,10 @@ def test_the_new_scopes_are_on_the_operations():
 # -- the cell ----------------------------------------------------------------
 def test_the_cell_rehearses_correct_with_its_counters():
     """``cellbench.run --rehearse`` of the cell, in a process of its own
-    (one CPU device, as the cell has one chip)."""
+    (one CPU device, as the cell has one chip).  Over 40 s in the driver's
+    run (a process start, the program's compile and the reference's at
+    rehearsal size): the one tier-1 hold on the cell's own runner,
+    example and comparison end to end, which no in-process case is."""
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     env["JAX_PLATFORMS"] = "cpu"
     done = subprocess.run(

@@ -658,7 +658,10 @@ def test_router_control_rounds_both_operands_of_the_routers_product():
 # -- the cell ----------------------------------------------------------------
 def test_the_cell_rehearses_correct_with_its_counters():
     """``cellbench.run --rehearse`` of the cell, in a process of its own
-    (one CPU device, as the cell has one chip)."""
+    (one CPU device, as the cell has one chip).  Over 40 s in the driver's
+    run (a process start, the program's compile and the reference's at
+    rehearsal size): the one tier-1 hold on the cell's own runner,
+    example and comparison end to end, which no in-process case is."""
     import json
     import subprocess
 

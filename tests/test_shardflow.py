@@ -436,7 +436,8 @@ class TestPinnedAttribution:
 
         model = ResNet50(num_classes=1000, train=False)
         x = jnp.zeros((8, 64, 64, 3), jnp.float32)
-        params = model.init(jax.random.PRNGKey(0), x[:1])
+        # one program, not op by op
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), x[:1])
 
         def loss_fn(p, b):
             imgs, labels = b

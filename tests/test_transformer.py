@@ -353,8 +353,9 @@ class TestGenerate:
         model, params, prompt = self._setup()
         fast = generate(model, params, prompt, 6, use_cache=False)
         buf = prompt
+        forward = jax.jit(model.apply)  # a program a length, not op by op
         for _ in range(6):
-            logits = model.apply(params, buf)
+            logits = forward(params, buf)
             nxt = jnp.argmax(logits[:, -1], axis=-1)
             buf = jnp.concatenate([buf, nxt[:, None]], axis=1)
         np.testing.assert_array_equal(np.asarray(fast), np.asarray(buf))
@@ -458,8 +459,9 @@ class TestGenerate:
         twin = _recompute_twin(moe, 1, 8)
         assert twin.capacity == 8  # the no-drop bound, not the model's 2
         buf = prompt
+        forward = jax.jit(twin.apply)  # a program a length, not op by op
         for _ in range(4):
-            out = twin.apply(params, buf)
+            out = forward(params, buf)
             logits = out[0] if isinstance(out, tuple) else out
             nxt = jnp.argmax(
                 logits[:, -1].astype(jnp.float32), axis=-1

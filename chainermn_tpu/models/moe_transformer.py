@@ -89,12 +89,16 @@ class RouterOptions:
     where one runs).  ``routed_scale``: a factor on the renormalised
     weights.  ``shared_gated=False``: the shared expert without its
     gate, ``W_d(silu(W_g x) * W_u x)`` added as it is (no
-    ``shared_gate``)."""
+    ``shared_gate``); several shared experts are one of their summed
+    width.  ``seq_aux``: the load-balancing loss counted a sequence
+    (:func:`~chainermn_tpu.parallel.expert_parallel.sequence_balancing_loss`)
+    instead of over all rows of the batch."""
 
     score: str = "softmax"
     selection_bias: bool = False
     routed_scale: float = 1.0
     shared_gated: bool = True
+    seq_aux: bool = False
 
 
 class MoeMlp(nn.Module):
@@ -214,6 +218,7 @@ class MoeMlp(nn.Module):
             num_experts=self.n_experts, k=self.k, first=first,
             aux_stat_axes=self.aux_stat_axes, score=ro.score,
             selection_bias=bias, routed_scale=ro.routed_scale,
+            aux_sequences=b if ro.seq_aux else None,
         )
         if self.expert_axis is not None:
             y = _cc.psum(y, self.expert_axis)
@@ -249,8 +254,9 @@ class MoeMlp(nn.Module):
         if self.shared_d_ff or self.router_options != RouterOptions():
             raise ValueError(
                 "the shared expert (gated or ungated), sigmoid scores, "
-                "the selection bias and the scaling factor on the routed "
-                "weights (router_options) come with dropless routing")
+                "the selection bias, the scaling factor on the routed "
+                "weights and the balance loss a sequence (router_options) "
+                "come with dropless routing")
         # Per-chip expert blocks are independent draws (fold the axis
         # index into the RNG — tensor_parallel's sharded-init convention).
         from chainermn_tpu.parallel.tensor_parallel import _sharded_init

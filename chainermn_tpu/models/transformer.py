@@ -30,6 +30,7 @@ from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
@@ -72,7 +73,8 @@ class BlockOptions:
     ``n_heads // n_kv_heads`` query heads.  ``head_dim``: width of a
     head where it is not ``d_model // n_heads``.  ``rope_theta``: rotary
     positions of that base on q and k (the model then holds no position
-    table).  ``qk_norm``: an RMSNorm over each head of q and k, before
+    table; in a ``"latent_attention"`` layer they turn the channels all
+    heads share, in neighbouring pairs: :func:`rotate_pairs`).  ``qk_norm``: an RMSNorm over each head of q and k, before
     the rotation.  ``block_diffusion``: block length ``B`` of
     block-diffusion training; the sequence axis then holds the clean
     copy of every sequence followed by its noised copy, both at
@@ -430,6 +432,34 @@ def apply_rope(x, positions, theta: float, fraction: float = 1.0):
     ).astype(x.dtype)
 
 
+def rotate_pairs(x, positions, theta: float, turned: int):
+    """Rotary positions on the last ``turned`` channels of ``x (b, s,
+    heads, dh)``, the neighbours convention: the pair ``(2i, 2i + 1)``
+    of them is turned by ``positions * theta ** (-2i / turned)``, ``(a,
+    b) -> (a cos - b sin, a sin + b cos)``, and the leading channels
+    pass.  ``x`` is not taken apart (on a TPU a slice of a head's
+    channels and the concatenation back cost more than the rotation):
+    the result is ``x * cos + (x @ swap) * sin`` with ``swap`` the
+    signed permutation that brings every turned channel its partner
+    (exact in any dtype) and ``cos`` 1, ``sin`` 0 on the leading
+    channels; angles and products float32."""
+    s, dh = x.shape[1], x.shape[-1]
+    lead = dh - turned
+    freq = theta ** (-jnp.arange(0, turned, 2, dtype=jnp.float32) / turned)
+    ang = jnp.repeat(
+        positions.astype(jnp.float32)[:, None] * freq[None, :], 2, axis=-1)
+    cos = jnp.concatenate([jnp.ones((s, lead)), jnp.cos(ang)], axis=-1)
+    sin = jnp.concatenate([jnp.zeros((s, lead)), jnp.sin(ang)], axis=-1)
+    swap = np.zeros((dh, dh), np.float32)
+    even = np.arange(lead, dh, 2)
+    swap[even + 1, even], swap[even, even + 1] = -1.0, 1.0
+    partner = jnp.einsum("bshd,de->bshe", x, jnp.asarray(swap, x.dtype),
+                         precision=lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos[None, :, None, :]
+            + partner * sin[None, :, None, :]).astype(x.dtype)
+
+
 #: device scope of the attention projections (q, k, v, their norms and
 #: rotation, the output gate and the output projection) on the general
 #: path
@@ -713,7 +743,8 @@ class SelfAttention(nn.Module):
 #: ops.gated_delta, its scan's inside it); of the KDA mixer, its
 #: convolution and its scan (ops.gated_delta's own scope inside it), and
 #: of latent attention's projections (the compression, its norm and
-#: expansion, the queries and the output projection)
+#: expansion, the queries and the output projection) and, beside them,
+#: of the rotation of its shared channels
 GATED_MLP_SCOPE = "gated_mlp"
 SSM_MIXER_SCOPE = "ssm_mixer"
 GDN_MIXER_SCOPE = "gdn_mixer"
@@ -722,6 +753,7 @@ KDA_MIXER_SCOPE = "kda_mixer"
 KDA_CONV_SCOPE = "kda_conv"
 KDA_SCAN_SCOPE = "kda_scan"
 LATENT_PROJ_SCOPE = "latent_proj"
+LATENT_ROPE_SCOPE = "latent_rope"
 
 
 class GatedMlp(nn.Module):
@@ -1007,8 +1039,7 @@ class KdaMixer(nn.Module):
 
 
 class LatentAttention(nn.Module):
-    """Multi-head latent attention without a position (DeepSeek-V2,
-    arXiv:2405.04434, as Kimi Linear's full-attention layers run it):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434):
     keys and values are expanded, a head its own, from one compression
     of ``latent_kv_rank`` channels that all heads share.  With ``dn =
     latent_nope_dim``, ``ds = latent_shared_dim``, ``dv =
@@ -1017,11 +1048,19 @@ class LatentAttention(nn.Module):
         [q_a | q_b] = q_proj(x)                 a head: dn | ds
         [c | k_b] = kv_a_proj(x)                latent_kv_rank | ds
         [k_a | v] = kv_b_proj(RMSNorm(c))       a head: dn | dv
+        q_b, k_b = R_t q_b, R_t k_b             with rope_theta only
         q = [q_a | q_b];  k = [k_a | k_b]       k_b the same for all heads
         o_proj(causal_softmax_attention(q, k, v))   at (dn + ds) ** -0.5
 
-    No channel is rotated (the ``ds`` channels are where other models of
-    the family put a rotation: ``rope_theta`` is refused here).  Keys
+    ``rope_theta``: the family's decoupled rotation (DeepSeek-V2 / V3,
+    Moonlight).  ``R_t`` turns the neighbouring channels ``(2i, 2i +
+    1)`` of the ``ds`` shared ones by ``t * rope_theta ** (-2i / ds)``,
+    ``t`` the position inside the sequence from 0; the ``dn`` channels
+    carry no position (:func:`rotate_pairs`, on the whole query
+    without taking it apart; angles and products float32).  Without
+    ``rope_theta`` (``no_positions``) no channel
+    is rotated, as Kimi Linear's full-attention layers run it, and the
+    program holds no operation of the rotation.  Keys
     are wider than values: with ``use_flash`` the block-causal kernels
     take the two widths apart, else a dense masked softmax runs.
     ``attention_scale`` replaces the scale.  Single-device in the
@@ -1043,9 +1082,6 @@ class LatentAttention(nn.Module):
             raise ValueError(
                 "latent attention is single-device in sequence and heads "
                 "and has no cache: no seq_axis, tp_axis or decode")
-        if o.rope_theta:
-            raise ValueError("latent attention has no rotary form: "
-                             "no_positions, not rope_theta")
         b, s, d = x.shape
         h, rank = self.n_heads, o.latent_kv_rank
         dn, ds, dv = o.latent_nope_dim, o.latent_shared_dim, \
@@ -1064,6 +1100,13 @@ class LatentAttention(nn.Module):
                     functools.partial(rms_norm, eps=o.norm_eps,
                                       dtype=self.dtype))(latent, gain)
                 ).reshape(b, s, h, dn + dv), [dn], axis=-1)
+        if o.rope_theta:
+            # float32 inside, recomputed in the backward pass
+            turn = jax.checkpoint(lambda t: rotate_pairs(
+                t, jnp.arange(s), o.rope_theta, ds))
+            with jax.named_scope(LATENT_ROPE_SCOPE):
+                q, shared = turn(q), turn(shared[:, :, None])[:, :, 0]
+        with jax.named_scope(LATENT_PROJ_SCOPE):
             k = jnp.concatenate([own, jnp.broadcast_to(
                 shared[:, :, None], (b, s, h, ds))], axis=-1)
         scale = o.attention_scale or (dn + ds) ** -0.5

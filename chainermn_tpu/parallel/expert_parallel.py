@@ -235,6 +235,25 @@ def load_balancing_loss(probs: jnp.ndarray,
     return e * jnp.sum(frac * mean_prob)
 
 
+def sequence_balancing_loss(probs: jnp.ndarray, raw_routes: jnp.ndarray,
+                            sequences: int, axes=None) -> jnp.ndarray:
+    """:func:`load_balancing_loss` counted a sequence (DeepSeek-V3's
+    ``seq_aux``, arXiv:2412.19437 eq. 17-20): ``probs`` and
+    ``raw_routes (sequences * T, e)`` hold ``sequences`` whole sequences
+    of ``T`` rows one after another; the fractions and mean
+    probabilities are taken over each sequence's rows, and the products
+    are averaged over the sequences.  With one sequence it is
+    :func:`load_balancing_loss`; sequences that route differently read
+    higher than their batch does.  ``axes``: mesh axes that split the
+    batch into whole sequences, equally many a shard; the mean is over
+    all of them."""
+    e = probs.shape[-1]
+    loss = jnp.mean(jax.vmap(load_balancing_loss)(
+        probs.reshape(sequences, -1, e),
+        raw_routes.reshape(sequences, -1, e)))
+    return lax.pmean(loss, axes) if axes else loss
+
+
 def ep_flow_specs(axis_name: str) -> dict:
     """The MoE layer's sharding declaration for the analysis pass
     (``analysis.shardflow``): tokens arrive sharded over the expert
@@ -639,7 +658,8 @@ MOE_EXPERTS_SCOPE = "moe_experts"
 def held_experts_moe(x, router_w, w_gate, w_up, w_down, *,
                      num_experts: int, k: int, first=0,
                      aux_stat_axes=None, score: str = "softmax",
-                     selection_bias=None, routed_scale: float = 1.0):
+                     selection_bias=None, routed_scale: float = 1.0,
+                     aux_sequences: Optional[int] = None):
     """One chip's part of a dropless mixture-of-experts layer.
 
     ``x (tokens, d)``; ``router_w (d, num_experts)``; ``w_gate`` /
@@ -654,7 +674,9 @@ def held_experts_moe(x, router_w, w_gate, w_up, w_down, *,
     outside the gradient.  Returns ``(y, aux, counters, chosen)``: the
     held experts' part of the result; the load-balancing loss over all
     ``num_experts`` (:func:`load_balancing_loss`, on the scores over
-    their sum where they are sigmoids); and ``moe_rows_routed`` (routes
+    their sum where they are sigmoids; with ``aux_sequences``, the
+    whole sequences ``x`` holds one after another,
+    :func:`sequence_balancing_loss`); and ``moe_rows_routed`` (routes
     to held experts), ``moe_rows_computed`` (rows the expert products
     ran over), ``moe_dropped`` (held routes no path computed: always 0)
     and, under a bias, ``moe_routes_biased`` (routes, of all ``tokens x
@@ -693,7 +715,9 @@ def held_experts_moe(x, router_w, w_gate, w_up, w_down, *,
             weights = weights * routed_scale
         raw_routes = jnp.sum(
             jax.nn.one_hot(chosen, num_experts, dtype=probs.dtype), axis=1)
-        aux = load_balancing_loss(probs, raw_routes, axes=aux_stat_axes)
+        aux = load_balancing_loss(probs, raw_routes, axes=aux_stat_axes) \
+            if aux_sequences is None else sequence_balancing_loss(
+                probs, raw_routes, aux_sequences, axes=aux_stat_axes)
         plan = plan_held_routes(chosen, first, count, block_rows, n_blocks)
     def fast(x, weights, w_gate, w_up, w_down):
         with jax.named_scope(MOE_ROUTE_SCOPE):

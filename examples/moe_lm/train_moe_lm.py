@@ -80,6 +80,23 @@ four that follow; ``cellbench/configs/kimi-linear-48b-a3b.json``):
       --vocab 20480 --seq-len 8192 --batchsize 2 --rmsnorm --norm-eps 1e-5 \
       --untied-head --dropless --flash --remat-blocks --chunked-ce 8 \
       --lr 1e-5 --aux-coef 1e-3
+
+With ``--rope-theta`` a ``latent_attention`` layer rotates the channels
+all heads share (the family's decoupled rotation), and ``--seq-aux``
+counts the load-balancing loss a sequence.  One chip's share of
+Moonlight-16B-A3B (experts 0..7 of 64, an eighth of the vocabulary, the
+leading dense layer and the five that follow;
+``cellbench/configs/moonlight-16b-a3b.json``):
+
+    python examples/moe_lm/train_moe_lm.py --d-model 2048 --n-heads 16 \
+      --rope-theta 50000 --layer-types latent_attention \
+      --latent-kv-rank 512 --d-ff 1408 --shared-d-ff 2816 \
+      --shared-ungated --n-experts 64 --top-k 6 --held 0,8 --moe-every 1 \
+      --first-dense 1 --dense-d-ff 11264 --gated-mlp --n-layers 6 \
+      --router-score sigmoid --router-bias --routed-scale 2.446 --seq-aux \
+      --vocab 20480 --seq-len 8192 --batchsize 2 --rmsnorm --norm-eps 1e-5 \
+      --untied-head --dropless --flash --remat-blocks --chunked-ce 8 \
+      --lr 1e-5 --aux-coef 1e-3
 """
 
 import argparse
@@ -163,10 +180,13 @@ def main(argv=None):
         "the block's options (models.transformer.BlockOptions)")
     g.add_argument("--rope-theta", type=float, default=None,
                    help="rotary positions of this base: the general "
-                        "block, no position table (needs --sp 1)")
+                        "block, no position table (needs --sp 1); in a "
+                        "latent_attention layer the rotation of the "
+                        "--latent-shared-dim channels all heads share")
     g.add_argument("--no-positions", action="store_true",
                    help="no position anywhere: the general block, no "
-                        "position table, no rotation (needs --sp 1)")
+                        "position table, no rotation, a latent_attention "
+                        "layer's shared channels unrotated (needs --sp 1)")
     g.add_argument("--rmsnorm", action="store_true")
     g.add_argument("--n-kv-heads", type=int, default=None,
                    help="key/value heads shared by groups of query heads")
@@ -231,6 +251,10 @@ def main(argv=None):
                         "alone by the optimizer")
     g.add_argument("--routed-scale", type=float, default=1.0,
                    help="factor on the renormalised routed weights")
+    g.add_argument("--seq-aux", action="store_true",
+                   help="the load-balancing loss counted a sequence "
+                        "and averaged over the sequences, not over all "
+                        "rows of the batch (--dropless)")
     g.add_argument("--first-dense", type=int, default=0, metavar="K",
                    help="the first K layers' MLPs are dense")
     g.add_argument("--dense-d-ff", type=int, default=None,
@@ -262,7 +286,8 @@ def main(argv=None):
                         or args.chunked_ce or args.latent_kv_rank
                         or args.shared_ungated or args.router_bias
                         or args.router_score != "softmax"
-                        or args.routed_scale != 1.0 or args.first_dense
+                        or args.routed_scale != 1.0 or args.seq_aux
+                        or args.first_dense
                         or args.dense_d_ff or args.gated_mlp):
         p.error("the block's options come with --rope-theta or "
                 "--no-positions")
@@ -376,7 +401,8 @@ def main(argv=None):
             router_options=RouterOptions(
                 score=args.router_score, selection_bias=args.router_bias,
                 routed_scale=args.routed_scale,
-                shared_gated=not args.shared_ungated),
+                shared_gated=not args.shared_ungated,
+                seq_aux=args.seq_aux),
             first_dense=args.first_dense, dense_d_ff=args.dense_d_ff,
             tie_head=not args.untied_head,
             return_hidden=bool(args.block_diffusion or args.chunked_ce),

@@ -324,17 +324,24 @@ def test_mixers_against_the_reference(layer, kind):
 
 
 def test_new_mixers_are_single_device_and_latent_attention_unrotated():
+    """Under ``no_positions`` the latent-attention layer holds no
+    rotation (it takes one with ``rope_theta``:
+    ``tests/test_moonlight.py``), and both mixers still refuse a
+    sequence axis, a head axis and a cache."""
     x = jnp.zeros((1, 16, 64))
     for kind in ("kda", "latent_attention"):
-        with pytest.raises(ValueError, match="single-device"):
-            make_mixer(kind, 4, _options(SHARE), jnp.float32,
-                       tp_axis="mn_model").init(jax.random.PRNGKey(0), x)
-    import dataclasses
-    rotated = dataclasses.replace(_options(SHARE), no_positions=False,
-                                  rope_theta=1e4)
-    with pytest.raises(ValueError, match="rotary"):
-        make_mixer("latent_attention", 4, rotated, jnp.float32).init(
-            jax.random.PRNGKey(0), x)
+        for refused in (dict(tp_axis="mn_model"), dict(seq_axis="mn_seq"),
+                        dict(decode=True)):
+            with pytest.raises(ValueError, match="single-device"):
+                make_mixer(kind, 4, _options(SHARE), jnp.float32,
+                           **refused).init(jax.random.PRNGKey(0), x)
+    options = _options(SHARE)
+    assert options.no_positions and options.rope_theta is None
+    mixer = make_mixer("latent_attention", 4, options, jnp.float32)
+    variables = jax.eval_shape(mixer.init, jax.random.PRNGKey(0), x)
+    text = jax.jit(mixer.apply).lower(variables, x).as_text(debug_info=True)
+    assert "latent_proj" in text and "latent_rope" not in text
+    assert "sine" not in text
 
 
 def test_kinds_and_what_their_blocks_keep():

@@ -127,7 +127,9 @@ class BlockOptions:
     the Gated DeltaNet mixer's ``[q | k | v | z]`` (``gdn_in``), the
     KDA mixer's ``[q | k | v]`` (``kda_in``) and latent attention's
     queries (``latent_in``), whose matmuls the backward then does not
-    run again.  The budget is
+    run again, and before all of them the attention kernels' result
+    (``attn_out``: the output and its log-sum-exp), whose forward
+    launch the backward then does not run again.  The budget is
     the program's to fill from what it observes
     (:func:`remat_budget`: the device's memory less the state the step
     holds less a reserve); 0, the default, keeps the input alone."""
@@ -187,16 +189,33 @@ class BlockOptions:
         return kind
 
     def remat_widths(self, d_ff: int, n_heads: int = 0,
-                     dtype=jnp.bfloat16) -> dict:
+                     dtype=jnp.bfloat16, d_model: int = 0) -> dict:
         """Width (last axis) of each result of :data:`REMAT_NAMES` that
         a model of these options and activations of ``dtype`` has: ``[g
         | u]`` of the gated MLP, ``[z | xBC | dt]`` of the state-space
         mixer, ``[q | k | v | z]`` of the Gated DeltaNet mixer, ``[q | k
         | v]`` of the KDA mixer, the ``n_heads`` queries of latent
-        attention.  With KDA layers whose scan runs its XLA form here
-        also :data:`KDA_WORK`, no result of a name: it only widens what
-        :func:`remat_budget` leaves the step."""
+        attention.  ``attn_out``, where the attention layers run the
+        kernels (``use_flash``): a token's ``n_heads`` outputs of the
+        values' width (``latent_value_dim`` in a latent layer; in an
+        ``attention`` layer ``head_dim``, or ``d_model / n_heads``
+        without it) and, in the plan's two-byte units, its ``n_heads``
+        float32 log-sum-exps.  None under ``block_diffusion``, whose
+        layer runs two launches under the one name: no cell recomputes
+        such blocks, and a plan that does not list the name leaves
+        their launches as they are.  With KDA layers whose scan runs
+        its XLA form here also :data:`KDA_WORK`, no result of a name:
+        it only widens what :func:`remat_budget` leaves the step."""
         widths = {}
+        kinds = self.layer_types or ("attention",)
+        if self.use_flash and n_heads and not self.block_diffusion:
+            values = {"attention": n_heads * self.head_dim
+                      if self.head_dim else d_model,
+                      "latent_attention": n_heads * self.latent_value_dim}
+            widest = max((w for kind, w in values.items() if kind in kinds),
+                         default=0)
+            if widest:
+                widths["attn_out"] = widest + 2 * n_heads
         if self.gated_mlp:
             widths["mlp_in"] = 2 * d_ff
         if "mamba" in (self.layer_types or ()):
@@ -224,18 +243,27 @@ class BlockOptions:
 
 #: the results a block can keep across its recomputation
 #: (``jax.ad_checkpoint.checkpoint_name``), in the order a budget is
-#: spent on them: :class:`GatedMlp`'s ``in_proj`` result in every layer,
+#: spent on them.  First the attention kernels' result (``out`` and its
+#: log-sum-exp, named in ``ops.pallas_attention``'s forward rules) in
+#: the ``attention`` and ``latent_attention`` layers: it saves the
+#: recomputation a kernel launch, not a matmul, and paid 113 ms a GB
+#: kept where it was first read (7.67 ms for 68 MB a layer of 16 heads,
+#: ``PERF.md`` section 6, PR 48) against the 7 and 14 ms a GB of the
+#: next two.  Then
+#: :class:`GatedMlp`'s ``in_proj`` result in every layer,
 #: :class:`Mamba2Mixer`'s in the ``mamba`` layers, :class:`GatedDeltaMixer`'s
 #: ``in_proj_qkvz`` result in the ``linear_attention`` layers,
 #: :class:`KdaMixer`'s ``in_proj_qkv`` result in the ``kda`` layers,
 #: :class:`LatentAttention`'s ``q_proj`` result in its layers (each saves
 #: one matmul over ``d_model`` a layer; what the first two paid on the
 #: chip: ``PERF.md`` section 6, PR 40).
-REMAT_NAMES = ("mlp_in", "ssm_in", "gdn_in", "kda_in", "latent_in")
-#: the one kind of layer (:data:`LAYER_KINDS`) that has a result of that
+REMAT_NAMES = ("attn_out", "mlp_in", "ssm_in", "gdn_in", "kda_in",
+               "latent_in")
+#: the kinds of layer (:data:`LAYER_KINDS`) that have a result of that
 #: name; a name not here is every layer's with a dense MLP
-_REMAT_KIND = {"ssm_in": LAYER_KINDS[1], "gdn_in": LAYER_KINDS[2],
-               "kda_in": LAYER_KINDS[3], "latent_in": LAYER_KINDS[4]}
+_REMAT_KIND = {"attn_out": (LAYER_KINDS[0], LAYER_KINDS[4]),
+               "ssm_in": LAYER_KINDS[1:2], "gdn_in": LAYER_KINDS[2:3],
+               "kda_in": LAYER_KINDS[3:4], "latent_in": LAYER_KINDS[4:5]}
 
 #: a width among :meth:`BlockOptions.remat_widths` that is no kept
 #: result's: what the channel-wise delta rule's XLA form holds a token in
@@ -285,7 +313,7 @@ def remat_plan(layer_kinds, tokens: int, widths: dict, budget_bytes: int,
             continue
         cost = tokens * widths[name] * itemsize
         for i, kind in enumerate(layer_kinds):
-            if _REMAT_KIND.get(name, kind) != kind or (
+            if kind not in _REMAT_KIND.get(name, (kind,)) or (
                     name == "mlp_in" and dense and not dense[i]):
                 continue
             if cost > left:
@@ -301,7 +329,7 @@ def model_remat_widths(model) -> dict:
     another) and its heads."""
     return model.options.remat_widths(
         getattr(model, "dense_d_ff", None) or model.d_ff
-        or 4 * model.d_model, model.n_heads, model.dtype)
+        or 4 * model.d_model, model.n_heads, model.dtype, model.d_model)
 
 
 def model_remat_plan(model, tokens: int):

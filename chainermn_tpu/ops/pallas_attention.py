@@ -36,11 +36,38 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+
+#: the name (``jax.ad_checkpoint.checkpoint_name``) of a forward launch's
+#: two results as the backward rules hold them: the output and its
+#: log-sum-exp, one name for both, so that no policy keeps one without
+#: the other.  Under a ``jax.checkpoint`` whose policy saves it
+#: (``models.transformer.REMAT_NAMES``) the recomputation holds no
+#: forward launch: the backward kernels read the kept pair.  Under any
+#: other policy, or none, the name is an identity.
+ATTN_OUT = "attn_out"
+
+
+def _named(out, lse):
+    """``(out, lse)`` of a forward launch under :data:`ATTN_OUT`: what
+    the residuals AND the returned pair must be made of (a name on the
+    caller's copy keeps a tensor no backward rule reads).  Heads
+    narrower than the 128 lanes are named side by side, ``(b, s, h
+    dv)``: kept as ``(b, s, h, dv)`` they are padded to the lanes in
+    HBM (at width 64 the kept result held twice its bytes); at whole
+    lanes that form is the slower one (``PERF.md`` section 6, PR 48)."""
+    if out.shape[-1] % 128:
+        b, s, h, dv = out.shape
+        out = checkpoint_name(out.reshape(b, s, h * dv), ATTN_OUT).reshape(
+            b, s, h, dv)
+    else:
+        out = checkpoint_name(out, ATTN_OUT)
+    return out, checkpoint_name(lse, ATTN_OUT)
 
 
 def _should_interpret(interpret: Optional[bool]) -> bool:
@@ -899,6 +926,12 @@ def flash_attention(q, k, v, causal=False, scale=None,
     (PERF.md, PR 28).  Same result up to fp32 summation order inside a
     diagonal block; every other launch runs exactly the code it ran
     before.
+
+    The backward rule's residuals are ``(q, k, v, out, lse)`` with the
+    forward launch's two results named :data:`ATTN_OUT`: a
+    ``jax.checkpoint`` around the caller whose policy saves that name
+    keeps them, and its recomputation then holds no forward launch
+    (under any other policy, or none, the name is an identity).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -1043,8 +1076,9 @@ def _flash_fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
                     bwd_block_q=None, bwd_block_k=None):
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    out, lse = _flash_forward(q, k, v, causal, scale, block_q, block_k,
-                              _should_interpret(interpret))
+    out, lse = _named(*_flash_forward(
+        q, k, v, causal, scale, block_q, block_k,
+        _should_interpret(interpret)))
     return out, (q, k, v, out, lse)
 
 
@@ -1128,8 +1162,8 @@ def _flash_with_lse_fwd_rule(q, k, v, causal, scale, block_q, block_k,
         # Sub-lane-tile compiled shapes: dense path for value AND grads.
         out, lse = _dense_attention_with_lse(q, k, v, causal, scale)
         return (out, lse), (q, k, v, None, None)
-    out, lse_bh = _flash_forward(q, k, v, causal, scale, block_q,
-                                 block_k, interp)
+    out, lse_bh = _named(*_flash_forward(q, k, v, causal, scale, block_q,
+                                         block_k, interp))
     b, s_q, h, _ = q.shape
     lse = jnp.moveaxis(lse_bh.reshape(b, h, s_q), 1, 2)  # (b, s_q, h)
     return (out, lse), (q, k, v, out, lse_bh)
@@ -1813,6 +1847,12 @@ def block_causal_attention_with_lse(q, k, v, block, strict=False,
     a finite average to be weighed with 0).  ``lse`` is (b, s, hq).
     ``s`` must be a whole number of square kernel blocks (``block_size``,
     default 1024, clamped to ``s``), each a whole number of ``block``.
+
+    The backward rule's residuals are ``(q, k, v, out, lse)``, the
+    launch's two results named :data:`ATTN_OUT`: a block recomputed
+    under a plan that lists the name (``models.transformer.remat_plan``)
+    keeps the pair, and its recomputation runs ``q``, ``k`` and ``v``
+    again for the backward kernels but no forward launch.
     """
     return _bc_fwd_rule(q, k, v, block, strict, scale, block_size,
                         interpret, tile)[0]
@@ -1821,8 +1861,9 @@ def block_causal_attention_with_lse(q, k, v, block, strict=False,
 def _bc_fwd_rule(q, k, v, block, strict, scale, block_size, interpret,
                  tile):
     scale = q.shape[-1] ** -0.5 if scale is None else scale
-    out, lse = _bc_forward(q, k, v, block, strict, scale, block_size,
-                           _should_interpret(interpret), tile)
+    out, lse = _named(*_bc_forward(
+        q, k, v, block, strict, scale, block_size,
+        _should_interpret(interpret), tile))
     b, s, hq, _ = q.shape
     return ((out, jnp.moveaxis(lse.reshape(b, hq, s), 1, 2)),
             (q, k, v, out, lse))

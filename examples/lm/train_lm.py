@@ -115,8 +115,11 @@ def main(argv=None):
                         "reported limit less parameters and optimizer "
                         "state less a reserve: "
                         "models.transformer.remat_budget; nothing off "
-                        "the TPU), the in_proj results of the gated MLP "
-                        "(mlp_in) and of the Mamba-2 mixer (ssm_in)")
+                        "the TPU), first the attention kernels' "
+                        "result (attn_out, with --flash on the general "
+                        "path: the backward then runs no forward launch "
+                        "again), then the in_proj results of the gated "
+                        "MLP (mlp_in) and of the Mamba-2 mixer (ssm_in)")
     p.add_argument("--chunked-ce", type=int, default=0, metavar="CHUNKS",
                    help="head + cross-entropy over this many vocabulary "
                         "chunks (ops.chunked_lm_loss): the logits are "
@@ -271,7 +274,7 @@ def main(argv=None):
         # under any plan: the step is traced once, with this one)
         tokens = batch // comm.dp_size * args.seq_len // comm.sp_size
         widths = options.remat_widths(args.d_ff or 4 * args.d_model,
-                                      args.n_heads)
+                                      args.n_heads, d_model=args.d_model)
         options = dataclasses.replace(
             options, remat_budget_bytes=remat_budget(
                 comm.mesh.local_devices[0], (params, opt_state), tokens,

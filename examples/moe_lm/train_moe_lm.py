@@ -266,8 +266,11 @@ def main(argv=None):
                    help="compute each block's forward again in the "
                         "backward pass; kept besides the blocks' inputs, "
                         "as far as the device's memory goes "
-                        "(models.transformer.remat_budget): the dense "
-                        "MLP's and the mixers' in-projections' results "
+                        "(models.transformer.remat_budget): first the "
+                        "attention kernels' result (attn_out, with "
+                        "--flash: the backward then runs no forward "
+                        "launch again), then the dense MLP's and the "
+                        "mixers' in-projections' results "
                         "(models.transformer.REMAT_NAMES)")
     g.add_argument("--chunked-ce", type=int, default=0, metavar="CHUNKS",
                    help="head + cross-entropy over this many vocabulary "

@@ -8,6 +8,7 @@ against the uncut layer, and ``MoeTransformerLM`` against
 ``cellbench/reference/kimi_linear.py`` (the recurrence a token, a dense
 masked softmax, a loop over the held experts)."""
 
+import dataclasses
 import json
 import os
 import sys
@@ -347,9 +348,13 @@ def test_new_mixers_are_single_device_and_latent_attention_unrotated():
 def test_kinds_and_what_their_blocks_keep():
     assert LAYER_KINDS == ("attention", "mamba", "linear_attention", "kda",
                            "latent_attention")
-    assert REMAT_NAMES == ("mlp_in", "ssm_in", "gdn_in", "kda_in",
-                           "latent_in")
+    assert REMAT_NAMES == ("attn_out", "mlp_in", "ssm_in", "gdn_in",
+                           "kda_in", "latent_in")
     o = _options(CONFIG)
+    # with the kernels the latent layer has a result to keep: 32 heads of
+    # 128 and their float32 log-sum-exps, spent first
+    flash = dataclasses.replace(o, use_flash=True).remat_widths(9216, 32)
+    assert list(flash)[0] == "attn_out" and flash["attn_out"] == 4160
     widths = o.remat_widths(9216, 32)
     # kda_work is no result: it widens what remat_budget leaves the step
     assert widths == {"mlp_in": 18432, "kda_in": 12288, "latent_in": 6144,

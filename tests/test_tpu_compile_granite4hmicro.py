@@ -29,10 +29,10 @@ _CELL_LAYERS = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 #: plan -> (sequences a step, layers, what the example says it keeps)
 _PLANS = {
     # the cell: one 8192-token sequence, all ten layers, every result
-    "cell": (1, _CELL_LAYERS, "mlp_in x10, ssm_in x9"),
+    "cell": (1, _CELL_LAYERS, "attn_out x1, mlp_in x10, ssm_in x9"),
     # two sequences a step, a shorter plan from the same code; five of
     # the layers, on a device that reports the other five's state less
-    "two_sequences": (2, _CELL_LAYERS[3:8], "mlp_in x4"),
+    "two_sequences": (2, _CELL_LAYERS[3:8], "attn_out x1, mlp_in x4"),
 }
 
 #: HLO ``copy`` instructions under the ``ssm_mixer`` scope in the cell's
@@ -65,7 +65,8 @@ def hybrid_step(request, lm_step_builder):
     sizes = dict(n_layers=len(layer_types), d_model=2048, n_heads=32,
                  vocab=12544, seq_len=8192, per_chip_batch=rows, d_ff=8192,
                  chunked_ce=7, lr=1e-4)
-    tokens, widths = rows * 8192, options.remat_widths(8192)
+    tokens, widths = rows * 8192, options.remat_widths(
+        8192, sizes["n_heads"], d_model=sizes["d_model"])
     with pytest.MonkeyPatch.context() as patch:
         # the program asks the backend which form of the scan to trace
         patch.setattr(jax, "default_backend", lambda: "tpu")
@@ -106,10 +107,16 @@ def test_the_step_fits_the_chip(hybrid_step):
 
 
 def test_the_kernels_are_in_the_step(hybrid_step):
-    """The scan's, the attention's and the convolution's kernels."""
+    """The scan's, the attention's and the convolution's kernels; the
+    attention layer keeps ``attn_out``, so its three launches are a
+    forward and the backward's two, none recomputed."""
     for kernel in ("_ssd_forward", "_ssd_backward", "_bdflash_forward",
                    "ssm_conv/_conv_backward"):
         assert f"{kernel}/pallas_call" in hybrid_step.text, kernel
+    attention = {name for name in re.findall(
+        r'op_name="([^"]*/pallas_call)"', hybrid_step.text)
+        if "/_bdflash_" in name}
+    assert len(attention) == 3, sorted(attention)
 
 
 @only_the_cell

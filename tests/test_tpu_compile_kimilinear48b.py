@@ -103,24 +103,28 @@ def kimilinear_step(moe_step_builder):
 
 def test_the_plan_is_what_the_example_chooses(kimilinear_step):
     """With the channel-wise rule's kernels ``remat_widths`` holds no
-    ``KDA_WORK`` and the plan is ``mlp_in x1, kda_in x4, latent_in x1``
-    (2.42 GB; with the XLA form nothing could be kept: ``PERF.md``
-    section 6, PR 43).  (This case's junit time is the module's one
+    ``KDA_WORK`` and the plan is ``attn_out x1, mlp_in x1, kda_in x4,
+    latent_in x1`` (2.56 GB, the latent layer's attention result first;
+    with the XLA form nothing could be kept: ``PERF.md`` section 6,
+    PR 43).  (This case's junit time is the module's one
     compile.)"""
     from chainermn_tpu.models.transformer import KDA_WORK
 
     # off the TPU the XLA form runs, and its reserve with it
     assert KDA_WORK in kimilinear_step.widths_off_tpu
     assert kimilinear_step.widths == {
-        "mlp_in": 18432, "kda_in": 12288, "latent_in": 6144}
-    assert kimilinear_step.said == "mlp_in x1, kda_in x4, latent_in x1"
+        "attn_out": 4160, "mlp_in": 18432, "kda_in": 12288,
+        "latent_in": 6144}
+    assert kimilinear_step.said \
+        == "attn_out x1, mlp_in x1, kda_in x4, latent_in x1"
     assert kimilinear_step.kept_bytes == kimilinear_step.tokens * 2 * (
-        18432 + 4 * 12288 + 6144)
+        4160 + 18432 + 4 * 12288 + 6144)
 
 
 def test_the_step_fits_the_chip(kimilinear_step):
-    """Arguments and temporaries (15.41 GB counted ahead of time) stay
-    1 GB under the limit the chip reports."""
+    """Arguments and temporaries (15.13 GB counted ahead of time with
+    the latent layer's attention result among the kept) stay 1 GB under
+    the limit the chip reports."""
     memory = kimilinear_step.memory
     assert memory.argument_size_in_bytes == pytest.approx(
         602_450_816 * 12, rel=1e-3)
@@ -131,16 +135,20 @@ def test_the_step_fits_the_chip(kimilinear_step):
 
 
 def test_the_kernels_are_in_the_step(kimilinear_step):
-    """The causal kernels at 192 / 128, the grouped products and the
-    delta rule's kernels; every ``pallas_call`` of the mixers lies under
-    ``kda_scan`` or ``kda_conv`` and no ``while`` is left under the
-    scan."""
+    """The causal kernels at 192 / 128 (a forward and the backward's
+    two: the block keeps ``attn_out`` and recomputes no launch), the
+    grouped products and the delta rule's kernels; every ``pallas_call``
+    of the mixers lies under ``kda_scan`` or ``kda_conv`` and no
+    ``while`` is left under the scan."""
     text = kimilinear_step.text
     for kernel in ("_bdflash_forward", "_bdflash_backward_dq",
                    "_bdflash_backward_dkdv", "_grouped_matmul",
                    "_grouped_matmul_dw", "_kda_forward", "_kda_backward"):
         assert f"{kernel}/pallas_call" in text, kernel
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    attention = [name for name in op_names if "LatentAttention" in name
+                 and name.endswith("/pallas_call")]
+    assert len(attention) == 3, sorted(attention)
     kernels = [name for name in op_names if "kda_mixer" in name
                and name.endswith("/pallas_call")]
     # four layers: the convolution's backward (PR 45) under its scope,

@@ -72,7 +72,8 @@ def qwen3next_step(moe_step_builder):
     with pytest.MonkeyPatch.context() as patch:
         # the program asks the backend which form of the scan to trace
         patch.setattr(jax, "default_backend", lambda: "tpu")
-        widths = options.remat_widths(cfg["moe_intermediate_size"])
+        widths = options.remat_widths(cfg["moe_intermediate_size"],
+                                      cfg["num_attention_heads"])
         _, state = moe_step_builder(options=options, **sizes)
         budget = remat_budget(
             types.SimpleNamespace(
@@ -89,9 +90,12 @@ def qwen3next_step(moe_step_builder):
 
 
 def test_the_plan_is_what_the_example_chooses(qwen3next_step):
-    """(This case's junit time is the module's one compile.)"""
+    """The full-attention layer's result first (16 heads of 256 and
+    their log-sum-exps), then the three mixers' in-projections.  (This
+    case's junit time is the module's one compile.)"""
     assert qwen3next_step.kept == (
-        "gdn_in x3", 3 * qwen3next_step.tokens * 12288 * 2)
+        "attn_out x1, gdn_in x3",
+        qwen3next_step.tokens * (4128 + 3 * 12288) * 2)
 
 
 def test_the_step_holds_what_the_chip_has_room_for(qwen3next_step):
@@ -99,7 +103,8 @@ def test_the_step_holds_what_the_chip_has_room_for(qwen3next_step):
     ``memory_analysis()`` counted 11.16 GB of temporaries where the chip
     reserved 8.61 while the delta rule ran in XLA (``PERF.md`` section
     6, PR 41); with its kernels (PR 42) it counts 7.09 GB where the
-    chip reserves 6.11."""
+    chip reserves 6.11; 6.46 GB with the convolution's kernel (PR 45)
+    and the attention layer's result kept (PR 48: 0.14 GB of them)."""
     memory = qwen3next_step.memory
     assert memory.argument_size_in_bytes == pytest.approx(
         625_667_136 * 12, rel=1e-3)
@@ -110,7 +115,9 @@ def test_the_step_holds_what_the_chip_has_room_for(qwen3next_step):
 
 
 def test_the_kernels_are_in_the_step(qwen3next_step):
-    """The causal kernels at head width 256, the grouped products and
+    """The causal kernels at head width 256 (a forward and the
+    backward's two: the block keeps ``attn_out`` and recomputes no
+    launch), the grouped products and
     the delta rule's kernels; every ``pallas_call`` of the mixers lies
     under ``gdn_scan`` or ``gdn_conv`` and no ``while`` is left under
     the scan."""
@@ -120,6 +127,9 @@ def test_the_kernels_are_in_the_step(qwen3next_step):
                    "_grouped_matmul_dw", "_gdn_forward", "_gdn_backward"):
         assert f"{kernel}/pallas_call" in text, kernel
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    attention = [name for name in op_names if "/_bdflash_" in name
+                 and name.endswith("/pallas_call")]
+    assert len(attention) == 3, sorted(attention)
     kernels = [name for name in op_names if "gdn_mixer" in name
                and name.endswith("/pallas_call")]
     # three layers: the convolution's backward (PR 45) under its scope

@@ -129,7 +129,11 @@ class BlockOptions:
     queries (``latent_in``), whose matmuls the backward then does not
     run again, and before all of them the attention kernels' result
     (``attn_out``: the output and its log-sum-exp), whose forward
-    launch the backward then does not run again.  The budget is
+    launch the backward then does not run again, and after all of them
+    the delta rule's kernels' (``scan_out``: ``o`` and the launch's
+    float32 residuals, the state entering every chunk and every chunk's
+    ``T``) in the ``linear_attention`` and ``kda`` layers, on the same
+    terms.  The budget is
     the program's to fill from what it observes
     (:func:`remat_budget`: the device's memory less the state the step
     holds less a reserve); 0, the default, keeps the input alone."""
@@ -203,11 +207,30 @@ class BlockOptions:
         float32 log-sum-exps.  None under ``block_diffusion``, whose
         layer runs two launches under the one name: no cell recomputes
         such blocks, and a plan that does not list the name leaves
-        their launches as they are.  With KDA layers whose scan runs
-        its XLA form here also :data:`KDA_WORK`, no result of a name:
-        it only widens what :func:`remat_budget` leaves the step."""
+        their launches as they are.  ``scan_out``, only where the delta
+        rule's layers run their kernels here
+        (``ops.gated_delta.runs_kernels``; off the TPU and at sizes
+        that do not tile nothing of that name is there to keep): in the
+        plan's two-byte units a token's ``heads x dv`` of ``o``, its
+        share of the float32 state entering its chunk (``heads x dk x
+        dv x 2 / chunk``) and of the chunk's float32 ``T`` (``heads x 2
+        x chunk``), 24 576 at 32 heads of 128 and a chunk of 64.  With
+        KDA layers whose scan runs its XLA form here also
+        :data:`KDA_WORK`, no result of a name: it only widens what
+        :func:`remat_budget` leaves the step."""
+        from chainermn_tpu.ops.gated_delta import runs_kernels
+
         widths = {}
         kinds = self.layer_types or ("attention",)
+        heads, dk, dv = (self.gdn_value_heads, self.gdn_key_dim,
+                         self.gdn_value_dim)
+        # whether each kind of delta-rule layer here runs its kernels
+        scan_kernels = {
+            kind: runs_kernels(self.gdn_chunk, heads, key_heads, dk, dv,
+                               dtype, channels)
+            for kind, key_heads, channels in (
+                ("linear_attention", self.gdn_key_heads, False),
+                ("kda", heads, True)) if kind in kinds}
         if self.use_flash and n_heads and not self.block_diffusion:
             values = {"attention": n_heads * self.head_dim
                       if self.head_dim else d_model,
@@ -225,19 +248,15 @@ class BlockOptions:
             widths["gdn_in"] = 2 * self.gdn_key_heads * self.gdn_key_dim \
                 + 2 * self.gdn_value_heads * self.gdn_value_dim
         if "kda" in (self.layer_types or ()):
-            widths["kda_in"] = self.gdn_value_heads * (
-                2 * self.gdn_key_dim + self.gdn_value_dim)
-            from chainermn_tpu.ops.gated_delta import runs_kernels
-
-            if not runs_kernels(
-                    self.gdn_chunk, self.gdn_value_heads,
-                    self.gdn_value_heads, self.gdn_key_dim,
-                    self.gdn_value_dim, dtype, channels=True):
-                widths[KDA_WORK] = 2 * KDA_WORK_TENSORS \
-                    * self.gdn_value_heads * self.gdn_key_dim
+            widths["kda_in"] = heads * (2 * dk + dv)
+            if not scan_kernels["kda"]:
+                widths[KDA_WORK] = 2 * KDA_WORK_TENSORS * heads * dk
         if "latent_attention" in (self.layer_types or ()):
             widths["latent_in"] = n_heads * (
                 self.latent_nope_dim + self.latent_shared_dim)
+        if any(scan_kernels.values()):
+            widths["scan_out"] = heads * dv + heads * dk * dv * 2 \
+                // self.gdn_chunk + heads * 2 * self.gdn_chunk
         return widths
 
 
@@ -256,14 +275,24 @@ class BlockOptions:
 #: :class:`KdaMixer`'s ``in_proj_qkv`` result in the ``kda`` layers,
 #: :class:`LatentAttention`'s ``q_proj`` result in its layers (each saves
 #: one matmul over ``d_model`` a layer; what the first two paid on the
-#: chip: ``PERF.md`` section 6, PR 40).
+#: chip: ``PERF.md`` section 6, PR 40).  Last the delta rule's kernels'
+#: result (``o``, the state entering every chunk and every chunk's
+#: ``T``, named in ``ops.gated_delta_kernels``' and ``ops.kda_kernels``'
+#: forward rules) in the ``linear_attention`` and ``kda`` layers: a
+#: launch again, but of 805 MB a layer of 32 heads at 16 384 tokens
+#: (two of its three arrays are float32 residuals): it paid 7.2 ms a GB
+#: kept where it was first read (17.4 ms of step for 2.42 GB in three
+#: layers; 12.4 where a launch is 10.6 ms, ``PERF.md`` section 6,
+#: PR 49), what the in-projections pay.  Last, so that it takes what
+#: the other names leave and displaces none of them.
 REMAT_NAMES = ("attn_out", "mlp_in", "ssm_in", "gdn_in", "kda_in",
-               "latent_in")
+               "latent_in", "scan_out")
 #: the kinds of layer (:data:`LAYER_KINDS`) that have a result of that
 #: name; a name not here is every layer's with a dense MLP
 _REMAT_KIND = {"attn_out": (LAYER_KINDS[0], LAYER_KINDS[4]),
                "ssm_in": LAYER_KINDS[1:2], "gdn_in": LAYER_KINDS[2:3],
-               "kda_in": LAYER_KINDS[3:4], "latent_in": LAYER_KINDS[4:5]}
+               "kda_in": LAYER_KINDS[3:4], "latent_in": LAYER_KINDS[4:5],
+               "scan_out": LAYER_KINDS[2:4]}
 
 #: a width among :meth:`BlockOptions.remat_widths` that is no kept
 #: result's: what the channel-wise delta rule's XLA form holds a token in
@@ -278,9 +307,10 @@ _REMAT_KIND = {"attn_out": (LAYER_KINDS[0], LAYER_KINDS[4]),
 #: result kept do not: ``PERF.md`` section 6, PR 43).  Goes with the
 #: XLA form: where the scan runs its kernels (``ops.gated_delta.
 #: runs_kernels``) :meth:`BlockOptions.remat_widths` leaves it out, and
-#: what the kernels hold (the entering states and ``T`` of one block's
-#: backward, 671 MB at that shape) lies inside :data:`REMAT_TEMPORARIES`
-#: (``PERF.md`` section 6, PR 44).
+#: what the kernels hold of a layer whose ``scan_out`` is not kept (the
+#: entering states and ``T`` of one block's backward, 671 MB at that
+#: shape) lies inside :data:`REMAT_TEMPORARIES` (``PERF.md`` section 6,
+#: PR 44); a kept layer's are counted by the plan.
 KDA_WORK = "kda_work"
 KDA_WORK_TENSORS = 5
 
@@ -289,7 +319,12 @@ KDA_WORK_TENSORS = 5
 #: of ``PERF.md`` reads 6.6 of them ahead of time at one 8192-token
 #: sequence and 6.3 at two: the blocks' inputs, one block's backward,
 #: the head's chunks; known from shapes only this roughly), and bytes
-#: clear of the device's limit besides
+#: clear of the device's limit besides.  "Widest" leaves ``scan_out``
+#: out: its width is twice the widest activation's only because two of
+#: its three arrays are float32 residuals, the step's temporaries do
+#: not grow with it, and a reserve of eight of it (6.44 GB where 3.22
+#: and 4.83 stand in the two cells that have the name) would have the
+#: plans keep less than without the name
 REMAT_TEMPORARIES = 8
 REMAT_CLEAR_BYTES = 1 << 30
 
@@ -300,7 +335,12 @@ def remat_plan(layer_kinds, tokens: int, widths: dict, budget_bytes: int,
     recomputation, a tuple of :data:`REMAT_NAMES` a layer: names are
     added while their bytes (``tokens x widths[name] x itemsize`` a
     layer) fit into ``budget_bytes``, in :data:`REMAT_NAMES`' order, the
-    first layers first.  ``layer_kinds``: each layer's mixer
+    first layers first; ``scan_out`` the last layers first (the backward
+    pass starts at the last block with every kept result still held,
+    and a block that keeps ``scan_out`` holds there the states and ``T``
+    it would have computed again: ahead of time the last of four
+    layers' 805 MB cost the step's peak nothing and the first's 0.85
+    GB, ``PERF.md`` section 6, PR 49).  ``layer_kinds``: each layer's mixer
     (:meth:`BlockOptions.layer_type`); ``tokens``: the positions one
     device holds a step; ``widths``: :meth:`BlockOptions.remat_widths`;
     ``dense``: whether each layer's MLP is the dense one (``None``:
@@ -308,11 +348,12 @@ def remat_plan(layer_kinds, tokens: int, widths: dict, budget_bytes: int,
     nothing kept."""
     kept = [() for _ in layer_kinds]
     left = budget_bytes
+    layers = list(enumerate(layer_kinds))
     for name in REMAT_NAMES:
         if name not in widths:
             continue
         cost = tokens * widths[name] * itemsize
-        for i, kind in enumerate(layer_kinds):
+        for i, kind in reversed(layers) if name == "scan_out" else layers:
             if kind not in _REMAT_KIND.get(name, (kind,)) or (
                     name == "mlp_in" and dense and not dense[i]):
                 continue
@@ -375,8 +416,9 @@ def remat_budget(device, state, tokens: int, widths: dict,
     results: the limit the device reports (``memory_stats()
     ["bytes_limit"]``) less the bytes it holds of ``state`` (a tree of
     arrays: parameters and the optimizer's state), less
-    :data:`REMAT_TEMPORARIES` tensors of the widest result for the
-    step's own temporaries, less :data:`REMAT_CLEAR_BYTES`.  0 where the
+    :data:`REMAT_TEMPORARIES` tensors of the widest result but
+    ``scan_out`` for the step's own temporaries, less
+    :data:`REMAT_CLEAR_BYTES`.  0 where the
     device reports no limit (off the TPU) or nothing is left."""
     limit = (device.memory_stats() or {}).get("bytes_limit")
     if not limit or not widths:
@@ -384,7 +426,8 @@ def remat_budget(device, state, tokens: int, widths: dict,
     held = sum(
         math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
         for x in jax.tree_util.tree_leaves(state))
-    reserve = REMAT_TEMPORARIES * tokens * max(widths.values()) * itemsize \
+    widest = max(w for name, w in widths.items() if name != "scan_out")
+    reserve = REMAT_TEMPORARIES * tokens * widest * itemsize \
         + REMAT_CLEAR_BYTES
     return max(0, int(limit) - held - reserve)
 

@@ -81,15 +81,21 @@ the kernels do not tile.
   ``gated_delta_scan`` is then a ``jax.custom_vjp``: a forward that is
   not differentiated keeps nothing; the differentiated one keeps its
   operands, the two layouts of ``G`` and ``beta``, the state entering
-  every chunk (``(chunks, heads, dk, dv)`` float32 a sequence, 268 MB at
-  the cell's shape) and every chunk's ``T`` (67 MB); the backward kernel
+  every chunk (``(chunks, heads, dk, dv)`` float32 a sequence: 268 MB
+  a sequence of 8192 positions and 32 heads of 128, 537 MB a layer at
+  the cells' two) and every chunk's ``T`` (67 MB a sequence, 134 MB a
+  layer); the backward kernel
   walks the chunks last to first with the states' cotangent in VMEM and
   computes decays, ``U``, ``W`` and ``V'`` again per tile.  The
   channel-wise rule's do the same a value head, with the ``(chunk, dk)``
   tile of ``g`` read as the mixer left it, its running sum and every
   decayed operand made on the chip, and ``dg`` a channel written in
-  ``g``'s layout (twice the residuals: 537 and 134 MB at the cell's
-  shape).
+  ``g``'s layout (the same residuals: 32 heads in both cells).  The
+  launch's results, ``o`` and the two residuals (805 MB a layer with
+  ``o``'s 134), carry the name ``scan_out``
+  (:data:`gated_delta_kernels.SCAN_OUT`): a block recomputed under a
+  plan that lists it (``models.transformer.remat_plan``) keeps them
+  and runs no forward launch again.
 * **The XLA form** has three stages: what needs no state,
   :data:`CHUNKS_PER_PASS` chunks at a time (:data:`CHUNKS_PER_PASS_CHANNELS`
   under the channel-wise rule) under ``jax.checkpoint`` with

@@ -40,6 +40,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -67,6 +68,17 @@ CHUNKS_PER_POINT = 4
 SOLVE_BLOCK = 16
 LANES = 128
 _F32 = jnp.float32
+#: the name (``jax.ad_checkpoint.checkpoint_name``) of a differentiated
+#: forward launch's three results, this rule's and the channel-wise
+#: rule's (a model has one or the other): ``o``, the state entering
+#: every chunk and every chunk's ``T``, one name for all three, since a
+#: launch with one result not kept still runs.  Under a
+#: ``jax.checkpoint`` whose policy saves it
+#: (``models.transformer.REMAT_NAMES``) the recomputation holds no
+#: forward launch: the backward kernel reads the kept states and ``T``,
+#: the block's backward the kept ``o``.  Under any other policy, or
+#: none, the name is an identity.
+SCAN_OUT = "scan_out"
 
 
 def tiles(chunk: int, heads: int, key_heads: int, dk: int, dv: int) -> bool:
@@ -441,9 +453,19 @@ def gated_delta_chunks(q, k, v, cum, beta, chunk, dtype, interpret):
     return o
 
 
+def named(o, states, inverses):
+    """A differentiated forward launch's results under
+    :data:`SCAN_OUT`: what the residuals AND the returned ``o`` must be
+    made of (a name on the caller's copy keeps a tensor no backward
+    rule reads)."""
+    return tuple(checkpoint_name(t, SCAN_OUT)
+                 for t in (o, states, inverses))
+
+
 def _gdn_fwd(q, k, v, cum, beta, chunk, dtype, interpret):
-    (o, states, inverses), rows = _forward(
+    out, rows = _forward(
         q, k, v, cum, beta, chunk, dtype, interpret, keep=True)
+    o, states, inverses = named(*out)
     return o, (q, k, v, states, inverses, rows)
 
 

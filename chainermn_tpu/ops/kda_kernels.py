@@ -421,8 +421,8 @@ def kda_chunks(q, k, v, g, beta, chunk, dtype, interpret):
 
 def _kda_fwd(q, k, v, g, beta, chunk, dtype, interpret):
     rows = _rows(beta, chunk)
-    o, states, inverses = _forward(q, k, v, g, rows, chunk, dtype,
-                                   interpret, keep=True)
+    o, states, inverses = scalar_rule.named(*_forward(
+        q, k, v, g, rows, chunk, dtype, interpret, keep=True))
     return o, (q, k, v, g, rows, states, inverses)
 
 

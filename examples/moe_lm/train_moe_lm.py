@@ -270,7 +270,8 @@ def main(argv=None):
                         "attention kernels' result (attn_out, with "
                         "--flash: the backward then runs no forward "
                         "launch again), then the dense MLP's and the "
-                        "mixers' in-projections' results "
+                        "mixers' in-projections' results, last the "
+                        "delta rule's kernels' (scan_out) "
                         "(models.transformer.REMAT_NAMES)")
     g.add_argument("--chunked-ce", type=int, default=0, metavar="CHUNKS",
                    help="head + cross-entropy over this many vocabulary "

@@ -349,7 +349,7 @@ def test_kinds_and_what_their_blocks_keep():
     assert LAYER_KINDS == ("attention", "mamba", "linear_attention", "kda",
                            "latent_attention")
     assert REMAT_NAMES == ("attn_out", "mlp_in", "ssm_in", "gdn_in",
-                           "kda_in", "latent_in")
+                           "kda_in", "latent_in", "scan_out")
     o = _options(CONFIG)
     # with the kernels the latent layer has a result to keep: 32 heads of
     # 128 and their float32 log-sum-exps, spent first

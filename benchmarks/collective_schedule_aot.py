@@ -79,9 +79,11 @@ def _abstract_arguments(mesh, opt, params, specs, tokens, batch_spec):
 
 def build_lm_step(devices, *, n_layers=18, d_model=1536, n_heads=12,
                   vocab=50257, seq_len=2048, per_chip_batch=4, d_ff=None,
-                  options=None, chunked_ce=0, lr=1e-3):
+                  options=None, chunked_ce=0, lr=1e-3, grad_wire=False):
     """The LM cells' step over ``devices`` (described or attached) and
     its abstract arguments ``(params, opt_state, batch)``, shardings on.
+    ``grad_wire``: the example's ``--grad-wire`` step (no
+    ``param_specs``: the optimizer's bucketed wire ships the gradients).
     ``options`` (a ``BlockOptions``), ``d_ff`` and ``chunked_ce`` build
     the example's model under its block flags instead of the GPT-2
     block with the flash kernels as ``attention_fn``."""
@@ -132,7 +134,7 @@ def build_lm_step(devices, *, n_layers=18, d_model=1536, n_heads=12,
 
     step = cmn.build_train_step(
         comm, loss_fn, opt, data_axes=comm.data_axis_names,
-        param_specs=specs, batch_specs=batch_spec)
+        param_specs=None if grad_wire else specs, batch_specs=batch_spec)
 
     return step, _abstract_arguments(mesh, opt, params, specs, tokens,
                                      batch_spec)
@@ -220,6 +222,9 @@ def main(argv=None):
     p.add_argument("--chips", type=int, default=4, choices=(1, 2, 4))
     p.add_argument("--options", type=json.loads, default=None,
                    help="JSON object: replaces the builder's option set")
+    p.add_argument("--grad-wire", action="store_true",
+                   help="the example's --grad-wire step: the bucketed "
+                        "wire's collectives in place of a psum a leaf")
     p.add_argument("--hlo", default="", help="write the program text here")
     args = p.parse_args(argv)
 
@@ -238,7 +243,8 @@ def main(argv=None):
                           args.options)
     with one_process(), patch:
         step, abstract = build_lm_step(
-            topo.devices[:args.chips], n_layers=args.layers)
+            topo.devices[:args.chips], n_layers=args.layers,
+            grad_wire=args.grad_wire)
         t0 = time.perf_counter()
         compiled = step.get_jitted(*abstract[:2]).lower(*abstract).compile()
     text = compiled.as_text()

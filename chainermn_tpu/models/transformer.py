@@ -26,7 +26,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -56,9 +56,31 @@ class MlpBlock(nn.Module):
 #: (``BlockOptions.layer_types``; the examples' ``--layer-types`` and
 #: :func:`remat_plan` read them here): :class:`SelfAttention`,
 #: :class:`Mamba2Mixer`, :class:`GatedDeltaMixer`, :class:`KdaMixer`,
-#: :class:`LatentAttention`
+#: :class:`LatentAttention`, and :class:`SelfAttention` again under the
+#: window layers' fields of :class:`BlockOptions`
 LAYER_KINDS = ("attention", "mamba", "linear_attention", "kda",
-               "latent_attention")
+               "latent_attention", "window_attention")
+
+
+class YarnScaling(NamedTuple):
+    """YaRN (arXiv:2309.00071) on a rotation's frequencies, as
+    ``transformers``' ``_compute_yarn_parameters`` reads a config's
+    ``rope_parameters``: positions interpolated by ``factor`` on the
+    slow channels, left as they are on the fast ones, a linear ramp
+    between (:func:`yarn_frequencies`), and cos and sin times
+    ``attention_factor`` (``None``: ``0.1 ln(factor) + 1``)."""
+
+    factor: float
+    original_length: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    @property
+    def cos_sin_factor(self) -> float:
+        if self.attention_factor is not None:
+            return self.attention_factor
+        return 0.1 * math.log(self.factor) + 1.0 if self.factor > 1 else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +110,19 @@ class BlockOptions:
     output projection reads ``attention * sigmoid(gate)``.
     ``zero_centered_norm``: every RMSNorm gain of the block and of the
     model (the q/k norms too, not a mixer's own gated norm) is ``1 +
-    w`` with ``w`` initialised 0.
+    w`` with ``w`` initialised 0.  ``head_gate``: one gate a head,
+    ``sigmoid(g_proj(x))`` with ``g_proj (d, heads)``, on the head's
+    whole output before the output projection (arXiv:2505.06708's
+    head-wise form; ``attn_output_gate`` is its element-wise one).
+    ``rope_yarn``: a :class:`YarnScaling` on the ``"attention"``
+    layers' rotation.  The ``"window_attention"`` layers are
+    :class:`SelfAttention` too, each query seeing the last ``window``
+    keys, its own among them (``i - window < j <= i``), with
+    ``window_heads`` query heads (0: the model's) on the same
+    ``n_kv_heads`` of ``head_dim``, and a rotation of base
+    ``window_rope_theta`` over ``window_rotary_fraction`` of a head
+    (``None``: the attention layers'), never YaRN-scaled: a layer's
+    head count, window and rotation are its kind's.
     Any of the attention options takes :class:`SelfAttention` off its
     fused-qkv path onto separate ``q_proj`` / ``k_proj`` / ``v_proj`` /
     ``o_proj`` kernels; that path is single-device in the sequence and
@@ -149,6 +183,12 @@ class BlockOptions:
     attention_scale: Optional[float] = None
     rotary_fraction: float = 1.0
     attn_output_gate: bool = False
+    head_gate: bool = False
+    rope_yarn: Optional[YarnScaling] = None
+    window: int = 0
+    window_heads: int = 0
+    window_rope_theta: Optional[float] = None
+    window_rotary_fraction: Optional[float] = None
     zero_centered_norm: bool = False
     layer_types: Optional[Tuple[str, ...]] = None
     ssm_heads: int = 0
@@ -180,7 +220,26 @@ class BlockOptions:
                     or self.qk_norm or self.block_diffusion
                     or self.attention_scale or self.no_positions
                     or self.attn_output_gate
-                    or self.rotary_fraction != 1.0)
+                    or self.rotary_fraction != 1.0
+                    # the general path's raises (no seq_axis, tp_axis or
+                    # decode) cover these too
+                    or self.layer_attention)
+
+    @property
+    def layer_attention(self) -> bool:
+        """Whether attention here has what only the general path and no
+        cache has: window layers (their fields), a gate a head, a
+        scaled rotation."""
+        return bool(self.head_gate or self.rope_yarn or self.window
+                    or self.window_heads or self.window_rope_theta
+                    or self.window_rotary_fraction is not None
+                    or "window_attention" in (self.layer_types or ()))
+
+    def attention_heads(self, kind: str, n_heads: int) -> int:
+        """The query heads of a layer of ``kind`` in a model of
+        ``n_heads``: the window layers have their own count."""
+        return self.window_heads or n_heads \
+            if kind == "window_attention" else n_heads
 
     def layer_type(self, layer: int) -> str:
         """The kind of layer ``layer``'s sequence mixer."""
@@ -203,8 +262,10 @@ class BlockOptions:
         kernels (``use_flash``): a token's ``n_heads`` outputs of the
         values' width (``latent_value_dim`` in a latent layer; in an
         ``attention`` layer ``head_dim``, or ``d_model / n_heads``
-        without it) and, in the plan's two-byte units, its ``n_heads``
-        float32 log-sum-exps.  None under ``block_diffusion``, whose
+        without it; a ``window_attention`` layer's ``window_heads``
+        of them) and, in the plan's two-byte units, its ``n_heads``
+        float32 log-sum-exps: the widest kind's (the plan has one width
+        a name).  None under ``block_diffusion``, whose
         layer runs two launches under the one name: no cell recomputes
         such blocks, and a plan that does not list the name leaves
         their launches as they are.  ``scan_out``, only where the delta
@@ -232,13 +293,19 @@ class BlockOptions:
                 ("linear_attention", self.gdn_key_heads, False),
                 ("kda", heads, True)) if kind in kinds}
         if self.use_flash and n_heads and not self.block_diffusion:
-            values = {"attention": n_heads * self.head_dim
-                      if self.head_dim else d_model,
-                      "latent_attention": n_heads * self.latent_value_dim}
+            windowed = self.attention_heads("window_attention", n_heads)
+            # a token's values and, as two units each, its log-sum-exps
+            values = {"attention": (n_heads * self.head_dim
+                                    if self.head_dim else d_model)
+                      + 2 * n_heads,
+                      "latent_attention": n_heads * (
+                          self.latent_value_dim + 2),
+                      "window_attention": windowed * (
+                          (self.head_dim or d_model // n_heads) + 2)}
             widest = max((w for kind, w in values.items() if kind in kinds),
                          default=0)
             if widest:
-                widths["attn_out"] = widest + 2 * n_heads
+                widths["attn_out"] = widest
         if self.gated_mlp:
             widths["mlp_in"] = 2 * d_ff
         if "mamba" in (self.layer_types or ()):
@@ -289,7 +356,7 @@ REMAT_NAMES = ("attn_out", "mlp_in", "ssm_in", "gdn_in", "kda_in",
                "latent_in", "scan_out")
 #: the kinds of layer (:data:`LAYER_KINDS`) that have a result of that
 #: name; a name not here is every layer's with a dense MLP
-_REMAT_KIND = {"attn_out": (LAYER_KINDS[0], LAYER_KINDS[4]),
+_REMAT_KIND = {"attn_out": (LAYER_KINDS[0], LAYER_KINDS[4], LAYER_KINDS[5]),
                "ssm_in": LAYER_KINDS[1:2], "gdn_in": LAYER_KINDS[2:3],
                "kda_in": LAYER_KINDS[3:4], "latent_in": LAYER_KINDS[4:5],
                "scan_out": LAYER_KINDS[2:4]}
@@ -481,21 +548,54 @@ def make_norm(options: BlockOptions, dtype=jnp.float32, **kw):
     return nn.LayerNorm(dtype=dtype, **kw)
 
 
-def apply_rope(x, positions, theta: float, fraction: float = 1.0):
+def yarn_frequencies(theta: float, turned: int, yarn: YarnScaling):
+    """The ``turned // 2`` frequencies of a YaRN-scaled rotation over
+    ``turned`` channels, as ``transformers``' ``_compute_yarn_parameters``
+    computes them: channel pair ``i`` turns by ``theta ** (-2i /
+    turned)`` a position where it completes ``beta_fast`` turns or more
+    over ``original_length`` positions (left as trained), by that over
+    ``factor`` where it completes ``beta_slow`` or fewer (positions
+    interpolated), and by the linear blend between, the two ends cut to
+    whole channel indices."""
+    def index_of(turns):  # the channel pair that completes ``turns``
+        return turned * math.log(yarn.original_length / (
+            turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(index_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(index_of(yarn.beta_slow)), turned - 1)
+    if low == high:
+        high += 0.001
+    plain = theta ** (-jnp.arange(turned // 2, dtype=jnp.float32)
+                      / (turned // 2))
+    ramp = jnp.clip((jnp.arange(turned // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return plain / yarn.factor * ramp + plain * (1.0 - ramp)
+
+
+def apply_rope(x, positions, theta: float, fraction: float = 1.0,
+               yarn: Optional[YarnScaling] = None):
     """Rotary positions on ``x (b, s, heads, dh)``, the halves
     convention (``rotate_half``), angles in float32.  ``fraction``: the
     leading ``fraction * dh`` channels of a head are rotated (among
-    themselves), the rest pass as they are."""
+    themselves), the rest pass as they are.  ``yarn``: the rotated
+    channels' frequencies are :func:`yarn_frequencies`', their cos and
+    sin times its ``cos_sin_factor`` (the channels that pass are not
+    scaled)."""
     if fraction != 1.0:
         turned = int(x.shape[-1] * fraction)
         return jnp.concatenate(
-            [apply_rope(x[..., :turned], positions, theta),
+            [apply_rope(x[..., :turned], positions, theta, yarn=yarn),
              x[..., turned:]], axis=-1)
     half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is not None:
+        freq = yarn_frequencies(theta, x.shape[-1], yarn)
+    else:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[:, None] * freq[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.cos_sin_factor, sin * yarn.cos_sin_factor
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :half], x32[..., half:]
     return jnp.concatenate(
@@ -533,8 +633,10 @@ def rotate_pairs(x, positions, theta: float, turned: int):
 
 #: device scope of the attention projections (q, k, v, their norms and
 #: rotation, the output gate and the output projection) on the general
-#: path
+#: path; inside it, of the rotation alone and of the gate a head
 ATTN_PROJ_SCOPE = "attn_proj"
+ATTN_ROPE_SCOPE = "attn_rope"
+HEAD_GATE_SCOPE = "head_gate"
 
 
 # Data-parallel mesh axis names this package's communicators bind
@@ -608,6 +710,10 @@ class SelfAttention(nn.Module):
     cache_len: int = 0
     attention_fn: Optional[Callable] = None
     options: BlockOptions = BlockOptions()
+    # a "window_attention" layer: ``n_heads`` is then the window layers'
+    # count, and the window and the rotation are ``options``' window
+    # fields (make_mixer sets all three)
+    windowed: bool = False
 
     def _general(self, x, causal: bool):
         """The path of :class:`BlockOptions`' attention options."""
@@ -615,8 +721,9 @@ class SelfAttention(nn.Module):
         if self.tp_axis is not None or self.seq_axis is not None \
                 or self.decode:
             raise ValueError(
-                "grouped-query / rotary / block-diffusion attention is "
-                "single-device in sequence and heads: no seq_axis, "
+                "grouped-query / rotary / block-diffusion / window / "
+                "head-gated attention and a head count of a layer's own "
+                "are single-device in sequence and heads: no seq_axis, "
                 "tp_axis or decode")
         from chainermn_tpu.ops import pallas_attention as pa
 
@@ -628,12 +735,25 @@ class SelfAttention(nn.Module):
                                   dtype=self.dtype)
         # both copies of a block-diffusion pair sit at 0..s/2-1
         pos = jnp.arange(s) % (s // 2 if o.block_diffusion else s)
+        # the layer kind's rotation and window
+        theta, fraction, yarn, window = (
+            o.rope_theta, o.rotary_fraction, o.rope_yarn, None)
+        if self.windowed:
+            if o.window <= 0 or o.block_diffusion or not causal:
+                raise ValueError(
+                    "a window_attention layer needs options.window > 0 "
+                    "under the causal mask (no block_diffusion)")
+            window, yarn = o.window, None
+            theta = o.window_rope_theta or theta
+            if o.window_rotary_fraction is not None:
+                fraction = o.window_rotary_fraction
 
         def norm_and_rotate(t, gain):
             if gain is not None:
                 t = rms_norm(t, gain, o.norm_eps, self.dtype)
-            if o.rope_theta:
-                t = apply_rope(t, pos, o.rope_theta, o.rotary_fraction)
+            if theta:
+                with jax.named_scope(ATTN_ROPE_SCOPE):
+                    t = apply_rope(t, pos, theta, fraction, yarn)
             return t
 
         with jax.named_scope(ATTN_PROJ_SCOPE):
@@ -646,6 +766,7 @@ class SelfAttention(nn.Module):
                 q = dense(hq * dh, name="q_proj")(x).reshape(b, s, hq, dh)
             k = dense(hkv * dh, name="k_proj")(x).reshape(b, s, hkv, dh)
             v = dense(hkv * dh, name="v_proj")(x).reshape(b, s, hkv, dh)
+            head_gate = dense(hq, name="g_proj")(x) if o.head_gate else None
             gains = [norm_gain(self, n, dh, o.zero_centered_norm)
                      if o.qk_norm else None for n in ("q_norm", "k_norm")]
             # float32 inside, recomputed in the backward pass: only the
@@ -658,22 +779,28 @@ class SelfAttention(nn.Module):
             out = attend(q, k, v, o.block_diffusion,
                          scale=o.attention_scale)
         elif o.use_flash and causal:
-            # causal is block-causal at block length 1
+            # causal is block-causal at block length 1; only a launch
+            # with a window is handed the argument
             out, _ = pa.block_causal_attention_with_lse(
-                q, k, v, 1, scale=o.attention_scale)
+                q, k, v, 1, scale=o.attention_scale,
+                **({"window": window} if window else {}))
         else:
             from chainermn_tpu.ops import multi_head_attention
 
             rep = lambda t: jnp.repeat(t, hq // hkv, axis=2)
             out = multi_head_attention(q, rep(k), rep(v), causal=causal,
-                                       scale=o.attention_scale)
+                                       scale=o.attention_scale,
+                                       window=window)
         with jax.named_scope(ATTN_PROJ_SCOPE):
+            # the gates: float32 inside, recomputed in the backward pass
+            gated = jax.checkpoint(lambda out, gate: (
+                out.astype(jnp.float32) * jax.nn.sigmoid(
+                    gate.astype(jnp.float32))).astype(out.dtype))
             if gate is not None:
-                # float32 inside, recomputed in the backward pass
-                out = jax.checkpoint(lambda out, gate: (
-                    out.astype(jnp.float32) * jax.nn.sigmoid(
-                        gate.astype(jnp.float32))).astype(out.dtype))(
-                    out, gate)
+                out = gated(out, gate)
+            if head_gate is not None:
+                with jax.named_scope(HEAD_GATE_SCOPE):
+                    out = gated(out, head_gate[..., None])
             return dense(d, name="o_proj")(out.reshape(b, s, hq * dh))
 
     def _decode_attend(self, q, k, v, b, heads, dh, scale):
@@ -1205,6 +1332,10 @@ def make_mixer(kind: str, n_heads: int, options: BlockOptions, dtype,
     if kind == "attention":
         return SelfAttention(n_heads, dtype=dtype, options=options,
                              **attention)
+    if kind == "window_attention":
+        return SelfAttention(options.attention_heads(kind, n_heads),
+                             dtype=dtype, options=options, windowed=True,
+                             **attention)
     refused = {name: attention[name] for name in (
         "seq_axis", "tp_axis", "decode") if name in attention}
     if kind == "latent_attention":
@@ -1665,6 +1796,14 @@ def generate(model: TransformerLM, params, prompt: jnp.ndarray,
     # so the TP-tier requirements above already hold; sampling gathers
     # only the frontier logits row per token (_full_vocab).
     vp_axis = tp_axis if vocab_parallel else None
+    if use_cache is not False and getattr(
+            model, "options", BlockOptions()).layer_attention:
+        raise ValueError(
+            "generate() cannot serve this model from a cache: window "
+            "layers, a gate a head, a head count of a layer's own and a "
+            "scaled rotation are training-only (SelfAttention's decode "
+            "path has none of them, serving/ no page lifetime a layer "
+            "kind); use_cache=False recomputes the whole forward a token")
     if use_cache is None:
         use_cache = _has_decode_field(model)
     if use_cache:

@@ -44,6 +44,13 @@ from .pallas_attention import _out_struct
 
 #: bytes a weight-gradient block may hold in VMEM (it is double-buffered)
 _DW_BLOCK_BYTES = 13 * 2 ** 18  # 3.25 MiB
+#: bytes of a group's weights that the row products still take whole
+#: (double-buffered, beside the row block, the result and its float32
+#: product: 2048 x 1408 in bfloat16, 5.5 MiB, runs so; 3072 x 1024, 6
+#: MiB, was refused ahead of time at 16.61 of 16 MiB of scoped VMEM),
+#: and of a tile of its result's columns where they are more
+_ROWS_WHOLE_BYTES = 23 * 2 ** 18  # 5.75 MiB
+_ROWS_TILE_BYTES = 3 * 2 ** 20
 
 
 def sum_to_vma(cotangent, primal):
@@ -106,6 +113,29 @@ def _rows_product(x, w, block_group, block_rows, transpose_w, interpret):
     block_group, x, w = vary_alike(block_group, x, w)
     r, k = x.shape
     n = w.shape[1] if transpose_w else w.shape[2]
+    tn = _rows_tile(k, n, w.dtype.itemsize)
+    if tn != n:
+        # a tile of the result's columns a grid point, the tiles
+        # outermost: a group's tile of the weights is still fetched
+        # once, the row blocks once a tile
+        w_tile, w_at = ((1, tn, k), lambda j, b, bg: (bg[b], j, 0)) \
+            if transpose_w else ((1, k, tn), lambda j, b, bg: (bg[b], 0, j))
+        return pl.pallas_call(
+            functools.partial(_rows_kernel, transpose_w=transpose_w),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n // tn, r // block_rows),
+                in_specs=[
+                    pl.BlockSpec((block_rows, k), lambda j, b, bg: (b, 0)),
+                    pl.BlockSpec(w_tile, w_at),
+                ],
+                out_specs=pl.BlockSpec((block_rows, tn),
+                                       lambda j, b, bg: (b, j)),
+            ),
+            out_shape=_out_struct((r, n), x.dtype, x, w),
+            interpret=bool(interpret),
+            name="_grouped_matmul",
+        )(block_group, x, w)
     w_block = (1, n, k) if transpose_w else (1, k, n)
     return pl.pallas_call(
         functools.partial(_rows_kernel, transpose_w=transpose_w),
@@ -122,6 +152,18 @@ def _rows_product(x, w, block_group, block_rows, transpose_w, interpret):
         interpret=bool(interpret),
         name="_grouped_matmul",
     )(block_group, x, w)
+
+
+def _rows_tile(k: int, n: int, itemsize: int) -> int:
+    """Columns of the result a grid point of the row products computes:
+    all ``n`` while a group's ``(k, n)`` weights fit their VMEM share,
+    else ``n``'s largest divisor that is a multiple of 128 and whose
+    tile of the weights fits :data:`_ROWS_TILE_BYTES`."""
+    if k * n * itemsize <= _ROWS_WHOLE_BYTES:
+        return n
+    fits = [t for t in range(128, n, 128)
+            if n % t == 0 and k * t * itemsize <= _ROWS_TILE_BYTES]
+    return max(fits) if fits else n
 
 
 def _dw_tile(k: int, n: int) -> int:

@@ -198,7 +198,7 @@ def _not(a):
 
 
 def _block_class(first_q, first_k, *, s_k, s_kp, causal, block_q, block_k,
-                 s_q=None, s_qp=None):
+                 s_q=None, s_qp=None, window=None):
     """THE taxonomy predicate: (interior, masked) for one block.
 
     The single source of truth for block classification — the split
@@ -212,9 +212,21 @@ def _block_class(first_q, first_k, *, s_k, s_kp, causal, block_q, block_k,
     reclassifies its whole block row as masked (its recomputed p would
     otherwise contribute to dk/dv and its garbage lse to dq).  Each
     tail predicate is emitted only when the corresponding padding
-    exists (static), so an aligned launch never compares indices."""
+    exists (static), so an aligned launch never compares indices.
+
+    ``window`` (static; ``None`` on every launch without one, which
+    then traces exactly what it did before the argument): key ``j`` is
+    live for query ``i`` only while ``j > i - window``.  A block whose
+    last key lies at or below its first row's bound holds no live pair
+    (dead: wholly below the window); one whose first key lies at or
+    below its LAST row's bound holds pairs on both sides of it
+    (masked)."""
     live = (first_k <= first_q + block_q - 1) if causal else True
     needs_mask = (first_k + block_k - 1 > first_q) if causal else False
+    if window is not None:
+        live = _and(live, first_k + block_k - 1 > first_q - window)
+        needs_mask = needs_mask | (
+            first_k + window <= first_q + block_q - 1)
     if s_k < s_kp:
         needs_mask = needs_mask | (first_k + block_k > s_k)
     if s_q is not None and s_q < s_qp:
@@ -276,15 +288,22 @@ def _tile_classes(block, t):
 _WHOLE = slice(None)
 
 
-def _strips(block, tile, masked):
+def _strips(block, tile, masked, below=False):
     """The compute schedule of a block: ``(rows, cols, masked cols)``
     strips.  Without a tile (or for an interior block) the block whole;
     with one, an aligned diagonal block as one strip per q tile, each
     against the run of its live k tiles as ONE rectangle (one matmul a
     product) whose last tile -- ``masked cols``, relative to the run --
-    the diagonal crosses.  Dead tiles appear nowhere."""
+    the diagonal crosses.  Dead tiles appear nowhere.  ``below``: the
+    block a window's lower bound crosses squarely (its keys a whole
+    window before its queries: live strictly above its own diagonal),
+    the mirror image: a q tile against the run from its own k tile on,
+    whose FIRST tile the bound crosses."""
     if tile is None or not masked:
         return [(_WHOLE, _WHOLE, _WHOLE if masked else None)]
+    if below:
+        return [(slice(r * tile, (r + 1) * tile), slice(r * tile, block),
+                 slice(0, tile)) for r in range(block // tile)]
     strips = []
     for r, line in enumerate(_tile_classes(block, tile)):
         n_live = len(line) - line.count("dead")
@@ -298,13 +317,17 @@ def _strips(block, tile, masked):
 def _select(mask, x, fill, masked):
     """``where(mask, x, fill)`` on the ``masked`` columns of ``x``
     (``None``: nowhere; the whole: everywhere) -- the columns before
-    them pass through untouched, whole lane tiles sliced off and put
-    back."""
+    them (after them, in a strip of ``_strips(..., below=True)``) pass
+    through untouched, whole lane tiles sliced off and put back."""
     if masked is None:
         return x
     if masked == _WHOLE:
         return jnp.where(mask, x, fill)
-    assert masked.stop == x.shape[1]
+    if masked.stop != x.shape[1]:  # a window block's strip: its first tile
+        assert not masked.start
+        return jnp.concatenate([
+            jnp.where(mask, x[:, :masked.stop], fill), x[:, masked.stop:]],
+            axis=1)
     tail = jnp.where(mask, x[:, masked.start:], fill)
     if not masked.start:
         return tail
@@ -312,7 +335,8 @@ def _select(mask, x, fill, masked):
 
 
 def block_census(s_q: int, s_k: int, block_q: int, block_k: int,
-                 causal: bool, kind: str = "fwd", tile=None) -> dict:
+                 causal: bool, kind: str = "fwd", tile=None,
+                 window=None) -> dict:
     """Static census of the block taxonomy for one (batch*head) program:
     how many blocks of each class a launch executes.
 
@@ -331,21 +355,50 @@ def block_census(s_q: int, s_k: int, block_q: int, block_k: int,
     without a tile); ``executed_units`` / ``masked_units`` are the
     block-units of work and of masked work a program runs (seq 2048 at
     1024 blocks: 3 and 2 whole, 2.5 and 1 at tile 512, 2.25 and 0.5 at
-    tile 256)."""
+    tile 256).
+
+    ``window``: the census of a window launch of the block-causal
+    family (:func:`block_causal_attention_with_lse`; square blocks that
+    tile the sequence and the window).  Its grid sweeps ``window //
+    block + 1`` k blocks a q block (:func:`_swept_k`, what the kernels
+    run), not all of them: the classes count the grid points
+    **visited** (``visited``: their number; ``dead``: the points a
+    sweep near the sequence's start runs past the diagonal), ``live``
+    the (q block, k block) points that hold a live pair by the
+    predicate over ALL points, visited or not, and ``below_window`` the
+    visited points whose k block lies wholly below the window (0: the
+    sweep starts at the window's first block)."""
     if kind not in ("fwd", "bwd"):
         raise ValueError(f"kind must be fwd/bwd, got {kind!r}")
     s_qp, s_kp = _round_up(s_q, block_q), _round_up(s_k, block_k)
     n_q, n_k = s_qp // block_q, s_kp // block_k
     census = {"dead": 0, "interior": 0, "masked": 0,
               "n_q_blocks": n_q, "n_k_blocks": n_k}
-    for j in range(n_q):
-        for kb in range(n_k):
-            interior, masked = _block_class(
-                j * block_q, kb * block_k, s_k=s_k, s_kp=s_kp,
-                causal=causal, block_q=block_q, block_k=block_k,
-                s_q=s_q if kind == "bwd" else None, s_qp=s_qp,
-            )
-            census[_class_name(interior, masked)] += 1
+    if window is not None:
+        if not (causal and s_q == s_k == s_qp and block_q == block_k
+                and window % block_q == 0):
+            raise ValueError(
+                "a window launch is causal over square blocks that tile "
+                "the sequence and the window")
+        n_t = window // block_q + 1
+        points = [(j, _swept_k(j, t, n_t, window))
+                  for j in range(n_q) for t in range(n_t)]
+        whole = [(j, kb) for j in range(n_q) for kb in range(n_k)]
+        is_live = lambda j, kb: any(_bc_class(j, kb, block_q, s_q, window))
+        census.update(
+            visited=len(points), k_blocks_swept=n_t,
+            live=sum(is_live(j, kb) for j, kb in whole),
+            below_window=sum((kb + 1) * block_k - 1 <= j * block_q - window
+                             for j, kb in points))
+    else:
+        points = [(j, kb) for j in range(n_q) for kb in range(n_k)]
+    for j, kb in points:
+        interior, masked = _block_class(
+            j * block_q, kb * block_k, s_k=s_k, s_kp=s_kp,
+            causal=causal, block_q=block_q, block_k=block_k,
+            s_q=s_q if kind == "bwd" else None, s_qp=s_qp, window=window,
+        )
+        census[_class_name(interior, masked)] += 1
     t = _compute_tile(
         block_q, block_k, kind, causal=causal, tile=tile,
         aligned=s_k == s_kp and (kind == "fwd" or s_q == s_qp),
@@ -1476,23 +1529,83 @@ def _block_causal_mask(size: int, block: int, strict: bool):
     return k_idx < (first if strict else first + block)
 
 
-def _bc_class(j, kb, bs, s):
-    return _block_class(j * bs, kb * bs, s_q=s, s_qp=s, s_k=s, s_kp=s,
-                        causal=True, block_q=bs, block_k=bs)
+def _bc_class(j, kb, bs, s, window=None):
+    interior, masked = _block_class(
+        j * bs, kb * bs, s_q=s, s_qp=s, s_k=s, s_kp=s, causal=True,
+        block_q=bs, block_k=bs, window=window)
+    if window is not None:
+        # the dk/dv sweep of a window launch runs past the last q block
+        inside = j * bs < s
+        interior, masked = _and(inside, interior), _and(inside, masked)
+    return interior, masked
+
+
+# A window launch (``window`` keys a query, its own among them; block
+# length 1, inclusive) cuts the sequence into blocks of at most the
+# window that divide it.  Of q block ``j``'s row of k blocks the live
+# ones are then the last ``window // bs + 1`` up to the diagonal: the
+# diagonal block under the causal mask, the block a whole window before
+# it under the mirror image (key ``c`` of it live for query ``r`` iff
+# ``c > r``), whole blocks between.  The grid's innermost axis sweeps
+# exactly those: no k block wholly below the window is visited or
+# fetched.  Near the sequence's start the sweep begins at block 0 and its
+# last points fall past the diagonal (dead, nothing fetched for them).
+
+
+def _swept_k(j, t, n_t, window):
+    """The k block of sweep point ``t`` (of ``n_t``) of q block ``j``:
+    ``t`` itself without a window."""
+    if window is None:
+        return t
+    first = j - (n_t - 1)
+    return (max(first, 0) if isinstance(first, int)
+            else jnp.maximum(first, 0)) + t
+
+
+def _swept_q(kb, t, n_q, window):
+    """The q block of sweep point ``t`` of k block ``kb`` in the dk/dv
+    kernel, whose innermost axis holds ``n_q`` points a query head of the
+    group: all q blocks without a window, with one the ``n_q`` from the
+    diagonal on (past the sequence's end: dead)."""
+    j = lax.rem(t, n_q)
+    return j if window is None else kb + j
+
+
+def _bc_mask(size, block, strict, below):
+    """The mask of a masked block or tile at offsets (0, 0): the causal
+    family's on the diagonal, its mirror image (``below``) where a
+    window's lower bound crosses."""
+    if not below:
+        return _block_causal_mask(size, block, strict)
+    q_idx = lax.broadcasted_iota(jnp.int32, (size, size), 0)
+    k_idx = lax.broadcasted_iota(jnp.int32, (size, size), 1)
+    return k_idx > q_idx
+
+
+def _when_masked(masked, kb, j, window, body):
+    """Run ``body(below)`` on a masked block: without a window the
+    diagonal's mask; with one, the diagonal's or the lower bound's by
+    where the block lies."""
+    if window is None:
+        _when(masked)(lambda: body(False))
+        return
+    _when(_and(masked, kb == j))(lambda: body(False))
+    _when(_and(masked, kb != j))(lambda: body(True))
 
 
 def _bc_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                    l_ref, *, s: int, scale: float, bs: int, block: int,
-                   strict: bool, tile):
+                   strict: bool, tile, window=None):
     j = pl.program_id(1)
-    kb = pl.program_id(2)
-    n_kb = pl.num_programs(2)
-    interior, masked = _bc_class(j, kb, bs, s)
+    t = pl.program_id(2)
+    n_t = pl.num_programs(2)
+    kb = _swept_k(j, t, n_t, window)
+    interior, masked = _bc_class(j, kb, bs, s, window)
 
-    def _attend(with_mask):
-        mask = _block_causal_mask(tile or bs, block, strict) \
+    def _attend(with_mask, below=False):
+        mask = _bc_mask(tile or bs, block, strict, below) \
             if with_mask else None
-        for rows, cols, masked_cols in _strips(bs, tile, with_mask):
+        for rows, cols, masked_cols in _strips(bs, tile, with_mask, below):
             q = q_ref[0, rows, :].astype(jnp.float32) * scale
             k_blk = k_ref[0, cols, :].astype(jnp.float32)
             v_blk = v_ref[0, cols, :].astype(jnp.float32)
@@ -1504,7 +1617,7 @@ def _bc_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
             m_blk = jnp.max(sc, axis=-1, keepdims=True)
             stat_shape = (m_blk.shape[0], m_ref.shape[1])
 
-            @pl.when(kb == 0)
+            @pl.when(t == 0)
             def _first():
                 p = jnp.exp(sc - m_blk)
                 m_ref[rows, :] = jnp.broadcast_to(m_blk, stat_shape)
@@ -1515,7 +1628,7 @@ def _bc_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
                     preferred_element_type=jnp.float32,
                 )
 
-            @pl.when(kb != 0)
+            @pl.when(t != 0)
             def _rest():
                 m_old = m_ref[rows, 0:1]
                 m_new = jnp.maximum(m_old, m_blk)
@@ -1535,11 +1648,10 @@ def _bc_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
     def _fast():
         _attend(with_mask=False)
 
-    @_when(masked)
-    def _slow():
-        _attend(with_mask=True)
+    _when_masked(masked, kb, j, window,
+                 lambda below: _attend(with_mask=True, below=below))
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(t == n_t - 1)
     def _finalize():
         o_ref[0] = (
             acc_ref[:] / jnp.maximum(l_ref[:, 0:1], 1e-30)
@@ -1567,21 +1679,22 @@ def _bc_probabilities(q_ref, k_ref, lse_ref, rows, cols, mask, masked_cols,
 
 def _bc_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dq_acc, *, s: int, scale: float, bs: int,
-                      block: int, strict: bool, tile):
+                      block: int, strict: bool, tile, window=None):
     j = pl.program_id(1)
-    kb = pl.program_id(2)
-    n_kb = pl.num_programs(2)
+    t = pl.program_id(2)
+    n_t = pl.num_programs(2)
+    kb = _swept_k(j, t, n_t, window)
 
-    @pl.when(kb == 0)
+    @pl.when(t == 0)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    interior, masked = _bc_class(j, kb, bs, s)
+    interior, masked = _bc_class(j, kb, bs, s, window)
 
-    def _accum(with_mask):
-        mask = _block_causal_mask(tile or bs, block, strict) \
+    def _accum(with_mask, below=False):
+        mask = _bc_mask(tile or bs, block, strict, below) \
             if with_mask else None
-        for rows, cols, masked_cols in _strips(bs, tile, with_mask):
+        for rows, cols, masked_cols in _strips(bs, tile, with_mask, below):
             _, k_blk, p = _bc_probabilities(
                 q_ref, k_ref, lse_ref, rows, cols, mask, masked_cols, scale)
             v_blk = v_ref[0, cols, :].astype(jnp.float32)
@@ -1600,11 +1713,10 @@ def _bc_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _fast():
         _accum(with_mask=False)
 
-    @_when(masked)
-    def _slow():
-        _accum(with_mask=True)
+    _when_masked(masked, kb, j, window,
+                 lambda below: _accum(with_mask=True, below=below))
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(t == n_t - 1)
     def _finalize():
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -1612,26 +1724,28 @@ def _bc_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bc_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_acc, dv_acc, *, s: int,
                        scale: float, bs: int, block: int, strict: bool,
-                       tile, n_q: int):
+                       tile, n_q: int, window=None):
     """Grid (batch * kv heads, k blocks, group * q blocks): the innermost
-    axis sweeps the query blocks of each of the group's heads in turn,
-    so dk and dv of the shared head accumulate over the whole group."""
+    axis sweeps the query blocks of each of the group's heads in turn
+    (of a window launch the ``n_q`` blocks from the diagonal on:
+    :func:`_swept_q`), so dk and dv of the shared head accumulate over
+    the whole group."""
     kb = pl.program_id(1)
     t = pl.program_id(2)
     n_t = pl.num_programs(2)
-    j = lax.rem(t, n_q)
+    j = _swept_q(kb, t, n_q, window)
 
     @pl.when(t == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    interior, masked = _bc_class(j, kb, bs, s)
+    interior, masked = _bc_class(j, kb, bs, s, window)
 
-    def _accum(with_mask):
-        mask = _block_causal_mask(tile or bs, block, strict) \
+    def _accum(with_mask, below=False):
+        mask = _bc_mask(tile or bs, block, strict, below) \
             if with_mask else None
-        for rows, cols, masked_cols in _strips(bs, tile, with_mask):
+        for rows, cols, masked_cols in _strips(bs, tile, with_mask, below):
             q, _, p = _bc_probabilities(
                 q_ref, k_ref, lse_ref, rows, cols, mask, masked_cols, scale)
             v_blk = v_ref[0, cols, :].astype(jnp.float32)
@@ -1654,9 +1768,8 @@ def _bc_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _fast():
         _accum(with_mask=False)
 
-    @_when(masked)
-    def _slow():
-        _accum(with_mask=True)
+    _when_masked(masked, kb, j, window,
+                 lambda below: _accum(with_mask=True, below=below))
 
     @pl.when(t == n_t - 1)
     def _finalize():
@@ -1664,14 +1777,10 @@ def _bc_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _bc_geometry(q, k, block, block_size, interpret, kind, tile):
-    """``(group, bs, tile)`` of a launch, after the contract's checks."""
-    b, s, hq, d = q.shape
-    hkv = k.shape[2]
-    if k.shape != (b, s, hkv, d) or hq % hkv:
-        raise ValueError(
-            f"block-causal attention needs q (b, s, hq, d) and k "
-            f"(b, s, hkv, d) with hq % hkv == 0; got {q.shape}, {k.shape}")
+def _bc_block(s, d, block_size, interpret, window=None):
+    """The square kernel block of a launch over ``s`` positions at head
+    width ``d``: ``block_size`` (default 1024) under the family's
+    clamps, and at most the window."""
     size = _clamp_blocks_for_dim(block_size, block_size, d, warn=False)[0]
     if block_size is None and d > 128:
         # these bodies keep a (bs, d) float32 accumulator beside whole
@@ -1680,15 +1789,54 @@ def _bc_geometry(q, k, block, block_size, interpret, kind, tile):
         # ahead of time for a described v5e; the launch alone
         # compiles); half the block fits
         size = min(size, _DEFAULT_BLOCK // 2)
-    bs = _effective_q_block(size, s, interpret)
+    if window is not None:
+        size = min(size, window)
+    return _effective_q_block(size, s, interpret)
+
+
+def window_launch_census(s: int, window: int, d: int, block_size=None,
+                         interpret: bool = False) -> dict:
+    """:func:`block_census` of the geometry a window launch over ``s``
+    positions ACTUALLY runs (its block resolved as the launch resolves
+    it), ``{"block", "fwd", "bwd"}``; ``{}`` where the window reaches
+    the whole sequence and the launch runs without one."""
+    if _live_window(window, s) is None:
+        return {}
+    bs = _bc_block(s, d, block_size, interpret, window)
+    return {"block": bs, **{
+        kind: block_census(s, s, bs, bs, True, kind, window=window)
+        for kind in ("fwd", "bwd")}}
+
+
+def _bc_geometry(q, k, block, block_size, interpret, kind, tile,
+                 window=None):
+    """``(group, bs, tile)`` of a launch, after the contract's checks.
+    A window launch's blocks are at most the window and divide it."""
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    if k.shape != (b, s, hkv, d) or hq % hkv:
+        raise ValueError(
+            f"block-causal attention needs q (b, s, hq, d) and k "
+            f"(b, s, hkv, d) with hq % hkv == 0; got {q.shape}, {k.shape}")
+    bs = _bc_block(s, d, block_size, interpret, window)
     if s % bs or bs % block:
         raise ValueError(
             f"sequence length {s} must be a whole number of {bs}-blocks, "
             f"each a whole number of mask blocks of {block}")
+    if window is not None and (window % bs or block != 1):
+        raise ValueError(
+            f"a window of {window} keys must be a whole number of "
+            f"{bs}-blocks, under the causal mask (block 1, not {block})")
     t = _compute_tile(bs, bs, kind, causal=True, aligned=True, tile=tile)
     if t is not None and t % block:
         t = None
     return hq // hkv, bs, t
+
+
+def _kernel_name(kind: str, window) -> str:
+    """A launch's name in a device trace: the window launches apart
+    from the others."""
+    return f"_{'bd' if window is None else 'swa'}flash_{kind}"
 
 
 def _to_bh(x):
@@ -1700,24 +1848,31 @@ def _to_bh(x):
 @functools.partial(
     jax.jit,
     static_argnames=("block", "strict", "scale", "block_size", "interpret",
-                     "tile"),
+                     "tile", "window"),
 )
 def _bc_forward(q, k, v, block, strict, scale, block_size, interpret,
-                tile=None):
+                tile=None, window=None):
     b, s, hq, d = q.shape
     group, bs, t = _bc_geometry(q, k, block, block_size, interpret, "fwd",
-                                tile)
+                                tile, window)
     dv = v.shape[-1]
     n = s // bs
     kv_index = lambda i, j, kb: (i // group, jnp.minimum(kb, j), 0)
+    kwargs, n_t = {}, n
+    if window is not None:
+        if strict:
+            raise ValueError("a window launch is inclusive, not strict")
+        kwargs, n_t = {"window": window}, window // bs + 1
+        kv_index = lambda i, j, t: (
+            i // group, jnp.minimum(_swept_k(j, t, n_t, window), j), 0)
     out, lse = pl.pallas_call(
         functools.partial(_bc_fwd_kernel, s=s, scale=scale, bs=bs,
-                          block=block, strict=strict, tile=t),
+                          block=block, strict=strict, tile=t, **kwargs),
         out_shape=[
             _out_struct((b * hq, s, dv), q.dtype, q, k, v),
             _out_struct((b * hq, 8, s), jnp.float32, q, k, v),
         ],
-        grid=(b * hq, n, n),
+        grid=(b * hq, n, n_t),
         in_specs=[
             pl.BlockSpec((1, bs, d), lambda i, j, kb: (i, j, 0)),
             pl.BlockSpec((1, bs, d), kv_index),
@@ -1733,7 +1888,7 @@ def _bc_forward(q, k, v, block, strict, scale, block_size, interpret,
             pltpu.VMEM((bs, 128), jnp.float32),
         ],
         interpret=interpret,
-        name="_bdflash_forward",
+        name=_kernel_name("forward", window),
     )(_to_bh(q), _to_bh(k), _to_bh(v))
     return (jnp.moveaxis(out.reshape(b, hq, s, dv), 1, 2),
             lse[:, 0])  # (b, s, hq, dv), (b * hq, s)
@@ -1742,19 +1897,21 @@ def _bc_forward(q, k, v, block, strict, scale, block_size, interpret,
 @functools.partial(
     jax.jit,
     static_argnames=("block", "strict", "scale", "block_size", "interpret",
-                     "tile"),
+                     "tile", "window"),
 )
 def _bc_backward(q, k, v, out, lse, g, g_lse, block, strict, scale,
-                 block_size, interpret, tile=None):
+                 block_size, interpret, tile=None, window=None):
     """dq, dk, dv of one launch; ``g_lse`` (b * hq, s) is the cotangent
     of the log-sum-exp, folded into ``delta`` as in
     :func:`_flash_backward`."""
     b, s, hq, d = q.shape
     hkv = k.shape[2]
     group, bs, t = _bc_geometry(q, k, block, block_size, interpret, "bwd",
-                                tile)
+                                tile, window)
     dv = v.shape[-1]
     n = s // bs
+    # a window launch sweeps the blocks its window reaches, not all n
+    n_t = n if window is None else window // bs + 1
     qb, kb_, vb, dob = _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(g)
     delta = jnp.sum(dob.astype(jnp.float32)
                     * _to_bh(out).astype(jnp.float32), axis=-1) \
@@ -1765,12 +1922,16 @@ def _bc_backward(q, k, v, out, lse, g, g_lse, block, strict, scale,
                   tile=t)
 
     kv_index = lambda i, j, kb: (i // group, jnp.minimum(kb, j), 0)
+    if window is not None:
+        kwargs["window"] = window
+        kv_index = lambda i, j, t: (
+            i // group, jnp.minimum(_swept_k(j, t, n_t, window), j), 0)
     row = lambda i, j, kb: (i, j, 0)
     stat = lambda i, j, kb: (i, 0, j)
     dq = pl.pallas_call(
         functools.partial(_bc_bwd_dq_kernel, **kwargs),
         out_shape=_out_struct((b * hq, s, d), q.dtype, q, k, v, g),
-        grid=(b * hq, n, n),
+        grid=(b * hq, n, n_t),
         in_specs=[
             pl.BlockSpec((1, bs, d), row),
             pl.BlockSpec((1, bs, d), kv_index),
@@ -1782,7 +1943,7 @@ def _bc_backward(q, k, v, out, lse, g, g_lse, block, strict, scale,
         out_specs=pl.BlockSpec((1, bs, d), row),
         scratch_shapes=[pltpu.VMEM((bs, d), jnp.float32)],
         interpret=interpret,
-        name="_bdflash_backward_dq",
+        name=_kernel_name("backward_dq", window),
     )(qb, kb_, vb, dob, lse8, delta)
 
     # query block of sweep point t under K/V head i: head i * group +
@@ -1792,16 +1953,22 @@ def _bc_backward(q, k, v, out, lse, g, g_lse, block, strict, scale,
     def q_of(i, kb, t):
         return i * group + t // n, jnp.maximum(lax.rem(t, n), kb)
 
+    if window is not None:
+        # past the sequence's end the last block again: nothing fetched
+        def q_of(i, kb, t):  # noqa: F811
+            return (i * group + t // n_t,
+                    jnp.minimum(_swept_q(kb, t, n_t, window), n - 1))
+
     q_row = lambda i, kb, t: (*q_of(i, kb, t), 0)
     q_stat = lambda i, kb, t: (q_of(i, kb, t)[0], 0, q_of(i, kb, t)[1])
     kv_row = lambda i, kb, t: (i, kb, 0)
     dk, dv_out = pl.pallas_call(
-        functools.partial(_bc_bwd_dkv_kernel, n_q=n, **kwargs),
+        functools.partial(_bc_bwd_dkv_kernel, n_q=n_t, **kwargs),
         out_shape=[
             _out_struct((b * hkv, s, d), k.dtype, q, k, v, g),
             _out_struct((b * hkv, s, dv), v.dtype, q, k, v, g),
         ],
-        grid=(b * hkv, n, group * n),
+        grid=(b * hkv, n, group * n_t),
         in_specs=[
             pl.BlockSpec((1, bs, d), q_row),
             pl.BlockSpec((1, bs, d), kv_row),
@@ -1819,7 +1986,7 @@ def _bc_backward(q, k, v, out, lse, g, g_lse, block, strict, scale,
             pltpu.VMEM((bs, dv), jnp.float32),
         ],
         interpret=interpret,
-        name="_bdflash_backward_dkdv",
+        name=_kernel_name("backward_dkdv", window),
     )(qb, kb_, vb, dob, lse8, delta)
 
     def from_bh(x, h):
@@ -1828,10 +1995,10 @@ def _bc_backward(q, k, v, out, lse, g, g_lse, block, strict, scale,
     return from_bh(dq, hq), from_bh(dk, hkv), from_bh(dv_out, hkv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def block_causal_attention_with_lse(q, k, v, block, strict=False,
                                     scale=None, block_size=None,
-                                    interpret=None, tile=None):
+                                    interpret=None, tile=None, window=None):
     """Block-causal grouped-query flash attention returning ``(out,
     lse)``, both differentiable.
 
@@ -1848,6 +2015,15 @@ def block_causal_attention_with_lse(q, k, v, block, strict=False,
     ``s`` must be a whole number of square kernel blocks (``block_size``,
     default 1024, clamped to ``s``), each a whole number of ``block``.
 
+    ``window`` (with ``block=1``, inclusive): key ``j`` is live for
+    query ``i`` iff ``i - window < j <= i``, ``window`` keys, the
+    query's own among them.  The launch (kernels ``_swaflash_*``) cuts
+    the sequence into blocks of at most ``window`` that divide it and
+    visits only the k blocks a q block's window reaches
+    (``block_census(..., window=...)`` counts them); a window that
+    reaches the whole sequence (``window >= s``) is none, and runs the
+    launch without one.
+
     The backward rule's residuals are ``(q, k, v, out, lse)``, the
     launch's two results named :data:`ATTN_OUT`: a block recomputed
     under a plan that lists the name (``models.transformer.remat_plan``)
@@ -1855,22 +2031,28 @@ def block_causal_attention_with_lse(q, k, v, block, strict=False,
     again for the backward kernels but no forward launch.
     """
     return _bc_fwd_rule(q, k, v, block, strict, scale, block_size,
-                        interpret, tile)[0]
+                        interpret, tile, window)[0]
+
+
+def _live_window(window, s):
+    """``window``, or ``None`` where it reaches the whole sequence."""
+    return None if window is None or window >= s else window
 
 
 def _bc_fwd_rule(q, k, v, block, strict, scale, block_size, interpret,
-                 tile):
+                 tile, window):
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     out, lse = _named(*_bc_forward(
         q, k, v, block, strict, scale, block_size,
-        _should_interpret(interpret), tile))
+        _should_interpret(interpret), tile,
+        window=_live_window(window, q.shape[1])))
     b, s, hq, _ = q.shape
     return ((out, jnp.moveaxis(lse.reshape(b, hq, s), 1, 2)),
             (q, k, v, out, lse))
 
 
 def _bc_bwd_rule(block, strict, scale, block_size, interpret, tile,
-                 residuals, g):
+                 window, residuals, g):
     q, k, v, out, lse = residuals
     g_out, g_lse = g
     b, s, hq, _ = q.shape
@@ -1878,18 +2060,21 @@ def _bc_bwd_rule(block, strict, scale, block_size, interpret, tile,
     return _bc_backward(
         q, k, v, out, lse, g_out,
         jnp.moveaxis(g_lse, 1, 2).reshape(b * hq, s), block, strict,
-        scale, block_size, _should_interpret(interpret), tile)
+        scale, block_size, _should_interpret(interpret), tile,
+        window=_live_window(window, s))
 
 
 block_causal_attention_with_lse.defvjp(_bc_fwd_rule, _bc_bwd_rule)
 
 
-def block_causal_mask(s: int, block: int, strict: bool = False):
+def block_causal_mask(s: int, block: int, strict: bool = False,
+                      window=None):
     """The dense (s, s) mask of the definition: the tests' and the dense
     form's side of :func:`block_causal_attention_with_lse`."""
     i = jnp.arange(s)[:, None]
     j = jnp.arange(s)[None, :]
-    return j < block * (i // block + (0 if strict else 1))
+    live = j < block * (i // block + (0 if strict else 1))
+    return live if window is None else live & (j > i - window)
 
 
 def block_diffusion_mask(s: int, block: int):

@@ -127,6 +127,12 @@ def main(argv=None):
     p.add_argument("--steps", type=int, default=200)
     p.add_argument("--lr", type=float, default=1e-3)
     p.add_argument("--report-every", type=int, default=20)
+    p.add_argument("--grad-wire", action="store_true",
+                   help="data-parallel only (--sp 1 --tp 1): build the "
+                        "step without param_specs, so that "
+                        "create_multi_node_optimizer's bucketed wire "
+                        "ships the gradients instead of autodiff's "
+                        "all-reduce a leaf")
     p.add_argument("--flash", action="store_true",
                    help="use the Pallas flash-attention kernel (TPU)")
     p.add_argument("--vocab-parallel", action="store_true",
@@ -148,6 +154,11 @@ def main(argv=None):
     p.add_argument("--cpu-mesh", action="store_true",
                    help="run on a virtual CPU device mesh (testing)")
     args = p.parse_args(argv)
+    if args.grad_wire and (args.sp != 1 or args.tp != 1
+                           or args.vocab_parallel):
+        p.error("--grad-wire is the data-parallel step's: --sp 1 --tp 1 "
+                "and a dense vocabulary (sharded parameters need "
+                "param_specs)")
 
     import chainermn_tpu as cmn
 
@@ -325,9 +336,13 @@ def main(argv=None):
             main = cc.pmean(main, ax)
         return main
 
+    # --grad-wire: the parameters replicated and the optimizer's own
+    # exchange of the gradients (its bucketed flat wire); otherwise the
+    # hybrid body, whose reductions are autodiff's, one a leaf
     step = cmn.build_train_step(
         comm, loss_fn, opt, data_axes=comm.data_axis_names,
-        param_specs=specs, batch_specs=P("mn_data", "mn_seq"),
+        param_specs=None if args.grad_wire else specs,
+        batch_specs=P("mn_data", "mn_seq"),
     )
     params, opt_state = step.place(params, opt_state)
 

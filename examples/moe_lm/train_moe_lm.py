@@ -97,6 +97,27 @@ leading dense layer and the five that follow;
       --vocab 20480 --seq-len 8192 --batchsize 2 --rmsnorm --norm-eps 1e-5 \
       --untied-head --dropless --flash --remat-blocks --chunked-ce 8 \
       --lr 1e-5 --aux-coef 1e-3
+
+``window_attention`` layers see the last ``--window`` keys, with
+``--window-heads`` query heads and a rotation of their own
+(``--window-rope-theta``, ``--window-rotary-fraction``); ``--rope-yarn``
+scales the ``attention`` layers' rotation and ``--head-gate`` gates
+every head's output.  One chip's share of Laguna-S-2.1 (experts 0..7 of
+256, an eighth of the vocabulary, the leading dense layer and one
+period of three window layers and a full one;
+``cellbench/configs/laguna-s-2.1.json``):
+
+    python examples/moe_lm/train_moe_lm.py --d-model 3072 --n-heads 48 \
+      --n-kv-heads 8 --head-dim 128 --head-gate --rope-theta 500000 \
+      --rotary-fraction 0.5 --rope-yarn 128,8192,32,1,1.4852030263919618 \
+      --layer-types attention,window_attention,window_attention,window_attention \
+      --window 512 --window-heads 72 --window-rope-theta 10000 \
+      --window-rotary-fraction 1 --d-ff 1024 --shared-d-ff 1024 \
+      --n-experts 256 --top-k 10 --held 0,8 --moe-every 1 \
+      --routed-scale 2.5 --first-dense 1 --dense-d-ff 12288 --gated-mlp \
+      --n-layers 5 --vocab 12544 --seq-len 8192 --batchsize 1 --rmsnorm \
+      --untied-head --dropless --flash --remat-blocks --chunked-ce 8 \
+      --lr 1e-5 --aux-coef 1e-3
 """
 
 import argparse
@@ -219,6 +240,25 @@ def main(argv=None):
     g.add_argument("--attn-output-gate", action="store_true",
                    help="q_proj twice as wide: a sigmoid gate a head on "
                         "what the output projection reads")
+    g.add_argument("--head-gate", action="store_true",
+                   help="a sigmoid gate a head (g_proj (d, heads)) on the "
+                        "head's whole output")
+    g.add_argument("--rope-yarn", default=None,
+                   metavar="FACTOR,LENGTH[,FAST,SLOW[,ATTENTION_FACTOR]]",
+                   help="YaRN on the attention layers' rotation "
+                        "(models.transformer.YarnScaling)")
+    g.add_argument("--window", type=int, default=0,
+                   help="keys a window_attention layer's query sees, its "
+                        "own among them")
+    g.add_argument("--window-heads", type=int, default=0,
+                   help="query heads of the window_attention layers "
+                        "(default: --n-heads)")
+    g.add_argument("--window-rope-theta", type=float, default=None,
+                   help="base of the window_attention layers' rotation "
+                        "(default: --rope-theta)")
+    g.add_argument("--window-rotary-fraction", type=float, default=None,
+                   help="the leading share of a head a window_attention "
+                        "layer rotates (default: --rotary-fraction)")
     g.add_argument("--layer-types", default=None,
                    help="comma-separated kinds of sequence mixer, a "
                         "layer each, repeated over the depth")
@@ -292,7 +332,10 @@ def main(argv=None):
                         or args.router_score != "softmax"
                         or args.routed_scale != 1.0 or args.seq_aux
                         or args.first_dense
-                        or args.dense_d_ff or args.gated_mlp):
+                        or args.dense_d_ff or args.gated_mlp
+                        or args.head_gate or args.rope_yarn or args.window
+                        or args.window_heads or args.window_rope_theta
+                        or args.window_rotary_fraction is not None):
         p.error("the block's options come with --rope-theta or "
                 "--no-positions")
     if args.chunked_ce and args.block_diffusion:
@@ -337,6 +380,7 @@ def main(argv=None):
         HEAD_CE_SCOPE,
         LAYER_KINDS,
         BlockOptions,
+        YarnScaling,
         block_diffusion_loss,
         model_remat_widths,
         noised_copy,
@@ -349,6 +393,13 @@ def main(argv=None):
         if args.layer_types else None
     if set(layer_types or ()) - set(LAYER_KINDS):
         p.error(f"--layer-types holds {', '.join(LAYER_KINDS)}")
+    if ("window_attention" in (layer_types or ())) != bool(args.window):
+        p.error("--window comes with window_attention layers, and they "
+                "with it")
+    yarn = None
+    if args.rope_yarn:
+        factor, length, *rest = (float(x) for x in args.rope_yarn.split(","))
+        yarn = YarnScaling(factor, int(length), *rest)
 
     comm = cmn.create_communicator(
         "mesh", devices=devices, sp_size=args.sp, tp_size=args.tp
@@ -378,6 +429,10 @@ def main(argv=None):
         block_diffusion=args.block_diffusion, use_flash=args.flash,
         rotary_fraction=args.rotary_fraction,
         attn_output_gate=args.attn_output_gate,
+        head_gate=args.head_gate, rope_yarn=yarn, window=args.window,
+        window_heads=args.window_heads,
+        window_rope_theta=args.window_rope_theta,
+        window_rotary_fraction=args.window_rotary_fraction,
         zero_centered_norm=args.zero_centered_norm,
         layer_types=layer_types,
         gdn_key_heads=args.gdn_key_heads,

@@ -347,7 +347,7 @@ def test_new_mixers_are_single_device_and_latent_attention_unrotated():
 
 def test_kinds_and_what_their_blocks_keep():
     assert LAYER_KINDS == ("attention", "mamba", "linear_attention", "kda",
-                           "latent_attention")
+                           "latent_attention", "window_attention")
     assert REMAT_NAMES == ("attn_out", "mlp_in", "ssm_in", "gdn_in",
                            "kda_in", "latent_in", "scan_out")
     o = _options(CONFIG)

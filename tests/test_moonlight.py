@@ -641,8 +641,9 @@ def test_the_cells_files_say_what_the_issue_asked_for():
     assert len(cell) == 1 and cell[0]["chips"] == 1 \
         and cell[0]["config"] == "moonlight-16b-a3b" \
         and cell[0]["traffic"] == "train_mla_s8192"
-    assert bench["workloads"][-1] is cell[0] and len(bench["workloads"]) == 8
-    entry = bench["configs"][-1]
+    # the eighth cell and the seventh configuration: later PRs append
+    assert bench["workloads"][7] is cell[0]
+    entry = bench["configs"][6]
     assert entry["name"] == "moonlight-16b-a3b" \
         and entry["file"] == "cellbench/configs/moonlight-16b-a3b.json" \
         and entry["source"] == CONFIG["source"] \
@@ -673,7 +674,7 @@ def test_the_cells_files_say_what_the_issue_asked_for():
     assert traffic["rehearse"] == {"seq_len": 96, "per_chip_batch": 2}
     rate = [m for m in bench["end_to_end"]
             if m["name"] == "tokens_per_s_per_chip"][0]
-    assert rate["workloads"][-1] == cell[0]["name"]
+    assert rate["workloads"][6] == cell[0]["name"]
     mine = [m for m in bench["per_layer"] if m["name"].endswith(".moonlight")]
     assert [m["name"].split(".")[0] for m in mine] == [
         "mfu_pct", "mla_proj_ms", "mla_rope_ms", "flash_ms",

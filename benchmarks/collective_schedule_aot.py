@@ -25,10 +25,23 @@ about 25 s.
 
 Run:  python benchmarks/collective_schedule_aot.py [--layers 3]
           [--chips 1] [--options '{"xla_...": "true"}'] [--hlo out.txt]
+          [--grad-wire [--packed]]
 
 ``--options`` replaces the option set of the step builder's rule
 (``{}``: the program without the rule), to read a candidate before a
 chip is asked for.
+
+``--grad-wire`` compiles ``cgpt590m_dpwire4_s2048``'s step instead (no
+``param_specs``: ``create_multi_node_optimizer``'s own exchange) and
+prints how the wire splits the gradients (leaves in place, buckets
+packed, bytes of each: ``optimizers._split_wire``) beside the
+schedule's counts.  Its four forms are a command each:
+
+    --grad-wire --packed                  A  every leaf packed, no options
+                                             (the wire before PR 51)
+    --grad-wire --packed --options rule   B  packed, the rule's options
+    --grad-wire --options '{}'            C  large leaves in place, none
+    --grad-wire                           D  in place + options (the step)
 """
 
 import argparse
@@ -83,7 +96,7 @@ def build_lm_step(devices, *, n_layers=18, d_model=1536, n_heads=12,
     """The LM cells' step over ``devices`` (described or attached) and
     its abstract arguments ``(params, opt_state, batch)``, shardings on.
     ``grad_wire``: the example's ``--grad-wire`` step (no
-    ``param_specs``: the optimizer's bucketed wire ships the gradients).
+    ``param_specs``: the optimizer's own wire ships the gradients).
     ``options`` (a ``BlockOptions``), ``d_ff`` and ``chunked_ce`` build
     the example's model under its block flags instead of the GPT-2
     block with the flash kernels as ``attention_fn``."""
@@ -220,11 +233,17 @@ def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--layers", type=int, default=18)
     p.add_argument("--chips", type=int, default=4, choices=(1, 2, 4))
-    p.add_argument("--options", type=json.loads, default=None,
-                   help="JSON object: replaces the builder's option set")
+    p.add_argument("--options", default=None,
+                   type=lambda s: s if s == "rule" else json.loads(s),
+                   help="JSON object: replaces the builder's option set "
+                        "('rule': the builder's own, with --packed)")
     p.add_argument("--grad-wire", action="store_true",
-                   help="the example's --grad-wire step: the bucketed "
-                        "wire's collectives in place of a psum a leaf")
+                   help="the example's --grad-wire step: the wire's "
+                        "collectives in place of autodiff's psum a leaf")
+    p.add_argument("--packed", action="store_true",
+                   help="with --grad-wire: every leaf packed into the "
+                        "plan's buckets, whatever the mesh (and the "
+                        "step compiled with --options all the same)")
     p.add_argument("--hlo", default="", help="write the program text here")
     args = p.parse_args(argv)
 
@@ -238,15 +257,29 @@ def main(argv=None):
 
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")
-    patch = contextlib.nullcontext() if args.options is None else \
-        mock.patch.object(optimizers, "_ASYNC_GRAD_REDUCE_OPTIONS",
-                          args.options)
-    with one_process(), patch:
+    rule = dict(optimizers._ASYNC_GRAD_REDUCE_OPTIONS)
+    if args.options == "rule":
+        args.options = rule
+    split_wire = optimizers._split_wire
+    with contextlib.ExitStack() as patches:
+        patches.enter_context(one_process())
+        if args.options is not None:
+            patches.enter_context(mock.patch.object(
+                optimizers, "_ASYNC_GRAD_REDUCE_OPTIONS", args.options))
+        if args.packed:
+            patches.enter_context(mock.patch.object(
+                optimizers, "_split_wire",
+                lambda *a, **kw: split_wire(*a, **{**kw,
+                                                   "in_place": False})))
         step, abstract = build_lm_step(
             topo.devices[:args.chips], n_layers=args.layers,
             grad_wire=args.grad_wire)
         t0 = time.perf_counter()
-        compiled = step.get_jitted(*abstract[:2]).lower(*abstract).compile()
+        lowered = step.get_jitted(*abstract[:2]).lower(*abstract)
+        # a packed wire is handed no options by the builder: the form
+        # under them (B) is compiled with them here
+        compiled = lowered.compile(
+            compiler_options=args.options if args.packed else None)
     text = compiled.as_text()
     if args.hlo:
         with open(args.hlo, "w") as f:
@@ -260,8 +293,16 @@ def main(argv=None):
                   f"{'async' if op.asynchronous else 'sync ':5s} "
                   f"compute inside {op.compute_inside:2d}  "
                   f"{(op.op_name or '')[-64:]}")
+    split = {}
+    if args.grad_wire:
+        from chainermn_tpu.observability import process_record
+
+        split = [e["args"] for e in process_record()["spans"]
+                 if e["name"] == "setup.build_step"][-1]
     print(json.dumps({
-        "layers": args.layers, "chips": args.chips,
+        "layers": args.layers, "chips": args.chips, **split,
+        "compiled_with": "the builder's options" if not args.packed
+        else sorted(args.options or ()),
         "compile_s": round(time.perf_counter() - t0, 1),
         "argument_gb": memory.argument_size_in_bytes / 1e9,
         "temp_gb": memory.temp_size_in_bytes / 1e9,

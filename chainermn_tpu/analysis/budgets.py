@@ -15,6 +15,18 @@ replaces the bucket all-reduces with one reduce-scatter down and one
 all-gather up per bucket.  The MoE expert path adds exactly 2 all_to_all
 per MoE layer (dispatch + return) and the pipeline path 1 ppermute per
 stage edge per direction.
+
+What the contract covers (PR 51): these ceilings are of steps traced on
+a CPU mesh, where the wire packs every leaf, and they hold there as they
+did.  A count is not a time: on four v5e chips four bucket all-reduces
+of 321-784 MB made a 333 ms step where 74 all-reduces of a leaf each
+made a 250 ms one (ledger, PR 50), and on such a mesh the wire now ships
+a leaf of ``bucket_bytes`` or more in its own shape, one asynchronous
+all-reduce each, beside <= 6 buckets of the smaller ones
+(``optimizers._split_wire``; my chip run, PR 51: 262.5 ms).  A TPU-mesh
+step is therefore not held to these numbers; its form is read from the
+compiled program (``step.collective_schedule``,
+``benchmarks/collective_schedule_aot.py --grad-wire``).
 """
 
 from __future__ import annotations

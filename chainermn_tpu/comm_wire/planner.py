@@ -28,6 +28,24 @@ bucket size grows until the plan fits the slot budget — so a compiled
 train step's collective count stays bounded by a constant (buckets +
 the loss pmean) regardless of model size, which is also what the HLO
 op-count tests pin.
+
+Where that holds, and what the chip showed of it (PR 51): the constant
+bounds the leaves the wire PACKS, which on a CPU mesh and on one chip is
+every leaf (every budget of ``analysis.budgets`` is traced there).  The
+target amortizes a launch, so it only argues for packing leaves UNDER
+it; the ceiling then coalesced upward past it, and on four v5e chips the
+2.36 GB of Cerebras-GPT-590M's gradients in four buckets of 321-784 MB
+cost a third of the step (ledger, PR 50: 333 ms a step, 24 614
+tokens/s/chip, where autodiff's all-reduce a leaf gives 250 ms and
+32 792), none of it the launches the target was set against: copies
+into and out of the flat buffers, all-reduces that wait for a bucket's
+last leaf, an update that no longer rides the matmul that made the
+gradient.
+On such a mesh ``optimizers._split_wire`` therefore leaves a leaf
+already at ``bucket_bytes`` out of the plan (74 of that model's 184
+leaves, all but 1.0 MB of its bytes) and plans the rest here as before
+(my chip run, PR 51: 262.5 ms, 31 256 tokens/s/chip; the same leaves in
+place without the async options 285.8 ms, the buckets with them 326.6).
 """
 
 from __future__ import annotations

@@ -587,6 +587,15 @@ class ProcessRecord:
         with self._lock:
             self._attributes.setdefault(name, {}).update(args)
 
+    def open_phase_attributes(self) -> dict:
+        """The attributes of the innermost phase open on this thread
+        (the root's outside every phase), as the dict its span keeps:
+        what the caller writes there shows in the record, also after
+        the phase has closed (a step builder learns its gradients'
+        shapes at the step's first call)."""
+        local = self._thread()
+        return local.phases[-1].args if local.phases else self.root["args"]
+
     # -- the program's first lines -------------------------------------
     def program_starts(self, t: float) -> _Phase:
         """``t``: the first line of the package's ``__init__``.  Records
@@ -767,6 +776,12 @@ def phase_attributes(name: str, **args) -> None:
     """:meth:`ProcessRecord.phase_attributes` of this process's record:
     ``args`` become attributes of the next phase called ``name``."""
     PROCESS.phase_attributes(name, **args)
+
+
+def open_phase_attributes() -> dict:
+    """:meth:`ProcessRecord.open_phase_attributes` of this process's
+    record: the open phase's attributes, to write into now or later."""
+    return PROCESS.open_phase_attributes()
 
 
 def phased(name: str):

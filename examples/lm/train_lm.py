@@ -130,9 +130,10 @@ def main(argv=None):
     p.add_argument("--grad-wire", action="store_true",
                    help="data-parallel only (--sp 1 --tp 1): build the "
                         "step without param_specs, so that "
-                        "create_multi_node_optimizer's bucketed wire "
-                        "ships the gradients instead of autodiff's "
-                        "all-reduce a leaf")
+                        "create_multi_node_optimizer's own wire ships "
+                        "the gradients (small leaves in buckets, and on "
+                        "a multi-chip TPU mesh large ones in place) "
+                        "instead of autodiff's all-reduce a leaf")
     p.add_argument("--flash", action="store_true",
                    help="use the Pallas flash-attention kernel (TPU)")
     p.add_argument("--vocab-parallel", action="store_true",
@@ -337,7 +338,8 @@ def main(argv=None):
         return main
 
     # --grad-wire: the parameters replicated and the optimizer's own
-    # exchange of the gradients (its bucketed flat wire); otherwise the
+    # exchange of the gradients (its wire: buckets of the small leaves,
+    # the large ones in place on a multi-chip TPU mesh); otherwise the
     # hybrid body, whose reductions are autodiff's, one a leaf
     step = cmn.build_train_step(
         comm, loss_fn, opt, data_axes=comm.data_axis_names,

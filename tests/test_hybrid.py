@@ -274,15 +274,25 @@ class TestGradReduceCompilerOptions:
         assert np.isfinite(float(metrics["loss"]))
         assert seen and all(options is None for options in seen)
 
-    @pytest.mark.parametrize("platform,dp,tp,expect_options", [
-        ("cpu", 4, 1, False),  # a CPU mesh never gets options
-        ("tpu", 1, 1, False),  # one chip: the parent's program
-        ("tpu", 4, 1, True),   # gradients summed across every TPU chip
-        ("tpu", 2, 2, False),  # dp x tp: the model axis' all-reduces sit
-        ("tpu", 1, 4, False),  #   on the critical path; never read or timed
+    #: the ``param_specs`` body sums over the data axes; the plain body
+    #: (the optimizer's own exchange) over all of the communicator's
+    DATA, ALL = ("mn_data", "mn_seq"), ("mn_data", "mn_seq", "mn_model")
+
+    @pytest.mark.parametrize("platform,dp,tp,axes,expect_options", [
+        ("cpu", 4, 1, DATA, False),  # a CPU mesh never gets options
+        ("tpu", 1, 1, DATA, False),  # one chip: the parent's program
+        ("tpu", 4, 1, DATA, True),   # gradients summed across every chip
+        ("tpu", 2, 2, DATA, False),  # dp x tp: the model axis' all-reduces
+        ("tpu", 1, 4, DATA, False),  #   sit on the critical path; never
+        #                                read or timed
+        # the plain body: every axis is a data axis to it
+        ("cpu", 4, 1, ALL, False),
+        ("tpu", 1, 1, ALL, False),   # ResNet's one-chip cell
+        ("tpu", 4, 1, ALL, True),    # cgpt590m_dpwire4_s2048
+        ("tpu", 2, 2, ALL, True),    # the same sum, the mesh shaped 2 x 2
     ])
     def test_rule_reads_platform_and_reduced_extent(
-        self, platform, dp, tp, expect_options
+        self, platform, dp, tp, axes, expect_options
     ):
         from types import SimpleNamespace
 
@@ -297,7 +307,7 @@ class TestGradReduceCompilerOptions:
                 dp, 1, tp),
             shape={"mn_data": dp, "mn_seq": 1, "mn_model": tp},
         )
-        got = _grad_reduce_compiler_options(mesh, ("mn_data", "mn_seq"))
+        got = _grad_reduce_compiler_options(mesh, axes)
         assert got == (_ASYNC_GRAD_REDUCE_OPTIONS if expect_options
                        else None)
         if expect_options:
